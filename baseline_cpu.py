@@ -600,9 +600,8 @@ def main() -> None:
     from sklearn.model_selection import StratifiedKFold
 
     path = "/root/reference/test-data/PassengerDataAllWithHeader.csv"
-    # median of 5 back-to-back in-process runs — the SAME protocol the TPU
-    # bench reports (bench.py bench_titanic), so vs_baseline stays
-    # like-for-like; all samples recorded
+    # median of 5 back-to-back in-process runs — the same protocol as
+    # bench.py's default mode (bench_flagship); all samples recorded
     samples = []
     for _rep in range(5):
         t0 = time.perf_counter()

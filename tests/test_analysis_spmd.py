@@ -1,7 +1,7 @@
 """SPMD contract auditor (analysis/spmd.py, TPS0xx) — seeded
 positive/negative corpus for every code, the jaxpr/HLO collective
 census, the per-host collective-tape reconciler (parallel/guarded.py),
-the compat-shim census parity, the CLI gate, and the <10s/<30s/<2%
+the CLI gate, and the <10s/<30s/<2%
 performance pins."""
 import json
 import os
@@ -139,7 +139,7 @@ def test_tps001_assignment_clears_on_agreed_value():
 KERNEL_TMPL = """
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from transmogrifai_tpu.parallel.compat import shard_map
+    from jax import shard_map
     import jax
 
     DATA_AXIS = "data"
@@ -169,7 +169,7 @@ def test_tps002_unresolvable_axis_skipped():
     rep = scan("""
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from transmogrifai_tpu.parallel.compat import shard_map
+        from jax import shard_map
         import jax
 
         @partial(shard_map, mesh=mesh, in_specs=(P("data", None),),
@@ -198,7 +198,7 @@ def test_tps003_axis_not_in_mesh_vocabulary_positive():
     rep = scan("""
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from transmogrifai_tpu.parallel.compat import shard_map
+        from jax import shard_map
         from transmogrifai_tpu.parallel.mesh import make_mesh
         import jax
 
@@ -244,7 +244,7 @@ def test_tps004_raw_moment_variance_positive():
     rep = scan("""
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from transmogrifai_tpu.parallel.compat import shard_map
+        from jax import shard_map
         import jax
 
         @partial(shard_map, mesh=mesh, in_specs=(P("data", None),),
@@ -261,7 +261,7 @@ def test_tps004_f64_in_kernel_positive():
     rep = scan("""
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from transmogrifai_tpu.parallel.compat import shard_map
+        from jax import shard_map
         import jax
         import jax.numpy as jnp
 
@@ -279,7 +279,7 @@ def test_tps004_centered_two_pass_negative():
     rep = scan("""
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from transmogrifai_tpu.parallel.compat import shard_map
+        from jax import shard_map
         import jax
 
         @partial(shard_map, mesh=mesh,
@@ -465,10 +465,11 @@ def test_hlo_kind_parsing_both_spellings():
 def test_jaxpr_collectives_helper():
     import jax
 
-    from transmogrifai_tpu.parallel.compat import abstract_mesh, shard_map
+    from jax import shard_map
+    from jax.sharding import AbstractMesh
     from jax.sharding import PartitionSpec as P
 
-    mesh = abstract_mesh(("data", 4), ("model", 1))
+    mesh = AbstractMesh((4, 1), ("data", "model"))
 
     @partial(shard_map, mesh=mesh, in_specs=(P("data", None),),
              out_specs=P(), check_vma=False)
@@ -480,62 +481,6 @@ def test_jaxpr_collectives_helper():
     ).jaxpr
     cen = SP.jaxpr_collectives(closed)
     assert cen == [{"primitive": "psum", "axes": "data", "count": 1}]
-
-
-# ==========================================================================
-# compat shim: BOTH branches must yield identical TPS census results
-# ==========================================================================
-def _census_via_compat(mesh):
-    import jax
-
-    from transmogrifai_tpu.parallel.compat import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    @partial(shard_map, mesh=mesh, in_specs=(P("data", None),),
-             out_specs=P(), check_vma=False)
-    def body(xs):
-        return jax.lax.psum(xs.sum(axis=0), "data")
-
-    closed = jax.jit(body).trace(
-        jax.ShapeDtypeStruct((16, 3), np.float32)
-    ).jaxpr
-    return SP.jaxpr_collectives(closed)
-
-
-def test_compat_shim_census_parity_both_branches(monkeypatch):
-    """A future jax bump must not silently blind the analyzer: the
-    new-API (jax.shard_map / check_vma) and legacy
-    (jax.experimental.shard_map / check_rep) shim branches must produce
-    the IDENTICAL collective census for the same kernel."""
-    import jax
-
-    from jax.experimental.shard_map import shard_map as legacy_impl
-    from transmogrifai_tpu.parallel.compat import abstract_mesh
-
-    # distinct mesh shapes per branch: the factories are lru_cached by
-    # mesh, so sharing one mesh could hand branch B branch A's kernel
-    mesh_new = abstract_mesh(("data", 4), ("model", 1))
-    mesh_legacy = abstract_mesh(("data", 8), ("model", 1))
-
-    # --- branch 1: the new top-level API (monkeypatched onto jax when
-    # this generation predates it), check_vma spelling
-    def new_api(f=None, *, check_vma=None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        if f is None:
-            return partial(legacy_impl, **kw)
-        return legacy_impl(f, **kw)
-
-    monkeypatch.setattr(jax, "shard_map", new_api, raising=False)
-    census_new = _census_via_compat(mesh_new)
-
-    # --- branch 2: the legacy experimental API, check_rep spelling
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    census_legacy = _census_via_compat(mesh_legacy)
-
-    assert census_new == census_legacy == [
-        {"primitive": "psum", "axes": "data", "count": 1}
-    ]
 
 
 # ==========================================================================

@@ -430,6 +430,43 @@ class TestFallback:
         fn.batch(flagship["rows"][:16])  # no 4th attempt
         assert cstats.delta(before)["fusedFallbacks"] == 0
 
+    def test_compiler_refusal_is_reported_once_and_disables(
+        self, flagship, fused_cutoff, monkeypatch, caplog,
+    ):
+        """A program the compiler refuses fails identically on every
+        batch: the first failed dispatch probes the lowering, logs ONE
+        error naming the program, and disables it — no three-strike
+        wait, no per-batch failed retrace."""
+        import logging
+
+        # no bank: an executable an earlier test banked under this
+        # fingerprint would run instead of tracing the refusing core
+        monkeypatch.setenv("TPTPU_AOT", "0")
+        fn = score_function(flagship["model"])
+        assert fn.prime_fused()
+        prog = fn.fused_state["program"]
+
+        def refuse(plane, p):
+            raise NotImplementedError("unsupported shape cast")
+
+        monkeypatch.setattr(prog._spec, "core", refuse)
+        with caplog.at_level(logging.WARNING):
+            out = fn.batch(flagship["rows"][:16])
+        assert len(out) == 16 and "prediction" in out[0][next(iter(out[0]))]
+        md = fn.metadata()["fused"]
+        assert md["active"] is False
+        assert md["fallbacks"] == 1
+        assert md["reason"].startswith("does not compile on")
+        errors = [
+            r for r in caplog.records
+            if r.levelno == logging.ERROR and "does not compile" in r.message
+        ]
+        assert len(errors) == 1
+        assert prog.fingerprint in errors[0].message
+        before = cstats.snapshot()
+        fn.batch(flagship["rows"][:16])  # stays staged, nothing retried
+        assert cstats.delta(before)["fusedFallbacks"] == 0
+
     def test_fallback_twin_parity(self, flagship, fused_cutoff,
                                   monkeypatch):
         """The staged continuation after a fused failure produces the

@@ -101,26 +101,10 @@ def flagship(tmp_path_factory):
 
 
 def _load_bench():
-    """Load bench.py WITHOUT keeping its process-global side effect:
-    module import calls _enable_compile_cache(), which points the jax
-    compilation cache at the repo's .jax_cache with a zero compile-time
-    floor — under that config, later in-process aot.export blobs can
-    deserialize unusable ('Symbols not found'), breaking unrelated
-    persistent-bank tests that run after this suite."""
-    import jax
-
-    prev_dir = jax.config.jax_compilation_cache_dir
-    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
     path = os.path.join(os.path.dirname(__file__), "..", "bench.py")
     spec = importlib.util.spec_from_file_location("bench_mod_runlog", path)
     mod = importlib.util.module_from_spec(spec)
-    try:
-        spec.loader.exec_module(mod)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", prev_min
-        )
+    spec.loader.exec_module(mod)
     return mod
 
 

@@ -50,7 +50,7 @@ def _gather_ref(binned, trees):
     per_tree = jax.vmap(
         lambda sf, sb, lv: TR.predict_tree(binned, TR.Tree(sf, sb, lv))
     )(trees.split_feat, trees.split_bin, trees.leaf_value)
-    return np.asarray(per_tree).T  # [N, T]
+    return np.asarray(per_tree)  # [T, N]
 
 
 class TestKernelParity:
@@ -70,6 +70,40 @@ class TestKernelParity:
         )
         np.testing.assert_array_equal(got, _gather_ref(binned, trees))
 
+    def test_deep_trees_take_the_chunked_levels(self):
+        # depth 9 at an 8-tree tile: levels 7 and 8 span 1024 and 2048
+        # lanes, so the per-level chunk loop runs more than one step and
+        # the leaf level is picked without being materialized
+        rng = np.random.default_rng(11)
+        trees = _random_stack(rng, t=11, depth=9, f=9, bins=32)
+        binned = jnp.asarray(
+            rng.integers(0, 32, size=(70, 9)).astype(np.int32)
+        )
+        got = np.asarray(
+            SP.serve_trees_pallas(
+                binned, trees.split_feat, trees.split_bin,
+                trees.leaf_value, tree_tile=8, interpret=True, num_bins=32,
+            )
+        )
+        np.testing.assert_array_equal(got, _gather_ref(binned, trees))
+
+    @pytest.mark.parametrize("num_bins", [None, 1000])
+    def test_codes_past_one_byte_stay_exact(self, num_bins):
+        # bin codes above 256 are not bf16-exact; the kernel splits them
+        # into bytes unless the caller's bin count says one operand holds
+        rng = np.random.default_rng(13)
+        trees = _random_stack(rng, t=4, depth=5, f=6, bins=1000)
+        binned = jnp.asarray(
+            rng.integers(0, 1000, size=(50, 6)).astype(np.int32)
+        )
+        got = np.asarray(
+            SP.serve_trees_pallas(
+                binned, trees.split_feat, trees.split_bin,
+                trees.leaf_value, interpret=True, num_bins=num_bins,
+            )
+        )
+        np.testing.assert_array_equal(got, _gather_ref(binned, trees))
+
     def test_ragged_shapes_pad_and_slice(self):
         # N and T far from tile multiples: padded rows/trees must be
         # invisible in the sliced result
@@ -81,10 +115,10 @@ class TestKernelParity:
         got = np.asarray(
             SP.serve_trees_pallas(
                 binned, trees.split_feat, trees.split_bin,
-                trees.leaf_value, row_tile=64, tree_tile=8, interpret=True,
+                trees.leaf_value, row_tile=128, tree_tile=8, interpret=True,
             )
         )
-        assert got.shape == (17, 3)
+        assert got.shape == (3, 17)
         np.testing.assert_array_equal(got, _gather_ref(binned, trees))
 
     def test_leaf_only_trees(self):
@@ -126,7 +160,7 @@ class TestKernelParity:
                 interpret=True,
             )
         )
-        ref = 0.5 + 0.3 * _gather_ref(binned, trees).sum(axis=1)
+        ref = 0.5 + 0.3 * _gather_ref(binned, trees).sum(axis=0)
         np.testing.assert_allclose(boosted, ref, rtol=1e-6, atol=1e-6)
 
 

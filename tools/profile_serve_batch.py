@@ -13,14 +13,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import jax
-
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"),
-)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-
 from transmogrifai_tpu.features import from_dataset
 from transmogrifai_tpu.local.scoring import score_function
 from transmogrifai_tpu.ops import transmogrify

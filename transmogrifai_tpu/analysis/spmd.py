@@ -1037,8 +1037,7 @@ def reconcile_hlo_census(
 def jaxpr_collectives(closed) -> list[dict[str, Any]]:
     """The collective census of one (closed) jaxpr: count + primitive +
     axes of every collective primitive, recursed through scan/cond/pjit
-    bodies. The unit both census legs and the compat-shim parity tests
-    share."""
+    bodies. The unit both census legs share."""
     from . import program as PJ
 
     counts: dict[tuple[str, str], int] = {}

@@ -3,10 +3,10 @@
 The staged serving loop crosses the host↔device boundary per stage family:
 vectorizers featurize on HOST into the fusion plane, the plane uploads,
 the predictor dispatches, predictions download. For steady-state batches
-the boundary IS the margin (serve_batch_vs_sklearn ~1.07-1.3, BENCH_r05),
-so this module compiles the fitted serving plan — numeric coercion, pivot
-scatter, dense-plane assembly, feature removal, and model predict — into
-ONE donated, bucketed XLA dispatch:
+the boundary is a large share of the batch, so this module compiles the
+fitted serving plan — numeric coercion, pivot scatter, dense-plane
+assembly, feature removal, and model predict — into ONE donated, bucketed
+XLA dispatch:
 
 * **ingest** stays host-side and shrinks to codecs: numeric value/mask
   arrays and the CSR text-interning kernels' code arrays
@@ -677,6 +677,20 @@ class FusedServingProgram:
             "dispatchSeconds": (t2 - t1) + dl,
             "lanes": lanes,
         }
+
+    def build_error(self, b: int) -> Exception | None:
+        """Lower and compile the base program for a ``b``-row bucket over
+        placeholder ingest; the exception if the compiler refuses it,
+        else None. A refusal is deterministic — the caller reports it
+        once instead of retrying a dispatch that can never succeed."""
+        ingest = tuple(m.dummy(b) for m in self.members)
+        try:
+            _plain_jit("fused_serve", _fused_eval).lower(
+                ingest, self._params_host, spec=self._spec
+            ).compile()
+        except Exception as e:  # whatever tracing/lowering/XLA raised
+            return e
+        return None
 
     def _dispatch_base(self, ingest, params):
         """ONE donated dispatch; ``ingest`` is consumed — the TPX003 AST

@@ -5,8 +5,7 @@ which model families the traced DAG will exercise, and therefore which
 banked executables the run will need. Warmup starts a daemon thread that
 loads exactly those (``utils.aot.prewarm(names=...)``) while the main
 thread runs host-side ingest/feature prep, so program acquisition overlaps
-work instead of serializing in front of the first fit dispatch (the cold
-5.0-6.7 s vs steady 2.8 s gap of BENCH_r05).
+work instead of serializing in front of the first fit dispatch.
 
 One warmup runs per (scope, names) per process; repeats are free no-ops.
 The loaded-program count and overlapped seconds land in the

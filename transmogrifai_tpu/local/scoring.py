@@ -189,12 +189,14 @@ def score_function(
     epilogue, categorical code columns shrink to their narrowest dtype.
     ``None`` (the default) defers to the ``TPTPU_FUSED_QUANT`` env knob;
     staged scoring and parity seams are unaffected either way."""
+    from ..compiler import cache as _ccache
     from ..compiler import warmup as _warmup
     from ..models.base import PredictorModel
     from ..workflow.dag import compute_dag
 
     from ..stages.base import Estimator
 
+    _ccache.enable_persistent_cache()
     # overlap loading the banked scoring executables with closure build
     # (compiler.warmup — one background load per process)
     _warmup.start_warmup(_warmup.SCORE_PROGRAMS, scope="score")
@@ -384,37 +386,56 @@ def score_function(
             fused_counters["dispatches"] += 1
             fused_counters["consecutiveErrors"] = 0
 
-    def _count_fused_fallback(reason: str, exc: Exception | None = None):
+    def _count_fused_fallback(
+        reason: str, exc: Exception | None = None, b: int = 0
+    ):
         from ..compiler import stats as cstats
 
-        disabled = False
         with _fused_lock:
             fused_counters["fallbacks"] += 1
             fused_counters["lastFallback"] = reason
             fused_counters["fallbackReasons"][reason] = (
                 fused_counters["fallbackReasons"].get(reason, 0) + 1
             )
-            if reason == "dispatch_error":
+            prog = fused_holder["program"]
+            errors = 0
+            if reason == "dispatch_error" and prog is not None:
                 fused_counters["consecutiveErrors"] += 1
-                if (
-                    fused_counters["consecutiveErrors"]
-                    >= _FUSED_MAX_CONSECUTIVE_ERRORS
-                    and fused_holder["program"] is not None
-                ):
-                    # a program failing every batch is broken, not
-                    # unlucky: stop retrying (each retry re-pays a failed
-                    # trace), keep the staged loop, and say so in the
-                    # audit (TPX008 reason)
-                    fused_holder["program"] = None
-                    fused_holder["reason"] = (
-                        f"disabled after "
-                        f"{fused_counters['consecutiveErrors']} "
-                        f"consecutive dispatch errors (last: "
-                        f"{type(exc).__name__ if exc else reason})"
-                    )
-                    disabled = True
+                errors = fused_counters["consecutiveErrors"]
         cstats.stats().record_fused_fallback(reason)
         _tevents.emit("fused_fallback", reason=reason)
+        # a program the compiler refuses fails the same way on every
+        # batch: on the first error find out (one extra lowering, on the
+        # failure path only) and say so once, at error, instead of three
+        # warnings and three failed traces
+        refused = prog.build_error(b) if errors == 1 else None
+        disabled = None
+        if refused is not None:
+            import jax
+
+            disabled = (
+                f"does not compile on {jax.default_backend()} "
+                f"({type(refused).__name__})"
+            )
+        elif errors >= _FUSED_MAX_CONSECUTIVE_ERRORS:
+            # a program failing every batch is broken, not unlucky: stop
+            # retrying (each retry re-pays a failed trace), keep the
+            # staged loop, and say so in the audit (TPX008 reason)
+            disabled = (
+                f"disabled after {errors} consecutive dispatch errors "
+                f"(last: {type(exc).__name__ if exc else reason})"
+            )
+        if disabled is not None:
+            with _fused_lock:
+                fused_holder["program"] = None
+                fused_holder["reason"] = disabled
+        if refused is not None:
+            log.error(
+                "fused program %s (%s) %s and is disabled for this "
+                "closure; batches take the staged loop: %s",
+                prog.fingerprint, prog.pspec.descriptor, disabled, refused,
+            )
+            return
         log.warning(
             "fused dispatch degraded to the staged loop (%s%s)%s",
             reason,
@@ -806,7 +827,7 @@ def score_function(
                     except (ScoreGuardError, SchemaViolationError):
                         raise  # explicit escalations stay escalations
                     except Exception as e:
-                        _count_fused_fallback("dispatch_error", e)
+                        _count_fused_fallback("dispatch_error", e, b)
                 else:
                     _count_fused_fallback("prefix_degraded")
                 if done:

@@ -248,7 +248,7 @@ def make_global_array(local_rows: np.ndarray, mesh, num_rows: int):
 def _global_stats_kernels(mesh):
     import jax
     import jax.numpy as jnp
-    from .compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axes = (DCN_AXIS, DATA_AXIS)
@@ -306,12 +306,9 @@ def program_trace_specs():
     hosts of four chips — so the TPJ IR lints and the TPS collective
     census inspect the exact cross-host programs without a pod."""
     import jax
+    from jax.sharding import AbstractMesh
 
-    from .compat import abstract_mesh
-
-    mesh = abstract_mesh((DCN_AXIS, 2), (DATA_AXIS, 4), (MODEL_AXIS, 1))
-    if mesh is None:  # ancient jax: fall back to the real-device mesh
-        mesh = make_multihost_mesh()
+    mesh = AbstractMesh((2, 4, 1), (DCN_AXIS, DATA_AXIS, MODEL_AXIS))
     total = 1
     for name in mesh.axis_names:
         total *= int(mesh.shape[name])

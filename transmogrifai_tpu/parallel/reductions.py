@@ -41,7 +41,7 @@ _guarded = guarded_collective
 def _stats_kernels(mesh):
     import jax
     import jax.numpy as jnp
-    from .compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     @partial(
@@ -146,7 +146,7 @@ def _pcentered_gram(x: np.ndarray, mesh) -> tuple[np.ndarray, np.ndarray, float]
 @lru_cache(maxsize=None)
 def _gram_kernels(mesh):
     import jax
-    from .compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     @partial(
@@ -195,7 +195,7 @@ def _pxtx(x: np.ndarray, mesh) -> np.ndarray:
 @lru_cache(maxsize=None)
 def _xtx_kernel(mesh):
     import jax
-    from .compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     @partial(
@@ -242,7 +242,7 @@ def _phistogram(
 def _hist_kernel(mesh, num_bins: int):
     import jax
     import jax.numpy as jnp
-    from .compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     @partial(
@@ -308,7 +308,7 @@ def _pcontingency(
 @lru_cache(maxsize=None)
 def _contingency_kernel(mesh):
     import jax
-    from .compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     @partial(
@@ -328,20 +328,12 @@ def _contingency_kernel(mesh):
 # trace-spec registration (analysis/program.py TPJ + analysis/spmd.py TPS)
 # --------------------------------------------------------------------------
 def _spec_trace_mesh():
-    """The auditors' 8-way data mesh: device-free AbstractMesh when this
-    jax has one (traces anywhere), else a real mesh over the visible
-    devices. The lru_cached kernel factories accept either — both are
-    hashable and shard_map traces over both."""
-    from .compat import abstract_mesh
+    """The auditors' 8-way data mesh: a device-free AbstractMesh, so the
+    kernels trace on any host. The lru_cached kernel factories accept it
+    like a real mesh — both are hashable and shard_map traces over both."""
+    from jax.sharding import AbstractMesh
 
-    mesh = abstract_mesh((DATA_AXIS, 8), ("model", 1))
-    if mesh is not None:
-        return mesh
-    import jax
-
-    from .mesh import make_mesh
-
-    return make_mesh(n_data=len(jax.devices()), n_model=1)
+    return AbstractMesh((8, 1), (DATA_AXIS, "model"))
 
 
 def program_trace_specs():

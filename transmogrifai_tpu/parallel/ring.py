@@ -54,7 +54,7 @@ def _ring_gram_kernel(mesh):
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from .compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     d = mesh.shape[DATA_AXIS]
@@ -96,13 +96,9 @@ def program_trace_specs():
     psum-family reductions. AbstractMesh traces device-free; the ring
     step count is mesh-static so the kernel traces at any column width."""
     import jax
+    from jax.sharding import AbstractMesh
 
-    from .compat import abstract_mesh
-    from .mesh import make_mesh
-
-    mesh = abstract_mesh((DATA_AXIS, 8), ("model", 1))
-    if mesh is None:
-        mesh = make_mesh(n_data=len(jax.devices()), n_model=1)
+    mesh = AbstractMesh((8, 1), (DATA_AXIS, "model"))
     d = int(mesh.shape[DATA_AXIS])
     return [
         dict(

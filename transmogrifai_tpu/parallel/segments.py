@@ -35,7 +35,7 @@ _NEUTRAL = {
 def _segment_kernels(mesh, num_segments: int, op: str):
     import jax
     import jax.numpy as jnp
-    from .compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     neutral = _NEUTRAL[op]
@@ -72,13 +72,9 @@ def program_trace_specs():
     """Register the segment-reduce kernels (sum + max — the psum and the
     pmax lowering families) with the program auditor."""
     import jax
+    from jax.sharding import AbstractMesh
 
-    from .compat import abstract_mesh
-    from .mesh import make_mesh
-
-    mesh = abstract_mesh((DATA_AXIS, 8), ("model", 1))
-    if mesh is None:
-        mesh = make_mesh(n_data=len(jax.devices()), n_model=1)
+    mesh = AbstractMesh((8, 1), (DATA_AXIS, "model"))
     total = 1
     for name in mesh.axis_names:
         total *= int(mesh.shape[name])

@@ -1,6 +1,6 @@
 """Retry with exponential backoff — transient-vs-fatal classification.
 
-Preemptible TPU slices and tunneled compile helpers fail in two distinct
+Preemptible TPU slices and remote services fail in two distinct
 ways: *transient* (a dropped connection, a preempted device, an interrupted
 syscall — retrying is cheap and usually succeeds) and *fatal* (a shape
 error, a malformed grid — retrying re-raises the same exception forever).

@@ -139,7 +139,7 @@ class Validator:
 
     #: candidate-fit parallelism (OpValidator.scala:371-379 default 8).
     #: Families sweep in a thread pool: device executions serialize on the
-    #: chip anyway (they are milliseconds — see BASELINE.md round 2), but
+    #: chip anyway, but
     #: each family's program acquisition (tracing + XLA compile-cache
     #: round-trips, the actual wall-clock cost) overlaps across threads.
     parallelism: int = 8
@@ -260,7 +260,7 @@ class Validator:
         # is the wall-clock cost; device execs serialize on-chip anyway).
         # The ONE broken combination is threads × multi-device XLA:CPU:
         # concurrent multi-device dispatch intermittently aborts its async
-        # runtime (memory: xla-cpu-mesh-gotchas). Gate on that backend —
+        # runtime. Gate on that backend —
         # a real multi-chip TPU mesh keeps the overlap (round-2 VERDICT
         # item 6: the old device-count gate would serialize acquisition
         # exactly where it costs the most).

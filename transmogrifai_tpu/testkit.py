@@ -502,3 +502,77 @@ def random_dataset(
         g = gen if seed is None else gen.with_seed(seed + 1000 * i)
         cols[name] = g.to_column(n)
     return Dataset.of(cols)
+
+
+def flagship_dataset(n_rows: int, seed: int = 42) -> Dataset:
+    """Seeded stand-in for the flagship binary-classification table.
+
+    Twenty-three raw predictors of the types the fused scoring graph
+    covers — ten ``Real`` and five ``Integral`` with missing values, three
+    ``Binary``, four ``PickList`` and one free ``Text`` field whose
+    cardinality sends it to the hashing vectorizer — plus a ``label``
+    (``RealNN`` 0/1) that depends on several of them through a threshold
+    interaction as well as linear terms, so the SanityChecker keeps
+    features and a tree family can win the sweep. Built column-wise in
+    numpy: a 10^5-row table is a fraction of a second."""
+    from .types.columns import NumericColumn, TextColumn
+
+    rng = np.random.default_rng(seed)
+    n = int(n_rows)
+    cols: dict[str, Column] = {}
+
+    reals = rng.normal(size=(n, 10))
+    reals[:, 4] = np.exp(reals[:, 4])              # one skewed column
+    for j in range(10):
+        present = rng.random(n) > 0.08
+        cols[f"real_{j}"] = NumericColumn(
+            T.Real, np.where(present, reals[:, j], 0.0), present
+        )
+    ints = rng.poisson(lam=[2.0, 5.0, 9.0, 20.0, 40.0], size=(n, 5))
+    for j in range(5):
+        present = rng.random(n) > 0.05
+        cols[f"int_{j}"] = NumericColumn(
+            T.Integral,
+            np.where(present, ints[:, j], 0).astype(np.int64), present,
+        )
+    bins = rng.random((n, 3)) < np.array([0.5, 0.2, 0.7])
+    for j in range(3):
+        present = rng.random(n) > 0.05
+        cols[f"bin_{j}"] = NumericColumn(
+            T.Binary, bins[:, j] & present, present
+        )
+    picks = []
+    for j, levels in enumerate((3, 5, 8, 12)):
+        code = rng.integers(0, levels, size=n)
+        picks.append(code)
+        values = np.array(
+            [f"p{j}_{c}" for c in range(levels)], dtype=object
+        )[code]
+        values[rng.random(n) < 0.05] = None
+        cols[f"pick_{j}"] = TextColumn(T.PickList, values)
+    vocab = np.array([f"w{i:03d}" for i in range(400)], dtype=object)
+    tokens = vocab[rng.integers(0, len(vocab), size=(n, 8))]
+    lengths = rng.integers(3, 9, size=n)
+    urgent = rng.random(n) < 0.3                   # the token that matters
+    text = np.empty(n, dtype=object)
+    for i in range(n):
+        words = list(tokens[i, : lengths[i]])
+        if urgent[i]:
+            words[0] = "urgent"
+        text[i] = " ".join(words)
+    text[rng.random(n) < 0.05] = None
+    cols["text_0"] = TextColumn(T.Text, text)
+
+    logit = (
+        1.1 * reals[:, 0] - 0.8 * reals[:, 1]
+        + 1.5 * (reals[:, 2] > 0.3) * (reals[:, 3] < 0.0)
+        + 0.05 * (ints[:, 2] - 9.0)
+        + 0.6 * bins[:, 0]
+        + 0.9 * (picks[1] == 2) - 0.7 * (picks[3] >= 9)
+        + 0.8 * urgent
+        - 0.9
+    )
+    label = (logit + rng.logistic(size=n) > 0.0).astype(np.float64)
+    out = {"label": NumericColumn(T.RealNN, label, np.ones(n, dtype=bool))}
+    out.update(cols)
+    return Dataset.of(out)

@@ -298,9 +298,11 @@ class Workflow:
         # model families in THIS DAG will need on a background thread, so
         # program acquisition overlaps the reader/feature phases below
         # instead of serializing in front of the first fit dispatch
+        from ..compiler import cache as _ccache
         from ..compiler import warmup as _warmup
         from ..featurize import stats as _fstats
 
+        _ccache.enable_persistent_cache()
         _warmup.start_warmup(_warmup.train_programs(stages), scope="train")
         # featurize-plane ledger for THIS train (rows/s per stage, pool
         # utilization, interning + fallback-kernel counts) — the delta
@@ -880,8 +882,10 @@ class WorkflowModel:
         keep_intermediate_features: bool = False,
     ) -> Dataset:
         """Apply the fitted DAG (OpWorkflowModel.score, OpWorkflowModel.scala:259)."""
+        from ..compiler import cache as _ccache
         from ..compiler import warmup as _warmup
 
+        _ccache.enable_persistent_cache()
         # overlap loading the banked scoring executables with raw-data prep
         _warmup.start_warmup(_warmup.SCORE_PROGRAMS, scope="score")
         raw = self._prepare_raw(dataset, reader)
