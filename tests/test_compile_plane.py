@@ -427,6 +427,57 @@ class TestFreshProcessCache:
         assert second["compileCacheHitRate"] == pytest.approx(1.0)
 
 
+_CHILD_SCOPED = r"""
+import os, sys
+import jax, jax.numpy as jnp
+from transmogrifai_tpu.compiler import cache as ccache
+
+placed = ccache.enable_persistent_cache()
+assert placed == os.environ["JAX_COMPILATION_CACHE_DIR"], placed
+assert jax.config.jax_compilation_cache_include_metadata_in_key
+
+def make(scope):
+    def step(x):
+        if scope:
+            with jax.named_scope(scope):
+                return jnp.sin(x) * 2.0
+        return jnp.sin(x) * 2.0
+    return step
+
+x = jnp.arange(8.0)
+for scope in (None, "tree/histogram", None):
+    jax.jit(make(scope))(x).block_until_ready()
+print(len([f for f in os.listdir(placed) if f.endswith("-cache")]))
+"""
+
+
+class TestJaxCacheKey:
+    def test_a_metadata_only_edit_gets_its_own_cache_entry(self, tmp_path):
+        """JAX's cache must not hand a program the executable compiled
+        before a scope name existed: the resolver puts op metadata into
+        the key, so the scoped twin of a function is a second entry (with
+        JAX's default key the two collapse into one)."""
+        env = dict(os.environ)
+        env.update(
+            JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+            JAX_ENABLE_COMPILATION_CACHE="true",
+            JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+            JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0",
+        )
+        p = subprocess.run(
+            [sys.executable, "-c", _CHILD_SCOPED],
+            capture_output=True, text=True, timeout=240, env=env,
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        )
+        assert p.returncode == 0, p.stderr[-2000:]
+        # the unscoped program, its scoped twin, and nothing new for the
+        # repeat of the first (JAX's default key would keep one for all)
+        entries = int(p.stdout.strip().splitlines()[-1])
+        names = [f for f in os.listdir(tmp_path) if f.endswith("-cache")]
+        assert sum(n.startswith("jit_step-") for n in names) == 2
+        assert entries == len(names)
+
+
 # --------------------------------------------------------- summary surface
 class TestCompileStatsSurface:
     @pytest.fixture(scope="class")

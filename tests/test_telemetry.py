@@ -622,3 +622,381 @@ def test_cli_metrics_and_trace_commands(tmp_path, capsys):
     assert run_trace(trace_path, events_path) == 0
     doc = json.load(open(trace_path))
     assert "traceEvents" in doc
+
+
+# ------------------------------------------- ids, parents and traces (PR 25)
+def test_span_ids_parent_and_trace_on_one_thread():
+    tspans.reset_for_tests()
+    with tspans.span("selector/sweep") as root:
+        assert tspans.current() is root
+        with tspans.span("selector/validate") as mid:
+            with tspans.span("tree/fit_dispatch") as leaf:
+                pass
+        tspans.record_span("serve/stage/x", 0.0, 0.1)
+    assert tspans.current() is None
+    with tspans.span("selector/sweep") as other:
+        pass
+    recs = {r["id"]: r for r in tspans.snapshot_events()}
+    assert len(recs) == 5, "ids are unique"
+    assert root.parent is None and root.trace == root.id
+    assert (mid.parent, mid.trace) == (root.id, root.id)
+    assert (leaf.parent, leaf.trace) == (mid.id, root.id)
+    assert recs[leaf.id]["parent"] == mid.id and recs[leaf.id]["trace"] == root.id
+    post_hoc = next(r for r in recs.values() if r["name"] == "serve/stage/x")
+    assert post_hoc["parent"] == root.id and post_hoc["trace"] == root.id
+    assert other.trace == other.id != root.id, "one trace per root"
+    assert root.id < mid.id < leaf.id < other.id, "one process-wide counter"
+
+
+def test_span_parent_is_handed_across_a_thread_pool():
+    from concurrent.futures import ThreadPoolExecutor
+
+    tspans.reset_for_tests()
+
+    def work(handle, i):
+        assert tspans.current() is None, "a pool thread starts with no stack"
+        with tspans.span("selector/family", parent=handle, family=i) as fam:
+            with tspans.span("tree/bin_prepare") as inner:
+                pass
+        return fam, inner
+
+    with tspans.span("selector/validate") as caller:
+        handle = tspans.current()
+        with ThreadPoolExecutor(max_workers=3) as pool:
+            got = list(pool.map(lambda i: work(handle, i), range(3)))
+    for fam, inner in got:
+        assert (fam.parent, fam.trace) == (caller.id, caller.id)
+        assert (inner.parent, inner.trace) == (fam.id, caller.id)
+    recs = tspans.snapshot_events()
+    assert {r["trace"] for r in recs} == {caller.id}
+    by_id = {r["id"]: r for r in recs}
+    assert by_id[got[0][0].id]["tid"] != by_id[caller.id]["tid"]
+    # without the hand-over the link is lost: the worker's span is a root
+    with tspans.span("selector/validate") as caller2:
+        with ThreadPoolExecutor(max_workers=1) as pool:
+            orphan = pool.submit(lambda: work(None, 9)).result()[0]
+    assert orphan.parent is None and orphan.trace == orphan.id != caller2.id
+
+
+def test_chrome_trace_export_carries_ids():
+    tspans.reset_for_tests()
+    with tspans.span("selector/sweep", rows=7) as root:
+        with tspans.span("selector/row_select"):
+            pass
+    events = texport.export_chrome_trace()["traceEvents"]
+    child, parent = events[0]["args"], events[1]["args"]
+    assert parent == {"rows": 7, "id": root.id, "parent": None, "trace": root.id}
+    assert child["parent"] == root.id and child["trace"] == root.id
+
+
+class _CountingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``."""
+
+    made: list = []
+
+    def __init__(self, name, **kwargs):
+        type(self).made.append((name, kwargs))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_span_enters_a_profiler_annotation_only_when_enabled(monkeypatch):
+    monkeypatch.setattr(tspans, "_ANNOTATION", _CountingAnnotation)
+    monkeypatch.setattr(_CountingAnnotation, "made", [])
+    tspans.reset_for_tests()
+    with tspans.span("tree/thresholds", rows=3) as sp:
+        pass
+    assert _CountingAnnotation.made == [(
+        "tptpu:tree/thresholds",
+        {"id": sp.id, "parent": 0, "trace": sp.id},
+    )]
+    tspans.record_span("serve/stage/x", 0.0, 0.1)
+    assert len(_CountingAnnotation.made) == 1, "post-hoc records stay as is"
+    tspans.set_enabled(False)
+    with tspans.span("tree/thresholds") as off:
+        assert tspans.current() is None
+    assert len(_CountingAnnotation.made) == 1, "disabled: no annotation made"
+    assert off.id is None and off.trace is None
+
+
+def test_tptpu_annotations_land_in_a_profiler_trace(tmp_path):
+    """The program's spans on the profiler's clock: inside a
+    ``jax.profiler`` trace, ``tptpu:<name>`` events nest as the spans do."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tspans.span("selector/sweep"):
+            with tspans.span("tree/thresholds"):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = {
+        e.name: (int(e.start_ns), int(e.start_ns + e.duration_ns))
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines for e in line.events
+        if e.name.startswith("tptpu:")
+    }
+    assert set(found) == {"tptpu:selector/sweep", "tptpu:tree/thresholds"}
+    outer, inner = found["tptpu:selector/sweep"], found["tptpu:tree/thresholds"]
+    assert outer[0] <= inner[0] and inner[1] <= outer[1]
+    assert inner[1] - inner[0] >= 2_000_000
+
+
+# ------------------------------------- the sweep's and the tree fit's spans
+SWEEP_TREE = {
+    "selector/sweep": None,
+    "selector/row_select": "selector/sweep",
+    "selector/validate": "selector/sweep",
+    "selector/family": "selector/validate",
+    "tree/bin_prepare": "selector/family",
+    "tree/thresholds": "tree/bin_prepare",
+    "tree/upload": "tree/bin_prepare",
+    "tree/bin_dispatch": "tree/bin_prepare",
+    "tree/feature_groups": "tree/bin_prepare",
+    "tree/fit_dispatch": "selector/family",
+    "selector/refit": "selector/sweep",
+    # the fold lanes' metrics under the family, the refit lane's (the train
+    # evaluation) under the refit
+    "selector/evaluate": ("selector/family", "selector/refit"),
+    # one read of the fold lanes' outputs under the family; under the
+    # refit the winner lane's outputs and its lane of the tree stack
+    "tree/await_outputs": ("selector/family", "selector/refit"),
+}
+
+
+def _tree_table(n=600, seed=0):
+    rng = np.random.default_rng(seed)
+    x = np.concatenate(
+        [rng.normal(size=(n, 4)), rng.integers(0, 2, (n, 3))], axis=1
+    ).astype(np.float32)
+    y = (x[:, 0] + x[:, 4] + rng.normal(size=n) > 0.5).astype(np.float64)
+    return x, y
+
+
+@pytest.fixture(scope="module")
+def xgb_sweep():
+    """One ``ModelSelector.fit_arrays`` over an XGBoost grid (2 points,
+    one split + the refit lane) on a small table, scatter histograms."""
+    from transmogrifai_tpu.models import gbdt
+    from transmogrifai_tpu.selector.model_selector import make_candidates
+    from transmogrifai_tpu.selector.validators import TrainValidationSplit
+
+    x, y = _tree_table()
+    models = make_candidates("BinaryClassification", ["OpXGBoostClassifier"])
+    for _est, grid in models:
+        grid.update(num_round=[2], max_depth=[3])
+    selector = BinaryClassificationModelSelector(
+        seed=3, models=models, validator=TrainValidationSplit(seed=3)
+    )
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPTPU_HIST", "scatter")
+        gbdt._BINNED_CACHE.clear()
+        tspans.reset_for_tests()
+        t0 = time.perf_counter()
+        selected = selector.fit_arrays(x, y, np.ones(len(y), np.float32))
+        wall = time.perf_counter() - t0
+    return {
+        "selected": selected, "wall": wall,
+        "records": list(tspans.snapshot_events()),
+    }
+
+
+def test_fit_arrays_yields_the_sweep_span_tree(xgb_sweep):
+    recs = xgb_sweep["records"]
+    by_id = {r["id"]: r for r in recs}
+    (root,) = [r for r in recs if r["parent"] is None]
+    assert root["name"] == "selector/sweep"
+    assert {r["trace"] for r in recs} == {root["id"]}, "one trace id"
+    assert {r["name"] for r in recs} == set(SWEEP_TREE)
+    for r in recs:
+        want = SWEEP_TREE[r["name"]]
+        got = by_id[r["parent"]]["name"] if r["parent"] else None
+        assert got in (want if isinstance(want, tuple) else (want,)), r
+    counts = {n: sum(r["name"] == n for r in recs) for n in SWEEP_TREE}
+    assert counts.pop("tree/await_outputs") == 3
+    assert counts.pop("selector/evaluate") == 2
+    assert set(counts.values()) == {1}, counts
+    # the candidate pool ran the family on its own thread
+    fam = next(r for r in recs if r["name"] == "selector/family")
+    assert fam["tid"] != root["tid"]
+    # attributes at the boundaries
+    assert root["args"] == {
+        "rows": 600, "cols": 7, "families": 1, "points": 2, "lanes": 4,
+    }
+    evaluated = [r["args"] for r in recs if r["name"] == "selector/evaluate"]
+    assert evaluated == [
+        {"lanes": 2, "rows": 600}, {"lanes": 1, "rows": 600},
+    ]
+    args = {r["name"]: r.get("args", {}) for r in recs}
+    assert args["selector/row_select"] == {
+        "rows_in": 600, "rows_out": 600, "bytes_copied": 600 * 7 * 4 + 600 * 8,
+    }
+    assert args["selector/validate"] == {"extra_masks": 1, "folds": 1}
+    assert args["selector/family"] == {
+        "family": "XGBoostClassifier", "points": 2, "attempts": 1,
+    }
+    assert args["tree/fit_dispatch"] == {
+        "lanes": 4, "rounds": 2, "depth": 3, "bins": 32,
+        "hist_impl": "scatter",
+    }
+    assert args["tree/feature_groups"] == {"narrow": 3, "wide": 4}
+    assert args["selector/refit"] == {"prefit": True}
+    assert args["tree/bin_prepare"]["cache"] == "miss"
+
+
+def test_sweep_children_lie_inside_parents_and_root_self_time_is_small(
+    xgb_sweep,
+):
+    recs = xgb_sweep["records"]
+    by_id = {r["id"]: r for r in recs}
+    (root,) = [r for r in recs if r["parent"] is None]
+    for r in recs:
+        if r["parent"] is None:
+            continue
+        p = by_id[r["parent"]]
+        assert p["ts"] <= r["ts"], (r, p)
+        assert r["ts"] + r["dur"] <= p["ts"] + p["dur"] + 1e-6, (r, p)
+    covered = sum(r["dur"] for r in recs if r["parent"] == root["id"])
+    assert root["dur"] - covered < 0.10 * root["dur"]
+    # on one thread at a time, the spans without children and the self
+    # time of those with children add up to the root
+    parents = {r["parent"] for r in recs}
+    leaves = sum(r["dur"] for r in recs if r["id"] not in parents)
+    selfs = sum(
+        r["dur"] - sum(c["dur"] for c in recs if c["parent"] == r["id"])
+        for r in recs if r["id"] in parents
+    )
+    assert leaves + selfs == pytest.approx(root["dur"], rel=1e-6)
+
+
+def test_binned_records_miss_then_hit_and_the_ledger_agrees():
+    from transmogrifai_tpu.models import gbdt
+
+    x, _y = _tree_table(seed=1)
+    est = gbdt.XGBoostClassifier(max_bins=8)
+    gbdt._BINNED_CACHE.clear()
+    tspans.reset_for_tests()
+    before = gbdt.bin_cache_stats().snapshot()
+    first = est._binned(x)
+    second = est._binned(x)
+    assert second[1] is first[1], "the hit hands back the cached codes"
+    preps = [
+        r["args"] for r in tspans.snapshot_events()
+        if r["name"] == "tree/bin_prepare"
+    ]
+    assert [a["cache"] for a in preps] == ["miss", "hit"]
+    entries = list(gbdt._BINNED_CACHE.values())
+    assert len(entries) == 1
+    for a in preps:
+        assert a["cache_entries"] == 1
+        assert a["cache_device_bytes"] == entries[0][2].nbytes == 600 * 7 * 4
+        assert a["cache_host_bytes"] == x.nbytes + entries[0][1].nbytes
+    # the miss did the work, the hit none of it
+    names = [r["name"] for r in tspans.snapshot_events()]
+    assert names.count("tree/thresholds") == 1
+    assert names.count("tree/bin_dispatch") == 1
+    now = gbdt.bin_cache_stats().snapshot()
+    assert now["binCacheLookups"] - before["binCacheLookups"] == 2
+    assert now["binCacheHits"] - before["binCacheHits"] == 1
+    assert now["binCacheEntries"] == 1
+    assert now["binCacheDeviceBytes"] == preps[-1]["cache_device_bytes"]
+    # an operator's scrape sees the same ledger
+    text = texport.render_prometheus()
+    assert f"tptpu_tree_bin_cache_lookups {now['binCacheLookups']}" in text
+    assert "tptpu_tree_bin_cache_device_bytes 16800" in text
+
+
+def test_disabled_telemetry_leaves_sweep_path_unrecorded():
+    from transmogrifai_tpu.models import gbdt
+    from transmogrifai_tpu.models import trees as TR
+
+    x, _y = _tree_table(seed=2)
+    est = gbdt.XGBoostClassifier(max_bins=8)
+    gbdt._BINNED_CACHE.clear()
+    tspans.reset_for_tests()
+    before = gbdt.bin_cache_stats().snapshot()
+    spans_before = tmetrics.REGISTRY.counter("tptpu_spans_recorded_total").value
+    tspans.set_enabled(False)
+    thresholds, binned, _groups = est._binned(x)
+    est._binned(x)
+    host = TR.await_outputs(binned)
+    tspans.set_enabled(True)
+    assert thresholds.shape == (7, 7) and host.shape == (600, 7)
+    assert tspans.snapshot_events() == []
+    assert gbdt.bin_cache_stats().snapshot() == before, "no ledger change"
+    assert (
+        tmetrics.REGISTRY.counter("tptpu_spans_recorded_total").value
+        == spans_before
+    )
+    assert len(gbdt._BINNED_CACHE) == 1, "the cache itself still works"
+
+
+TREE_SCOPES = (
+    "tree/histogram", "tree/split_search", "tree/partition", "tree/leaf",
+    "tree/gradients", "tree/outputs",
+)
+
+
+@pytest.mark.parametrize("program", ["boost", "forest", "bin"])
+def test_device_scope_names_are_in_the_lowered_program(program):
+    """``jax.named_scope`` names reach the op metadata of the fit programs
+    (what a device trace shows for each op)."""
+    import jax
+    import jax.numpy as jnp
+
+    from transmogrifai_tpu.models import trees as TR
+
+    n, f, k = 64, 5, 2
+    S = jax.ShapeDtypeStruct
+    groups = (S((2,), jnp.int32), S((3,), jnp.int32))
+    kf = S((k,), jnp.float32)
+    if program == "boost":
+        lowered = TR._boost_rounds_batched.lower(
+            S((n, f), jnp.int32), S((n,), jnp.float32), S((k, n), jnp.float32),
+            S((k, n), jnp.float32), kf, S((), jnp.float32),
+            S((), jnp.float32), kf, S((), jnp.float32), groups,
+            num_rounds=1, max_depth=2, num_bins=4,
+            objective="binary:logistic", hist_impl="scatter",
+        )
+        wanted = TREE_SCOPES + ("tree/group_columns",)
+    elif program == "forest":
+        lowered = TR._forest_trees_scan.lower(
+            S((n, f), jnp.int32), S((n,), jnp.float32), S((k, n), jnp.float32),
+            S((1,), jnp.uint32), kf, kf, kf, kf, groups, None, None, None,
+            num_trees=2, max_depth=2, num_bins=4, bootstrap=True, lowp=False,
+            hist_impl="scatter",
+        )
+        wanted = TREE_SCOPES + ("tree/group_columns",)
+    else:
+        lowered = jax.jit(TR.bin_data).lower(
+            S((n, f), jnp.float32), S((f, 3), jnp.float32)
+        )
+        wanted = ("tree/bin",)
+    text = lowered.as_text(debug_info=True)
+    missing = [s for s in wanted if f"{s}/" not in text and f"{s}\"" not in text]
+    assert not missing, missing
+
+
+def test_sweep_span_overhead_under_two_percent(xgb_sweep):
+    """The <2% guard with the profiler annotation inside ``span``: price a
+    span (annotation included), multiply by what one sweep records."""
+    n = 5000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with tspans.span("tree/overhead_probe", rows=1):
+            pass
+    per_span = (time.perf_counter() - t0) / n
+    attributed = len(xgb_sweep["records"]) * per_span
+    assert len(xgb_sweep["records"]) < 40, "some tens of spans a sweep"
+    assert attributed < 0.02 * xgb_sweep["wall"], (
+        per_span, attributed, xgb_sweep["wall"],
+    )

@@ -14,6 +14,10 @@ directory:
 
 ``TPTPU_COMPILE_CACHE`` moves the bank alone (the tests isolate it that
 way); JAX's cache never follows it.
+
+Both change with the source: the bank through its salt (``utils/aot.py``),
+JAX's cache because ``enable_persistent_cache`` puts the op metadata into
+its key. Neither hands back a program compiled from other source text.
 """
 from __future__ import annotations
 
@@ -38,12 +42,23 @@ def enable_persistent_cache() -> str:
     (``Workflow.train``, ``score_function``, ``chip_smoke.py``,
     ``bench.py``); repeats are free. JAX's own switch
     (``JAX_ENABLE_COMPILATION_CACHE=false``) still turns the cache off."""
+    import jax
+
     path = cache_dir()
     if not os.environ.get(_JAX_ENV):
-        import jax
-
         if jax.config.jax_compilation_cache_dir != path:
             jax.config.update("jax_compilation_cache_dir", path)
+    # JAX leaves op metadata (scope names, source lines) out of its key by
+    # default, so an edit that changes only metadata gets back the
+    # executable compiled before it: a profile then shows the old names,
+    # and on XLA:CPU such an executable, once the bank has serialized it,
+    # fails to load ("Function ... not found": the instruction numbering
+    # moved with the metadata). The bank's salt changes with the source;
+    # with the metadata in its key JAX's cache does too.
+    if not jax.config.jax_compilation_cache_include_metadata_in_key:
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", True
+        )
     return path
 
 

@@ -128,6 +128,14 @@ def prefetch_f32(arr) -> None:
         log.debug("prefetch skipped: %s", e)
 
 
+def prefetch_pending(arr) -> bool:
+    """Whether ``device_f32(arr)`` would pick up a prefetched buffer
+    rather than upload (what the ``tree/upload`` span records)."""
+    with _PREFETCH_LOCK:
+        hit = _PREFETCH.get(id(arr))
+    return hit is not None and hit[0]() is arr and not _mesh_active()
+
+
 def device_f32(arr):
     """The prefetched device buffer for ``arr`` if one is in flight (and
     the source object is still alive — a dead ref means the id may have

@@ -28,6 +28,8 @@ import numpy as np
 
 log = logging.getLogger(__name__)
 
+from ..telemetry import metrics as _tm
+from ..telemetry import spans as _tspans
 from .base import PredictorEstimator, PredictorModel
 from . import trees as TR
 
@@ -37,6 +39,59 @@ import threading as _threading
 # _TreeEstimator._binned
 _BINNED_CACHE: dict = {}
 _BINNED_LOCK = _threading.Lock()
+
+
+class BinCacheStats(_tm.LedgerCore):
+    """``treeStats`` — what ``_BINNED_CACHE`` was asked and what it holds:
+    look-ups and hits (cumulative), and the entries and device bytes it
+    kept after the last look-up. Registered as the ``tree`` source of
+    ``telemetry.render_prometheus()``; the same numbers ride each
+    ``tree/bin_prepare`` span as attributes."""
+
+    def __init__(self) -> None:
+        super().__init__((
+            "binCacheLookups", "binCacheHits",         # cumulative
+            "binCacheEntries", "binCacheDeviceBytes",  # after the last look-up
+        ))
+
+    def record_lookup(self, hit: bool, entries: int, device_bytes: int) -> None:
+        with self._lock:
+            self._counts["binCacheLookups"] += 1
+            self._counts["binCacheHits"] += int(hit)
+            self._counts["binCacheEntries"] = entries
+            self._counts["binCacheDeviceBytes"] = device_bytes
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return dict(self._counts)
+
+    def reset(self) -> None:
+        with self._lock:
+            self._reset_counts()
+
+
+_BIN_STATS = BinCacheStats()
+_tm.REGISTRY.register_source("tree", _BIN_STATS.snapshot)
+
+
+def bin_cache_stats() -> BinCacheStats:
+    return _BIN_STATS
+
+
+def _bin_cache_census() -> dict:
+    """What the cache holds now: entries, the device bytes of their bin
+    codes, and the host bytes its strong references keep alive (matrix and
+    thresholds)."""
+    with _BINNED_LOCK:
+        held = list(_BINNED_CACHE.values())
+    return {
+        "cache_entries": len(held),
+        "cache_device_bytes": sum(int(e[2].nbytes) for e in held),
+        "cache_host_bytes": sum(
+            int(a.nbytes) for e in held for a in e[:2]
+            if isinstance(a, np.ndarray)
+        ),
+    }
 
 
 def _sigmoid(m: np.ndarray) -> np.ndarray:
@@ -119,10 +174,16 @@ class _LazySlice:
             else:
                 from ..utils.aot import aot_call
 
-                out = aot_call(
-                    "stack_lane", _stack_lane,
-                    (trees, np.int32(self.lane)), {},
-                )
+                # a dispatch on the fit program's result: it can block
+                # until that program has run
+                with _tspans.span("tree/await_outputs") as sp:
+                    out = aot_call(
+                        "stack_lane", _stack_lane,
+                        (trees, np.int32(self.lane)), {},
+                    )
+                    sp.attrs["bytes"] = sum(
+                        int(a.nbytes) for a in jax.tree.leaves(out)
+                    )
             cache[self.lane] = out
         return out
 
@@ -639,30 +700,56 @@ class _TreeEstimator(PredictorEstimator):
             getattr(x, "shape", None), getattr(x, "strides", None),
             int(self.max_bins),
         )
-        with _BINNED_LOCK:
-            hit = _BINNED_CACHE.get(key)
-        if hit is not None:
-            return hit[1], hit[2], hit[3]
-        thresholds = TR.quantile_thresholds(x, self.max_bins)
+        with _tspans.span("tree/bin_prepare") as sp:
+            with _BINNED_LOCK:
+                hit = _BINNED_CACHE.get(key)
+            entry = hit if hit is not None else self._bin_into_cache(x, key)
+            if _tspans.enabled():
+                census = _bin_cache_census()
+                sp.attrs.update(
+                    cache="hit" if hit is not None else "miss", **census
+                )
+                _BIN_STATS.record_lookup(
+                    hit is not None, census["cache_entries"],
+                    census["cache_device_bytes"],
+                )
+        return entry[1], entry[2], entry[3]
+
+    def _bin_into_cache(self, x, key):
+        """The cache miss: thresholds on the host, the matrix and the
+        thresholds to the device, the binning program, the 0/1-column
+        scan; returns the new cache entry."""
+        with _tspans.span(
+            "tree/thresholds", rows=int(x.shape[0]), cols=int(x.shape[1]),
+            bins=int(self.max_bins), dtype=str(getattr(x, "dtype", "")),
+        ):
+            thresholds = TR.quantile_thresholds(x, self.max_bins)
         # through the AOT executable bank: a plain bin_data call would
         # acquire its program on the sweep's critical path
         from ..utils.aot import aot_call
 
-        from ..compiler.dispatch import device_f32
+        from ..compiler.dispatch import device_f32, prefetch_pending
 
         # device_f32 picks up the async upload the DAG fit prefetched for
         # this matrix, when one is in flight (compiler.dispatch)
-        binned = aot_call(
-            "bin_data", _bin_data_jit,
-            (device_f32(x), jnp.asarray(thresholds)),
-            {},
-        )
-        fgroups = _feature_bin_groups(x)
+        with _tspans.span(
+            "tree/upload",
+            bytes=4 * int(x.size) + int(thresholds.nbytes),
+            prefetched=prefetch_pending(x),
+        ):
+            operands = (device_f32(x), jnp.asarray(thresholds))
+        with _tspans.span("tree/bin_dispatch"):
+            binned = aot_call("bin_data", _bin_data_jit, operands, {})
+        with _tspans.span("tree/feature_groups") as sp:
+            fgroups = _feature_bin_groups(x)
+            narrow = 0 if fgroups is None else int(fgroups[0].shape[0])
+            sp.attrs.update(narrow=narrow, wide=int(x.shape[1]) - narrow)
+        entry = (x, thresholds, binned, fgroups)
         with _BINNED_LOCK:
-            _BINNED_CACHE[key] = (x, thresholds, binned, fgroups)
+            _BINNED_CACHE[key] = entry
             while len(_BINNED_CACHE) > 4:
                 _BINNED_CACHE.pop(next(iter(_BINNED_CACHE)))
-        return thresholds, binned, fgroups
+        return entry
 
     def _fit_group_masks(self, x, y, masks, group_points):
         """Fit len(masks) × len(group_points) same-static-shape models in
@@ -760,9 +847,6 @@ class _TreeEstimator(PredictorEstimator):
                     )
                 ):
                     return None
-            import time as _t
-
-            _t0 = _t.perf_counter()
             xj = None
             outputs: dict[int, np.ndarray] = {}
             for m in flat:
@@ -774,13 +858,8 @@ class _TreeEstimator(PredictorEstimator):
                     # the fit program already computed every lane's raw
                     # outputs on the training matrix — one tiny download,
                     # no traversal program, no x upload
-                    outputs[sid] = np.asarray(stack["outputs"])
-                    log.debug(
-                        "sweep_eval outputs reused +%.2fs",
-                        _t.perf_counter() - _t0,
-                    )
+                    outputs[sid] = TR.await_outputs(stack["outputs"])
                     continue
-                log.debug("sweep_eval stack start +%.2fs", _t.perf_counter() - _t0)
                 k = stack["k"]
                 eta_v = np.ones(k, dtype=np.float32)
                 base_v = np.zeros(k, dtype=np.float32)
@@ -806,31 +885,31 @@ class _TreeEstimator(PredictorEstimator):
                     ),
                     {},
                 )
-                log.debug("sweep_eval dispatched +%.2fs", _t.perf_counter() - _t0)
-                outputs[sid] = np.asarray(out)  # [K, N]
-                log.debug("sweep_eval downloaded +%.2fs", _t.perf_counter() - _t0)
-            _t1 = _t.perf_counter()
+                outputs[sid] = TR.await_outputs(out)  # [K, N]
             values: list[list[float]] = [
                 [] for _ in range(len(models_by_fold[0]))
             ]
-            for fi, (_train_mask, val_mask) in enumerate(folds):
-                val_idx = np.nonzero(val_mask)[0]
-                for gi, m in enumerate(models_by_fold[fi]):
-                    lanes = getattr(m, "_sweep_lanes", None)
-                    out_m = outputs[id(m._sweep_stack)]
-                    if lanes is not None:
-                        rows = out_m[lanes][:, val_idx]  # [C, n_val]
-                        pred, prob, _ = m.predictions_from_sweep_multi(rows)
-                    else:
-                        pred, prob, _ = m.predictions_from_sweep(
-                            out_m[m._sweep_lane][val_idx]
+            with _tspans.span(
+                "selector/evaluate", lanes=len(flat), rows=len(y)
+            ):
+                for fi, (_train_mask, val_mask) in enumerate(folds):
+                    val_idx = np.nonzero(val_mask)[0]
+                    for gi, m in enumerate(models_by_fold[fi]):
+                        lanes = getattr(m, "_sweep_lanes", None)
+                        out_m = outputs[id(m._sweep_stack)]
+                        if lanes is not None:
+                            rows = out_m[lanes][:, val_idx]  # [C, n_val]
+                            pred, prob, _ = (
+                                m.predictions_from_sweep_multi(rows)
+                            )
+                        else:
+                            pred, prob, _ = m.predictions_from_sweep(
+                                out_m[m._sweep_lane][val_idx]
+                            )
+                        metrics = evaluator.evaluate_arrays(
+                            y[val_idx], pred, prob
                         )
-                    metrics = evaluator.evaluate_arrays(y[val_idx], pred, prob)
-                    values[gi].append(evaluator.metric_of(metrics))
-            log.debug(
-                "sweep_eval: device outputs %.2fs, host metrics %.2fs",
-                _t1 - _t0, _t.perf_counter() - _t1,
-            )
+                        values[gi].append(evaluator.metric_of(metrics))
             return values
         except Exception:
             log.warning("batched sweep-eval failed; falling back", exc_info=True)
@@ -852,16 +931,9 @@ class _TreeEstimator(PredictorEstimator):
         training matrix, computed by the fit program itself) ride the stack
         so sweep_eval_batched needs no re-traversal program.
         """
-        import time as _t
-
-        _t0 = _t.perf_counter()
         base = self.with_params(**group_points[0])
         thresholds, binned, fgroups = base._binned(x)
         self._last_feature_groups = fgroups
-        log.debug(
-            "%s group fit: binned in %.2fs", type(self).__name__,
-            _t.perf_counter() - _t0,
-        )
         norm = normalize or (lambda m: m)
         merged = [norm({**self.get_params(), **p}) for p in group_points]
         n_masks, n_pts = masks.shape[0], len(merged)
@@ -883,11 +955,15 @@ class _TreeEstimator(PredictorEstimator):
                 [float(m[name]) for m in merged] * n_masks, dtype=np.float32
             )
 
-        trees, outputs = run_batched(binned, merged[0], row_mask_k, knob, fgroups)
-        log.debug(
-            "%s group fit: dispatched at %.2fs", type(self).__name__,
-            _t.perf_counter() - _t0,
-        )
+        m0 = merged[0]
+        # the asynchronous dispatch of boost_chunk / forest_scan
+        with _tspans.span(
+            "tree/fit_dispatch", lanes=n_masks * n_pts,
+            rounds=int(m0.get("num_round", m0.get("num_trees", 1))),
+            depth=int(m0["max_depth"]), bins=int(m0["max_bins"]),
+            hist_impl=TR._resolved_impl(),
+        ):
+            trees, outputs = run_batched(binned, m0, row_mask_k, knob, fgroups)
         # the stacked trees STAY on device for sweep_eval_batched (one
         # validation program per stack); per-model tree arrays materialize
         # lazily via _LazySlice — eager host pulls download the whole
@@ -900,7 +976,7 @@ class _TreeEstimator(PredictorEstimator):
         is_dev = bool(leaves) and hasattr(leaves[0], "devices")
         multi_dev = is_dev and len(leaves[0].devices()) > 1
         if multi_dev or not is_dev:
-            trees = jax.tree.map(lambda a: np.asarray(a), trees)
+            trees = TR.await_outputs(trees)
         stack = {
             "trees": trees,
             "thresholds": thresholds,
@@ -1373,24 +1449,29 @@ class RandomForestClassifier(_TreeEstimator):
         # max_depth is in _STATIC_GRID_KEYS, so every point of this group
         # shares one depth — no per-lane depth caps needed here
         m0 = merged[0]
-        trees, outs = TR.fit_forest_batched(
-            binned, tg, rm,
-            num_trees=int(m0["num_trees"]),
-            max_depth=int(m0["max_depth"]),
-            num_bins=int(m0["max_bins"]),
-            subsample_rate=knob("subsampling_rate"),
-            colsample_rate=float(colsample),
-            min_instances=knob("min_instances_per_node"),
-            min_info_gain=knob("min_info_gain"),
-            seed=int(m0["seed"]),
-            lowp=True,
-            feature_groups=fgroups,
-            return_outputs=True,
-        )
+        with _tspans.span(
+            "tree/fit_dispatch", lanes=n_masks * n_pts * c,
+            rounds=int(m0["num_trees"]), depth=int(m0["max_depth"]),
+            bins=int(m0["max_bins"]), hist_impl=TR._resolved_impl(),
+        ):
+            trees, outs = TR.fit_forest_batched(
+                binned, tg, rm,
+                num_trees=int(m0["num_trees"]),
+                max_depth=int(m0["max_depth"]),
+                num_bins=int(m0["max_bins"]),
+                subsample_rate=knob("subsampling_rate"),
+                colsample_rate=float(colsample),
+                min_instances=knob("min_instances_per_node"),
+                min_info_gain=knob("min_info_gain"),
+                seed=int(m0["seed"]),
+                lowp=True,
+                feature_groups=fgroups,
+                return_outputs=True,
+            )
         leaves = jax.tree.leaves(trees)
         is_dev = bool(leaves) and hasattr(leaves[0], "devices")
         if (is_dev and len(leaves[0].devices()) > 1) or not is_dev:
-            trees = jax.tree.map(lambda a: np.asarray(a), trees)
+            trees = TR.await_outputs(trees)
         stack = {"trees": trees, "thresholds": thresholds,
                  "k": n_masks * n_pts * c, "outputs": outs}
         models = [

@@ -32,6 +32,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry import runlog as _runlog
+from ..telemetry import spans as _tspans
+
 
 class Tree(NamedTuple):
     """Dense perfect-binary-tree arrays. Level d uses slots [0, 2^d)."""
@@ -39,6 +42,24 @@ class Tree(NamedTuple):
     split_feat: jax.Array  # [depth, 2^depth] int32, -1 = leaf (route left)
     split_bin: jax.Array   # [depth, 2^depth] int32, go right when bin > split_bin
     leaf_value: jax.Array  # [2^depth] float32
+
+
+def await_outputs(value):
+    """``value`` (an array or a pytree of arrays) on the host. A read of a
+    device result blocks until the program that makes it has run, so it is
+    a ``tree/await_outputs`` span — how long the host waited for the
+    device — and one download on the run ledger's transfer census. What is
+    on the host already passes through."""
+    leaves = jax.tree.leaves(value)
+    if all(isinstance(a, np.ndarray) for a in leaves):
+        return value
+    with _tspans.span("tree/await_outputs") as sp:
+        t0 = _tspans.clock()
+        out = jax.tree.map(np.asarray, value)
+        nbytes = sum(int(a.nbytes) for a in jax.tree.leaves(out))
+        sp.attrs["bytes"] = nbytes
+        _runlog.record_download(nbytes, _tspans.clock() - t0)
+    return out
 
 
 def quantile_thresholds(x: np.ndarray, max_bins: int = 32) -> np.ndarray:
@@ -64,8 +85,9 @@ def bin_data(x: jax.Array, thresholds: jax.Array) -> jax.Array:
     def step(acc, thr_col):  # thr_col [F]
         return acc + (x > thr_col[None, :]).astype(jnp.int32), None
 
-    acc0 = jnp.zeros(x.shape, dtype=jnp.int32)
-    codes, _ = jax.lax.scan(step, acc0, jnp.swapaxes(thresholds, 0, 1))
+    with jax.named_scope("tree/bin"):
+        acc0 = jnp.zeros(x.shape, dtype=jnp.int32)
+        codes, _ = jax.lax.scan(step, acc0, jnp.swapaxes(thresholds, 0, 1))
     return codes
 
 
@@ -284,17 +306,19 @@ def _grow_tree_impl(
         # arrays may be traced (per-tree colsample subsets); shapes are
         # static, values aren't. Empty groups simply drop out.
         groups = []
-        if narrow_idx.shape[0]:
-            groups.append(
-                (
-                    (binned[:, narrow_idx] > 0).astype(jnp.int32),
-                    feat_mask[:, narrow_idx], 2, narrow_idx,
+        with jax.named_scope("tree/group_columns"):
+            if narrow_idx.shape[0]:
+                groups.append(
+                    (
+                        (binned[:, narrow_idx] > 0).astype(jnp.int32),
+                        feat_mask[:, narrow_idx], 2, narrow_idx,
+                    )
                 )
-            )
-        if wide_idx.shape[0]:
-            groups.append(
-                (binned[:, wide_idx], feat_mask[:, wide_idx], b, wide_idx)
-            )
+            if wide_idx.shape[0]:
+                groups.append(
+                    (binned[:, wide_idx], feat_mask[:, wide_idx], b,
+                     wide_idx)
+                )
         if not groups:
             groups = [(binned, feat_mask, b, None)]
     else:
@@ -339,11 +363,13 @@ def _grow_tree_impl(
     # nesting, and the [N, Fg·Bg] temporary is small at GEMM row counts.
     if use_gemm:
         dt1h = jnp.bfloat16 if lowp else jnp.float32
-        groups = [
-            (gb_, gm, bb, gi,
-             jax.nn.one_hot(gb_, bb, dtype=dt1h).reshape(gb_.shape[0], -1))
-            for gb_, gm, bb, gi in groups
-        ]
+        with jax.named_scope("tree/group_columns"):
+            groups = [
+                (gb_, gm, bb, gi,
+                 jax.nn.one_hot(gb_, bb, dtype=dt1h).reshape(
+                     gb_.shape[0], -1))
+                for gb_, gm, bb, gi in groups
+            ]
     else:
         groups = [(gb_, gm, bb, gi, None) for gb_, gm, bb, gi in groups]
 
@@ -433,14 +459,24 @@ def _grow_tree_impl(
         """(gain, orig feat, bin) of the best split per compact slot for
         ONE feature group."""
         if use_fused:
-            bg, bf, bb = build_best_split_pallas(
-                gbinned, loc, g, h, gmask,
-                lam_k, gam_k, mcw_k,
-                num_nodes=chunk_nodes, num_bins=gb, lowp=lowp,
-            )
+            # histogram and arg-best in one kernel: the histogram's scope
+            with jax.named_scope("tree/histogram"):
+                bg, bf, bb = build_best_split_pallas(
+                    gbinned, loc, g, h, gmask,
+                    lam_k, gam_k, mcw_k,
+                    num_nodes=chunk_nodes, num_bins=gb, lowp=lowp,
+                )
             if gidx is not None:
-                bf = gidx[jnp.maximum(bf, 0)].astype(jnp.int32)
+                with jax.named_scope("tree/split_search"):
+                    bf = gidx[jnp.maximum(bf, 0)].astype(jnp.int32)
             return bg, bf, bb
+        with jax.named_scope("tree/histogram"):
+            hist = build_histogram(gbinned, gb, codes1h, loc, chunk_nodes)
+        with jax.named_scope("tree/split_search"):
+            return best_split(hist, gmask, gb, gidx, chunk_nodes)
+
+    def build_histogram(gbinned, gb, codes1h, loc, chunk_nodes):
+        """[K, M, Fg, Bg, 2] (grad, hess) sums of one feature group."""
         if use_gemm:
             hist = build_histogram_gemm(gbinned, loc, chunk_nodes, gb, codes1h)
         elif impl == "pallas":
@@ -466,6 +502,9 @@ def _grow_tree_impl(
             # the Rabit-allreduce moment: per-shard partial histograms
             # reduce over ICI; everything after sees the global histogram
             hist = jax.lax.psum(hist, axis_name)
+        return hist
+
+    def best_split(hist, gmask, gb, gidx, chunk_nodes):
         hg, hh = hist[..., 0], hist[..., 1]  # [K, M, Fg, Bg]
 
         gl = jnp.cumsum(hg, axis=3)[..., :-1]
@@ -496,8 +535,9 @@ def _grow_tree_impl(
         """Best (feat, bin) per compact slot in [c0, c0 + chunk_nodes),
         merged across feature groups (tie-break: lowest original feature
         id — matches the single-group argmax order)."""
-        active = (local >= c0) & (local < c0 + chunk_nodes)
-        loc = jnp.where(active, local - c0, -1)  # [K, N]
+        with jax.named_scope("tree/partition"):
+            active = (local >= c0) & (local < c0 + chunk_nodes)
+            loc = jnp.where(active, local - c0, -1)  # [K, N]
         bg, bf, bb = None, None, None
         for gbinned, gmask, grp_b, gidx, codes1h in groups:
             gg, gf, gbin = group_stats(
@@ -506,30 +546,34 @@ def _grow_tree_impl(
             if bg is None:
                 bg, bf, bb = gg, gf, gbin
             else:
-                take = (gg > bg) | ((gg == bg) & (gf < bf))
-                bg = jnp.where(take, gg, bg)
-                bf = jnp.where(take, gf, bf)
-                bb = jnp.where(take, gbin, bb)
-        do_split = bg > jnp.maximum(mig, 0.0)
-        return (
-            jnp.where(do_split, bf, -1),
-            jnp.where(do_split, bb, 0),
-        )  # each [K, chunk]
+                with jax.named_scope("tree/split_search"):
+                    take = (gg > bg) | ((gg == bg) & (gf < bf))
+                    bg = jnp.where(take, gg, bg)
+                    bf = jnp.where(take, gf, bf)
+                    bb = jnp.where(take, gbin, bb)
+        with jax.named_scope("tree/split_search"):
+            do_split = bg > jnp.maximum(mig, 0.0)
+            return (
+                jnp.where(do_split, bf, -1),
+                jnp.where(do_split, bb, 0),
+            )  # each [K, chunk]
 
     sentinel = jnp.int32(max_nodes)  # out-of-range → dropped by scatters
 
 
     if max_depth == 0:
         # root-only tree (legal Spark maxDepth=0): no splits, leaf = all rows
-        leaf_g0 = (g).sum(axis=1, keepdims=True)
-        leaf_h0 = (h).sum(axis=1, keepdims=True)
-        if axis_name is not None:
-            leaf_g0 = jax.lax.psum(leaf_g0, axis_name)
-            leaf_h0 = jax.lax.psum(leaf_h0, axis_name)
+        with jax.named_scope("tree/leaf"):
+            leaf_g0 = (g).sum(axis=1, keepdims=True)
+            leaf_h0 = (h).sum(axis=1, keepdims=True)
+            if axis_name is not None:
+                leaf_g0 = jax.lax.psum(leaf_g0, axis_name)
+                leaf_h0 = jax.lax.psum(leaf_h0, axis_name)
+            leaf_value0 = -leaf_g0 / (leaf_h0 + vec(reg_lambda)[:, None])
         return Tree(
             split_feat=jnp.full((k_fits, 0, 1), -1, dtype=jnp.int32),
             split_bin=jnp.zeros((k_fits, 0, 1), dtype=jnp.int32),
-            leaf_value=-leaf_g0 / (leaf_h0 + vec(reg_lambda)[:, None]),
+            leaf_value=leaf_value0,
         ), jnp.zeros((k_fits, n), dtype=jnp.int32)
 
     # ---- lax.scan over levels with ONE shared body: an unrolled level
@@ -580,11 +624,13 @@ def _grow_tree_impl(
         # the full routing chain (dead rows continue left) so leaf
         # assignment is unchanged.
         node, active, alive = carry
-        hist_node = jnp.where(active, node, sentinel)
-        (live, rank), local = compact_local(hist_node)
-        # dead rows out of every histogram / occupancy check, regardless
-        # of which slot the sentinel landed on after compaction
-        local = jnp.where(active, local, sentinel)
+        with jax.named_scope("tree/partition"):
+            hist_node = jnp.where(active, node, sentinel)
+            (live, rank), local = compact_local(hist_node)
+            # dead rows out of every histogram / occupancy check,
+            # regardless of which slot the sentinel landed on after
+            # compaction
+            local = jnp.where(active, local, sentinel)
 
         def live_level():
             def chunk_body(ci, fb):
@@ -658,22 +704,26 @@ def _grow_tree_impl(
         # (A one-shot post-scan densify over all levels measured ~35%
         # SLOWER than these per-level gathers — the [depth, K, max_nodes]
         # batched gather schedules worse than the level-sized ones.)
-        rank_c = jnp.minimum(rank, n_nodes - 1)
-        # one-hot select, NOT take_along_axis: the [K, max_nodes] gather
-        # from [K, cap] lowered to a serializing custom-fusion gather
-        # measured at ~1 ms per level — 1.2 s of the 1.7 s depth-12 RF
-        # program (trace: tools/trace_rf12.py)
-        feats_d = jnp.where(live, _small_table_lookup(feats_c, rank_c), -1)
-        bins_d = jnp.where(live, _small_table_lookup(bins_c, rank_c), 0)
+        with jax.named_scope("tree/partition"):
+            rank_c = jnp.minimum(rank, n_nodes - 1)
+            # one-hot select, NOT take_along_axis: the [K, max_nodes]
+            # gather from [K, cap] lowered to a serializing custom-fusion
+            # gather measured at ~1 ms per level — 1.2 s of the 1.7 s
+            # depth-12 RF program (trace: tools/trace_rf12.py)
+            feats_d = jnp.where(
+                live, _small_table_lookup(feats_c, rank_c), -1
+            )
+            bins_d = jnp.where(live, _small_table_lookup(bins_c, rank_c), 0)
 
-        # ---- route rows to children (gather via compact slots — cheaper)
-        slot = jnp.clip(local, 0, n_nodes - 1)
-        row_feat = _small_table_lookup(feats_c, slot)  # [K, N]
-        row_thr = _small_table_lookup(bins_c, slot)
-        code = _row_feature_select(binned, row_feat)
-        go_right = active & (row_feat >= 0) & (code > row_thr)
-        node = node * 2 + go_right.astype(jnp.int32)
-        active = active & (row_feat >= 0)
+            # ---- route rows to children (gather via compact slots —
+            # cheaper)
+            slot = jnp.clip(local, 0, n_nodes - 1)
+            row_feat = _small_table_lookup(feats_c, slot)  # [K, N]
+            row_thr = _small_table_lookup(bins_c, slot)
+            code = _row_feature_select(binned, row_feat)
+            go_right = active & (row_feat >= 0) & (code > row_thr)
+            node = node * 2 + go_right.astype(jnp.int32)
+            active = active & (row_feat >= 0)
         return (node, active, alive), (feats_d, bins_d)
 
     (node, active, _), (feats_s, bins_s) = jax.lax.scan(
@@ -688,12 +738,13 @@ def _grow_tree_impl(
     feats = jnp.swapaxes(feats_s, 0, 1)  # [K, depth, max_nodes]
     bins = jnp.swapaxes(bins_s, 0, 1)
 
-    leaf_g = _segment_sum_small(g, node, max_nodes)
-    leaf_h = _segment_sum_small(h, node, max_nodes)
-    if axis_name is not None:
-        leaf_g = jax.lax.psum(leaf_g, axis_name)
-        leaf_h = jax.lax.psum(leaf_h, axis_name)
-    leaf_value = -leaf_g / (leaf_h + vec(reg_lambda)[:, None])
+    with jax.named_scope("tree/leaf"):
+        leaf_g = _segment_sum_small(g, node, max_nodes)
+        leaf_h = _segment_sum_small(h, node, max_nodes)
+        if axis_name is not None:
+            leaf_g = jax.lax.psum(leaf_g, axis_name)
+            leaf_h = jax.lax.psum(leaf_h, axis_name)
+        leaf_value = -leaf_g / (leaf_h + vec(reg_lambda)[:, None])
     tree = Tree(split_feat=feats, split_bin=bins, leaf_value=leaf_value)
     # `node` is each row's final leaf slot — boosting's margin update reuses
     # it (leaf_value lookup) instead of re-traversing the tree (measured
@@ -1125,10 +1176,11 @@ def _forest_trees_scan(
     # ride the fit axis — the multiclass RF sweep trains every
     # class × fold × grid-point forest in this one program)
     target = jnp.asarray(target)
-    if target.ndim == 1:
-        gb = jnp.broadcast_to(-target[None, :], (k_fits, n))
-    else:
-        gb = -target
+    with jax.named_scope("tree/gradients"):
+        if target.ndim == 1:
+            gb = jnp.broadcast_to(-target[None, :], (k_fits, n))
+        else:
+            gb = -target
     ones = jnp.ones((k_fits, n), dtype=jnp.float32)
     mi_k = jnp.broadcast_to(
         jnp.asarray(min_instances, dtype=jnp.float32).reshape(-1), (k_fits,)
@@ -1160,13 +1212,15 @@ def _forest_trees_scan(
         )
         # this tree's prediction for EVERY row from the grower's own final
         # routing (leaf lookup — no re-traversal)
-        pred_t = _small_table_lookup(tree.leaf_value, node)
+        with jax.named_scope("tree/outputs"):
+            pred_t = _small_table_lookup(tree.leaf_value, node)
         return None, (tree, pred_t)
 
     _, (trees, preds) = jax.lax.scan(
         body, None, (tkeys, subset_n, subset_w)
     )  # [T, K, ...]
-    outs = preds.mean(axis=0)  # [K, N] forest mean-leaf outputs
+    with jax.named_scope("tree/outputs"):
+        outs = preds.mean(axis=0)  # [K, N] forest mean-leaf outputs
     return jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), trees), outs
 
 
@@ -1329,7 +1383,8 @@ def fit_boosted(
         return margin - y, jnp.ones_like(margin)
 
     def round_step(margin, _):
-        g, h = grads(margin)
+        with jax.named_scope("tree/gradients"):
+            g, h = grads(margin)
         tree = grow_tree(
             binned, g, h, row_mask, feat_mask,
             max_depth=max_depth, num_bins=num_bins,
@@ -1337,7 +1392,8 @@ def fit_boosted(
             min_child_weight=min_child_weight, min_info_gain=min_info_gain,
             parallel_fits=parallel_fits, feature_groups=feature_groups,
         )
-        margin = margin + eta * predict_tree(binned, tree)
+        with jax.named_scope("tree/outputs"):
+            margin = margin + eta * predict_tree(binned, tree)
         return margin, tree
 
     margin0 = jnp.full(n, base_score, dtype=jnp.float32)
@@ -1376,7 +1432,8 @@ def _boost_chunk_body(
         return margin - y[None, :], jnp.ones_like(margin)
 
     def round_step(margin, _):
-        g, h = grads(margin)
+        with jax.named_scope("tree/gradients"):
+            g, h = grads(margin)
         tree, leaf_slot = _grow_tree_impl(
             binned, g, h, row_mask, feat_mask,
             max_depth=max_depth, num_bins=num_bins,
@@ -1387,8 +1444,9 @@ def _boost_chunk_body(
         )
         # margin update straight from the grower's final routing — one
         # small-table lookup instead of a full predict_tree re-traversal
-        step = _small_table_lookup(tree.leaf_value, leaf_slot)  # [K, N]
-        margin = margin + eta_v[:, None] * step
+        with jax.named_scope("tree/outputs"):
+            step = _small_table_lookup(tree.leaf_value, leaf_slot)  # [K, N]
+            margin = margin + eta_v[:, None] * step
         return margin, tree
 
     margin, trees = jax.lax.scan(round_step, margin0, None, length=num_rounds)
@@ -1517,7 +1575,7 @@ def fit_boosted_batched(
         return chunks[0], margin
     # multi-chunk only off the default path: concatenate on HOST (eager
     # device concatenates cost a compile-cache round-trip per shape)
-    chunks = [jax.tree.map(np.asarray, c) for c in chunks]
+    chunks = await_outputs(chunks)
     return jax.tree.map(lambda *xs: np.concatenate(xs, axis=1), *chunks), margin
 
 
@@ -1605,7 +1663,8 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
         grp = rest if rest else None
         k_fits = rmasks.shape[1]
         n_local = binned.shape[0]
-        gb = jnp.broadcast_to(-target[None, :], (k_fits, n_local))
+        with jax.named_scope("tree/gradients"):
+            gb = jnp.broadcast_to(-target[None, :], (k_fits, n_local))
         ones = jnp.ones((k_fits, n_local), dtype=jnp.float32)
 
         def one_tree(_, xs):
@@ -1619,13 +1678,15 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
                 axis_name=DATA_AXIS, axis_size=size,
                 feature_groups=(sn, sw) if sn is not None else grp,
             )
-            pred_t = _small_table_lookup(tree.leaf_value, node)
+            with jax.named_scope("tree/outputs"):
+                pred_t = _small_table_lookup(tree.leaf_value, node)
             return None, (tree, pred_t)
 
         _, (trees, preds) = jax.lax.scan(
             one_tree, None, (rmasks, fmasks, subset_n, subset_w)
         )
-        outs = preds.mean(axis=0)  # [K, n_local]
+        with jax.named_scope("tree/outputs"):
+            outs = preds.mean(axis=0)  # [K, n_local]
         return jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), trees), outs
 
     rep = P()
@@ -1681,10 +1742,8 @@ def _fit_forest_batched_sharded(
     trees, outs = kern(binned_p, target_p, rmasks, fmasks, mi_k, mg_k,
                        *grp_args)
     # pull replicated trees to HOST once
-    return (
-        jax.tree.map(lambda a: np.asarray(a), trees),
-        np.asarray(outs)[:, :n],
-    )
+    trees, outs = await_outputs((trees, outs))
+    return trees, outs[:, :n]
 
 
 @lru_cache(maxsize=None)
@@ -1766,10 +1825,10 @@ def _fit_boosted_batched_sharded(
         # reshapes intermittently abort the XLA:CPU async runtime; margin
         # stays DEVICE-resident as the next chunk's carry. Chunks are
         # [K, rc, ...] (swap happens in-jit).
-        chunks.append(jax.tree.map(lambda a: np.asarray(a), trees_c))
+        chunks.append(await_outputs(trees_c))
         done += rc
     trees = jax.tree.map(lambda *xs: np.concatenate(xs, axis=1), *chunks)
-    return trees, np.asarray(margin)[:, :n]
+    return trees, await_outputs(margin)[:, :n]
 
 
 # --------------------------------------------------------------------------

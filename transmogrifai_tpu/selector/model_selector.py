@@ -42,7 +42,9 @@ from ..models.logistic import LogisticRegression
 from ..models.mlp import MLPClassifier
 from ..models.naive_bayes import NaiveBayes
 from ..models.svc import LinearSVC
+from ..models.trees import await_outputs
 from ..prep.splitters import DataBalancer, DataCutter, DataSplitter
+from ..telemetry import spans as _tspans
 from .validators import CrossValidator, TrainValidationSplit, Validator
 
 log = logging.getLogger(__name__)
@@ -298,6 +300,15 @@ class ModelSelector(PredictorEstimator):
         }
 
     def fit_arrays(self, x, y, row_mask) -> SelectedModel:
+        # one trace per sweep: every span below (and the fits the candidate
+        # pool runs on its threads) carries this root's id as ``trace``
+        with _tspans.span(
+            "selector/sweep", rows=int(x.shape[0]), cols=int(x.shape[1]),
+            families=len(self.models),
+        ) as root:
+            return self._sweep(x, y, row_mask, root)
+
+    def _sweep(self, x, y, row_mask, root) -> SelectedModel:
         from ..compiler import stats as cstats
         from ..featurize import stats as fstats
 
@@ -307,22 +318,29 @@ class ModelSelector(PredictorEstimator):
         # land in the summary next to the retry and failover ledgers
         compile_baseline = cstats.snapshot()
         featurize_baseline = fstats.snapshot()
-        train_idx = np.nonzero(row_mask > 0)[0]
-        xt, yt = x[train_idx], y[train_idx]
+        with _tspans.span("selector/row_select", rows_in=len(row_mask)) as sp:
+            train_idx = np.nonzero(row_mask > 0)[0]
+            xt, yt = x[train_idx], y[train_idx]
+            copied = xt.nbytes + yt.nbytes
 
-        # pre-validation prepare (DataCutter removes rare labels up front)
-        if isinstance(self.splitter, DataCutter):
-            keep = self.splitter.prepare(yt)
-            xt, yt = xt[keep], yt[keep]
+            # pre-validation prepare (DataCutter removes rare labels up front)
+            if isinstance(self.splitter, DataCutter):
+                keep = self.splitter.prepare(yt)
+                xt, yt = xt[keep], yt[keep]
+                copied += xt.nbytes + yt.nbytes
 
-        # validation prepare (balancing / down-sampling) is a deterministic
-        # seeded function of yt, so the refit mask is computable BEFORE
-        # validation — it rides the candidate sweep as an extra fit lane of
-        # the same batched program, so the winner's refit model is already
-        # trained when validation returns (no separate refit program)
-        final_mask = np.ones(len(yt), dtype=np.float32)
-        if self.splitter is not None and not isinstance(self.splitter, DataCutter):
-            final_mask = self.splitter.prepare(yt).astype(np.float32)
+            # validation prepare (balancing / down-sampling) is a
+            # deterministic seeded function of yt, so the refit mask is
+            # computable BEFORE validation — it rides the candidate sweep as
+            # an extra fit lane of the same batched program, so the winner's
+            # refit model is already trained when validation returns (no
+            # separate refit program)
+            final_mask = np.ones(len(yt), dtype=np.float32)
+            if self.splitter is not None and not isinstance(
+                self.splitter, DataCutter
+            ):
+                final_mask = self.splitter.prepare(yt).astype(np.float32)
+            sp.attrs.update(rows_out=len(yt), bytes_copied=int(copied))
 
         attempt_info: list = []
         if self.precomputed_results is not None:
@@ -332,104 +350,120 @@ class ModelSelector(PredictorEstimator):
             self.precomputed_results = None
             prefit = {}
         else:
-            results = self.validator.validate(
-                self.models, xt, yt, self.evaluator,
-                extra_masks=[final_mask],
-                checkpoint=self._checkpoint,
-                resume=self._checkpoint_resume,
-            )
+            extra_masks = [final_mask]
+            with _tspans.span(
+                "selector/validate", extra_masks=len(extra_masks)
+            ) as sp:
+                results = self.validator.validate(
+                    self.models, xt, yt, self.evaluator,
+                    extra_masks=extra_masks,
+                    checkpoint=self._checkpoint,
+                    resume=self._checkpoint_resume,
+                )
+                sp.attrs["folds"] = max(
+                    len(r.metric_values) for r in results
+                )
             prefit = getattr(self.validator, "last_extra_models", {})
             attempt_info = list(
                 getattr(self.validator, "last_attempt_info", [])
             )
-        best = Validator.best(results, self.evaluator)
-        log.info(
-            "ModelSelector best: %s %s (%s=%.4f over %d candidates)",
-            best.model_name,
-            best.grid,
-            self.evaluator.default_metric,
-            best.metric_mean,
-            len(results),
-        )
+            # every grid point is fitted once per fold and once on the
+            # refit mask
+            root.attrs["lanes"] = len(results) * (
+                sp.attrs["folds"] + len(extra_masks)
+            )
+        root.attrs["points"] = len(results)
+        with _tspans.span("selector/refit") as sp:
+            best = Validator.best(results, self.evaluator)
+            log.info(
+                "ModelSelector best: %s %s (%s=%.4f over %d candidates)",
+                best.model_name,
+                best.grid,
+                self.evaluator.default_metric,
+                best.metric_mean,
+                len(results),
+            )
 
-        family = next(
-            est for est, _ in self.models if est.uid == best.model_uid
-        )
-        final_est = family.with_params(**best.grid)
+            family = next(
+                est for est, _ in self.models if est.uid == best.model_uid
+            )
+            final_est = family.with_params(**best.grid)
 
-        splitter_summary = None
-        if self.splitter is not None and self.splitter.summary is not None:
-            splitter_summary = self.splitter.summary.to_json()
+            splitter_summary = None
+            if self.splitter is not None and self.splitter.summary is not None:
+                splitter_summary = self.splitter.summary.to_json()
 
-        # the winner's refit model usually already exists as the extra
-        # sweep lane fitted on final_mask (validate(extra_masks=...));
-        # families without the batched hook (or the workflow-CV path)
-        # refit directly — batched when possible so the program comes from
-        # the AOT executable bank
-        best_model = None
-        refit_raw = None
-        if best.model_uid in prefit:
-            points, extra_rows = prefit[best.model_uid]
-            if best.grid in points and extra_rows:
-                best_model = extra_rows[0][points.index(best.grid)]
-                # the refit lane's raw outputs on xt were computed by the
-                # fit program itself — grab them BEFORE detach frees the
-                # stack, so train evaluation needs no re-predict
-                stack = getattr(best_model, "_sweep_stack", None)
-                if stack is not None and stack.get("outputs") is not None:
-                    lanes = getattr(best_model, "_sweep_lanes", None)
-                    if lanes is not None:
-                        refit_raw = ("multi", np.asarray(
-                            stack["outputs"])[lanes])
-                    elif hasattr(best_model, "predictions_from_sweep"):
-                        refit_raw = ("single", np.asarray(
-                            stack["outputs"])[best_model._sweep_lane])
-                # free the sweep stacks: keep only the winner's own lane
-                detach = getattr(best_model, "detach_from_sweep", None)
-                if detach is not None:
-                    detach()
-        getattr(self.validator, "last_extra_models", {}).clear()
-        if best_model is None:
-            batched = getattr(final_est, "fit_arrays_batched_masks", None)
-            if batched is not None:
-                best_model = batched(
-                    xt, yt, [final_mask], [dict(best.grid)]
-                )[0][0]
+            # the winner's refit model usually already exists as the extra
+            # sweep lane fitted on final_mask (validate(extra_masks=...));
+            # families without the batched hook (or the workflow-CV path)
+            # refit directly — batched when possible so the program comes from
+            # the AOT executable bank
+            best_model = None
+            refit_raw = None
+            if best.model_uid in prefit:
+                points, extra_rows = prefit[best.model_uid]
+                if best.grid in points and extra_rows:
+                    best_model = extra_rows[0][points.index(best.grid)]
+                    # the refit lane's raw outputs on xt were computed by the
+                    # fit program itself — grab them BEFORE detach frees the
+                    # stack, so train evaluation needs no re-predict
+                    stack = getattr(best_model, "_sweep_stack", None)
+                    if stack is not None and stack.get("outputs") is not None:
+                        lanes = getattr(best_model, "_sweep_lanes", None)
+                        if lanes is not None:
+                            refit_raw = ("multi", await_outputs(
+                                stack["outputs"])[lanes])
+                        elif hasattr(best_model, "predictions_from_sweep"):
+                            refit_raw = ("single", await_outputs(
+                                stack["outputs"])[best_model._sweep_lane])
+                    # free the sweep stacks: keep only the winner's own lane
+                    detach = getattr(best_model, "detach_from_sweep", None)
+                    if detach is not None:
+                        detach()
+            getattr(self.validator, "last_extra_models", {}).clear()
+            sp.attrs["prefit"] = best_model is not None
+            if best_model is None:
+                batched = getattr(final_est, "fit_arrays_batched_masks", None)
+                if batched is not None:
+                    best_model = batched(
+                        xt, yt, [final_mask], [dict(best.grid)]
+                    )[0][0]
+                else:
+                    best_model = final_est.fit_arrays(xt, yt, final_mask)
+
+            if refit_raw is not None:
+                kind, raw = refit_raw
+                if kind == "multi":
+                    pred, prob, _ = best_model.predictions_from_sweep_multi(raw)
+                else:
+                    pred, prob, _ = best_model.predictions_from_sweep(raw)
             else:
-                best_model = final_est.fit_arrays(xt, yt, final_mask)
+                pred, prob, _ = best_model.predict_arrays(xt)
+            with _tspans.span("selector/evaluate", lanes=1, rows=len(yt)):
+                train_metrics = self.evaluator.evaluate_arrays(yt, pred, prob)
+                extra_train = {
+                    ev.name: ev.evaluate_arrays(yt, pred, prob)
+                    for ev in self.extra_evaluators
+                }
 
-        if refit_raw is not None:
-            kind, raw = refit_raw
-            if kind == "multi":
-                pred, prob, _ = best_model.predictions_from_sweep_multi(raw)
-            else:
-                pred, prob, _ = best_model.predictions_from_sweep(raw)
-        else:
-            pred, prob, _ = best_model.predict_arrays(xt)
-        train_metrics = self.evaluator.evaluate_arrays(yt, pred, prob)
-        extra_train = {
-            ev.name: ev.evaluate_arrays(yt, pred, prob)
-            for ev in self.extra_evaluators
-        }
-
-        summary = {
-            "problemKind": self.problem_kind,
-            "validationType": type(self.validator).__name__,
-            "evaluationMetric": self.evaluator.default_metric,
-            "bestModelName": f"{best.model_name}_{best.model_uid}",
-            "bestModelType": best.model_name,
-            "bestGrid": best.grid,
-            "validationResults": [r.to_json() for r in results],
-            "candidateAttempts": attempt_info,
-            "trainEvaluation": train_metrics,
-            "extraTrainEvaluations": extra_train,
-            "holdoutEvaluation": None,
-            "splitterSummary": splitter_summary,
-            "compileStats": cstats.delta(compile_baseline),
-            "featurizeStats": fstats.delta(featurize_baseline),
-        }
-        self.metadata["modelSelectorSummary"] = summary
-        return SelectedModel(best_model, summary)
+            summary = {
+                "problemKind": self.problem_kind,
+                "validationType": type(self.validator).__name__,
+                "evaluationMetric": self.evaluator.default_metric,
+                "bestModelName": f"{best.model_name}_{best.model_uid}",
+                "bestModelType": best.model_name,
+                "bestGrid": best.grid,
+                "validationResults": [r.to_json() for r in results],
+                "candidateAttempts": attempt_info,
+                "trainEvaluation": train_metrics,
+                "extraTrainEvaluations": extra_train,
+                "holdoutEvaluation": None,
+                "splitterSummary": splitter_summary,
+                "compileStats": cstats.delta(compile_baseline),
+                "featurizeStats": fstats.delta(featurize_baseline),
+            }
+            self.metadata["modelSelectorSummary"] = summary
+            return SelectedModel(best_model, summary)
 
 
 def BinaryClassificationModelSelector(

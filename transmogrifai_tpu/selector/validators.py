@@ -197,11 +197,25 @@ class Validator:
             except Exception as e:
                 points_list.append(e)
 
+        # the pool's threads start with an empty span stack: the span that
+        # is open here (the selector's) is handed to them as the parent
+        caller = _tspans.current()
+
         def run(i, est, points):
-            """One candidate: checkpoint hit, or retried sweep + save.
-            Returns (CandidateResults, attempts, from_checkpoint)."""
+            """One candidate under its ``selector/family`` span."""
             if isinstance(points, Exception):
                 raise points
+            with _tspans.span(
+                "selector/family", parent=caller,
+                family=type(est).__name__, points=len(points),
+            ) as sp:
+                out, attempts, from_ckpt = sweep_one(i, est, points)
+                sp.attrs["attempts"] = attempts
+            return out, attempts, from_ckpt
+
+        def sweep_one(i, est, points):
+            """One candidate: checkpoint hit, or retried sweep + save.
+            Returns (CandidateResults, attempts, from_checkpoint)."""
             # run-ledger pulse (telemetry/runlog.py): one timing per
             # candidate family sweep — the fold axis is batched into the
             # program, so the family IS the timing unit here (workflow CV
@@ -398,23 +412,28 @@ class Validator:
                         for p in points
                     ]
             val_idx = np.nonzero(val_mask)[0]
-            for gi, model in enumerate(models):
-                # lane-granular isolation: one lane's scoring failure
-                # poisons only its own grid point (NaN metric — ``best``
-                # filters non-finite means), not the whole family. Fit
-                # failures above still propagate: the retry machinery
-                # scripts those at the candidate level.
-                try:
-                    pred, prob, _ = model.predict_arrays(x[val_idx])
-                    metrics = evaluator.evaluate_arrays(y[val_idx], pred, prob)
-                    value = evaluator.metric_of(metrics)
-                except Exception as e:  # lane-level isolation
-                    log.warning(
-                        "Lane %d (%s) of %s failed scoring in fold %d: %s",
-                        gi, points[gi], type(est).__name__, fi, e,
-                    )
-                    value = float("nan")
-                per_point_values[gi].append(value)
+            with _tspans.span(
+                "selector/evaluate", lanes=len(models), rows=len(val_idx)
+            ):
+                for gi, model in enumerate(models):
+                    # lane-granular isolation: one lane's scoring failure
+                    # poisons only its own grid point (NaN metric —
+                    # ``best`` filters non-finite means), not the whole
+                    # family. Fit failures above still propagate: the retry
+                    # machinery scripts those at the candidate level.
+                    try:
+                        pred, prob, _ = model.predict_arrays(x[val_idx])
+                        metrics = evaluator.evaluate_arrays(
+                            y[val_idx], pred, prob
+                        )
+                        value = evaluator.metric_of(metrics)
+                    except Exception as e:  # lane-level isolation
+                        log.warning(
+                            "Lane %d (%s) of %s failed scoring in fold %d: "
+                            "%s", gi, points[gi], type(est).__name__, fi, e,
+                        )
+                        value = float("nan")
+                    per_point_values[gi].append(value)
         return [
             CandidateResult(
                 model_name=type(est).__name__,

@@ -54,6 +54,7 @@ from .metrics import (  # noqa: F401
 )
 from .spans import (  # noqa: F401
     clock,
+    current,
     enabled,
     record_serve_batch,
     record_span,

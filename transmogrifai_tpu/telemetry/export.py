@@ -10,8 +10,9 @@ exposition — scrapeable as-is by a Prometheus agent, printable via
 
 ``export_chrome_trace()`` converts the bounded span buffer to the Chrome
 trace-event format (complete ``"ph": "X"`` events, microsecond
-timestamps); the file opens directly in Perfetto / ``chrome://tracing``
-with layer → stage, fold → candidate, and batch → stage nesting.
+timestamps, each span's ``id`` / ``parent`` / ``trace`` in its ``args``);
+the file opens directly in Perfetto / ``chrome://tracing`` with
+layer → stage, fold → candidate, and batch → stage nesting.
 
 ``phase_breakdown()`` attributes buffered span time to the bench phases
 (ingest / featurize / compile / fit / eval). The mapping uses the
@@ -48,6 +49,7 @@ def _ensure_default_sources() -> None:
     from ..featurize import stats as _fstats  # noqa: F401
     from ..insights import ledger as _attr  # noqa: F401
     from ..local import scoring as _scoring  # noqa: F401
+    from ..models import gbdt as _gbdt  # noqa: F401  (the bin cache's ledger)
     from ..resilience import distributed as _dist  # noqa: F401
     from . import runlog as _runlog  # noqa: F401
 
@@ -180,8 +182,12 @@ def export_chrome_trace(path: str | None = None) -> dict[str, Any]:
             "ts": round(rec["ts"] * 1e6, 3),
             "dur": round(rec["dur"] * 1e6, 3),
         }
-        if rec.get("args"):
-            ev["args"] = rec["args"]
+        # who caused whom: same-thread nesting shows by time containment,
+        # the ids also link a sweep to the fits it ran on pool threads
+        ev["args"] = {
+            **rec.get("args", {}),
+            "id": rec["id"], "parent": rec["parent"], "trace": rec["trace"],
+        }
         events.append(ev)
     doc = {"traceEvents": events, "displayTimeUnit": "ms"}
     if path is not None:

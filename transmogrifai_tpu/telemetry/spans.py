@@ -4,10 +4,16 @@
 and records it three ways:
 
 * a **Chrome trace event** in a bounded in-process buffer (complete
-  ``"ph": "X"`` events; Perfetto nests same-thread spans by time
-  containment, so the exported JSON shows layer → fit/transform,
-  fold → candidate, batch → stage hierarchies with no parent bookkeeping
-  in the hot path);
+  ``"ph": "X"`` events). Every record carries an ``id``, the ``parent``
+  that caused it (``None`` for a root) and the ``trace`` it belongs to
+  (its root's ``id``), all from one process-wide counter. On one thread
+  the thread-local stack supplies the parent; across a pool the caller
+  hands it over: ``handle = current()`` before ``submit``,
+  ``span(name, parent=handle)`` in the worker;
+* a ``jax.profiler.TraceAnnotation`` named ``tptpu:<span name>`` around
+  the same block, so that in any profiler trace the program's spans lie
+  on host thread lines on the device trace's clock (the in-process
+  record keeps the injectable clock below);
 * an **exponential-bucket duration histogram** per span name in the
   metrics registry (``tptpu_span_seconds{span="..."}``) — true
   p50/p95/p99 per stage family;
@@ -28,6 +34,7 @@ dozens of span objects; per-stage detail spans engage above
 """
 from __future__ import annotations
 
+import itertools
 import os
 import threading
 import time
@@ -38,6 +45,7 @@ from . import metrics as _metrics
 
 __all__ = [
     "span",
+    "current",
     "record_span",
     "record_serve_batch",
     "clock",
@@ -78,6 +86,12 @@ _STATE: dict[str, Any] = {
 _EVENTS: deque = deque(maxlen=_env_int("TPTPU_TRACE_BUFFER", 65536))
 _SERVE_RING: deque = deque(maxlen=_env_int("TPTPU_SERVE_TRACE_RING", 64))
 _TIDS: dict[int, int] = {}
+#: span ids: one process-wide counter (``next`` on it is atomic, no lock)
+_IDS = itertools.count(1)
+#: prefix of the profiler annotations ``span`` enters (never ``bench:``,
+#: which is the benchmark's own)
+ANNOTATION_PREFIX = "tptpu:"
+_ANNOTATION: Any = None  # jax.profiler.TraceAnnotation, resolved on first use
 
 #: per-batch row floor below which scoring skips per-stage detail spans
 _DETAIL_MIN_ROWS = _env_int("TPTPU_TRACE_STAGE_ROWS", 16)
@@ -144,27 +158,45 @@ def _observe(name: str, dur: float) -> None:
     reg.counter("tptpu_spans_recorded_total").inc()
 
 
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, resolved once: the telemetry
+    package itself imports without JAX."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
 def _record(
     name: str,
     start: float,
     dur: float,
     attrs: dict | None,
-    parent: "span | None",
-    children: list | None,
-    root_trace: bool,
+    ids: tuple[int, int | None, int],
+    tree_parent: "span | None" = None,
+    children: list | None = None,
+    ring: bool = False,
 ) -> None:
+    """Append one record. ``ids`` is (id, parent id or None, trace id);
+    ``tree_parent`` is the enclosing span on this thread, whose serving-ring
+    trace tree gains this span as a child; ``ring`` sends a root
+    ``serve/*`` span's own tree to the serving ring."""
+    sid, parent_id, trace_id = ids
     rec: dict[str, Any] = {
         "name": name, "ts": start, "dur": dur, "tid": _tid(),
+        "id": sid, "parent": parent_id, "trace": trace_id,
     }
     if attrs:
         rec["args"] = dict(attrs)
     with _LOCK:
         _EVENTS.append(rec)
     _observe(name, dur)
-    if parent is not None:
-        kids = parent.children
+    if tree_parent is not None:
+        kids = tree_parent.children
         if kids is None:
-            kids = parent.children = []
+            kids = tree_parent.children = []
         if len(kids) < _CHILD_CAP:
             child: dict[str, Any] = {
                 "name": name, "durMs": round(dur * 1e3, 3),
@@ -172,7 +204,7 @@ def _record(
             if children:
                 child["children"] = children
             kids.append(child)
-    elif root_trace and name.startswith("serve/"):
+    elif ring and name.startswith("serve/"):
         trace = {
             "name": name,
             "durMs": round(dur * 1e3, 3),
@@ -183,16 +215,36 @@ def _record(
             _SERVE_RING.append(trace)
 
 
+def current() -> "span | None":
+    """The innermost span open on this thread (None outside any span or
+    with telemetry disabled): the handle to give a worker thread, which
+    opens its spans with ``span(name, parent=handle)``."""
+    stack = getattr(_TLS, "stack", None)
+    return stack[-1] if stack else None
+
+
 class span:
     """``with span("cv/fold", fold=2): ...`` — times the block and records
-    it (see module docstring). Near-free when telemetry is disabled."""
+    it (see module docstring). ``parent=`` takes the handle of a span open
+    on ANOTHER thread (:func:`current`, captured before the hand-over);
+    without it the parent is the innermost span open on this thread. Once
+    entered, ``id`` / ``parent`` / ``trace`` hold the record's integers, and
+    ``attrs`` may still gain what only the block learns (it is recorded on
+    exit). Near-free when telemetry is disabled: no id, no annotation."""
 
-    __slots__ = ("name", "attrs", "children", "_t0")
+    __slots__ = (
+        "name", "attrs", "children", "id", "parent", "trace",
+        "_cause", "_t0", "_ann",
+    )
 
-    def __init__(self, name: str, **attrs: Any):
+    def __init__(self, name: str, parent: "span | None" = None, **attrs: Any):
         self.name = name
         self.attrs = attrs
         self.children: list | None = None
+        self.id: int | None = None
+        self.parent: int | None = None
+        self.trace: int | None = None
+        self._cause = parent
         self._t0 = -1.0
 
     def __enter__(self) -> "span":
@@ -201,7 +253,21 @@ class span:
         stack = getattr(_TLS, "stack", None)
         if stack is None:
             stack = _TLS.stack = []
+        cause = self._cause
+        if cause is None and stack:
+            cause = stack[-1]
+        self.id = next(_IDS)
+        if cause is not None and cause.id is not None:
+            self.parent, self.trace = cause.id, cause.trace
+        else:
+            self.trace = self.id
         stack.append(self)
+        # on the profiler's clock too; a no-op object outside a trace
+        self._ann = _annotation()(
+            ANNOTATION_PREFIX + self.name,
+            id=self.id, parent=self.parent or 0, trace=self.trace,
+        )
+        self._ann.__enter__()
         self._t0 = _CLOCK()
         return self
 
@@ -209,25 +275,40 @@ class span:
         if self._t0 < 0.0:  # entered disabled
             return False
         dur = _CLOCK() - self._t0
+        self._ann.__exit__(None, None, None)
         stack = getattr(_TLS, "stack", None)
-        parent = None
+        tree_parent = None
         if stack and stack[-1] is self:
             stack.pop()
-            parent = stack[-1] if stack else None
+            # a parent handed over from another thread keeps its serving
+            # tree to itself: its children list is not shared state
+            if stack and self._cause is None:
+                tree_parent = stack[-1]
         _record(
-            self.name, self._t0, dur, self.attrs, parent, self.children,
-            root_trace=parent is None,
+            self.name, self._t0, dur, self.attrs,
+            (self.id, self.parent, self.trace), tree_parent, self.children,
+            ring=self.parent is None,
         )
         return False
 
 
+def _post_hoc_ids() -> tuple[int, int | None, int]:
+    """Ids of a record made after the fact: a child of the span open on
+    this thread, else a root of its own."""
+    sid = next(_IDS)
+    cause = current()
+    if cause is not None and cause.id is not None:
+        return sid, cause.id, cause.trace
+    return sid, None, sid
+
+
 def record_span(name: str, start: float, dur: float, **attrs: Any) -> None:
     """Record an already-measured interval (the scoring loop aggregates
-    per-stage timings with raw clock reads, then emits spans in bulk).
-    Chrome nesting still works — Perfetto nests by time containment."""
+    per-stage timings with raw clock reads, then emits spans in bulk):
+    no profiler annotation, since the interval is over."""
     if not _STATE["enabled"]:
         return
-    _record(name, start, dur, attrs, None, None, root_trace=False)
+    _record(name, start, dur, attrs, _post_hoc_ids())
 
 
 def record_serve_batch(
@@ -249,8 +330,10 @@ def record_serve_batch(
         )
     reg.counter("tptpu_serve_batches_total").inc()
     reg.counter("tptpu_serve_rows_total").inc(rows)
+    sid, parent_id, trace_id = _post_hoc_ids()
     rec = {
         "name": "serve/batch", "ts": started, "dur": total, "tid": _tid(),
+        "id": sid, "parent": parent_id, "trace": trace_id,
         "args": {"rows": rows, "entry": entry},
     }
     trace = {

@@ -267,6 +267,18 @@ class Workflow:
         stream cursor makes a mid-ingest crash resume with < 1 chunk of
         rework. ``stream=False`` forces full materialization even for an
         unbounded reader. See docs/robustness.md "Out-of-core fit"."""
+        # one trace per train: every span of this call on this thread
+        # (ingest, layers, fits, the selector's sweep) shares this root
+        with _tspans.span("train/run"):
+            return self._train(
+                checkpoint_dir, resume, on_mesh_mismatch, progress, run_dir,
+                stream,
+            )
+
+    def _train(
+        self, checkpoint_dir, resume, on_mesh_mismatch, progress, run_dir,
+        stream,
+    ) -> "WorkflowModel":
         if not self.result_features:
             raise ValueError("setResultFeatures must be called before train")
         if self.reader is None:
