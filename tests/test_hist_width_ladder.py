@@ -1,0 +1,264 @@
+"""The histogram width ladder (models/trees.py): a tree level's histograms
+are built at the smallest rung that holds its live node slots. Trees grown
+with the ladder must equal trees grown at the full chunk width, on every
+histogram implementation, and the counts the fit hands back must say what
+was built."""
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from transmogrifai_tpu.models import hist_pallas as HP
+from transmogrifai_tpu.models import trees as TR
+
+BINS = 8
+DEPTH = 9  # cap 512 slots: a real-valued target fills three rungs and more
+
+
+def _table(kind: str, n: int, seed: int = 0):
+    """(binned [N, F], target [N]) whose tree is balanced (every level
+    fills), skewed (one deep thin branch: deep levels hold few live
+    nodes), or short (the signal is one split deep)."""
+    rng = np.random.default_rng(seed)
+    f = 5
+    x = rng.normal(size=(n, f)).astype(np.float32)
+    if kind == "balanced":
+        t = x[:, 0] + x[:, 1] * x[:, 2] + 0.5 * np.sin(3 * x[:, 3])
+        t = t + 0.3 * rng.normal(size=n)
+    elif kind == "skewed":
+        # 95% of the rows are identical: only the rest can keep splitting
+        x[: int(n * 0.95)] = 0.0
+        t = x[:, 0] * x[:, 1] + x[:, 2]
+    else:
+        t = (x[:, 0] > 0.2).astype(np.float32) * 2.0
+    thr = TR.quantile_thresholds(x, BINS)
+    binned = TR.bin_data(jnp.asarray(x), jnp.asarray(thr))
+    return binned, t.astype(np.float32)
+
+
+def _grow(binned, grad, hess, row_mask, impl):
+    k = grad.shape[0]
+    fn = jax.jit(functools.partial(
+        TR._grow_tree_impl, max_depth=DEPTH, num_bins=BINS,
+        reg_lambda=1.0, gamma=0.0,
+        min_child_weight=np.asarray([1.0, 4.0], np.float32)[:k],
+        hist_impl=impl,
+    ))
+    feat_mask = jnp.ones((k, binned.shape[1]), jnp.float32)
+    tree, _node, slots = fn(
+        binned, jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(row_mask),
+        feat_mask,
+    )
+    return jax.tree.map(np.asarray, (tree, slots))
+
+
+@pytest.fixture()
+def interpret_kernels(monkeypatch):
+    """The Pallas builders in interpret mode, as the tree grower imports
+    them (CPU has no Mosaic)."""
+    monkeypatch.setattr(
+        HP, "build_histogram_pallas_binloop",
+        functools.partial(HP.build_histogram_pallas_binloop, interpret=True),
+    )
+
+
+@pytest.mark.parametrize("values", ["half", "real"])
+@pytest.mark.parametrize("kind", ["balanced", "skewed", "short"])
+@pytest.mark.parametrize("impl", ["scatter", "gemm", "pallas"])
+def test_ladder_grows_the_full_width_trees(
+    impl, kind, values, monkeypatch, interpret_kernels
+):
+    # the Pallas kernels take over above 4,096 rows, the GEMM serves below
+    n = 4608 if impl == "pallas" else 3072
+    binned, t = _table(kind, n)
+    if values == "half":
+        # one boosting round of binary:logistic: g = +-0.5, h = 0.25
+        grad = np.where(t > np.median(t), -0.5, 0.5).astype(np.float32)
+        hess = np.full(n, 0.25, np.float32)
+    else:
+        grad, hess = -t, np.ones(n, np.float32)
+    # lane 1 stops early: a quarter of the rows and a larger child weight
+    row_mask = np.ones((2, n), np.float32)
+    row_mask[1, n // 4:] = 0.0
+    grad2, hess2 = np.stack([grad, grad]), np.stack([hess, hess])
+
+    tree, slots = _grow(binned, grad2, hess2, row_mask, impl)
+    monkeypatch.setattr(TR, "_width_ladder", lambda chunk: (chunk,))
+    full, full_slots = _grow(binned, grad2, hess2, row_mask, impl)
+
+    assert (full_slots.built[full_slots.built > 0] >= 128).all()
+    if kind == "balanced" and values == "real":
+        assert len(set(slots.built.tolist())) >= 3, "three rungs engage"
+    np.testing.assert_array_equal(slots.live, full_slots.live)
+    assert (slots.built <= full_slots.built).all()
+    assert (slots.live <= slots.built).all()
+    np.testing.assert_array_equal(tree.split_feat, full.split_feat)
+    np.testing.assert_array_equal(tree.split_bin, full.split_bin)
+    if values == "half" or impl != "pallas":
+        # +-0.5 / 0.25 sums are exact in float32 whatever the order; the
+        # scatter and the GEMM add a node's rows in the same order at
+        # every width
+        np.testing.assert_array_equal(tree.leaf_value, full.leaf_value)
+    else:
+        # the leaf sums are made outside the histogram builds, from the
+        # same routing: equal trees give equal leaves. The tolerance is
+        # for what the compiler may reassociate between two programs.
+        np.testing.assert_allclose(
+            tree.leaf_value, full.leaf_value, rtol=1e-6, atol=1e-7
+        )
+
+
+def test_slot_counts_of_a_hand_checkable_tree():
+    """Four equal groups of rows told apart by two 2-bin columns: the root
+    splits, both children split, then nothing is left to gain. Level 0
+    (1 live slot) and level 1 (2) are built at the floor, level 2 (4 live,
+    no split) too, and the early exit skips the rest."""
+    n, depth = 4096, 7
+    rng = np.random.default_rng(5)
+    a = rng.integers(0, 2, size=n)
+    b = rng.integers(0, 2, size=n)
+    binned = jnp.asarray(np.stack([a, b], axis=1).astype(np.int32))
+    target = (2.0 * a + b).astype(np.float32)
+    fn = jax.jit(functools.partial(
+        TR._grow_tree_impl, max_depth=depth, num_bins=2, reg_lambda=0.0,
+        gamma=0.0, min_child_weight=1.0, min_info_gain=1e-6,
+        hist_impl="scatter",
+    ))
+    tree, _node, slots = fn(
+        binned, jnp.asarray(-target)[None], jnp.ones((1, n), jnp.float32),
+        jnp.ones((1, n), jnp.float32), jnp.ones((1, 2), jnp.float32),
+    )
+    # cap = 2**7 = 128 slots in one chunk: rungs 32, 64, 128
+    assert TR._width_ladder(128) == (32, 64, 128)
+    floor = TR._HIST_WIDTH_FLOOR
+    np.testing.assert_array_equal(
+        np.asarray(slots.live), [1, 2, 4, 0, 0, 0, 0]
+    )
+    np.testing.assert_array_equal(
+        np.asarray(slots.built), [floor, floor, floor, 0, 0, 0, 0]
+    )
+    assert (np.asarray(tree.split_feat)[0, 2] == -1).all()
+
+
+def test_a_full_level_is_built_at_its_own_width():
+    """Every row its own leaf candidate: 256 distinct codes on one column
+    keep every level full, so level d has 2**d live slots and is built at
+    the smallest rung that holds them; the last level needs both chunks."""
+    n, depth, bins = 4096, 9, 512
+    code = (np.arange(n) % 512).astype(np.int32)
+    binned = jnp.asarray(code[:, None])
+    target = code.astype(np.float32)
+    fn = jax.jit(functools.partial(
+        TR._grow_tree_impl, max_depth=depth, num_bins=bins, reg_lambda=0.0,
+        gamma=0.0, min_child_weight=1.0, min_info_gain=0.0,
+        hist_impl="gemm",
+    ))
+    _tree, _node, slots = fn(
+        binned, jnp.asarray(-target)[None], jnp.ones((1, n), jnp.float32),
+        jnp.ones((1, n), jnp.float32), jnp.ones((1, 1), jnp.float32),
+    )
+    # the GEMM caps a chunk at 128 slots: rungs 32, 64, 128; cap 512
+    np.testing.assert_array_equal(
+        np.asarray(slots.live), [1, 2, 4, 8, 16, 32, 64, 128, 256]
+    )
+    np.testing.assert_array_equal(
+        np.asarray(slots.built), [32, 32, 32, 32, 32, 32, 64, 128, 256]
+    )
+
+
+@pytest.mark.parametrize(
+    "chunk,ladder",
+    [
+        (1, (1,)), (8, (8,)), (32, (32,)), (64, (32, 64)),
+        (128, (32, 64, 128)), (256, (32, 64, 128, 256)),
+        (4096, (512, 1024, 2048, 4096)),
+    ],
+)
+def test_ladder_is_a_function_of_the_chunk_width(chunk, ladder):
+    assert TR._width_ladder(chunk) == ladder
+    assert len(ladder) <= 4 and ladder[-1] == chunk
+
+
+def test_small_programs_lower_to_one_level_body():
+    """A chunk at or under the floor has one rung: no branch over widths in
+    the program (the level scan holds one histogram build per group)."""
+    n = 512
+    binned, t = _table("balanced", n)
+    args = (
+        binned, jnp.asarray(-t)[None], jnp.ones((1, n), jnp.float32),
+        jnp.ones((1, n), jnp.float32), jnp.ones((1, 5), jnp.float32),
+    )
+
+    def scatters(depth):
+        text = jax.jit(functools.partial(
+            TR._grow_tree_impl, max_depth=depth, num_bins=BINS,
+            hist_impl="scatter",
+        )).lower(*args).as_text()
+        return text.count("stablehlo.scatter")
+
+    # depth 5: 32 slots, the floor; depth 7: 128 slots, three rungs
+    assert scatters(7) == 3 * scatters(5)
+
+
+def test_await_outputs_lands_the_counts_on_span_and_ledger():
+    from transmogrifai_tpu.models import gbdt
+    from transmogrifai_tpu.telemetry import export as texport
+    from transmogrifai_tpu.telemetry import spans as tspans
+
+    n = 3072
+    binned, t = _table("balanced", n)
+    y = (t > np.median(t)).astype(np.float32)
+    trees, margin, slots = TR.fit_boosted_batched(
+        binned, y, np.ones((2, n), np.float32), num_rounds=2,
+        max_depth=DEPTH, num_bins=BINS, eta=0.3, return_slots=True,
+    )
+    assert np.asarray(slots.live).shape == (2, DEPTH)
+    tspans.reset_for_tests()
+    before = TR.hist_slot_stats().snapshot()
+    stack = {"outputs": margin, "hist_slots": slots}
+    out = gbdt.await_stack_outputs(stack)
+    again = gbdt.await_stack_outputs(stack)  # the counts ride the first read
+    assert out.shape == (2, n) and again.shape == (2, n)
+    live = int(np.asarray(slots.live).sum())
+    built = int(np.asarray(slots.built).sum())
+    assert 0 < live <= built
+    recs = [
+        r["args"] for r in tspans.snapshot_events()
+        if r["name"] == "tree/await_outputs"
+    ]
+    assert [a.get("slots_live") for a in recs] == [live, None]
+    assert recs[0]["slots_built"] == built
+    now = TR.hist_slot_stats().snapshot()
+    assert now["histSlotsLive"] - before["histSlotsLive"] == live
+    assert now["histSlotsBuilt"] - before["histSlotsBuilt"] == built
+    text = texport.render_prometheus()
+    assert f"tptpu_tree_hist_slots_built {now['histSlotsBuilt']}" in text
+
+
+@pytest.mark.parametrize(
+    "slots,tiles",
+    [(256, (1024, 8)), (128, (2048, 16)), (64, (2048, 104)),
+     (32, (2048, 104)), (8, (2048, 104))],
+)
+def test_kernel_tiles_by_width_at_the_flagship_shape(slots, tiles):
+    """302 wide columns x 32 bins, four value variants: the full width
+    keeps the tiles it had, a narrower build gets a fuller feature tile,
+    never over the MXU's 128 rows, evenly filled (three tiles of 104 for
+    302 columns, not 128 + 128 + 46)."""
+    assert HP.binloop_tiles(302, slots, 32) == tiles
+
+
+@pytest.mark.parametrize("f", [3, 55, 302, 500])
+@pytest.mark.parametrize("slots", [8, 64, 256])
+def test_kernel_tiles_are_sublane_multiples_within_the_mxu(f, slots):
+    row_tile, feat_tile = HP.binloop_tiles(f, slots, 32)
+    assert row_tile % 128 == 0 and 128 <= row_tile <= 2048
+    assert feat_tile % HP.FEAT_TILE == 0 and 8 <= feat_tile <= 128
+    # evening the tiles never adds a tile or pads more than one sublane
+    # group per tile
+    f8 = -(-f // 8) * 8
+    tiles = -(-f8 // feat_tile)
+    assert tiles * feat_tile - f8 < 8 * tiles

@@ -71,7 +71,10 @@ class BinCacheStats(_tm.LedgerCore):
 
 
 _BIN_STATS = BinCacheStats()
-_tm.REGISTRY.register_source("tree", _BIN_STATS.snapshot)
+_tm.REGISTRY.register_source(
+    "tree",
+    lambda: {**_BIN_STATS.snapshot(), **TR.hist_slot_stats().snapshot()},
+)
 
 
 def bin_cache_stats() -> BinCacheStats:
@@ -186,6 +189,15 @@ class _LazySlice:
                     )
             cache[self.lane] = out
         return out
+
+
+def await_stack_outputs(stack: dict):
+    """A fitted stack's [K, N] training outputs on the host. The first
+    read of a stack hands its fit's ``HistSlots`` to the same span: the
+    program that made the outputs made them too."""
+    return TR.await_outputs(
+        stack["outputs"], hist_slots=stack.pop("hist_slots", None)
+    )
 
 
 def _resolve_trees(t):
@@ -858,7 +870,7 @@ class _TreeEstimator(PredictorEstimator):
                     # the fit program already computed every lane's raw
                     # outputs on the training matrix — one tiny download,
                     # no traversal program, no x upload
-                    outputs[sid] = TR.await_outputs(stack["outputs"])
+                    outputs[sid] = await_stack_outputs(stack)
                     continue
                 k = stack["k"]
                 eta_v = np.ones(k, dtype=np.float32)
@@ -923,9 +935,10 @@ class _TreeEstimator(PredictorEstimator):
         (fit k = mask_index * n_points + point_index), run the family's
         batched trainer, slice the [K, ...] tree pytree back out.
 
-        ``run_batched(binned, m0, row_mask_K, knob) -> ([K, ...] tree
-        pytree, [K, N] training outputs-or-None)`` where ``knob(name)``
-        returns the [K] float32 array for a param;
+        ``run_batched(binned, m0, row_mask_K, knob, fgroups) -> ([K, ...]
+        tree pytree, [K, N] training outputs-or-None, the fit's
+        ``HistSlots``)`` where ``knob(name)`` returns the [K] float32 array
+        for a param;
         ``make_model(thresholds, sliced_trees, merged_params, mask_index)``.
         The training outputs (every lane's raw model output on the full
         training matrix, computed by the fit program itself) ride the stack
@@ -963,7 +976,9 @@ class _TreeEstimator(PredictorEstimator):
             depth=int(m0["max_depth"]), bins=int(m0["max_bins"]),
             hist_impl=TR._resolved_impl(),
         ):
-            trees, outputs = run_batched(binned, m0, row_mask_k, knob, fgroups)
+            trees, outputs, slots = run_batched(
+                binned, m0, row_mask_k, knob, fgroups
+            )
         # the stacked trees STAY on device for sweep_eval_batched (one
         # validation program per stack); per-model tree arrays materialize
         # lazily via _LazySlice — eager host pulls download the whole
@@ -986,6 +1001,9 @@ class _TreeEstimator(PredictorEstimator):
             # flagship shapes). sweep_eval_batched downloads it instead of
             # dispatching a traversal program + x upload per stack.
             "outputs": outputs,
+            # what the fit's histogram builds were sized for: read with
+            # the outputs (await_stack_outputs)
+            "hist_slots": slots,
         }
         models = [
             [
@@ -1087,7 +1105,7 @@ class XGBoostClassifier(_TreeEstimator):
         yj = np.asarray(y, dtype=np.float32)
 
         def run_batched(binned, m0, row_mask_k, knob, fgroups):
-            trees, margin = TR.fit_boosted_batched(
+            trees, margin, slots = TR.fit_boosted_batched(
                 binned, yj, row_mask_k,
                 num_rounds=int(m0["num_round"]),
                 max_depth=int(m0["max_depth"]),
@@ -1097,10 +1115,10 @@ class XGBoostClassifier(_TreeEstimator):
                 min_child_weight=knob("min_child_weight"),
                 min_info_gain=knob("min_info_gain"),
                 objective="binary:logistic",
-                feature_groups=fgroups,
+                feature_groups=fgroups, return_slots=True,
             )
             # the final margin IS each lane's raw output on every row
-            return trees, margin
+            return trees, margin, slots
 
         return self._batched_group_fit(
             x, masks, group_points, run_batched,
@@ -1146,7 +1164,7 @@ class XGBoostRegressor(_TreeEstimator):
 
         def run_batched(binned, m0, row_mask_k, knob, fgroups):
             base_k = np.repeat(base_scores, n_pts).astype(np.float32)
-            trees, margin = TR.fit_boosted_batched(
+            trees, margin, slots = TR.fit_boosted_batched(
                 binned, yj, row_mask_k,
                 num_rounds=int(m0["num_round"]),
                 max_depth=int(m0["max_depth"]),
@@ -1157,9 +1175,9 @@ class XGBoostRegressor(_TreeEstimator):
                 min_info_gain=knob("min_info_gain"),
                 base_score=base_k,
                 objective="reg:squarederror",
-                feature_groups=fgroups,
+                feature_groups=fgroups, return_slots=True,
             )
-            return trees, margin
+            return trees, margin, slots
 
         return self._batched_group_fit(
             x, masks, group_points, run_batched,
@@ -1401,7 +1419,7 @@ class RandomForestClassifier(_TreeEstimator):
                     None if uniform
                     else depth_arr.astype(np.int32)
                 ),
-                return_outputs=True,
+                return_outputs=True, return_slots=True,
             )
 
         return self._batched_group_fit(
@@ -1454,7 +1472,7 @@ class RandomForestClassifier(_TreeEstimator):
             rounds=int(m0["num_trees"]), depth=int(m0["max_depth"]),
             bins=int(m0["max_bins"]), hist_impl=TR._resolved_impl(),
         ):
-            trees, outs = TR.fit_forest_batched(
+            trees, outs, slots = TR.fit_forest_batched(
                 binned, tg, rm,
                 num_trees=int(m0["num_trees"]),
                 max_depth=int(m0["max_depth"]),
@@ -1466,14 +1484,15 @@ class RandomForestClassifier(_TreeEstimator):
                 seed=int(m0["seed"]),
                 lowp=True,
                 feature_groups=fgroups,
-                return_outputs=True,
+                return_outputs=True, return_slots=True,
             )
         leaves = jax.tree.leaves(trees)
         is_dev = bool(leaves) and hasattr(leaves[0], "devices")
         if (is_dev and len(leaves[0].devices()) > 1) or not is_dev:
             trees = TR.await_outputs(trees)
         stack = {"trees": trees, "thresholds": thresholds,
-                 "k": n_masks * n_pts * c, "outputs": outs}
+                 "k": n_masks * n_pts * c, "outputs": outs,
+                 "hist_slots": slots}
         models = [
             [
                 ForestClassifierModel(
@@ -1575,7 +1594,7 @@ class RandomForestRegressor(_TreeEstimator):
                     None if uniform
                     else depth_arr.astype(np.int32)
                 ),
-                return_outputs=True,
+                return_outputs=True, return_slots=True,
             )
 
         return self._batched_group_fit(

@@ -362,36 +362,38 @@ def _hist_binloop_kernel(binned_ref, node_ref, g_ref, h_ref, outg_ref,
             outh_ref[0, b, :, :] = outh_ref[0, b, :, :] + hh
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_nodes", "num_bins", "row_tile", "lowp", "interpret"),
-)
-def build_histogram_pallas_binloop(
-    binned: jax.Array,   # [N, F] int32 codes in [0, num_bins), SHARED
-    node: jax.Array,     # [K, N] int32 node slot per row per fit (-1 = dead)
-    grad: jax.Array,     # [K, N] f32 (pre-masked)
-    hess: jax.Array,     # [K, N] f32
-    num_nodes: int,
-    num_bins: int,
-    row_tile: int | None = None,
-    lowp: bool = False,
-    interpret: bool = False,
-) -> jax.Array:
-    """hist [K, num_nodes, F, num_bins, 2] via the bin-loop kernel (see
-    _hist_binloop_kernel). Same contract as build_histogram_pallas_batched."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+def binloop_tiles(
+    f: int, num_nodes: int, num_bins: int, lowp: bool = False,
+    row_tile: int | None = None, feat_tile: int | None = None,
+) -> tuple[int, int]:
+    """(row_tile, feat_tile) of the bin-loop kernel at ``num_nodes`` node
+    slots, from shapes alone. Both grow as the node axis narrows, so a
+    level built at the width of its live nodes gains twice: a narrower
+    ``[T, nvar·M]`` one-hot operand, and more features (the dot's row
+    dimension) per grid step. At 1,002,701 x 302 x 32 bins, 4 lanes and 4
+    variants, on a v5e (PR 26, tools/bench_hist_kernel.py; seconds a call):
 
-    k_fits, n = node.shape
-    f = binned.shape[1]
+        slots  row_tile  feat_tile  s
+          256      1024          8  2.406
+          128      2048         16  0.648
+           64      2048        104  0.176
+           32      2048        104  0.134
+
+    The time follows slots / feat_tile, not slots alone: at 256 slots the
+    one-hot temporaries take 5.2 of the model's 6 MB and leave the dot 8
+    rows of the MXU's 128. Forced tiles say what that costs: 256 slots at
+    (256, 64) 0.803 s, 128 slots at (1024, 64) 0.325 s (ROADMAP S1)."""
     m_pad = _round_up(max(num_nodes, 8), 8)
     nvar = 2 if lowp else 4
     if row_tile is None:
-        # 2048 measured best at the 1M-row scale shapes (1024: 190 ms,
-        # 2048: 141 ms, 4096: 263 ms per build at 1M×500×32, M=64)
+        # the [T, nvar·M] stacked operand and the [T, M] one-hot copies are
+        # the big VMEM temporaries: T·nvar·M stays bounded, lane-aligned
         row_tile = max(
             128, min(2048, ((1 << 20) // (nvar * m_pad)) // 128 * 128)
         )
+
+    if feat_tile is not None:
+        return row_tile, feat_tile
 
     def vmem_bytes(ft: int) -> int:
         # binned block + 2 output accumulators + stacked operand + comb
@@ -405,14 +407,59 @@ def build_histogram_pallas_binloop(
     # budget 6 MB by this model: Mosaic double-buffers grid blocks and
     # carries dot/select temporaries the model does not count (measured
     # ~2x) — 12 MB nominal blew the 16 MB scoped-vmem stack
+    # ... and at most 128 features, the MXU's row dimension: more gains
+    # nothing, and under 128 node slots the output blocks pad their lane
+    # axis to 128, which the model does not count (256 features at 16
+    # slots asked for 20 MB of scoped VMEM at 1M x 302 and did not compile)
     feat_tile = FEAT_TILE
     while (
-        feat_tile * 2 <= _round_up(f, FEAT_TILE)
+        feat_tile * 2 <= min(_round_up(f, FEAT_TILE), 128)
         and vmem_bytes(feat_tile * 2) <= (6 << 20)
     ):
         feat_tile *= 2
     while vmem_bytes(feat_tile) > (6 << 20) and row_tile > 512:
         row_tile //= 2
+    # the same number of feature tiles, evenly filled: a 128-feature tile
+    # pads 302 columns to 384 (a quarter more dots, and a larger [F, N]
+    # copy of the codes), three tiles of 104 pad them to 312 (measured at
+    # 32 slots: 0.144 s a call at 128, 0.134 at 104, 0.178 at 64)
+    f8 = _round_up(f, FEAT_TILE)
+    feat_tile = _round_up(-(-f8 // -(-f8 // feat_tile)), FEAT_TILE)
+    return row_tile, feat_tile
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "num_nodes", "num_bins", "row_tile", "lowp", "interpret", "feat_tile",
+    ),
+)
+def build_histogram_pallas_binloop(
+    binned: jax.Array,   # [N, F] int32 codes in [0, num_bins), SHARED
+    node: jax.Array,     # [K, N] int32 node slot per row per fit (-1 = dead)
+    grad: jax.Array,     # [K, N] f32 (pre-masked)
+    hess: jax.Array,     # [K, N] f32
+    num_nodes: int,
+    num_bins: int,
+    row_tile: int | None = None,
+    lowp: bool = False,
+    interpret: bool = False,
+    feat_tile: int | None = None,
+) -> jax.Array:
+    """hist [K, num_nodes, F, num_bins, 2] via the bin-loop kernel (see
+    _hist_binloop_kernel). Same contract as build_histogram_pallas_batched;
+    ``row_tile`` / ``feat_tile`` override ``binloop_tiles`` (timing probes:
+    tools/bench_hist_kernel.py)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    k_fits, n = node.shape
+    f = binned.shape[1]
+    m_pad = _round_up(max(num_nodes, 8), 8)
+    row_tile, feat_tile = binloop_tiles(
+        f, num_nodes, num_bins, lowp=lowp, row_tile=row_tile,
+        feat_tile=feat_tile,
+    )
     n_pad = _round_up(max(n, row_tile), row_tile)
     f_pad = _round_up(f, feat_tile)
 

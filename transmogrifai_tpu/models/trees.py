@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry import metrics as _tm
 from ..telemetry import runlog as _runlog
 from ..telemetry import spans as _tspans
 
@@ -44,12 +45,75 @@ class Tree(NamedTuple):
     leaf_value: jax.Array  # [2^depth] float32
 
 
-def await_outputs(value):
+class HistSlots(NamedTuple):
+    """What a fit's histogram builds were sized for, per tree and level
+    (``[..., depth]`` int32): the level's live compact node slots (the
+    widest lane's) and the slots its builds were made at (the rung of
+    ``_width_ladder`` taken, or ``chunk_nodes`` per chunk that ran; 0 for
+    a level the early exit skipped). ``live / built`` is the occupancy of
+    the histogram kernel's node axis."""
+
+    live: jax.Array
+    built: jax.Array
+
+
+# The narrowest width a level's histograms are built at. At 32 slots the
+# bin-loop kernel's [T, nvar·M] operand is exactly one 128-lane tile
+# (nvar 4); below it the lanes only pad (on a v5e at 1M x 302 x 32 bins a
+# build takes 0.134 s at 32 slots and at 8, 0.176 s at 64: PR 26).
+_HIST_WIDTH_FLOOR = 32
+_HIST_WIDTH_RUNGS = 4
+
+
+def _width_ladder(chunk_nodes: int) -> tuple[int, ...]:
+    """The widths (node slots, ascending) a level's histograms may be built
+    at: ``chunk_nodes`` and up to three halvings of it, none under the
+    floor. Each rung is one more copy of the level body in the executable,
+    so there are at most four; a ``chunk_nodes`` at or under the floor has
+    the one rung."""
+    rungs = [chunk_nodes]
+    while (
+        len(rungs) < _HIST_WIDTH_RUNGS
+        and rungs[0] // 2 >= _HIST_WIDTH_FLOOR
+    ):
+        rungs.insert(0, rungs[0] // 2)
+    return tuple(rungs)
+
+
+class HistSlotStats(_tm.LedgerCore):
+    """Cumulative ``histSlotsLive`` / ``histSlotsBuilt`` of the fits whose
+    outputs ``await_outputs`` landed (part of the ``tree`` ledger:
+    ``models/gbdt.py`` registers it beside the bin cache's counters)."""
+
+    def __init__(self) -> None:
+        super().__init__(("histSlotsLive", "histSlotsBuilt"))
+
+    def record(self, live: int, built: int) -> None:
+        with self._lock:
+            self._counts["histSlotsLive"] += live
+            self._counts["histSlotsBuilt"] += built
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return dict(self._counts)
+
+
+_HIST_SLOT_STATS = HistSlotStats()
+
+
+def hist_slot_stats() -> HistSlotStats:
+    return _HIST_SLOT_STATS
+
+
+def await_outputs(value, hist_slots: HistSlots | None = None):
     """``value`` (an array or a pytree of arrays) on the host. A read of a
     device result blocks until the program that makes it has run, so it is
     a ``tree/await_outputs`` span — how long the host waited for the
     device — and one download on the run ledger's transfer census. What is
-    on the host already passes through."""
+    on the host already passes through. ``hist_slots`` are the counts the
+    same fit program returned: once its outputs have landed they are there
+    too, and their sums go onto the span (``slots_live``, ``slots_built``)
+    and the ``tree`` ledger."""
     leaves = jax.tree.leaves(value)
     if all(isinstance(a, np.ndarray) for a in leaves):
         return value
@@ -59,6 +123,11 @@ def await_outputs(value):
         nbytes = sum(int(a.nbytes) for a in jax.tree.leaves(out))
         sp.attrs["bytes"] = nbytes
         _runlog.record_download(nbytes, _tspans.clock() - t0)
+        if hist_slots is not None and _tspans.enabled():
+            live = int(np.asarray(hist_slots.live).sum())
+            built = int(np.asarray(hist_slots.built).sum())
+            sp.attrs.update(slots_live=live, slots_built=built)
+            _HIST_SLOT_STATS.record(live, built)
     return out
 
 
@@ -254,10 +323,11 @@ def _grow_tree_impl(
     axis_size: int = 1,
     feature_groups: tuple[jax.Array, jax.Array] | None = None,
     max_depth_v: jax.Array | None = None,
-) -> Tree:
+) -> tuple[Tree, jax.Array, HistSlots]:
     """Tree-growth body shared by the single-device jit wrapper and the
-    shard_map'd path. ``max_depth_v`` ([K] int32, optional) caps each
-    LANE's depth at runtime: levels >= a lane's cap emit no splits, so one
+    shard_map'd path: (tree, each row's final leaf slot, the histogram
+    builds' slot counts per level). ``max_depth_v`` ([K] int32, optional)
+    caps each LANE's depth at runtime: levels >= a lane's cap emit no splits, so one
     compiled program at the grid's max depth serves every depth point of a
     hyperparameter sweep (3 RF depth groups -> one program: acquisition,
     not execution, is the flagship's wall-clock). With ``axis_name`` set, the function runs per-shard
@@ -481,11 +551,11 @@ def _grow_tree_impl(
             hist = build_histogram_gemm(gbinned, loc, chunk_nodes, gb, codes1h)
         elif impl == "pallas":
             # bin-loop kernel for narrow bin counts: one whole-block
-            # compare per bin instead of the select-chain lane assembly —
-            # 381 -> 141 ms per build at 1M×500×32, bit-identical
-            # histograms (see _hist_binloop_kernel). Its cost is linear in
-            # num_bins, so wide-bin fits (e.g. 256-bin sketches) keep the
-            # lane-packed kernel (measured 2.2x better there).
+            # compare per bin instead of the select-chain lane assembly,
+            # bit-identical histograms (see _hist_binloop_kernel; seconds
+            # a build by node-slot width: hist_pallas.binloop_tiles). Its
+            # cost is linear in num_bins, so wide-bin fits (e.g. 256-bin
+            # sketches) keep the lane-packed kernel.
             if gb <= 64:
                 hist = build_histogram_pallas_binloop(
                     gbinned, loc, g, h, chunk_nodes, gb, lowp=lowp
@@ -574,21 +644,33 @@ def _grow_tree_impl(
             split_feat=jnp.full((k_fits, 0, 1), -1, dtype=jnp.int32),
             split_bin=jnp.zeros((k_fits, 0, 1), dtype=jnp.int32),
             leaf_value=leaf_value0,
-        ), jnp.zeros((k_fits, n), dtype=jnp.int32)
+        ), jnp.zeros((k_fits, n), dtype=jnp.int32), HistSlots(
+            live=jnp.zeros((0,), dtype=jnp.int32),
+            built=jnp.zeros((0,), dtype=jnp.int32),
+        )
 
     # ---- lax.scan over levels with ONE shared body: an unrolled level
     # loop multiplies the compiled body (and its compile time and
-    # executable size) by max_depth. Every
-    # level therefore uses the SAME static slot layout: `cap` compact slots
-    # in `num_chunks` fixed chunks, with node compaction numbering live
-    # slots densely from 0 so the per-chunk occupancy cond skips the
-    # provably-empty tail (level 0 has one live node → one chunk runs).
-    # Shallow levels pay a full-width chunk where the unrolled loop paid
-    # 2^d slots; that is kernel-grid noise next to shipping a 10× bigger
-    # executable.
+    # executable size) by max_depth. Every level therefore uses the SAME
+    # static slot layout: `cap` compact slots in `num_chunks` fixed chunks,
+    # with node compaction numbering live slots densely from 0 so the
+    # per-chunk occupancy cond skips the provably-empty tail.
+    #
+    # A level with few live slots does not pay for a whole chunk: its
+    # histograms are built and searched at the smallest rung of `ladder`
+    # that holds them (live_level below). A build costs at least in
+    # proportion to its width (the kernel's one-hot operand is
+    # [T, nvar·width], and its feature tile grows as the width falls:
+    # 2.41 s at 256 slots, 0.65 at 128, 0.18 at 64, 0.13 at 32 on a v5e at
+    # 1M x 302 x 32 bins), and in a depth-10 fit eight of the eleven
+    # builds have 128 live slots or fewer. The sharded path keeps the
+    # full width: its psums may not sit under a data-dependent branch.
     n_nodes = cap
     chunk_nodes = min(chunk_cap, n_nodes)
     num_chunks = (n_nodes + chunk_nodes - 1) // chunk_nodes
+    ladder = (
+        _width_ladder(chunk_nodes) if axis_name is None else (chunk_nodes,)
+    )
 
     def compact_local(hist_node):
         """Dense live-slot numbering via occupancy + cumsum rank. Slot =
@@ -632,9 +714,13 @@ def _grow_tree_impl(
             # compaction
             local = jnp.where(active, local, sentinel)
 
-        def live_level():
+        # live compact slots at this level, the widest lane's: slots are
+        # numbered densely from 0, so every live one is below this count
+        n_live = live.sum(axis=1, dtype=jnp.int32).max()
+
+        def chunk_loop():
             def chunk_body(ci, fb):
-                feats_a, bins_a = fb
+                feats_a, bins_a, built = fb
                 c0 = ci * chunk_nodes
                 if axis_name is None:
                     occupied = (
@@ -652,13 +738,16 @@ def _grow_tree_impl(
                             ),
                         ),
                     )
+                    built = built + jnp.where(occupied, chunk_nodes, 0)
                 else:
                     # the sharded path always computes — its psums can't
                     # sit under a data-dependent cond
                     cf, cb = chunk_stats(local, c0, chunk_nodes)
+                    built = built + chunk_nodes
                 return (
                     jax.lax.dynamic_update_slice(feats_a, cf, (0, c0)),
                     jax.lax.dynamic_update_slice(bins_a, cb, (0, c0)),
+                    built,
                 )
 
             feats_a0 = jnp.full(
@@ -667,10 +756,34 @@ def _grow_tree_impl(
             bins_a0 = jnp.zeros(
                 (k_fits, num_chunks * chunk_nodes), dtype=jnp.int32
             )
-            feats_a, bins_a = jax.lax.fori_loop(
-                0, num_chunks, chunk_body, (feats_a0, bins_a0)
+            feats_a, bins_a, built = jax.lax.fori_loop(
+                0, num_chunks, chunk_body, (feats_a0, bins_a0, jnp.int32(0))
             )
-            return feats_a[:, :n_nodes], bins_a[:, :n_nodes]
+            return feats_a[:, :n_nodes], bins_a[:, :n_nodes], built
+
+        def one_build(width):
+            """The whole level in ONE build at ``width`` slots (a rung that
+            holds every live slot), padded to the [K, n_nodes] the scan
+            carries."""
+            def run():
+                cf, cb = chunk_stats(local, 0, width)
+                pad = ((0, 0), (0, n_nodes - width))
+                return (
+                    jnp.pad(cf, pad, constant_values=-1),
+                    jnp.pad(cb, pad),
+                    jnp.int32(width),
+                )
+
+            return run
+
+        def live_level():
+            if len(ladder) == 1:
+                return chunk_loop()
+            narrow = ladder[:-1]
+            rung = sum((n_live > w).astype(jnp.int32) for w in narrow)
+            return jax.lax.switch(
+                rung, [one_build(w) for w in narrow] + [chunk_loop]
+            )
 
         # ---- early level exit: no-split is hereditary, so once a level
         # produces zero splits every deeper level is all-leaves — skip the
@@ -678,14 +791,15 @@ def _grow_tree_impl(
         # (replicated-predicate collectives under shard_map are not worth
         # the coupling).
         if axis_name is not None:
-            feats_c, bins_c = live_level()
+            feats_c, bins_c, built = live_level()
         else:
-            feats_c, bins_c = jax.lax.cond(
+            feats_c, bins_c, built = jax.lax.cond(
                 alive,
                 live_level,
                 lambda: (
                     jnp.full((k_fits, n_nodes), -1, dtype=jnp.int32),
                     jnp.zeros((k_fits, n_nodes), dtype=jnp.int32),
+                    jnp.int32(0),
                 ),
             )
         if max_depth_v is not None:
@@ -724,9 +838,9 @@ def _grow_tree_impl(
             go_right = active & (row_feat >= 0) & (code > row_thr)
             node = node * 2 + go_right.astype(jnp.int32)
             active = active & (row_feat >= 0)
-        return (node, active, alive), (feats_d, bins_d)
+        return (node, active, alive), (feats_d, bins_d, n_live, built)
 
-    (node, active, _), (feats_s, bins_s) = jax.lax.scan(
+    (node, active, _), (feats_s, bins_s, live_s, built_s) = jax.lax.scan(
         level_body,
         (
             jnp.zeros((k_fits, n), dtype=jnp.int32),
@@ -749,7 +863,7 @@ def _grow_tree_impl(
     # `node` is each row's final leaf slot — boosting's margin update reuses
     # it (leaf_value lookup) instead of re-traversing the tree (measured
     # ~100 ms/round of serialized gathers at 1M rows)
-    return tree, node
+    return tree, node, HistSlots(live=live_s, built=built_s)
 
 
 def predict_tree(binned: jax.Array, tree: Tree) -> jax.Array:
@@ -1149,7 +1263,7 @@ def _forest_trees_scan(
     min_info_gain,
     feature_groups=None, max_depth_v=None, subset_n=None, subset_w=None, *,
     num_trees, max_depth, num_bins, bootstrap, lowp, hist_impl=None,
-) -> tuple[Tree, jax.Array]:
+) -> tuple[Tree, jax.Array, HistSlots]:
     """The whole bagged forest as ONE program: ``lax.scan`` over the
     per-tree PRNG keys with a single tree-growth body (the same shape as
     the boosting rounds scan, which runs 200 rounds in under a second on
@@ -1166,10 +1280,10 @@ def _forest_trees_scan(
     masking gains over the full one-hot width (a ~30× FLOP cut on
     transmogrified matrices, where most columns are indicators).
 
-    Returns (Tree arrays [K, T, ...], training outputs [K, N]) — the
-    outputs are each lane's mean-leaf prediction over ALL rows, read from
-    the grower's own final routing, so the CV sweep needs no separate
-    eval traversal program."""
+    Returns (Tree arrays [K, T, ...], training outputs [K, N], HistSlots
+    [T, depth]) — the outputs are each lane's mean-leaf prediction over
+    ALL rows, read from the grower's own final routing, so the CV sweep
+    needs no separate eval traversal program."""
     k_fits, n = row_mask.shape
     f = binned.shape[1]
     # target: [N] shared, or [K, N] per-lane (one-vs-rest class indicators
@@ -1202,7 +1316,7 @@ def _forest_trees_scan(
             row_mask, n, f, bootstrap,
         )
         grp = (sn, sw) if sn is not None else feature_groups
-        tree, node = _grow_tree_impl(
+        tree, node, slots = _grow_tree_impl(
             binned, gb, ones, rm_t, fm_t,
             max_depth=max_depth, num_bins=num_bins,
             reg_lambda=0.0, gamma=0.0,
@@ -1214,14 +1328,16 @@ def _forest_trees_scan(
         # routing (leaf lookup — no re-traversal)
         with jax.named_scope("tree/outputs"):
             pred_t = _small_table_lookup(tree.leaf_value, node)
-        return None, (tree, pred_t)
+        return None, (tree, pred_t, slots)
 
-    _, (trees, preds) = jax.lax.scan(
+    _, (trees, preds, slots) = jax.lax.scan(
         body, None, (tkeys, subset_n, subset_w)
     )  # [T, K, ...]
     with jax.named_scope("tree/outputs"):
         outs = preds.mean(axis=0)  # [K, N] forest mean-leaf outputs
-    return jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), trees), outs
+    return (
+        jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), trees), outs, slots
+    )
 
 
 def fit_forest_batched(
@@ -1242,13 +1358,16 @@ def fit_forest_batched(
     feature_groups=None,
     max_depth_v=None,     # [K] int32: per-lane depth caps (see _grow_tree_impl)
     return_outputs: bool = False,
+    return_slots: bool = False,
 ) -> Tree:
     """K random forests batched over the fit axis, the whole bagged forest
     as ONE scan-over-trees program (_forest_trees_scan — one tree-growth
     body, no per-tree dispatches, no tree-folded wide kernels). Returns
     stacked Tree arrays [K, T, ...]; with ``return_outputs`` also the
     [K, N] training-matrix mean-leaf outputs (each lane's predictions on
-    every row — the CV sweep evaluates from these instead of re-traversing).
+    every row — the CV sweep evaluates from these instead of re-traversing),
+    and with ``return_slots`` last the fit's ``HistSlots`` [T, depth] (for
+    ``await_outputs``; None from a sharded fit, which has recorded them).
 
     A static ``colsample_rate`` < 1 with ``feature_groups`` samples an
     EXACT-COUNT feature subset per tree host-side (Spark's
@@ -1329,10 +1448,11 @@ def fit_forest_batched(
             bootstrap=bootstrap, lowp=lowp, feature_groups=feature_groups,
             subset_n=subset_n, subset_w=subset_w,
         )
-        return (trees, outs) if return_outputs else trees
+        # the sharded fit has pulled its results, and recorded its slots
+        return _fit_result(trees, outs, None, return_outputs, return_slots)
     from ..utils.aot import aot_call
 
-    trees, outs = aot_call(
+    trees, outs, slots = aot_call(
         "forest_scan", _forest_trees_scan,
         (binned, target, row_mask, seed_arr, sub, col, mi, mg,
          feature_groups, max_depth_v, subset_n, subset_w),
@@ -1345,7 +1465,15 @@ def fit_forest_batched(
              # see the trace-time impl choice
              hist_impl=_resolved_impl()),
     )
-    return (trees, outs) if return_outputs else trees
+    return _fit_result(trees, outs, slots, return_outputs, return_slots)
+
+
+def _fit_result(trees, outputs, slots, return_outputs, return_slots):
+    """trees, then what the caller asked for of (outputs, slots). A sharded
+    fit has no slots left to hand on: it pulls its results to the host
+    itself, and ``await_outputs`` recorded them there."""
+    extra = (outputs,) * return_outputs + (slots,) * return_slots
+    return (trees, *extra) if extra else trees
 
 
 @partial(
@@ -1416,11 +1544,12 @@ def _boost_chunk_body(
     min_child_weight, min_info_gain, feature_groups=None, *,
     num_rounds, max_depth, num_bins, objective,
     axis_name=None, axis_size=1, hist_impl=None,
-) -> tuple[Tree, jax.Array]:
+) -> tuple[Tree, jax.Array, HistSlots]:
     """A chunk of boosting rounds for all K fits (lax.scan inside one
     program) — shared by the single-device jit and the shard_map'd path
     (axis_name set: per-level histograms psum over the mesh axis; margins,
-    gradients and predictions stay row-local)."""
+    gradients and predictions stay row-local). Returns (trees [K, R, ...],
+    margins [K, N], HistSlots [R, depth])."""
     k_fits, n = row_mask.shape
     f = binned.shape[1]
     feat_mask = jnp.ones((k_fits, f), dtype=jnp.float32)
@@ -1434,7 +1563,7 @@ def _boost_chunk_body(
     def round_step(margin, _):
         with jax.named_scope("tree/gradients"):
             g, h = grads(margin)
-        tree, leaf_slot = _grow_tree_impl(
+        tree, leaf_slot, slots = _grow_tree_impl(
             binned, g, h, row_mask, feat_mask,
             max_depth=max_depth, num_bins=num_bins,
             reg_lambda=reg_lambda, gamma=gamma,
@@ -1447,13 +1576,15 @@ def _boost_chunk_body(
         with jax.named_scope("tree/outputs"):
             step = _small_table_lookup(tree.leaf_value, leaf_slot)  # [K, N]
             margin = margin + eta_v[:, None] * step
-        return margin, tree
+        return margin, (tree, slots)
 
-    margin, trees = jax.lax.scan(round_step, margin0, None, length=num_rounds)
+    margin, (trees, slots) = jax.lax.scan(
+        round_step, margin0, None, length=num_rounds
+    )
     # [R, K, ...] -> [K, R, ...] INSIDE the program: an eager transpose
     # after the fact costs a compile-cache round-trip per shape
     trees = jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), trees)
-    return trees, margin  # trees [K, R, ...]
+    return trees, margin, slots  # trees [K, R, ...]
 
 
 _boost_rounds_batched = partial(
@@ -1501,11 +1632,14 @@ def fit_boosted_batched(
     objective: str = "binary:logistic",
     mesh=None,
     feature_groups=None,
+    return_slots: bool = False,
 ) -> tuple[Tree, jax.Array]:
     """K boosting runs batched over the fit axis: every round grows all K
     trees in one histogram build; rounds scan in fixed-size chunks so each
     compiled program stays modest. Returns Tree arrays [K, R, ...] and the
-    training margins [K, N].
+    training margins [K, N]; with ``return_slots`` also the fit's
+    ``HistSlots`` [R, depth] (for ``await_outputs``; None from a sharded
+    fit, which has recorded them).
 
     With ``mesh`` set, rows shard over the mesh's data axis: gradients and
     margins live sharded, per-level histograms psum over ICI, and trees come
@@ -1529,13 +1663,14 @@ def fit_boosted_batched(
 
         mesh = execution_mesh()
     if mesh is not None:
-        return _fit_boosted_batched_sharded(
+        trees, margin = _fit_boosted_batched_sharded(
             mesh, binned, y, row_mask, jnp.asarray(eta_v), jnp.asarray(lam),
             jnp.asarray(gam), jnp.asarray(mcw), jnp.asarray(mig),
             base_score=base_score, num_rounds=num_rounds,
             max_depth=max_depth, num_bins=num_bins, objective=objective,
             feature_groups=feature_groups,
         )
+        return _fit_result(trees, margin, None, True, return_slots)
     # f32 numpy broadcast (no eager compile), then ONE device transfer so
     # chunk 1 and chunks 2+ present the same leaf type to the AOT key
     # (a numpy leaf has no .sharding; mixing host/device margins would
@@ -1558,11 +1693,12 @@ def fit_boosted_batched(
         ),
     )
     chunks = []
+    slot_chunks = []
     done = 0
     chunk_size = _boost_round_chunk(num_rounds)
     while done < num_rounds:
         rc = min(chunk_size, num_rounds - done)
-        trees_c, margin = aot_call(
+        trees_c, margin, slots_c = aot_call(
             "boost_chunk", boost_chunk_fn,
             (binned, y, row_mask, margin, eta_v, lam, gam, mcw, mig,
              feature_groups),
@@ -1570,13 +1706,16 @@ def fit_boosted_batched(
                  objective=objective, hist_impl=_resolved_impl()),
         )
         chunks.append(trees_c)  # each [K, rc, ...] (swap happens in-jit)
+        slot_chunks.append(slots_c)
         done += rc
     if len(chunks) == 1:
-        return chunks[0], margin
+        return _fit_result(chunks[0], margin, slots_c, True, return_slots)
     # multi-chunk only off the default path: concatenate on HOST (eager
     # device concatenates cost a compile-cache round-trip per shape)
-    chunks = await_outputs(chunks)
-    return jax.tree.map(lambda *xs: np.concatenate(xs, axis=1), *chunks), margin
+    chunks, slot_chunks = await_outputs((chunks, slot_chunks))
+    trees = jax.tree.map(lambda *xs: np.concatenate(xs, axis=1), *chunks)
+    slots = jax.tree.map(lambda *xs: np.concatenate(xs), *slot_chunks)
+    return _fit_result(trees, margin, slots, True, return_slots)
 
 
 # --------------------------------------------------------------------------
@@ -1669,7 +1808,7 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
 
         def one_tree(_, xs):
             rm_t, fm_t, sn, sw = xs
-            tree, node = _grow_tree_impl(
+            tree, node, slots = _grow_tree_impl(
                 binned, gb, ones, rm_t, fm_t,
                 max_depth=max_depth, num_bins=num_bins,
                 reg_lambda=0.0, gamma=0.0,
@@ -1680,14 +1819,17 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
             )
             with jax.named_scope("tree/outputs"):
                 pred_t = _small_table_lookup(tree.leaf_value, node)
-            return None, (tree, pred_t)
+            return None, (tree, pred_t, slots)
 
-        _, (trees, preds) = jax.lax.scan(
+        _, (trees, preds, slots) = jax.lax.scan(
             one_tree, None, (rmasks, fmasks, subset_n, subset_w)
         )
         with jax.named_scope("tree/outputs"):
             outs = preds.mean(axis=0)  # [K, n_local]
-        return jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), trees), outs
+        return (
+            jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), trees), outs,
+            slots,
+        )
 
     rep = P()
     sm = shard_map(
@@ -1704,6 +1846,7 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
         out_specs=(
             Tree(split_feat=rep, split_bin=rep, leaf_value=rep),
             P(None, DATA_AXIS),
+            HistSlots(live=rep, built=rep),
         ),
         check_vma=False,
     )
@@ -1739,10 +1882,10 @@ def _fit_forest_batched_sharded(
     grp_args = tuple(feature_groups) if feature_groups is not None else ()
     if subset_n is not None:
         grp_args = grp_args + (subset_n, subset_w)
-    trees, outs = kern(binned_p, target_p, rmasks, fmasks, mi_k, mg_k,
-                       *grp_args)
+    trees, outs, slots = kern(binned_p, target_p, rmasks, fmasks, mi_k, mg_k,
+                              *grp_args)
     # pull replicated trees to HOST once
-    trees, outs = await_outputs((trees, outs))
+    trees, outs = await_outputs((trees, outs), hist_slots=slots)
     return trees, outs[:, :n]
 
 
@@ -1782,6 +1925,7 @@ def _sharded_boost_kernel(mesh, num_rounds, max_depth, num_bins, objective,
         out_specs=(
             Tree(split_feat=rep, split_bin=rep, leaf_value=rep),
             P(None, DATA_AXIS),
+            HistSlots(live=rep, built=rep),
         ),
         check_vma=False,
     )
@@ -1818,14 +1962,14 @@ def _fit_boosted_batched_sharded(
                                      _resolved_impl(),
                                      has_groups=feature_groups is not None)
         grp_args = tuple(feature_groups) if feature_groups is not None else ()
-        trees_c, margin = kern(
+        trees_c, margin, slots_c = kern(
             binned_p, y_p, rm_p, margin, eta_v, lam, gam, mcw, mig, *grp_args
         )
         # host-fetch each chunk's replicated trees — eager multi-device
         # reshapes intermittently abort the XLA:CPU async runtime; margin
         # stays DEVICE-resident as the next chunk's carry. Chunks are
         # [K, rc, ...] (swap happens in-jit).
-        chunks.append(await_outputs(trees_c))
+        chunks.append(await_outputs(trees_c, hist_slots=slots_c))
         done += rc
     trees = jax.tree.map(lambda *xs: np.concatenate(xs, axis=1), *chunks)
     return trees, await_outputs(margin)[:, :n]

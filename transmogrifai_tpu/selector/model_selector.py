@@ -27,6 +27,7 @@ from ..evaluators import (
 )
 from ..models.base import PredictorEstimator, PredictorModel
 from ..models.gbdt import (
+    await_stack_outputs,
     DecisionTreeClassifier,
     DecisionTreeRegressor,
     GBTClassifier,
@@ -42,7 +43,6 @@ from ..models.logistic import LogisticRegression
 from ..models.mlp import MLPClassifier
 from ..models.naive_bayes import NaiveBayes
 from ..models.svc import LinearSVC
-from ..models.trees import await_outputs
 from ..prep.splitters import DataBalancer, DataCutter, DataSplitter
 from ..telemetry import spans as _tspans
 from .validators import CrossValidator, TrainValidationSplit, Validator
@@ -411,11 +411,16 @@ class ModelSelector(PredictorEstimator):
                     if stack is not None and stack.get("outputs") is not None:
                         lanes = getattr(best_model, "_sweep_lanes", None)
                         if lanes is not None:
-                            refit_raw = ("multi", await_outputs(
-                                stack["outputs"])[lanes])
+                            refit_raw = (
+                                "multi", await_stack_outputs(stack)[lanes]
+                            )
                         elif hasattr(best_model, "predictions_from_sweep"):
-                            refit_raw = ("single", await_outputs(
-                                stack["outputs"])[best_model._sweep_lane])
+                            refit_raw = (
+                                "single",
+                                await_stack_outputs(stack)[
+                                    best_model._sweep_lane
+                                ],
+                            )
                     # free the sweep stacks: keep only the winner's own lane
                     detach = getattr(best_model, "detach_from_sweep", None)
                     if detach is not None:
