@@ -971,11 +971,11 @@ def test_device_scope_names_are_in_the_lowered_program(program):
     elif program == "forest":
         lowered = TR._forest_trees_scan.lower(
             S((n, f), jnp.int32), S((n,), jnp.float32), S((k, n), jnp.float32),
-            S((1,), jnp.uint32), kf, kf, kf, kf, groups, None, None, None,
+            S((1,), jnp.uint32), kf, kf, kf, kf, groups, None,
             num_trees=2, max_depth=2, num_bins=4, bootstrap=True, lowp=False,
-            hist_impl="scatter",
+            hist_impl="scatter", feature_subset=2, info_gain_norm=4.0,
         )
-        wanted = TREE_SCOPES + ("tree/group_columns",)
+        wanted = TREE_SCOPES + ("tree/group_columns", "tree/node_subset")
     else:
         lowered = jax.jit(TR.bin_data).lower(
             S((n, f), jnp.float32), S((f, 3), jnp.float32)

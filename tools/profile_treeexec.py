@@ -48,14 +48,15 @@ def main() -> None:
     )
     rm24 = jnp.asarray(np.repeat(masks, 6, axis=0))  # K=24
     yj = jnp.asarray((y == 1).astype(np.float32))
-    colsample = 1.0 / np.sqrt(x.shape[1])
+    n_sub = int(np.ceil(np.sqrt(x.shape[1])))  # Spark's sqrt, a node
 
     for depth in (3, 6, 12):
         for rep in range(2):
             t0 = time.perf_counter()
             trees, outs = TR.fit_forest_batched(
                 binned, yj, rm24, num_trees=50, max_depth=depth,
-                num_bins=32, subsample_rate=1.0, colsample_rate=float(colsample),
+                num_bins=32, subsample_rate=1.0, feature_subset=n_sub,
+                info_gain_norm=4.0,
                 min_instances=10.0, min_info_gain=0.001, seed=42,
                 lowp=True, feature_groups=fg, return_outputs=True,
             )

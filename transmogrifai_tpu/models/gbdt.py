@@ -21,6 +21,7 @@ gini — both preserve the fitted-probability semantics used downstream.
 from __future__ import annotations
 
 import logging
+import math
 
 import jax
 import jax.numpy as jnp
@@ -692,6 +693,11 @@ class _TreeEstimator(PredictorEstimator):
     #: grid params that are STATIC in the jitted fit (shape-affecting);
     #: points sharing them batch into one vmapped fit
     _STATIC_GRID_KEYS: tuple = ()
+    #: the stop rule (``trees._grow_tree_impl``). 0: ``min_info_gain`` (and
+    #: XGBoost's ``gamma``) are absolute, the XGBoost families. Spark's
+    #: families compare the impurity decrease PER ROW: 4·bg/W for the Gini
+    #: of a 0/1 (one-vs-rest) target, 2·bg/W for the variance
+    _INFO_GAIN_NORM = 0.0
 
     def __init__(self, operation_name: str, max_depth: int, max_bins: int, uid=None):
         super().__init__(operation_name, uid=uid)
@@ -928,7 +934,8 @@ class _TreeEstimator(PredictorEstimator):
             return None
 
     def _batched_group_fit(
-        self, x, masks, group_points, run_batched, make_model, normalize=None
+        self, x, masks, group_points, run_batched, make_model, normalize=None,
+        dispatch_attrs=None,
     ):
         """Shared plumbing for the masks × points batched fit: bin once,
         merge (+ normalize) params, stack the float knobs mask-major
@@ -939,7 +946,9 @@ class _TreeEstimator(PredictorEstimator):
         tree pytree, [K, N] training outputs-or-None, the fit's
         ``HistSlots``)`` where ``knob(name)`` returns the [K] float32 array
         for a param;
-        ``make_model(thresholds, sliced_trees, merged_params, mask_index)``.
+        ``make_model(thresholds, sliced_trees, merged_params, mask_index)``;
+        ``dispatch_attrs(binned, m0)`` gives the family's own attributes of
+        the ``tree/fit_dispatch`` span.
         The training outputs (every lane's raw model output on the full
         training matrix, computed by the fit program itself) ride the stack
         so sweep_eval_batched needs no re-traversal program.
@@ -975,6 +984,7 @@ class _TreeEstimator(PredictorEstimator):
             rounds=int(m0.get("num_round", m0.get("num_trees", 1))),
             depth=int(m0["max_depth"]), bins=int(m0["max_bins"]),
             hist_impl=TR._resolved_impl(),
+            **(dispatch_attrs(binned, m0) if dispatch_attrs else {}),
         ):
             trees, outputs, slots = run_batched(
                 binned, m0, row_mask_k, knob, fgroups
@@ -1070,6 +1080,7 @@ class XGBoostClassifier(_TreeEstimator):
         present = y[row_mask > 0]
         num_classes = max(int(present.max()) + 1 if len(present) else 2, 2)
         kwargs = dict(
+            info_gain_norm=self._INFO_GAIN_NORM,
             num_rounds=int(self.num_round),
             max_depth=int(self.max_depth),
             num_bins=int(self.max_bins),
@@ -1116,6 +1127,7 @@ class XGBoostClassifier(_TreeEstimator):
                 min_info_gain=knob("min_info_gain"),
                 objective="binary:logistic",
                 feature_groups=fgroups, return_slots=True,
+                info_gain_norm=self._INFO_GAIN_NORM,
             )
             # the final margin IS each lane's raw output on every row
             return trees, margin, slots
@@ -1176,6 +1188,7 @@ class XGBoostRegressor(_TreeEstimator):
                 base_score=base_k,
                 objective="reg:squarederror",
                 feature_groups=fgroups, return_slots=True,
+                info_gain_norm=self._INFO_GAIN_NORM,
             )
             return trees, margin, slots
 
@@ -1205,6 +1218,7 @@ class XGBoostRegressor(_TreeEstimator):
             base_score=base,
             objective="reg:squarederror",
             feature_groups=fgroups,
+            info_gain_norm=self._INFO_GAIN_NORM,
         )
         return BoostedRegressionModel(thresholds, trees, float(self.eta), base)
 
@@ -1251,6 +1265,7 @@ class GBTClassifier(XGBoostClassifier):
         }
 
     _STATIC_GRID_KEYS = ("max_iter", "max_depth", "max_bins")
+    _INFO_GAIN_NORM = 2.0
 
     def fit_arrays(self, x, y, row_mask):
         # keep the boosted knobs in sync with the Spark-named params
@@ -1275,6 +1290,7 @@ class GBTClassifier(XGBoostClassifier):
 class GBTRegressor(XGBoostRegressor):
     model_type = "OpGBTRegressor"
     _STATIC_GRID_KEYS = ("max_iter", "max_depth", "max_bins")
+    _INFO_GAIN_NORM = 2.0
     _normalize_boost = GBTClassifier._normalize_boost
 
     def __init__(
@@ -1311,11 +1327,46 @@ class GBTRegressor(XGBoostRegressor):
         return super().fit_arrays(x, y, row_mask)
 
 
+FEATURE_SUBSET_STRATEGIES = ("auto", "all", "sqrt", "onethird", "log2")
+
+
+def resolve_feature_subset(
+    strategy: str, num_features: int, num_trees: int, classification: bool
+) -> int:
+    """Columns each tree NODE may split on, as Spark's
+    ``DecisionTreeMetadata.buildMetadata`` resolves ``featureSubsetStrategy``:
+    ``auto`` is ``all`` for one tree, else ``sqrt`` for classification and
+    ``onethird`` for regression; ``sqrt`` ⌈√F⌉, ``onethird`` ⌈F/3⌉, ``log2``
+    max(1, ⌈log2 F⌉), ``all`` F."""
+    s = str(strategy).lower()
+    if s not in FEATURE_SUBSET_STRATEGIES:
+        raise ValueError(
+            f"feature_subset_strategy {strategy!r}: one of "
+            f"{FEATURE_SUBSET_STRATEGIES}"
+        )
+    f = max(int(num_features), 1)
+    if s == "auto":
+        s = "all" if int(num_trees) == 1 else (
+            "sqrt" if classification else "onethird"
+        )
+    if s == "sqrt":
+        return min(f, math.ceil(math.sqrt(f)))
+    if s == "onethird":
+        return min(f, math.ceil(f / 3.0))
+    if s == "log2":
+        return min(f, max(1, math.ceil(math.log2(f))))
+    return f
+
+
 class RandomForestClassifier(_TreeEstimator):
     """OpRandomForestClassifier parity (Spark defaults: numTrees 20, maxDepth
-    5, featureSubsetStrategy 'auto' = sqrt for classification)."""
+    5, featureSubsetStrategy 'auto' = √F columns a node for a forest, Gini
+    impurity, minInfoGain compared with the impurity decrease per row, a
+    Poisson bootstrap when there is more than one tree)."""
 
     model_type = "OpRandomForestClassifier"
+    _INFO_GAIN_NORM = 4.0
+    _CLASSIFICATION = True
 
     def __init__(
         self,
@@ -1326,6 +1377,7 @@ class RandomForestClassifier(_TreeEstimator):
         subsampling_rate: float = 1.0,
         max_bins: int = 32,
         seed: int = 42,
+        feature_subset_strategy: str = "auto",
         uid: str | None = None,
     ):
         super().__init__("rfClassifier", max_depth, max_bins, uid=uid)
@@ -1334,6 +1386,7 @@ class RandomForestClassifier(_TreeEstimator):
         self.min_info_gain = min_info_gain
         self.subsampling_rate = subsampling_rate
         self.seed = seed
+        self.feature_subset_strategy = feature_subset_strategy
 
     def get_params(self):
         return {
@@ -1344,6 +1397,7 @@ class RandomForestClassifier(_TreeEstimator):
             "subsampling_rate": self.subsampling_rate,
             "max_bins": self.max_bins,
             "seed": self.seed,
+            "feature_subset_strategy": self.feature_subset_strategy,
         }
 
     # max_depth STAYS static by default: collapsing the depth groups into
@@ -1351,30 +1405,56 @@ class RandomForestClassifier(_TreeEstimator):
     # eval in one fat program (not re-measured on a local chip; see
     # ROADMAP D3). run_batched still
     # wires per-lane caps for custom groupings that mix depths.
-    _STATIC_GRID_KEYS = ("num_trees", "max_depth", "max_bins", "seed")
+    _STATIC_GRID_KEYS = (
+        "num_trees", "max_depth", "max_bins", "seed",
+        "feature_subset_strategy",
+    )
 
-    @staticmethod
-    def _colsample(num_features: int) -> float:
-        """Spark featureSubsetStrategy 'auto' = sqrt for classification."""
-        return 1.0 / np.sqrt(max(num_features, 1))
+    def _forest_statics(self, m: dict, num_features: int, rates) -> dict:
+        """What of Spark's forest a program is compiled for, from the
+        merged params ``m`` of one static group: the columns a node may
+        split on, whether rows are bootstrapped (Poisson counts; not for
+        one tree on every row), and the impurity's stop-rule norm."""
+        trees = int(m.get("num_trees", 1))
+        return dict(
+            feature_subset=resolve_feature_subset(
+                m.get("feature_subset_strategy", "auto"), num_features,
+                trees, self._CLASSIFICATION,
+            ),
+            bootstrap=trees > 1 or bool(np.any(np.asarray(rates) != 1.0)),
+            info_gain_norm=self._INFO_GAIN_NORM,
+        )
+
+    def _dispatch_attrs(self, binned, m0: dict) -> dict:
+        st = self._forest_statics(
+            m0, int(binned.shape[1]), m0.get("subsampling_rate", 1.0)
+        )
+        return dict(
+            trees=int(m0.get("num_trees", 1)),
+            feature_subset=str(m0.get("feature_subset_strategy", "auto")),
+            n_sub=st["feature_subset"], bootstrap=st["bootstrap"],
+        )
 
     def fit_arrays(self, x, y, row_mask):
         thresholds, binned, fgroups = self._binned(x)
         present = y[row_mask > 0]
         num_classes = max(int(present.max()) + 1 if len(present) else 2, 2)
-        colsample = self._colsample(x.shape[1])
         rm = jnp.asarray(row_mask, dtype=jnp.float32)
         kwargs = dict(
             num_trees=int(self.num_trees),
             max_depth=int(self.max_depth),
             num_bins=int(self.max_bins),
             subsample_rate=float(self.subsampling_rate),
-            colsample_rate=float(colsample),
             min_instances=float(self.min_instances_per_node),
             min_info_gain=float(self.min_info_gain),
             seed=int(self.seed),
             lowp=True,  # one-vs-rest indicators are bf16-exact
             feature_groups=fgroups,
+            # the forest's params, whatever a subclass exposes of them
+            **self._forest_statics(
+                RandomForestClassifier.get_params(self), x.shape[1],
+                self.subsampling_rate,
+            ),
         )
         if num_classes == 2:
             forests = [
@@ -1394,7 +1474,6 @@ class RandomForestClassifier(_TreeEstimator):
             return self._fit_group_masks_multiclass(
                 x, y, masks, group_points, num_classes
             )
-        colsample = self._colsample(x.shape[1])
         yj = np.asarray((y == 1), dtype=np.float32)
 
         def run_batched(binned, m0, row_mask_k, knob, fgroups):
@@ -1403,13 +1482,14 @@ class RandomForestClassifier(_TreeEstimator):
             # execution, dominates the flagship sweep)
             depth_arr = np.asarray(knob("max_depth"))
             uniform = bool((depth_arr == depth_arr[0]).all())
+            rates = knob("subsampling_rate")
             return TR.fit_forest_batched(
                 binned, yj, row_mask_k,
                 num_trees=int(m0["num_trees"]),
                 max_depth=int(depth_arr.max()),
                 num_bins=int(m0["max_bins"]),
-                subsample_rate=knob("subsampling_rate"),
-                colsample_rate=float(colsample),
+                subsample_rate=rates,
+                **self._forest_statics(m0, binned.shape[1], rates),
                 min_instances=knob("min_instances_per_node"),
                 min_info_gain=knob("min_info_gain"),
                 seed=int(m0["seed"]),
@@ -1425,6 +1505,7 @@ class RandomForestClassifier(_TreeEstimator):
         return self._batched_group_fit(
             x, masks, group_points, run_batched,
             lambda th, tr, m, mi: ForestClassifierModel(th, [tr]),
+            dispatch_attrs=self._dispatch_attrs,
         )
 
     def _fit_group_masks_multiclass(self, x, y, masks, group_points,
@@ -1444,7 +1525,6 @@ class RandomForestClassifier(_TreeEstimator):
             return None
         thresholds, binned, fgroups = self._binned(x)
         self._last_feature_groups = fgroups
-        colsample = self._colsample(x.shape[1])
         merged = [{**self.get_params(), **p} for p in group_points]
         n_masks, n_pts = masks.shape[0], len(merged)
         c = num_classes
@@ -1467,18 +1547,20 @@ class RandomForestClassifier(_TreeEstimator):
         # max_depth is in _STATIC_GRID_KEYS, so every point of this group
         # shares one depth — no per-lane depth caps needed here
         m0 = merged[0]
+        rates = knob("subsampling_rate")
         with _tspans.span(
             "tree/fit_dispatch", lanes=n_masks * n_pts * c,
             rounds=int(m0["num_trees"]), depth=int(m0["max_depth"]),
             bins=int(m0["max_bins"]), hist_impl=TR._resolved_impl(),
+            **self._dispatch_attrs(binned, m0),
         ):
             trees, outs, slots = TR.fit_forest_batched(
                 binned, tg, rm,
                 num_trees=int(m0["num_trees"]),
                 max_depth=int(m0["max_depth"]),
                 num_bins=int(m0["max_bins"]),
-                subsample_rate=knob("subsampling_rate"),
-                colsample_rate=float(colsample),
+                subsample_rate=rates,
+                **self._forest_statics(m0, binned.shape[1], rates),
                 min_instances=knob("min_instances_per_node"),
                 min_info_gain=knob("min_info_gain"),
                 seed=int(m0["seed"]),
@@ -1521,6 +1603,8 @@ class RandomForestClassifier(_TreeEstimator):
 
 class RandomForestRegressor(_TreeEstimator):
     model_type = "OpRandomForestRegressor"
+    _INFO_GAIN_NORM = 2.0
+    _CLASSIFICATION = False
 
     def __init__(
         self,
@@ -1531,6 +1615,7 @@ class RandomForestRegressor(_TreeEstimator):
         subsampling_rate: float = 1.0,
         max_bins: int = 32,
         seed: int = 42,
+        feature_subset_strategy: str = "auto",
         uid: str | None = None,
     ):
         super().__init__("rfRegressor", max_depth, max_bins, uid=uid)
@@ -1539,23 +1624,16 @@ class RandomForestRegressor(_TreeEstimator):
         self.min_info_gain = min_info_gain
         self.subsampling_rate = subsampling_rate
         self.seed = seed
+        self.feature_subset_strategy = feature_subset_strategy
 
     get_params = RandomForestClassifier.get_params
-    # max_depth STAYS static by default: collapsing the depth groups into
-    # one max-depth program via max_depth_v makes every lane pay deep-level
-    # eval in one fat program (not re-measured on a local chip; see
-    # ROADMAP D3). run_batched still
-    # wires per-lane caps for custom groupings that mix depths.
-    _STATIC_GRID_KEYS = ("num_trees", "max_depth", "max_bins", "seed")
-
-    @staticmethod
-    def _colsample(num_features: int) -> float:
-        """Spark featureSubsetStrategy 'auto' = onethird for regression."""
-        return 1.0 / 3.0
+    # max_depth stays static: see RandomForestClassifier
+    _STATIC_GRID_KEYS = RandomForestClassifier._STATIC_GRID_KEYS
+    _forest_statics = RandomForestClassifier._forest_statics
+    _dispatch_attrs = RandomForestClassifier._dispatch_attrs
 
     def fit_arrays(self, x, y, row_mask):
         thresholds, binned, fgroups = self._binned(x)
-        colsample = self._colsample(x.shape[1])
         trees = TR.fit_forest(
             binned,
             jnp.asarray(y, dtype=jnp.float32),
@@ -1564,28 +1642,31 @@ class RandomForestRegressor(_TreeEstimator):
             max_depth=int(self.max_depth),
             num_bins=int(self.max_bins),
             subsample_rate=float(self.subsampling_rate),
-            colsample_rate=colsample,
             min_instances=float(self.min_instances_per_node),
             min_info_gain=float(self.min_info_gain),
             seed=int(self.seed),
             feature_groups=fgroups,
+            **self._forest_statics(
+                RandomForestClassifier.get_params(self), x.shape[1],
+                self.subsampling_rate,
+            ),
         )
         return ForestRegressionModel(thresholds, trees)
 
     def _fit_group_masks(self, x, y, masks, group_points):
-        colsample = self._colsample(x.shape[1])
         yj = np.asarray(y, dtype=np.float32)
 
         def run_batched(binned, m0, row_mask_k, knob, fgroups):
             depth_arr = np.asarray(knob("max_depth"))
             uniform = bool((depth_arr == depth_arr[0]).all())
+            rates = knob("subsampling_rate")
             return TR.fit_forest_batched(
                 binned, yj, row_mask_k,
                 num_trees=int(m0["num_trees"]),
                 max_depth=int(depth_arr.max()),
                 num_bins=int(m0["max_bins"]),
-                subsample_rate=knob("subsampling_rate"),
-                colsample_rate=float(colsample),
+                subsample_rate=rates,
+                **self._forest_statics(m0, binned.shape[1], rates),
                 min_instances=knob("min_instances_per_node"),
                 min_info_gain=knob("min_info_gain"),
                 seed=int(m0["seed"]),
@@ -1600,17 +1681,20 @@ class RandomForestRegressor(_TreeEstimator):
         return self._batched_group_fit(
             x, masks, group_points, run_batched,
             lambda th, tr, m, mi: ForestRegressionModel(th, tr),
+            dispatch_attrs=self._dispatch_attrs,
         )
 
 
 class DecisionTreeClassifier(RandomForestClassifier):
-    """Single unbagged tree (OpDecisionTreeClassifier parity)."""
+    """Single unbagged tree (OpDecisionTreeClassifier parity): the forest
+    of ONE tree, which by Spark's rules searches every column at every
+    node (``auto`` is ``all``) and draws no bootstrap."""
 
     model_type = "OpDecisionTreeClassifier"
 
     def _fit_group_masks(self, x, y, masks, group_points):
-        # RF's batched fit bootstraps + column-samples; a decision tree is
-        # deterministic and full-feature — never inherit that path
+        # sequential fits: the forest's batched path reads forest params
+        # (num_trees, seed) that a decision tree does not expose
         return None
 
     def __init__(self, max_depth: int = 5, min_instances_per_node: int = 1,
@@ -1631,31 +1715,12 @@ class DecisionTreeClassifier(RandomForestClassifier):
             "max_bins": self.max_bins,
         }
 
-    def fit_arrays(self, x, y, row_mask):
-        thresholds, binned, fgroups = self._binned(x)
-        present = y[row_mask > 0]
-        num_classes = max(int(present.max()) + 1 if len(present) else 2, 2)
-        rm = jnp.asarray(row_mask, dtype=jnp.float32)
-        kwargs = dict(
-            num_trees=1, max_depth=int(self.max_depth),
-            num_bins=int(self.max_bins), subsample_rate=1.0, colsample_rate=1.0,
-            min_instances=float(self.min_instances_per_node),
-            min_info_gain=float(self.min_info_gain), seed=int(self.seed),
-            bootstrap=False, feature_groups=fgroups,
-        )
-        indicators = [1] if num_classes == 2 else list(range(num_classes))
-        forests = [
-            TR.fit_forest(binned, jnp.asarray((y == c).astype(np.float32)), rm, **kwargs)
-            for c in indicators
-        ]
-        return ForestClassifierModel(thresholds, forests)
-
 
 class DecisionTreeRegressor(RandomForestRegressor):
     model_type = "OpDecisionTreeRegressor"
 
     def _fit_group_masks(self, x, y, masks, group_points):
-        return None  # see DecisionTreeClassifier — no RF randomization
+        return None  # see DecisionTreeClassifier
 
     def __init__(self, max_depth: int = 5, min_instances_per_node: int = 1,
                  min_info_gain: float = 0.0, max_bins: int = 32, uid=None):
@@ -1666,20 +1731,6 @@ class DecisionTreeRegressor(RandomForestRegressor):
         )
 
     get_params = DecisionTreeClassifier.get_params
-
-    def fit_arrays(self, x, y, row_mask):
-        thresholds, binned, fgroups = self._binned(x)
-        trees = TR.fit_forest(
-            binned,
-            jnp.asarray(y, dtype=jnp.float32),
-            jnp.asarray(row_mask, dtype=jnp.float32),
-            num_trees=1, max_depth=int(self.max_depth),
-            num_bins=int(self.max_bins), subsample_rate=1.0, colsample_rate=1.0,
-            min_instances=float(self.min_instances_per_node),
-            min_info_gain=float(self.min_info_gain), seed=int(self.seed),
-            bootstrap=False, feature_groups=fgroups,
-        )
-        return ForestRegressionModel(thresholds, trees)
 
 
 # --------------------------------------------------------------------------

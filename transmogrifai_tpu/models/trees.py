@@ -46,15 +46,24 @@ class Tree(NamedTuple):
 
 
 class HistSlots(NamedTuple):
-    """What a fit's histogram builds were sized for, per tree and level
-    (``[..., depth]`` int32): the level's live compact node slots (the
-    widest lane's) and the slots its builds were made at (the rung of
-    ``_width_ladder`` taken, or ``chunk_nodes`` per chunk that ran; 0 for
-    a level the early exit skipped). ``live / built`` is the occupancy of
-    the histogram kernel's node axis."""
+    """What a fit counted of its own work, per tree and level (``[...,
+    depth]`` int32). ``live``: the level's live compact node slots (the
+    widest lane's); ``built``: the slots its histogram builds were made at
+    (the rung of ``_width_ladder`` taken, or ``chunk_nodes`` per chunk that
+    ran; 0 for a level the early exit skipped), so ``live / built`` is the
+    occupancy of the histogram kernel's node axis. ``chunks_run`` /
+    ``chunks_skipped``: the builds made, and the chunks the occupancy
+    branch left out. ``subset_admitted`` / ``subset_pairs``: over every
+    lane's live nodes, the (node, feature) pairs the split search admitted
+    and all there were (both 0 from a fit that draws no node subsets:
+    boosting)."""
 
     live: jax.Array
     built: jax.Array
+    chunks_run: jax.Array
+    chunks_skipped: jax.Array
+    subset_admitted: jax.Array
+    subset_pairs: jax.Array
 
 
 # The narrowest width a level's histograms are built at. At 32 slots the
@@ -81,17 +90,26 @@ def _width_ladder(chunk_nodes: int) -> tuple[int, ...]:
 
 
 class HistSlotStats(_tm.LedgerCore):
-    """Cumulative ``histSlotsLive`` / ``histSlotsBuilt`` of the fits whose
-    outputs ``await_outputs`` landed (part of the ``tree`` ledger:
-    ``models/gbdt.py`` registers it beside the bin cache's counters)."""
+    """Cumulative sums of the ``HistSlots`` of the fits whose outputs
+    ``await_outputs`` landed (part of the ``tree`` ledger: ``models/gbdt.py``
+    registers it beside the bin cache's counters)."""
+
+    #: ledger key of each ``HistSlots`` field
+    KEYS = {
+        "live": "histSlotsLive", "built": "histSlotsBuilt",
+        "chunks_run": "chunksRun", "chunks_skipped": "chunksSkipped",
+        "subset_admitted": "nodeSubsetAdmitted",
+        "subset_pairs": "nodeSubsetPairs",
+    }
 
     def __init__(self) -> None:
-        super().__init__(("histSlotsLive", "histSlotsBuilt"))
+        super().__init__(tuple(self.KEYS.values()))
 
-    def record(self, live: int, built: int) -> None:
+    def record(self, sums: dict) -> None:
+        """``sums``: field of ``HistSlots`` -> its sum over one fit."""
         with self._lock:
-            self._counts["histSlotsLive"] += live
-            self._counts["histSlotsBuilt"] += built
+            for field, value in sums.items():
+                self._counts[self.KEYS[field]] += value
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -112,8 +130,10 @@ def await_outputs(value, hist_slots: HistSlots | None = None):
     device — and one download on the run ledger's transfer census. What is
     on the host already passes through. ``hist_slots`` are the counts the
     same fit program returned: once its outputs have landed they are there
-    too, and their sums go onto the span (``slots_live``, ``slots_built``)
-    and the ``tree`` ledger."""
+    too, and their sums go onto the span (``slots_live``, ``slots_built``,
+    ``chunks_run``, ``chunks_skipped`` and, from a fit that counts node
+    subsets, ``subset_admitted``, ``subset_pairs``) and the ``tree``
+    ledger."""
     leaves = jax.tree.leaves(value)
     if all(isinstance(a, np.ndarray) for a in leaves):
         return value
@@ -124,10 +144,21 @@ def await_outputs(value, hist_slots: HistSlots | None = None):
         sp.attrs["bytes"] = nbytes
         _runlog.record_download(nbytes, _tspans.clock() - t0)
         if hist_slots is not None and _tspans.enabled():
-            live = int(np.asarray(hist_slots.live).sum())
-            built = int(np.asarray(hist_slots.built).sum())
-            sp.attrs.update(slots_live=live, slots_built=built)
-            _HIST_SLOT_STATS.record(live, built)
+            sums = {
+                field: int(np.asarray(a, dtype=np.int64).sum())
+                for field, a in hist_slots._asdict().items()
+            }
+            sp.attrs.update(
+                slots_live=sums["live"], slots_built=sums["built"],
+                chunks_run=sums["chunks_run"],
+                chunks_skipped=sums["chunks_skipped"],
+            )
+            if sums["subset_pairs"]:
+                sp.attrs.update(
+                    subset_admitted=sums["subset_admitted"],
+                    subset_pairs=sums["subset_pairs"],
+                )
+            _HIST_SLOT_STATS.record(sums)
     return out
 
 
@@ -257,6 +288,7 @@ def grow_tree(
     hist_impl: str | None = None,
     parallel_fits: int = 1,  # kept for API compat; K now rides the kernel grid
     feature_groups=None,
+    info_gain_norm: float = 0.0,
 ) -> Tree:
     """Single-fit tree growth — the K=1 case of grow_tree_batched."""
     tree = grow_tree_batched(
@@ -266,13 +298,16 @@ def grow_tree(
         reg_lambda=reg_lambda, gamma=gamma,
         min_child_weight=min_child_weight, min_info_gain=min_info_gain,
         hist_impl=hist_impl, feature_groups=feature_groups,
+        info_gain_norm=info_gain_norm,
     )
     return jax.tree.map(lambda a: a[0], tree)
 
 
 @partial(
     jax.jit,
-    static_argnames=("max_depth", "num_bins", "hist_impl", "lowp"),
+    static_argnames=(
+        "max_depth", "num_bins", "hist_impl", "lowp", "info_gain_norm",
+    ),
 )
 def grow_tree_batched(
     binned: jax.Array,     # [N, F] int32 codes, SHARED across fits
@@ -289,6 +324,7 @@ def grow_tree_batched(
     hist_impl: str | None = None,
     lowp: bool = False,
     feature_groups=None,
+    info_gain_norm: float = 0.0,
 ) -> Tree:
     """Grow K trees at once — one per batched fit (hyperparameter grid point
     × CV fold). The fit axis is a kernel GRID dimension of the histogram
@@ -302,6 +338,7 @@ def grow_tree_batched(
         reg_lambda=reg_lambda, gamma=gamma,
         min_child_weight=min_child_weight, min_info_gain=min_info_gain,
         hist_impl=hist_impl, lowp=lowp, feature_groups=feature_groups,
+        info_gain_norm=info_gain_norm,
     )[0]
 
 
@@ -323,6 +360,9 @@ def _grow_tree_impl(
     axis_size: int = 1,
     feature_groups: tuple[jax.Array, jax.Array] | None = None,
     max_depth_v: jax.Array | None = None,
+    info_gain_norm: float = 0.0,
+    node_subset: int | None = None,
+    node_key: jax.Array | None = None,
 ) -> tuple[Tree, jax.Array, HistSlots]:
     """Tree-growth body shared by the single-device jit wrapper and the
     shard_map'd path: (tree, each row's final leaf slot, the histogram
@@ -347,7 +387,24 @@ def _grow_tree_impl(
     work; the narrow group runs the same kernels at b=2 instead. Per-feature
     gains are bin-cumsum along each feature's own row, so grouped growth
     finds the SAME splits as ungrouped (tie-break by original feature id
-    preserved across the group merge)."""
+    preserved across the group merge).
+
+    ``info_gain_norm`` (static) chooses the stop rule. 0: a node splits when
+    its best gain ``bg`` (the formula above, a SUM over the node's rows) is
+    over ``min_info_gain`` — XGBoost's absolute ``gamma`` semantics. 4 (Gini
+    of a 0/1 target) or 2 (variance): Spark's rule — the impurity decrease
+    per row ``norm·bg/W`` (W the node's hessian sum, its weighted row count
+    in a forest) must reach ``min_info_gain`` and be positive. The arg-max
+    within a node is the same either way.
+
+    ``node_subset`` (static; forests) is Spark's ``featureSubsetStrategy``
+    count: every NODE searches ``node_subset`` distinct columns of the F,
+    ``jax.random.choice(jax.random.fold_in(node_key, j), F, (node_subset,),
+    replace=False)`` for the node at heap index ``j`` (root 1, children 2j
+    and 2j+1): the draw depends on the tree's key and the node alone, not
+    on the lane, the chunk, the compact slot, the width rung or the mesh.
+    ``node_subset == F`` draws nothing; None also counts nothing
+    (``HistSlots.subset_*`` stay 0)."""
     from .hist_pallas import (
         FUSED_SPLIT_MAX_ROWS,
         build_best_split_pallas,
@@ -448,9 +505,12 @@ def _grow_tree_impl(
     # Only possible when every row fits one VMEM tile and the bins fit the
     # kernel's 128-lane packing. The sharded path needs the raw histogram
     # for the cross-shard psum, so it always takes the two-step path.
+    draw_subsets = node_subset is not None and node_subset < f
     use_fused = (
         not use_gemm
         and impl == "pallas"
+        and not draw_subsets   # the kernel's mask is per lane, not per node
+        and not info_gain_norm  # and it returns no node weight
         and axis_name is None
         and n <= FUSED_SPLIT_MAX_ROWS
         and b <= 128
@@ -525,9 +585,12 @@ def _grow_tree_impl(
             loc.shape[0], chunk_nodes, fg, gb, 2
         )
 
-    def group_stats(gbinned, gmask, gb, gidx, codes1h, loc, chunk_nodes):
-        """(gain, orig feat, bin) of the best split per compact slot for
-        ONE feature group."""
+    def group_stats(gbinned, gmask, gb, gidx, codes1h, loc, chunk_nodes,
+                    sel):
+        """(gain, orig feat, bin, node weight) of the best split per compact
+        slot for ONE feature group; ``sel`` [K, M, node_subset] are the
+        slots' admissible columns (None: all). Also the (slot, feature)
+        pairs of this group that ``sel`` admits, [K, M] (None: all)."""
         if use_fused:
             # histogram and arg-best in one kernel: the histogram's scope
             with jax.named_scope("tree/histogram"):
@@ -539,11 +602,20 @@ def _grow_tree_impl(
             if gidx is not None:
                 with jax.named_scope("tree/split_search"):
                     bf = gidx[jnp.maximum(bf, 0)].astype(jnp.int32)
-            return bg, bf, bb
+            return (bg, bf, bb, None), None
+        nmask = None
+        if sel is not None:
+            with jax.named_scope("tree/node_subset"):
+                fid = (
+                    jnp.arange(gbinned.shape[1], dtype=jnp.int32)
+                    if gidx is None else gidx.astype(jnp.int32)
+                )
+                nmask = (sel[..., None] == fid).any(axis=2)  # [K, M, Fg]
         with jax.named_scope("tree/histogram"):
             hist = build_histogram(gbinned, gb, codes1h, loc, chunk_nodes)
         with jax.named_scope("tree/split_search"):
-            return best_split(hist, gmask, gb, gidx, chunk_nodes)
+            best = best_split(hist, gmask, nmask, gb, gidx, chunk_nodes)
+        return best, None if nmask is None else nmask.sum(axis=2)
 
     def build_histogram(gbinned, gb, codes1h, loc, chunk_nodes):
         """[K, M, Fg, Bg, 2] (grad, hess) sums of one feature group."""
@@ -574,7 +646,7 @@ def _grow_tree_impl(
             hist = jax.lax.psum(hist, axis_name)
         return hist
 
-    def best_split(hist, gmask, gb, gidx, chunk_nodes):
+    def best_split(hist, gmask, nmask, gb, gidx, chunk_nodes):
         hg, hh = hist[..., 0], hist[..., 1]  # [K, M, Fg, Bg]
 
         gl = jnp.cumsum(hg, axis=3)[..., :-1]
@@ -590,6 +662,8 @@ def _grow_tree_impl(
             & (hr >= mcw)
             & (gmask[:, None, :, None] > 0)
         )
+        if nmask is not None:
+            valid = valid & nmask[..., None]
         gain = jnp.where(valid, gain, -jnp.inf)
 
         flat_gain = gain.reshape(gain.shape[0], chunk_nodes, -1)
@@ -599,22 +673,53 @@ def _grow_tree_impl(
         best_bin = (best % (gb - 1)).astype(jnp.int32)
         if gidx is not None:
             best_feat = gidx[best_feat].astype(jnp.int32)
-        return best_gain, best_feat, best_bin
+        # every row of a node lies in one bin of each feature: any
+        # feature's total is the node's weight
+        return best_gain, best_feat, best_bin, ht[:, :, 0, 0]
 
-    def chunk_stats(local, c0, chunk_nodes):
+    def node_subsets(at, c0, chunk_nodes):
+        """[K, M, node_subset] int32: the admissible columns of the nodes
+        in compact slots [c0, c0 + M), drawn from each node's heap index
+        (see the docstring); ``at`` is the level's (live, rank, level)."""
+        live, rank, level = at
+        with jax.named_scope("tree/node_subset"):
+            ids = jnp.arange(max_nodes, dtype=jnp.int32)
+            slots = c0 + jnp.arange(chunk_nodes, dtype=jnp.int32)
+            # the node whose dense rank is the slot (0 where none is live:
+            # its draw is never read)
+            node_of = jnp.where(
+                live[:, :, None] & (rank[:, :, None] == slots),
+                ids[None, :, None], 0,
+            ).sum(axis=1)
+            heap = jnp.left_shift(jnp.int32(1), level) + node_of  # [K, M]
+
+            def draw(j):
+                return jax.random.choice(
+                    jax.random.fold_in(node_key, j), f, (node_subset,),
+                    replace=False,
+                ).astype(jnp.int32)
+
+            return jax.vmap(jax.vmap(draw))(heap)
+
+    def chunk_stats(local, c0, chunk_nodes, at):
         """Best (feat, bin) per compact slot in [c0, c0 + chunk_nodes),
         merged across feature groups (tie-break: lowest original feature
-        id — matches the single-group argmax order)."""
+        id — matches the single-group argmax order), and the (live node,
+        feature) pairs admitted and possible there."""
         with jax.named_scope("tree/partition"):
             active = (local >= c0) & (local < c0 + chunk_nodes)
             loc = jnp.where(active, local - c0, -1)  # [K, N]
-        bg, bf, bb = None, None, None
+        sel = node_subsets(at, c0, chunk_nodes) if draw_subsets else None
+        bg, bf, bb, bw = None, None, None, None
+        admitted = None
         for gbinned, gmask, grp_b, gidx, codes1h in groups:
-            gg, gf, gbin = group_stats(
-                gbinned, gmask, grp_b, gidx, codes1h, loc, chunk_nodes
+            (gg, gf, gbin, gw), adm = group_stats(
+                gbinned, gmask, grp_b, gidx, codes1h, loc, chunk_nodes, sel
             )
+            if adm is not None:
+                admitted = adm if admitted is None else admitted + adm
             if bg is None:
-                bg, bf, bb = gg, gf, gbin
+                bg, bf, bb, bw = gg, gf, gbin, gw
             else:
                 with jax.named_scope("tree/split_search"):
                     take = (gg > bg) | ((gg == bg) & (gf < bf))
@@ -622,11 +727,29 @@ def _grow_tree_impl(
                     bf = jnp.where(take, gf, bf)
                     bb = jnp.where(take, gbin, bb)
         with jax.named_scope("tree/split_search"):
-            do_split = bg > jnp.maximum(mig, 0.0)
+            if info_gain_norm:
+                do_split = (bg > 0.0) & (info_gain_norm * bg / bw >= mig)
+            else:
+                do_split = bg > jnp.maximum(mig, 0.0)
+            counts = (jnp.int32(0), jnp.int32(0))
+            if node_subset is not None:
+                # dense numbering: a lane's live slots are those below its
+                # live count
+                n_live_k = at[0].sum(axis=1, dtype=jnp.int32)
+                slot_live = (
+                    c0 + jnp.arange(chunk_nodes, dtype=jnp.int32)
+                ) < n_live_k[:, None]
+                pairs = slot_live.sum(dtype=jnp.int32) * f
+                counts = (
+                    pairs if admitted is None else
+                    jnp.where(slot_live, admitted, 0).sum(dtype=jnp.int32),
+                    pairs,
+                )
             return (
                 jnp.where(do_split, bf, -1),
                 jnp.where(do_split, bb, 0),
-            )  # each [K, chunk]
+                counts,
+            )  # [K, chunk] each, then two scalars
 
     sentinel = jnp.int32(max_nodes)  # out-of-range → dropped by scatters
 
@@ -645,8 +768,7 @@ def _grow_tree_impl(
             split_bin=jnp.zeros((k_fits, 0, 1), dtype=jnp.int32),
             leaf_value=leaf_value0,
         ), jnp.zeros((k_fits, n), dtype=jnp.int32), HistSlots(
-            live=jnp.zeros((0,), dtype=jnp.int32),
-            built=jnp.zeros((0,), dtype=jnp.int32),
+            *(jnp.zeros((0,), dtype=jnp.int32) for _ in HistSlots._fields)
         )
 
     # ---- lax.scan over levels with ONE shared body: an unrolled level
@@ -718,17 +840,20 @@ def _grow_tree_impl(
         # numbered densely from 0, so every live one is below this count
         n_live = live.sum(axis=1, dtype=jnp.int32).max()
 
+        at = (live, rank, level_idx)
+        zero2 = (jnp.int32(0), jnp.int32(0))
+
         def chunk_loop():
             def chunk_body(ci, fb):
-                feats_a, bins_a, built = fb
+                feats_a, bins_a, built, (adm, prs) = fb
                 c0 = ci * chunk_nodes
                 if axis_name is None:
                     occupied = (
                         (local >= c0) & (local < c0 + chunk_nodes)
                     ).any()
-                    cf, cb = jax.lax.cond(
+                    cf, cb, (ca, cp) = jax.lax.cond(
                         occupied,
-                        lambda: chunk_stats(local, c0, chunk_nodes),
+                        lambda: chunk_stats(local, c0, chunk_nodes, at),
                         lambda: (
                             jnp.full(
                                 (k_fits, chunk_nodes), -1, dtype=jnp.int32
@@ -736,18 +861,22 @@ def _grow_tree_impl(
                             jnp.zeros(
                                 (k_fits, chunk_nodes), dtype=jnp.int32
                             ),
+                            zero2,
                         ),
                     )
                     built = built + jnp.where(occupied, chunk_nodes, 0)
                 else:
                     # the sharded path always computes — its psums can't
                     # sit under a data-dependent cond
-                    cf, cb = chunk_stats(local, c0, chunk_nodes)
+                    cf, cb, (ca, cp) = chunk_stats(
+                        local, c0, chunk_nodes, at
+                    )
                     built = built + chunk_nodes
                 return (
                     jax.lax.dynamic_update_slice(feats_a, cf, (0, c0)),
                     jax.lax.dynamic_update_slice(bins_a, cb, (0, c0)),
                     built,
+                    (adm + ca, prs + cp),
                 )
 
             feats_a0 = jnp.full(
@@ -756,22 +885,24 @@ def _grow_tree_impl(
             bins_a0 = jnp.zeros(
                 (k_fits, num_chunks * chunk_nodes), dtype=jnp.int32
             )
-            feats_a, bins_a, built = jax.lax.fori_loop(
-                0, num_chunks, chunk_body, (feats_a0, bins_a0, jnp.int32(0))
+            feats_a, bins_a, built, counts = jax.lax.fori_loop(
+                0, num_chunks, chunk_body,
+                (feats_a0, bins_a0, jnp.int32(0), zero2),
             )
-            return feats_a[:, :n_nodes], bins_a[:, :n_nodes], built
+            return feats_a[:, :n_nodes], bins_a[:, :n_nodes], built, counts
 
         def one_build(width):
             """The whole level in ONE build at ``width`` slots (a rung that
             holds every live slot), padded to the [K, n_nodes] the scan
             carries."""
             def run():
-                cf, cb = chunk_stats(local, 0, width)
+                cf, cb, counts = chunk_stats(local, 0, width, at)
                 pad = ((0, 0), (0, n_nodes - width))
                 return (
                     jnp.pad(cf, pad, constant_values=-1),
                     jnp.pad(cb, pad),
                     jnp.int32(width),
+                    counts,
                 )
 
             return run
@@ -791,17 +922,25 @@ def _grow_tree_impl(
         # (replicated-predicate collectives under shard_map are not worth
         # the coupling).
         if axis_name is not None:
-            feats_c, bins_c, built = live_level()
+            feats_c, bins_c, built, counts = live_level()
         else:
-            feats_c, bins_c, built = jax.lax.cond(
+            feats_c, bins_c, built, counts = jax.lax.cond(
                 alive,
                 live_level,
                 lambda: (
                     jnp.full((k_fits, n_nodes), -1, dtype=jnp.int32),
                     jnp.zeros((k_fits, n_nodes), dtype=jnp.int32),
                     jnp.int32(0),
+                    zero2,
                 ),
             )
+        # builds made and chunks the occupancy branch left out: a rung is
+        # one build under a chunk's width, the chunk loop whole chunks
+        chunked = built >= chunk_nodes
+        runs = jnp.where(
+            chunked, built // chunk_nodes, (built > 0).astype(jnp.int32)
+        )
+        skipped = jnp.where(chunked, num_chunks - runs, 0)
         if max_depth_v is not None:
             # per-lane depth cap: a lane past its depth emits no splits
             # (identical trees to a program compiled at that lane's depth —
@@ -823,7 +962,7 @@ def _grow_tree_impl(
             # one-hot select, NOT take_along_axis: the [K, max_nodes]
             # gather from [K, cap] lowered to a serializing custom-fusion
             # gather measured at ~1 ms per level — 1.2 s of the 1.7 s
-            # depth-12 RF program (trace: tools/trace_rf12.py)
+            # depth-12 RF program (Titanic's 891 rows, round 5)
             feats_d = jnp.where(
                 live, _small_table_lookup(feats_c, rank_c), -1
             )
@@ -838,9 +977,12 @@ def _grow_tree_impl(
             go_right = active & (row_feat >= 0) & (code > row_thr)
             node = node * 2 + go_right.astype(jnp.int32)
             active = active & (row_feat >= 0)
-        return (node, active, alive), (feats_d, bins_d, n_live, built)
+        return (node, active, alive), (
+            feats_d, bins_d,
+            HistSlots(n_live, built, runs, skipped, *counts),
+        )
 
-    (node, active, _), (feats_s, bins_s, live_s, built_s) = jax.lax.scan(
+    (node, active, _), (feats_s, bins_s, slots_s) = jax.lax.scan(
         level_body,
         (
             jnp.zeros((k_fits, n), dtype=jnp.int32),
@@ -863,7 +1005,7 @@ def _grow_tree_impl(
     # `node` is each row's final leaf slot — boosting's margin update reuses
     # it (leaf_value lookup) instead of re-traversing the tree (measured
     # ~100 ms/round of serialized gathers at 1M rows)
-    return tree, node, HistSlots(live=live_s, built=built_s)
+    return tree, node, slots_s
 
 
 def predict_tree(binned: jax.Array, tree: Tree) -> jax.Array:
@@ -908,6 +1050,8 @@ def fit_forest(
     parallel_fits: int = 1,  # kept for API compat
     lowp: bool = False,
     feature_groups=None,
+    feature_subset: int | None = None,
+    info_gain_norm: float = 2.0,
 ) -> Tree:
     """Random forest of mean-target trees — the K=1 case of
     fit_forest_batched (Spark RandomForest parity: variance impurity ==
@@ -919,7 +1063,8 @@ def fit_forest(
         subsample_rate=subsample_rate, colsample_rate=colsample_rate,
         min_instances=min_instances, min_info_gain=min_info_gain,
         seed=int(seed), bootstrap=bootstrap, lowp=lowp,
-        feature_groups=feature_groups,
+        feature_groups=feature_groups, feature_subset=feature_subset,
+        info_gain_norm=info_gain_norm,
     )
     return jax.tree.map(lambda a: a[0], trees)
 
@@ -1226,6 +1371,12 @@ def sweep_forest_outputs(
     return jax.vmap(lambda t: predict_forest(binned, t))(trees)
 
 
+def _node_key(tkey):
+    """The key a tree's node subsets are folded from: the second half of
+    the tree's key (the first draws its bootstrap counts)."""
+    return jax.random.split(tkey)[1]
+
+
 @partial(jax.jit, static_argnames=("n", "f", "bootstrap"))
 def _bag_masks(tkey, sub, col, row_mask, n, f, bootstrap):
     """Bootstrap row counts + feature masks for one tree across K fits.
@@ -1256,13 +1407,15 @@ def _bag_masks(tkey, sub, col, row_mask, n, f, bootstrap):
     jax.jit,
     static_argnames=(
         "num_trees", "max_depth", "num_bins", "bootstrap", "lowp", "hist_impl",
+        "feature_subset", "info_gain_norm",
     ),
 )
 def _forest_trees_scan(
     binned, target, row_mask, seed_arr, sub, col, min_instances,
     min_info_gain,
-    feature_groups=None, max_depth_v=None, subset_n=None, subset_w=None, *,
+    feature_groups=None, max_depth_v=None, *,
     num_trees, max_depth, num_bins, bootstrap, lowp, hist_impl=None,
+    feature_subset, info_gain_norm,
 ) -> tuple[Tree, jax.Array, HistSlots]:
     """The whole bagged forest as ONE program: ``lax.scan`` over the
     per-tree PRNG keys with a single tree-growth body (the same shape as
@@ -1273,12 +1426,10 @@ def _forest_trees_scan(
     Masks are drawn per tree from the same keys, so forests are
     bit-identical to the per-tree path.
 
-    ``subset_n``/``subset_w`` ([T, n_sub] int32, optional) are per-tree
-    colsample feature subsets (narrow/wide partition) sampled host-side by
-    ``fit_forest_batched``: each tree's histogram work runs over only its
-    ~√F sampled columns via the feature_groups gather machinery instead of
-    masking gains over the full one-hot width (a ~30× FLOP cut on
-    transmogrified matrices, where most columns are indicators).
+    ``feature_subset`` columns are admissible at each NODE, drawn inside
+    the growth from the tree's second key and the node's heap index
+    (``_grow_tree_impl``): every tree builds its histograms over every
+    column, as the source's algorithm does.
 
     Returns (Tree arrays [K, T, ...], training outputs [K, N], HistSlots
     [T, depth]) — the outputs are each lane's mean-leaf prediction over
@@ -1309,20 +1460,16 @@ def _forest_trees_scan(
         jax.random.PRNGKey(seed_arr[0].astype(jnp.uint32)), num_trees
     )
 
-    def body(_, xs):
-        tk, sn, sw = xs
-        rm_t, fm_t = _bag_masks(
-            tk, sub, jnp.ones_like(col) if sn is not None else col,
-            row_mask, n, f, bootstrap,
-        )
-        grp = (sn, sw) if sn is not None else feature_groups
+    def body(_, tk):
+        rm_t, fm_t = _bag_masks(tk, sub, col, row_mask, n, f, bootstrap)
         tree, node, slots = _grow_tree_impl(
             binned, gb, ones, rm_t, fm_t,
             max_depth=max_depth, num_bins=num_bins,
             reg_lambda=0.0, gamma=0.0,
             min_child_weight=mi_k, min_info_gain=mg_k,
-            hist_impl=hist_impl, lowp=lowp, feature_groups=grp,
-            max_depth_v=max_depth_v,
+            hist_impl=hist_impl, lowp=lowp, feature_groups=feature_groups,
+            max_depth_v=max_depth_v, info_gain_norm=info_gain_norm,
+            node_subset=feature_subset, node_key=_node_key(tk),
         )
         # this tree's prediction for EVERY row from the grower's own final
         # routing (leaf lookup — no re-traversal)
@@ -1330,9 +1477,7 @@ def _forest_trees_scan(
             pred_t = _small_table_lookup(tree.leaf_value, node)
         return None, (tree, pred_t, slots)
 
-    _, (trees, preds, slots) = jax.lax.scan(
-        body, None, (tkeys, subset_n, subset_w)
-    )  # [T, K, ...]
+    _, (trees, preds, slots) = jax.lax.scan(body, None, tkeys)  # [T, K, ...]
     with jax.named_scope("tree/outputs"):
         outs = preds.mean(axis=0)  # [K, N] forest mean-leaf outputs
     return (
@@ -1359,6 +1504,8 @@ def fit_forest_batched(
     max_depth_v=None,     # [K] int32: per-lane depth caps (see _grow_tree_impl)
     return_outputs: bool = False,
     return_slots: bool = False,
+    feature_subset: int | None = None,
+    info_gain_norm: float = 2.0,
 ) -> Tree:
     """K random forests batched over the fit axis, the whole bagged forest
     as ONE scan-over-trees program (_forest_trees_scan — one tree-growth
@@ -1369,47 +1516,19 @@ def fit_forest_batched(
     and with ``return_slots`` last the fit's ``HistSlots`` [T, depth] (for
     ``await_outputs``; None from a sharded fit, which has recorded them).
 
-    A static ``colsample_rate`` < 1 with ``feature_groups`` samples an
-    EXACT-COUNT feature subset per tree host-side (Spark's
-    featureSubsetStrategy picks an exact number of features, not a
-    Bernoulli mask; subsets are proportionally stratified over the
-    narrow/wide bin groups) and the histogram work gathers only those
-    columns — ~30× less one-hot GEMM width at √F rates on transmogrified
-    matrices.
+    ``feature_subset`` (default: all F) is the count of columns each NODE
+    may split on (Spark's featureSubsetStrategy, resolved by the caller:
+    ``models/gbdt.py``); ``colsample_rate`` is a per-LANE Bernoulli column
+    mask a tree (XGBoost's colsample_bytree), and the node's subset
+    multiplies it. ``info_gain_norm``: 4 for the Gini impurity of a 0/1
+    target, 2 for the variance (see ``_grow_tree_impl``).
 
     With ``mesh`` set, rows shard over the mesh's data axis and each level's
     histogram psums over it (grows the same trees as the unsharded path —
     see _grow_tree_impl)."""
     k_fits, n = row_mask.shape
-    # ---- exact-count per-tree feature subsets (static rate only: the
-    # flagship RF path passes a python float; per-lane traced rates keep
-    # the dense-mask path)
-    subset_n = subset_w = None
-    rate = (
-        float(colsample_rate)
-        if isinstance(colsample_rate, (int, float)) else None
-    )
-    if rate is not None and rate < 1.0 and feature_groups is not None:
-        narrow_idx = np.asarray(feature_groups[0])
-        wide_idx = np.asarray(feature_groups[1])
-        f_n, f_w = len(narrow_idx), len(wide_idx)
-        f_all = f_n + f_w
-        n_sub = max(1, int(round(f_all * rate)))
-        if n_sub < f_all:
-            n_sub_n = min(f_n, int(round(n_sub * f_n / max(f_all, 1))))
-            n_sub_w = min(f_w, n_sub - n_sub_n)
-            n_sub_n = min(f_n, n_sub - n_sub_w)
-            rng = np.random.default_rng([int(seed), 0x5EED])
-            def draw(idx, k):
-                return np.stack([
-                    np.sort(rng.choice(idx, size=k, replace=False))
-                    for _ in range(num_trees)
-                ]).astype(np.int32) if k else np.zeros(
-                    (num_trees, 0), dtype=np.int32
-                )
-            subset_n = jnp.asarray(draw(narrow_idx, n_sub_n))
-            subset_w = jnp.asarray(draw(wide_idx, n_sub_w))
-            colsample_rate = 1.0  # masks are all-ones under subsets
+    if feature_subset is None:
+        feature_subset = int(binned.shape[1])
     # host-side numpy for every small knob: a dtype-converting or
     # broadcasting jnp op here is an EAGER device program compiled per
     # process; f32 numpy arrays transfer without compiling anything, and
@@ -1446,7 +1565,7 @@ def fit_forest_batched(
             jnp.asarray(col), mi, mg,
             num_trees=num_trees, max_depth=max_depth, num_bins=num_bins,
             bootstrap=bootstrap, lowp=lowp, feature_groups=feature_groups,
-            subset_n=subset_n, subset_w=subset_w,
+            feature_subset=feature_subset, info_gain_norm=info_gain_norm,
         )
         # the sharded fit has pulled its results, and recorded its slots
         return _fit_result(trees, outs, None, return_outputs, return_slots)
@@ -1455,8 +1574,10 @@ def fit_forest_batched(
     trees, outs, slots = aot_call(
         "forest_scan", _forest_trees_scan,
         (binned, target, row_mask, seed_arr, sub, col, mi, mg,
-         feature_groups, max_depth_v, subset_n, subset_w),
+         feature_groups, max_depth_v),
         dict(num_trees=num_trees,
+             feature_subset=int(feature_subset),
+             info_gain_norm=float(info_gain_norm),
              max_depth=max_depth, num_bins=num_bins, bootstrap=bootstrap,
              # lowp is only sound when target values are bf16-exact
              # (classification indicators); regression keeps f32
@@ -1478,7 +1599,10 @@ def _fit_result(trees, outputs, slots, return_outputs, return_slots):
 
 @partial(
     jax.jit,
-    static_argnames=("max_depth", "num_bins", "num_rounds", "objective", "parallel_fits"),
+    static_argnames=(
+        "max_depth", "num_bins", "num_rounds", "objective", "parallel_fits",
+        "info_gain_norm",
+    ),
 )
 def fit_boosted(
     binned: jax.Array,
@@ -1496,10 +1620,13 @@ def fit_boosted(
     objective: str = "binary:logistic",
     parallel_fits: int = 1,
     feature_groups=None,
+    info_gain_norm: float = 0.0,
 ) -> tuple[Tree, jax.Array]:
     """Gradient boosting (XGBoost/Spark-GBT parity): lax.scan over rounds,
     second-order gradients, shrinkage eta. Returns stacked trees [R, ...]
-    and the training margin."""
+    and the training margin. ``info_gain_norm``: 0 for XGBoost's absolute
+    stop rule, 2 for Spark GBT's per-row variance decrease
+    (``_grow_tree_impl``)."""
     n, f = binned.shape
     feat_mask = jnp.ones(f, dtype=jnp.float32)
 
@@ -1519,6 +1646,7 @@ def fit_boosted(
             reg_lambda=reg_lambda, gamma=gamma,
             min_child_weight=min_child_weight, min_info_gain=min_info_gain,
             parallel_fits=parallel_fits, feature_groups=feature_groups,
+            info_gain_norm=info_gain_norm,
         )
         with jax.named_scope("tree/outputs"):
             margin = margin + eta * predict_tree(binned, tree)
@@ -1543,7 +1671,7 @@ def _boost_chunk_body(
     binned, y, row_mask, margin0, eta_v, reg_lambda, gamma,
     min_child_weight, min_info_gain, feature_groups=None, *,
     num_rounds, max_depth, num_bins, objective,
-    axis_name=None, axis_size=1, hist_impl=None,
+    axis_name=None, axis_size=1, hist_impl=None, info_gain_norm=0.0,
 ) -> tuple[Tree, jax.Array, HistSlots]:
     """A chunk of boosting rounds for all K fits (lax.scan inside one
     program) — shared by the single-device jit and the shard_map'd path
@@ -1569,7 +1697,7 @@ def _boost_chunk_body(
             reg_lambda=reg_lambda, gamma=gamma,
             min_child_weight=min_child_weight, min_info_gain=min_info_gain,
             axis_name=axis_name, axis_size=axis_size, hist_impl=hist_impl,
-            feature_groups=feature_groups,
+            feature_groups=feature_groups, info_gain_norm=info_gain_norm,
         )
         # margin update straight from the grower's final routing — one
         # small-table lookup instead of a full predict_tree re-traversal
@@ -1591,7 +1719,7 @@ _boost_rounds_batched = partial(
     jax.jit,
     static_argnames=(
         "num_rounds", "max_depth", "num_bins", "objective",
-        "axis_name", "axis_size", "hist_impl",
+        "axis_name", "axis_size", "hist_impl", "info_gain_norm",
     ),
 )(_boost_chunk_body)
 
@@ -1633,6 +1761,7 @@ def fit_boosted_batched(
     mesh=None,
     feature_groups=None,
     return_slots: bool = False,
+    info_gain_norm: float = 0.0,
 ) -> tuple[Tree, jax.Array]:
     """K boosting runs batched over the fit axis: every round grows all K
     trees in one histogram build; rounds scan in fixed-size chunks so each
@@ -1668,7 +1797,7 @@ def fit_boosted_batched(
             jnp.asarray(gam), jnp.asarray(mcw), jnp.asarray(mig),
             base_score=base_score, num_rounds=num_rounds,
             max_depth=max_depth, num_bins=num_bins, objective=objective,
-            feature_groups=feature_groups,
+            feature_groups=feature_groups, info_gain_norm=info_gain_norm,
         )
         return _fit_result(trees, margin, None, True, return_slots)
     # f32 numpy broadcast (no eager compile), then ONE device transfer so
@@ -1689,7 +1818,7 @@ def fit_boosted_batched(
         "boost_chunk", _boost_rounds_batched, donate_argnums=(3,),
         static_argnames=(
             "num_rounds", "max_depth", "num_bins", "objective",
-            "axis_name", "axis_size", "hist_impl",
+            "axis_name", "axis_size", "hist_impl", "info_gain_norm",
         ),
     )
     chunks = []
@@ -1703,7 +1832,8 @@ def fit_boosted_batched(
             (binned, y, row_mask, margin, eta_v, lam, gam, mcw, mig,
              feature_groups),
             dict(num_rounds=rc, max_depth=max_depth, num_bins=num_bins,
-                 objective=objective, hist_impl=_resolved_impl()),
+                 objective=objective, hist_impl=_resolved_impl(),
+                 info_gain_norm=float(info_gain_norm)),
         )
         chunks.append(trees_c)  # each [K, rc, ...] (swap happens in-jit)
         slot_chunks.append(slots_c)
@@ -1779,13 +1909,15 @@ def _sharded_grow_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
 
 @lru_cache(maxsize=None)
 def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
-                                has_groups=False, has_subsets=False):
+                                has_groups=False, feature_subset=None,
+                                info_gain_norm=2.0):
     """jit(shard_map(scan-over-trees)): the sharded counterpart of
     _forest_trees_scan. Per-tree masks are drawn OUTSIDE (global-row
     semantics) and enter sharded on the row axis; the scan carries the
-    whole forest in one program, psum'ing each level's histograms. Also
-    emits [K, N] training outputs (row-sharded) like the single-device
-    scan."""
+    whole forest in one program, psum'ing each level's histograms. Each
+    tree's node-subset key rides the scan too (replicated: every shard
+    draws the same subsets). Also emits [K, N] training outputs
+    (row-sharded) like the single-device scan."""
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
@@ -1793,13 +1925,7 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
 
     size = mesh.shape[DATA_AXIS]
 
-    def body_fn(binned, target, rmasks, fmasks, mi_k, mg_k, *rest):
-        if has_subsets:
-            subset_n, subset_w = rest[-2:]
-            rest = rest[:-2]
-        else:
-            subset_n = subset_w = None
-        grp = rest if rest else None
+    def body_fn(binned, target, rmasks, fmasks, nkeys, mi_k, mg_k, *grp):
         k_fits = rmasks.shape[1]
         n_local = binned.shape[0]
         with jax.named_scope("tree/gradients"):
@@ -1807,7 +1933,7 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
         ones = jnp.ones((k_fits, n_local), dtype=jnp.float32)
 
         def one_tree(_, xs):
-            rm_t, fm_t, sn, sw = xs
+            rm_t, fm_t, nk = xs
             tree, node, slots = _grow_tree_impl(
                 binned, gb, ones, rm_t, fm_t,
                 max_depth=max_depth, num_bins=num_bins,
@@ -1815,14 +1941,16 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
                 min_child_weight=mi_k, min_info_gain=mg_k,
                 hist_impl=hist_impl, lowp=lowp,
                 axis_name=DATA_AXIS, axis_size=size,
-                feature_groups=(sn, sw) if sn is not None else grp,
+                feature_groups=grp if grp else None,
+                info_gain_norm=info_gain_norm,
+                node_subset=feature_subset, node_key=nk,
             )
             with jax.named_scope("tree/outputs"):
                 pred_t = _small_table_lookup(tree.leaf_value, node)
             return None, (tree, pred_t, slots)
 
         _, (trees, preds, slots) = jax.lax.scan(
-            one_tree, None, (rmasks, fmasks, subset_n, subset_w)
+            one_tree, None, (rmasks, fmasks, nkeys)
         )
         with jax.named_scope("tree/outputs"):
             outs = preds.mean(axis=0)  # [K, n_local]
@@ -1840,13 +1968,13 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
             P(DATA_AXIS),              # target [N]
             P(None, None, DATA_AXIS),  # rmasks [T, K, N]
             rep,                       # fmasks [T, K, F]
+            rep,                       # nkeys [T, 2]
             rep, rep,
-        ) + ((rep, rep) if has_groups else ())
-          + ((rep, rep) if has_subsets else ()),
+        ) + ((rep, rep) if has_groups else ()),
         out_specs=(
             Tree(split_feat=rep, split_bin=rep, leaf_value=rep),
             P(None, DATA_AXIS),
-            HistSlots(live=rep, built=rep),
+            HistSlots(*(rep for _ in HistSlots._fields)),
         ),
         check_vma=False,
     )
@@ -1856,7 +1984,7 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
 def _fit_forest_batched_sharded(
     mesh, binned, target, row_mask, tkeys, sub, col, mi, mg,
     num_trees, max_depth, num_bins, bootstrap, lowp, feature_groups=None,
-    subset_n=None, subset_w=None,
+    feature_subset=None, info_gain_norm=2.0,
 ) -> tuple[Tree, np.ndarray]:
     from ..parallel.mesh import DATA_AXIS
 
@@ -1877,13 +2005,12 @@ def _fit_forest_batched_sharded(
     kern = _sharded_forest_scan_kernel(
         mesh, max_depth, num_bins, _resolved_impl(), lowp,
         has_groups=feature_groups is not None,
-        has_subsets=subset_n is not None,
+        feature_subset=feature_subset, info_gain_norm=info_gain_norm,
     )
     grp_args = tuple(feature_groups) if feature_groups is not None else ()
-    if subset_n is not None:
-        grp_args = grp_args + (subset_n, subset_w)
-    trees, outs, slots = kern(binned_p, target_p, rmasks, fmasks, mi_k, mg_k,
-                              *grp_args)
+    nkeys = jax.vmap(_node_key)(tkeys)
+    trees, outs, slots = kern(binned_p, target_p, rmasks, fmasks, nkeys,
+                              mi_k, mg_k, *grp_args)
     # pull replicated trees to HOST once
     trees, outs = await_outputs((trees, outs), hist_slots=slots)
     return trees, outs[:, :n]
@@ -1891,7 +2018,8 @@ def _fit_forest_batched_sharded(
 
 @lru_cache(maxsize=None)
 def _sharded_boost_kernel(mesh, num_rounds, max_depth, num_bins, objective,
-                          hist_impl=None, has_groups=False):
+                          hist_impl=None, has_groups=False,
+                          info_gain_norm=0.0):
     """jit(shard_map(boost-round-chunk)): margins stay row-sharded across
     the scan; each round's histogram build psums over the data axis."""
     from jax import shard_map
@@ -1908,7 +2036,7 @@ def _sharded_boost_kernel(mesh, num_rounds, max_depth, num_bins, objective,
             grp if grp else None,
             num_rounds=num_rounds, max_depth=max_depth, num_bins=num_bins,
             objective=objective, axis_name=DATA_AXIS, axis_size=size,
-            hist_impl=hist_impl,
+            hist_impl=hist_impl, info_gain_norm=info_gain_norm,
         )
 
     rep = P()
@@ -1925,7 +2053,7 @@ def _sharded_boost_kernel(mesh, num_rounds, max_depth, num_bins, objective,
         out_specs=(
             Tree(split_feat=rep, split_bin=rep, leaf_value=rep),
             P(None, DATA_AXIS),
-            HistSlots(live=rep, built=rep),
+            HistSlots(*(rep for _ in HistSlots._fields)),
         ),
         check_vma=False,
     )
@@ -1935,7 +2063,7 @@ def _sharded_boost_kernel(mesh, num_rounds, max_depth, num_bins, objective,
 def _fit_boosted_batched_sharded(
     mesh, binned, y, row_mask, eta_v, lam, gam, mcw, mig,
     base_score, num_rounds, max_depth, num_bins, objective,
-    feature_groups=None,
+    feature_groups=None, info_gain_norm=0.0,
 ) -> tuple[Tree, jax.Array]:
     from ..parallel.mesh import DATA_AXIS
 
@@ -1960,7 +2088,8 @@ def _fit_boosted_batched_sharded(
         rc = min(chunk_size, num_rounds - done)
         kern = _sharded_boost_kernel(mesh, rc, max_depth, num_bins, objective,
                                      _resolved_impl(),
-                                     has_groups=feature_groups is not None)
+                                     has_groups=feature_groups is not None,
+                                     info_gain_norm=float(info_gain_norm))
         grp_args = tuple(feature_groups) if feature_groups is not None else ()
         trees_c, margin, slots_c = kern(
             binned_p, y_p, rm_p, margin, eta_v, lam, gam, mcw, mig, *grp_args
@@ -2021,11 +2150,12 @@ def program_trace_specs():
                 jax.ShapeDtypeStruct((k,), f32),       # sub
                 jax.ShapeDtypeStruct((k,), f32),       # col
                 s, s,                                  # mi, mg
-                None, None, None, None,
+                None, None,
             ),
             dict(
                 num_trees=2, max_depth=2, num_bins=4, bootstrap=True,
                 lowp=False, hist_impl=_resolved_impl(),
+                feature_subset=2, info_gain_norm=4.0,
             ),
         )
 
@@ -2039,7 +2169,7 @@ def program_trace_specs():
             donate_argnums=(3,),
             static_argnames=(
                 "num_rounds", "max_depth", "num_bins", "objective",
-                "axis_name", "axis_size", "hist_impl",
+                "axis_name", "axis_size", "hist_impl", "info_gain_norm",
             ),
         ),
         dict(
