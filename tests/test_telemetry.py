@@ -904,7 +904,19 @@ def test_binned_records_miss_then_hit_and_the_ledger_agrees():
     names = [r["name"] for r in tspans.snapshot_events()]
     assert names.count("tree/thresholds") == 1
     assert names.count("tree/bin_dispatch") == 1
+    # the miss names the route its column statistics took (a plane this
+    # small stays on the host), and the ledger counts it
+    (thr,) = [
+        r["args"] for r in tspans.snapshot_events()
+        if r["name"] == "tree/thresholds"
+    ]
+    assert thr == {
+        "rows": 600, "cols": 7, "bins": 8, "dtype": "float32",
+        "route": "host", "why": "small",
+    }
     now = gbdt.bin_cache_stats().snapshot()
+    assert now["thresholdsHost"] - before["thresholdsHost"] == 1
+    assert now["thresholdsDevice"] == before["thresholdsDevice"]
     assert now["binCacheLookups"] - before["binCacheLookups"] == 2
     assert now["binCacheHits"] - before["binCacheHits"] == 1
     assert now["binCacheEntries"] == 1
@@ -913,6 +925,7 @@ def test_binned_records_miss_then_hit_and_the_ledger_agrees():
     text = texport.render_prometheus()
     assert f"tptpu_tree_bin_cache_lookups {now['binCacheLookups']}" in text
     assert "tptpu_tree_bin_cache_device_bytes 16800" in text
+    assert f"tptpu_tree_thresholds_host {now['thresholdsHost']}" in text
 
 
 def test_disabled_telemetry_leaves_sweep_path_unrecorded():

@@ -14,11 +14,12 @@ mode; Mosaic refused it at every shape).
 
 Compiled at main-path shapes: the bin-loop histogram (32 bins) and the
 lane-packed histogram (256 bins) in both precisions, the serve-side
-traversal kernel at more than one tree tile (one-byte and split codes), and
-one whole ``boost_chunk`` program (65,536 x 64, depth 10) with the Pallas
-histogram inside. ``--serve-matrix`` adds 8/50/200/1000 trees x depth
-3/6/10/12. Exit codes: 0 all compiled, 1 a compile failed, 77 no TPU
-topology available here (the tier-1 test skips on it).
+traversal kernel at more than one tree tile (one-byte and split codes), one
+whole ``boost_chunk`` program (65,536 x 64, depth 10) with the Pallas
+histogram inside, and the binning's column statistics (the chunked sort).
+``--serve-matrix`` adds 8/50/200/1000 trees x depth 3/6/10/12. Exit codes:
+0 all compiled, 1 a compile failed, 77 no TPU topology available here (the
+tier-1 test skips on it).
 """
 from __future__ import annotations
 
@@ -92,6 +93,10 @@ def main(argv) -> int:
             num_rounds=200, max_depth=10, num_bins=32,
             objective="binary:logistic", hist_impl="pallas",
         ),
+    ))
+    jobs.append((
+        "bin_column_stats 65536x70, 32 bins (3 sorts of 32 columns)",
+        lambda: TR.bin_column_stats.lower(sds((n, 70), f32), max_bins=32),
     ))
 
     failed = 0

@@ -40,7 +40,8 @@ SCORE_PROGRAMS = frozenset(
 
 _TREE_PROGRAMS = frozenset(
     {
-        "bin_data", "boost_chunk", "forest_scan", "sweep_boost_outputs",
+        "bin_data", "bin_column_stats", "boost_chunk", "forest_scan",
+        "sweep_boost_outputs",
         "sweep_forest_outputs", "stack_lane", "predict_boosted",
         "predict_forest",
     }

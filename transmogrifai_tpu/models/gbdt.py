@@ -44,15 +44,18 @@ _BINNED_LOCK = _threading.Lock()
 
 class BinCacheStats(_tm.LedgerCore):
     """``treeStats`` — what ``_BINNED_CACHE`` was asked and what it holds:
-    look-ups and hits (cumulative), and the entries and device bytes it
-    kept after the last look-up. Registered as the ``tree`` source of
-    ``telemetry.render_prometheus()``; the same numbers ride each
-    ``tree/bin_prepare`` span as attributes."""
+    look-ups and hits (cumulative), the entries and device bytes it
+    kept after the last look-up, and its misses by the route their
+    thresholds took (``_bin_into_cache``). Registered as the ``tree``
+    source of ``telemetry.render_prometheus()``; the cache's numbers ride
+    each ``tree/bin_prepare`` span as attributes."""
 
     def __init__(self) -> None:
         super().__init__((
             "binCacheLookups", "binCacheHits",         # cumulative
             "binCacheEntries", "binCacheDeviceBytes",  # after the last look-up
+            # cache misses by where their column statistics were computed
+            "thresholdsDevice", "thresholdsHost",      # cumulative
         ))
 
     def record_lookup(self, hit: bool, entries: int, device_bytes: int) -> None:
@@ -125,16 +128,32 @@ def _feature_bin_groups(x: np.ndarray):
     ones. Tree growth searches the narrow group at 2 bins instead of
     max_bins — split-search cost scales with features×bins, so this is a
     ~10-16× cut on one-hot-heavy matrices with identical fitted trees
-    (trees._grow_tree_impl docstring). Host-side and cheap: one vectorized
-    pass over the matrix."""
+    (trees._grow_tree_impl docstring). Host-side: one vectorized pass
+    over the matrix (``trees.bin_column_stats`` makes the same pass on the
+    device where ``_bin_into_cache`` takes that route)."""
     xf = np.asarray(x)
     with np.errstate(invalid="ignore"):
         binary = ((xf == 0) | (xf == 1) | ~np.isfinite(xf)).all(axis=0)
+    return _groups_from_flags(binary)
+
+
+def _groups_from_flags(binary: np.ndarray):
+    """``_feature_bin_groups``' result from its per-column predicate."""
     narrow = np.nonzero(binary)[0].astype(np.int32)
     wide = np.nonzero(~binary)[0].astype(np.int32)
     if len(narrow) == 0:
         return None
     return jnp.asarray(narrow), jnp.asarray(wide)
+
+
+# Planes of at least this many values (rows x columns) take their column
+# statistics on the device (``_bin_into_cache``). The host's two passes cost
+# 51-70 ns a value, the program 1.9 ns (a v5e at 1,002,701 x 357: PERF.md
+# section 6, PR 28), but a shape the machine's bank has not seen compiles
+# for 11-16 s first: at 2^26 values (a 268 MB plane, 3.4-4.7 s of host
+# passes) four misses repay that; under it the host's pass is the smaller
+# risk, and the tier-1 suite's planes (under 2^20) compile no sort.
+_DEVICE_STATS_MIN_VALUES = 1 << 26
 
 
 _bin_data_jit = jax.jit(TR.bin_data)
@@ -734,40 +753,80 @@ class _TreeEstimator(PredictorEstimator):
         return entry[1], entry[2], entry[3]
 
     def _bin_into_cache(self, x, key):
-        """The cache miss: thresholds on the host, the matrix and the
-        thresholds to the device, the binning program, the 0/1-column
-        scan; returns the new cache entry."""
-        with _tspans.span(
-            "tree/thresholds", rows=int(x.shape[0]), cols=int(x.shape[1]),
-            bins=int(self.max_bins), dtype=str(getattr(x, "dtype", "")),
-        ):
-            thresholds = TR.quantile_thresholds(x, self.max_bins)
+        """The cache miss: the matrix to the device, the thresholds and
+        the 0/1-column flags, the binning program; returns the new cache
+        entry."""
         # through the AOT executable bank: a plain bin_data call would
         # acquire its program on the sweep's critical path
         from ..utils.aot import aot_call
 
         from ..compiler.dispatch import device_f32, prefetch_pending
 
+        rows, cols, bins = int(x.shape[0]), int(x.shape[1]), int(self.max_bins)
         # device_f32 picks up the async upload the DAG fit prefetched for
         # this matrix, when one is in flight (compiler.dispatch)
         with _tspans.span(
             "tree/upload",
-            bytes=4 * int(x.size) + int(thresholds.nbytes),
+            bytes=4 * int(x.size) + 4 * cols * (bins - 1),
             prefetched=prefetch_pending(x),
         ):
-            operands = (device_f32(x), jnp.asarray(thresholds))
+            xj = device_f32(x)
+        with _tspans.span(
+            "tree/thresholds", rows=rows, cols=cols, bins=bins,
+            dtype=str(getattr(x, "dtype", "")),
+        ) as sp:
+            thresholds, binary, why = self._column_stats(x, xj)
+            sp.attrs.update(
+                {"route": "device"} if why is None
+                else {"route": "host", "why": why}
+            )
+            if _tspans.enabled():
+                _BIN_STATS.bump(
+                    "thresholdsDevice" if why is None else "thresholdsHost"
+                )
         with _tspans.span("tree/bin_dispatch"):
-            binned = aot_call("bin_data", _bin_data_jit, operands, {})
+            binned = aot_call(
+                "bin_data", _bin_data_jit, (xj, jnp.asarray(thresholds)), {}
+            )
         with _tspans.span("tree/feature_groups") as sp:
-            fgroups = _feature_bin_groups(x)
+            fgroups = (
+                _feature_bin_groups(x) if binary is None
+                else _groups_from_flags(binary)
+            )
             narrow = 0 if fgroups is None else int(fgroups[0].shape[0])
-            sp.attrs.update(narrow=narrow, wide=int(x.shape[1]) - narrow)
+            sp.attrs.update(narrow=narrow, wide=cols - narrow)
         entry = (x, thresholds, binned, fgroups)
         with _BINNED_LOCK:
             _BINNED_CACHE[key] = entry
             while len(_BINNED_CACHE) > 4:
                 _BINNED_CACHE.pop(next(iter(_BINNED_CACHE)))
         return entry
+
+    def _column_stats(self, x, xj):
+        """(thresholds, 0/1-column flags or None, why the host or None):
+        what binning needs to know of each column of ``x``, from the
+        device's copy ``xj`` (``trees.bin_column_stats``) where the plane is
+        large, unsharded and free of NaN, else from the host's
+        (``trees.quantile_thresholds``; the flags are then
+        ``_feature_bin_groups``' to find). One algorithm run in two places:
+        the results are equal, so nothing but the input picks the place."""
+        from ..compiler.dispatch import _mesh_active
+        from ..utils.aot import aot_call
+
+        rows, cols = x.shape
+        if _mesh_active():  # a row-sharded plane would need a distributed sort
+            why = "mesh"
+        elif rows * cols < _DEVICE_STATS_MIN_VALUES:
+            why = "small"
+        else:
+            stats, binary, any_nan = jax.device_get(aot_call(
+                "bin_column_stats", TR.bin_column_stats, (xj,),
+                {"max_bins": int(self.max_bins)},
+            ))
+            if not any_nan:
+                return TR.thresholds_from_order_stats(stats, rows), binary, None
+            why = "nan"  # np.nanquantile counts each column's own rows
+        return TR.quantile_thresholds(x, self.max_bins), None, why
 
     def _fit_group_masks(self, x, y, masks, group_points):
         """Fit len(masks) × len(group_points) same-static-shape models in

@@ -172,7 +172,110 @@ def quantile_thresholds(x: np.ndarray, max_bins: int = 32) -> np.ndarray:
     qf = np.quantile if not np.isnan(xd).any() else np.nanquantile
     thr = qf(xd, qs, axis=0).T
     # make strictly non-decreasing; duplicate edges simply yield empty bins
-    return np.ascontiguousarray(thr, dtype=np.float32)
+    return _positive_zeros(np.ascontiguousarray(thr, dtype=np.float32))
+
+
+def _positive_zeros(thr: np.ndarray) -> np.ndarray:
+    """A zero edge is +0.0 (``x > -0.0`` is ``x > 0.0``): the sign
+    ``np.quantile`` leaves on one depends on where its partition put the
+    column's -0.0s, which no other route to the same edges can repeat."""
+    return thr + np.float32(0.0)
+
+
+# Columns per sort of ``bin_column_stats``. At 1,002,701 x 357 on a v5e the
+# program takes 0.67 s at 32 and at 64 columns a sort and 0.92 s at 128
+# (tools/bench_bin_prepare.py, PR 28); its temporaries are 0.26 GB at 32,
+# 0.77 GB at 64 and 1.03 GB at 128 whatever F is (the v5e compiler's
+# memory analysis), so the narrowest of the fast ones.
+_STATS_COL_CHUNK = 32
+
+
+def quantile_rows(n: int, max_bins: int):
+    """(lo, hi, gamma) of ``np.quantile``'s 'linear' method at the
+    ``max_bins - 1`` inner quantiles of ``n`` values: the two sorted rows
+    each quantile lies between and its weight, in numpy's own float64
+    arithmetic (``_quantile``: a virtual index at or past the last row
+    reads the last row twice and keeps its gamma)."""
+    qs = np.linspace(0, 1, max_bins + 1)[1:-1]
+    virtual = (n - 1) * qs
+    lo = np.floor(virtual)
+    hi = lo + 1
+    last = virtual >= n - 1
+    lo[last] = hi[last] = -1
+    lo, hi = lo.astype(np.intp), hi.astype(np.intp)
+    return lo % n, hi % n, virtual - lo
+
+
+def _f32_total_order(bits: jax.Array) -> jax.Array:
+    """int32 bit patterns of float32 values -> int32 keys in the values'
+    total order (-0.0 below +0.0, subnormals apart), and back: the map is
+    its own inverse. Integer compares are exact on every backend; float
+    ones flush subnormals to zero on the TPU and in XLA:CPU's sort."""
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+@partial(jax.jit, static_argnames=("max_bins",))
+def bin_column_stats(x: jax.Array, max_bins: int):
+    """What binning needs to know of each column of the float32 plane
+    ``x`` [N, F], from the device that already holds it:
+
+    - ``[F, max_bins - 1, 2]`` float32: the order statistics at
+      ``quantile_rows``' (lo, hi), exact values of the column, from a sort
+      along the rows in chunks of ``_STATS_COL_CHUNK`` columns;
+    - ``[F]`` bool: ``gbdt._feature_bin_groups``' predicate (the column
+      holds only 0, 1 or non-finite values);
+    - ``[]`` bool: whether any value is NaN (the caller then takes
+      ``np.nanquantile`` on the host: its ``n`` differs by column).
+
+    Every compare is on the values' bit patterns, and nothing here
+    interpolates: ``thresholds_from_order_stats`` does, on the host in
+    float64, so the thresholds equal ``quantile_thresholds``'."""
+    n, f = x.shape
+    lo, hi, _gamma = quantile_rows(n, max_bins)
+    rows = np.stack([lo, hi], axis=1).reshape(-1).astype(np.int32)
+    c = min(f, _STATS_COL_CHUNK)
+    n_chunks = -(-f // c)
+    # the last chunk is clamped into the plane (it overlaps its
+    # predecessor where F is no multiple of the chunk): no padded copy
+    starts = np.minimum(np.arange(n_chunks) * c, f - c).astype(np.int32)
+    col = np.arange(f)
+    chunk_of = np.minimum(col // c, n_chunks - 1)
+
+    def chunk_stats(start):
+        cols = jax.lax.dynamic_slice_in_dim(x, start, c, axis=1)
+        keys = _f32_total_order(jax.lax.bitcast_convert_type(cols, jnp.int32))
+        return jnp.sort(keys, axis=0)[rows]  # [2(B-1), c]
+
+    with jax.named_scope("tree/thresholds"):
+        picked = jax.lax.map(chunk_stats, jnp.asarray(starts))
+        picked = picked[chunk_of, :, col - starts[chunk_of]]  # [F, 2(B-1)]
+        stats = jax.lax.bitcast_convert_type(
+            _f32_total_order(picked), jnp.float32
+        )
+        bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+        magnitude = bits & jnp.int32(0x7FFFFFFF)
+        inf = jnp.int32(0x7F800000)
+        one = jnp.int32(0x3F800000)
+        binary = jnp.all(
+            (magnitude == 0) | (bits == one) | (magnitude >= inf), axis=0
+        )
+        any_nan = jnp.any(magnitude > inf)
+    return stats.reshape(f, max_bins - 1, 2), binary, any_nan
+
+
+def thresholds_from_order_stats(stats: np.ndarray, n_rows: int):
+    """``quantile_thresholds``' [F, max_bins-1] float32 from
+    ``bin_column_stats``' order statistics of an ``n_rows``-row plane:
+    ``np.quantile``'s linear interpolation (``_lerp``), operation for
+    operation, in float64."""
+    a = np.asarray(stats[..., 0], dtype=np.float64)
+    b = np.asarray(stats[..., 1], dtype=np.float64)
+    t = quantile_rows(n_rows, stats.shape[1] + 1)[2][None, :]
+    with np.errstate(invalid="ignore"):  # inf - inf is nan, as in numpy
+        diff = b - a
+        thr = a + diff * t
+        np.subtract(b, diff * (1 - t), out=thr, where=t >= 0.5)
+    return _positive_zeros(np.ascontiguousarray(thr, dtype=np.float32))
 
 
 def bin_data(x: jax.Array, thresholds: jax.Array) -> jax.Array:
@@ -2109,8 +2212,9 @@ def _fit_boosted_batched_sharded(
 # --------------------------------------------------------------------------
 def program_trace_specs():
     """Representative trace shapes for the banked fit-time tree programs
-    (the boosting-round chunk and the bagged-forest scan). The bucketed
-    axis is the fit-lane count K; rounds/trees/depth stay tiny — jaxpr
+    (the boosting-round chunk, the bagged-forest scan and the binning's
+    column statistics). The bucketed axis is the fit-lane count K (the
+    rows, for the statistics); rounds/trees/depth stay tiny — jaxpr
     structure is independent of them (they only change scan lengths)."""
     import jax
 
@@ -2177,5 +2281,13 @@ def program_trace_specs():
             fn=_forest_trees_scan,
             build=_forest,
             buckets=(4, 8), bucket_axis="lanes",
+        ),
+        dict(
+            name="bin_column_stats",
+            fn=bin_column_stats,
+            build=lambda n: (
+                (jax.ShapeDtypeStruct((n, 3), f32),), dict(max_bins=4)
+            ),
+            buckets=(8, 16),
         ),
     ]
