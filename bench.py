@@ -1091,7 +1091,7 @@ def bench_boosted_scale(
     max_depth: int = 6, num_bins: int = 32,
 ) -> dict:
     """Large-N proof for the two-phase tree path: 1M x 64 boosted trees
-    through fit_boosted_batched (the >FUSED_SPLIT_MAX_ROWS chunked path).
+    through fit_boosted_batched (the Pallas-kernel path, above 4,096 rows).
     Data generated ON DEVICE (the fit is what is timed, not an upload);
     binning thresholds come from a 100k-row device sample."""
     import jax

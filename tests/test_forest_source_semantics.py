@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from transmogrifai_tpu.models import gbdt as G
+from transmogrifai_tpu.models import hist_pallas as HP
 from transmogrifai_tpu.models import trees as TR
 
 BINS = 16
@@ -216,7 +217,7 @@ def test_a_node_draws_the_same_subset_whatever_builds_it(variant, monkeypatch):
         groups = _groups(x)
     else:
         monkeypatch.setattr(TR, "_resolved_impl", lambda: "gemm")
-        monkeypatch.setenv("TPTPU_GEMM_MCAP", variant.split("_")[1])
+        monkeypatch.setattr(HP, "_GEMM_CHUNK_CEIL", int(variant.split("_")[1]))
     _, got = _fit(codes, y, masks, "sqrt", 2, groups=groups, **kw)
     _assert_same(_lane(got, 0), want)
 
@@ -227,7 +228,7 @@ def test_chunked_levels_count_their_chunks(monkeypatch):
     n, depth = 4096, 8
     _x, codes, y = _table(n, seed=5)
     monkeypatch.setattr(TR, "_resolved_impl", lambda: "gemm")
-    monkeypatch.setenv("TPTPU_GEMM_MCAP", "32")
+    monkeypatch.setattr(HP, "_GEMM_CHUNK_CEIL", 32)
     n_sub = G.resolve_feature_subset("sqrt", codes.shape[1], 2, True)
     _trees, slots = TR.fit_forest_batched(
         jnp.asarray(codes), y, np.ones((1, n), F32), num_trees=2,

@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import pytest
 
 from transmogrifai_tpu.models.hist_pallas import (
-    build_best_split_pallas,
     build_histogram_pallas,
     build_histogram_pallas_binloop,
     build_histogram_scatter,
@@ -68,58 +67,6 @@ class TestHistogramKernel:
         out = build_histogram_pallas(binned, dead, g, h, m, b, row_tile=256,
                                      interpret=True)
         assert float(jnp.abs(out).sum()) == 0.0
-
-    def test_fused_split_matches_two_phase(self):
-        """The fused in-kernel gain/arg-best equals gains recomputed from
-        the two-phase histograms (same lambda/gamma/mcw masking)."""
-        rng = np.random.default_rng(3)
-        n, f, b, m, k = 200, 11, 8, 4, 3
-        binned = jnp.asarray(rng.integers(0, b, (n, f)), dtype=jnp.int32)
-        node = jnp.asarray(rng.integers(-1, m, (k, n)), dtype=jnp.int32)
-        g = jnp.asarray(rng.normal(size=(k, n)), dtype=jnp.float32)
-        h = jnp.asarray(rng.uniform(0.1, 1, (k, n)), dtype=jnp.float32)
-        fmask = np.ones((k, f), dtype=np.float32)
-        fmask[1, 0] = 0.0  # one disabled feature on one fit
-        lam = jnp.asarray([1.0, 0.5, 0.0], dtype=jnp.float32)
-        gam = jnp.asarray([0.0, 0.1, 0.0], dtype=jnp.float32)
-        mcw = jnp.asarray([1.0, 1.0, 2.0], dtype=jnp.float32)
-
-        bg, bf, bb = build_best_split_pallas(
-            binned, node, g, h, jnp.asarray(fmask), lam, gam, mcw,
-            num_nodes=m, num_bins=b, interpret=True,
-        )
-
-        hist = np.asarray(
-            build_histogram_scatter_batched(binned, node, g, h, m, b)
-        )
-        hg, hh = hist[..., 0], hist[..., 1]
-        gl = np.cumsum(hg, axis=3)[..., :-1]
-        hl = np.cumsum(hh, axis=3)[..., :-1]
-        gt = hg.sum(axis=3, keepdims=True)
-        ht = hh.sum(axis=3, keepdims=True)
-        gr, hr = gt - gl, ht - hl
-        lam4 = np.asarray(lam)[:, None, None, None]
-        gain = 0.5 * (
-            gl**2 / (hl + lam4) + gr**2 / (hr + lam4) - gt**2 / (ht + lam4)
-        ) - np.asarray(gam)[:, None, None, None]
-        mcw4 = np.asarray(mcw)[:, None, None, None]
-        valid = (hl >= mcw4) & (hr >= mcw4) & (fmask[:, None, :, None] > 0)
-        gain = np.where(valid, gain, -np.inf)
-        ref_best = gain.reshape(k, m, -1).max(axis=2)
-
-        np.testing.assert_allclose(
-            np.asarray(bg), ref_best, rtol=1e-4, atol=1e-4
-        )
-        # the selected (feat, bin) must achieve the best gain
-        for ki in range(k):
-            for mi in range(m):
-                if np.isfinite(ref_best[ki, mi]):
-                    sel = gain[ki, mi, int(bf[ki, mi]), int(bb[ki, mi])]
-                    np.testing.assert_allclose(
-                        sel, ref_best[ki, mi], rtol=1e-4, atol=1e-4
-                    )
-                else:
-                    assert int(bf[ki, mi]) == -1
 
     def test_unaligned_sizes(self):
         # n not a multiple of the row tile; f not a multiple of FEAT_TILE

@@ -7,12 +7,9 @@ override to exercise them on hardware, e.g. through the chip tool).
 
 Covered: the two histogram kernels the tree fits take above 4,096 rows
 (bin-loop at <=64 bins, lane-packed above) against the scatter reference,
-the serve-side traversal kernel against the gather traversal (bit
-parity, per tree and through both ensemble wrappers), and the fused split
-kernel. NOTE (ROADMAP D5): no fit reaches ``build_best_split_pallas``
-any more — ``_grow_tree_impl`` sends every fit of <=4,096 rows to the GEMM
-histogram, and the fused kernel only takes <=2,048 — so its test pins a
-kernel the tree grower cannot select.
+and the serve-side traversal kernel against the gather traversal (bit
+parity, per tree and through both ensemble wrappers). Which builder a fit
+takes is ``hist_pallas.histogram_plan``'s to say (tests/test_histogram_plan.py).
 """
 import numpy as np
 import pytest
@@ -36,52 +33,7 @@ def _case(n, f, b, k, seed=0):
     return binned, node, g, h, fmask
 
 
-@pytest.mark.parametrize("lowp", [False, True])
-def test_fused_split_matches_scatter_on_device(lowp):
-    from transmogrifai_tpu.models.hist_pallas import (
-        build_best_split_pallas,
-        build_histogram_scatter_batched,
-    )
-
-    n, f, b, k, m = 896, 12, 32, 3, 4
-    binned, node, g, h, fmask = _case(n, f, b, k)
-    lam = jnp.full((k,), 1.0)
-    gam = jnp.zeros((k,))
-    mcw = jnp.full((k,), 1.0)
-    bg, bf, bb = build_best_split_pallas(
-        jnp.asarray(binned), jnp.asarray(node), jnp.asarray(g),
-        jnp.asarray(h), jnp.asarray(fmask), lam, gam, mcw,
-        num_nodes=m, num_bins=b, lowp=lowp,
-    )
-    hist = build_histogram_scatter_batched(
-        jnp.asarray(binned), jnp.asarray(node), jnp.asarray(g),
-        jnp.asarray(h), m, b,
-    )
-    hg, hh = hist[..., 0], hist[..., 1]
-    gl = jnp.cumsum(hg, axis=3)[..., :-1]
-    hl = jnp.cumsum(hh, axis=3)[..., :-1]
-    gt = hg.sum(axis=3, keepdims=True)
-    ht = hh.sum(axis=3, keepdims=True)
-    gain = 0.5 * (
-        gl**2 / (hl + 1.0) + (gt - gl) ** 2 / (ht - hl + 1.0)
-        - gt**2 / (ht + 1.0)
-    )
-    valid = (hl >= 1.0) & (ht - hl >= 1.0)
-    gain = jnp.where(valid, gain, -jnp.inf)
-    flat = gain.reshape(k, m, -1)
-    ref_best = np.asarray(jnp.max(flat, axis=2))
-    got = np.asarray(bg)
-    tol = 0.05 if lowp else 1e-3
-    np.testing.assert_allclose(got, ref_best, rtol=tol, atol=tol)
-    # chosen split must achieve (near-)best gain
-    chosen = np.asarray(bf) * (b - 1) + np.asarray(bb)
-    picked = np.take_along_axis(
-        np.asarray(flat), chosen[..., None], axis=2
-    )[..., 0]
-    np.testing.assert_allclose(picked, ref_best, rtol=tol, atol=tol)
-
-
-def test_grow_tree_pallas_vs_scatter_on_device():
+def test_grow_tree_pallas_vs_scatter_on_device(monkeypatch):
     from transmogrifai_tpu.models import trees as TR
 
     rng = np.random.default_rng(1)
@@ -94,13 +46,8 @@ def test_grow_tree_pallas_vs_scatter_on_device():
     kw = dict(num_rounds=4, max_depth=5, num_bins=32, eta=0.3,
               objective="binary:logistic")
     tp, mp = TR.fit_boosted_batched(binned, jnp.asarray(y), masks, **kw)
-    import os
-
-    os.environ["TPTPU_HIST"] = "scatter"
-    try:
-        ts, ms = TR.fit_boosted_batched(binned, jnp.asarray(y), masks, **kw)
-    finally:
-        del os.environ["TPTPU_HIST"]
+    monkeypatch.setattr(TR, "_resolved_impl", lambda: "scatter")
+    ts, ms = TR.fit_boosted_batched(binned, jnp.asarray(y), masks, **kw)
     np.testing.assert_array_equal(
         np.asarray(tp.split_feat), np.asarray(ts.split_feat)
     )

@@ -57,11 +57,12 @@ def _grow(binned, grad, hess, row_mask, impl):
 
 @pytest.fixture()
 def interpret_kernels(monkeypatch):
-    """The Pallas builders in interpret mode, as the tree grower imports
-    them (CPU has no Mosaic)."""
-    monkeypatch.setattr(
-        HP, "build_histogram_pallas_binloop",
-        functools.partial(HP.build_histogram_pallas_binloop, interpret=True),
+    """The bin-loop builder in interpret mode, where the tree grower looks
+    it up (CPU has no Mosaic)."""
+    binloop = HP.BUILDERS["binloop"]
+    monkeypatch.setitem(
+        HP.BUILDERS, "binloop",
+        binloop._replace(build=functools.partial(binloop.build, interpret=True)),
     )
 
 

@@ -787,6 +787,7 @@ def xgb_sweep():
     """One ``ModelSelector.fit_arrays`` over an XGBoost grid (2 points,
     one split + the refit lane) on a small table, scatter histograms."""
     from transmogrifai_tpu.models import gbdt
+    from transmogrifai_tpu.models import trees as TR
     from transmogrifai_tpu.selector.model_selector import make_candidates
     from transmogrifai_tpu.selector.validators import TrainValidationSplit
 
@@ -798,7 +799,7 @@ def xgb_sweep():
         seed=3, models=models, validator=TrainValidationSplit(seed=3)
     )
     with pytest.MonkeyPatch.context() as mp:
-        mp.setenv("TPTPU_HIST", "scatter")
+        mp.setattr(TR, "_resolved_impl", lambda: "scatter")
         gbdt._BINNED_CACHE.clear()
         tspans.reset_for_tests()
         t0 = time.perf_counter()

@@ -66,7 +66,7 @@ def main(argv) -> int:
         ))
         jobs.append((
             f"hist lane-packed 256 bins lowp={lowp}",
-            lambda a=hist_args, lp=lowp: HP._build_histogram_pallas_batched
+            lambda a=hist_args, lp=lowp: HP.build_histogram_pallas_batched
             .lower(*a, num_nodes=m, num_bins=256, lowp=lp),
         ))
     serve = [(200, 10), (50, 12)]

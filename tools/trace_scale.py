@@ -19,18 +19,17 @@ def sync(out):
     for leaf in jax.tree.leaves(out):
         np.asarray(jnp.sum(leaf))
 
-chunk = TR._boost_round_chunk(ROUNDS)
-print("chunk size:", chunk, "hist impl:", TR._resolved_impl())
+print("rounds:", ROUNDS, "hist impl:", TR._resolved_impl())
 margin = jnp.zeros((1, N), dtype=jnp.float32)
 args = (binned, y, mask, margin, jnp.ones(1), jnp.float32(1.0),
         jnp.float32(0.0), jnp.float32(1.0), jnp.float32(0.0), None)
-statics = dict(num_rounds=chunk, max_depth=DEPTH, num_bins=BINS,
+statics = dict(num_rounds=ROUNDS, max_depth=DEPTH, num_bins=BINS,
                objective="binary:logistic", hist_impl=TR._resolved_impl())
 t0 = time.perf_counter(); out = TR._boost_rounds_batched(*args, **statics); sync(out)
 print(f"chunk first call (trace+compile+exec): {time.perf_counter()-t0:.2f}s")
 for i in range(3):
     t0 = time.perf_counter(); out = TR._boost_rounds_batched(*args, **statics); sync(out)
-    print(f"chunk warm exec {i}: {time.perf_counter()-t0:.2f}s  ({chunk} rounds)")
+    print(f"chunk warm exec {i}: {time.perf_counter()-t0:.2f}s  ({ROUNDS} rounds)")
 
 jax.profiler.start_trace("/tmp/jaxtrace_scale")
 out = TR._boost_rounds_batched(*args, **statics); sync(out)

@@ -15,10 +15,15 @@ two value columns of the same one-hot product.
 Grid: (F, N/T). The output block for feature f is revisited across row
 tiles (accumulation pattern: init at j==0, add afterwards). Padded rows
 carry node = -1 → their one-hot row is all zero → no contribution.
+
+The module holds every histogram builder a tree fit can take (the two
+kernels, the one-hot GEMM pair, the scatter reference) and the one function
+that picks among them, ``histogram_plan``.
 """
 from __future__ import annotations
 
 import functools
+from typing import Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -46,8 +51,7 @@ def _hist_pack(num_bins: int) -> tuple[int, int]:
 
 
 def _hist_kernel(binned_ref, node_ref, g_ref, h_ref, outg_ref, outh_ref,
-                 *, m_pad, b_pad, pack, sub_lanes, lowp, feat_tile,
-                 comb="base"):
+                 *, m_pad, b_pad, pack, sub_lanes, lowp, feat_tile):
     """One (fit, feature-tile, row-tile) step: accumulate grad/hess
     histograms for one batched fit (separate outputs — a trailing dim of 2
     would be tile-padded to 128 and blow VMEM). Output lanes are PACKED:
@@ -105,18 +109,14 @@ def _hist_kernel(binned_ref, node_ref, g_ref, h_ref, outg_ref, outh_ref,
         # own lane segment with nested selects, then a single 128-lane
         # equality — the per-sub compare+convert+add loop was the VPU cost
         # that dominated the whole build (trace: 18.0 of 18.6 s at 1M x 500).
-        # comb='const' is a timing probe (wrong results) isolating the
-        # dot+stack cost from the comb construction; round-5 measured the
-        # chain at 333 of 408 ms per 1M×500×32 build, which motivated the
-        # bin-loop kernel below (the default for ≤64 bins).
-        if comb == "const":
-            comb_oh = jnp.full((t, b_pad), jnp.bfloat16(1.0))
-        else:
-            code_b = binned_ref[q * pack + 0, :][:, None]
-            for sub in range(1, pack):
-                seg = binned_ref[q * pack + sub, :][:, None] + sub * sub_lanes
-                code_b = jnp.where(iota_b < sub * sub_lanes, code_b, seg)
-            comb_oh = (code_b == iota_b).astype(jnp.bfloat16)
+        # The select chain itself measured 333 of 408 ms per 1M×500×32
+        # build (round 5), which motivated the bin-loop kernel below (the
+        # builder at ≤64 bins: ``histogram_plan``).
+        code_b = binned_ref[q * pack + 0, :][:, None]
+        for sub in range(1, pack):
+            seg = binned_ref[q * pack + sub, :][:, None] + sub * sub_lanes
+            code_b = jnp.where(iota_b < sub * sub_lanes, code_b, seg)
+        comb_oh = (code_b == iota_b).astype(jnp.bfloat16)
         out = lax.dot_general(
             stack, comb_oh, contract,
             preferred_element_type=jnp.float32,
@@ -140,40 +140,11 @@ def _hist_kernel(binned_ref, node_ref, g_ref, h_ref, outg_ref, outh_ref,
             outh_ref[0, q, :, :] = outh_ref[0, q, :, :] + hh
 
 
-def build_histogram_pallas_batched(
-    binned, node, grad, hess, num_nodes, num_bins,
-    row_tile=None, lowp=False, interpret=False, comb=None,
-):
-    """hist [K, num_nodes, F, num_bins, 2] via the MXU one-hot formulation
-    (bin-axis packing + hi/lo bf16 value split — see _hist_kernel).
-
-    K batched fits (grid points × CV folds) share one binned matrix; the fit
-    axis rides the kernel grid, so the whole hyperparameter sweep's
-    histograms build in one custom call.
-
-    ``comb``: 'base' (default) or 'const' (a timing probe producing WRONG
-    results — isolates dot+stack cost from comb construction). The
-    TPTPU_HIST_COMB env knob is resolved HERE, outside the traced body, so
-    the jit cache keys on the resolved string (an env change between calls
-    can never serve a stale trace), and the knob also salts the AOT bank
-    (utils/aot.py) so probe executables cannot leak across processes."""
-    if comb is None:
-        import os
-
-        comb = os.environ.get("TPTPU_HIST_COMB", "base")
-    return _build_histogram_pallas_batched(
-        binned, node, grad, hess, num_nodes, num_bins,
-        row_tile=row_tile, lowp=lowp, interpret=interpret, comb=comb,
-    )
-
-
 @functools.partial(
     jax.jit,
-    static_argnames=(
-        "num_nodes", "num_bins", "row_tile", "lowp", "interpret", "comb",
-    ),
+    static_argnames=("num_nodes", "num_bins", "row_tile", "lowp", "interpret"),
 )
-def _build_histogram_pallas_batched(
+def build_histogram_pallas_batched(
     binned: jax.Array,   # [N, F] int32 codes in [0, num_bins), SHARED
     node: jax.Array,     # [K, N] int32 node slot per row per fit (-1 = dead)
     grad: jax.Array,     # [K, N] f32 (pre-masked)
@@ -183,8 +154,13 @@ def _build_histogram_pallas_batched(
     row_tile: int | None = None,
     lowp: bool = False,
     interpret: bool = False,
-    comb: str = "base",
 ) -> jax.Array:
+    """hist [K, num_nodes, F, num_bins, 2] via the MXU one-hot formulation
+    (bin-axis packing + hi/lo bf16 value split — see _hist_kernel).
+
+    K batched fits (grid points × CV folds) share one binned matrix; the fit
+    axis rides the kernel grid, so the whole hyperparameter sweep's
+    histograms build in one custom call."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -244,7 +220,7 @@ def _build_histogram_pallas_batched(
     out_g, out_h = pl.pallas_call(
         functools.partial(
             _hist_kernel, m_pad=m_pad, b_pad=b_pad, pack=pack,
-            sub_lanes=sub_lanes, lowp=lowp, feat_tile=feat_tile, comb=comb,
+            sub_lanes=sub_lanes, lowp=lowp, feat_tile=feat_tile,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((k_fits, groups, m_pad, b_pad), jnp.float32),
@@ -300,7 +276,7 @@ def _hist_binloop_kernel(binned_ref, node_ref, g_ref, h_ref, outg_ref,
     the per-group select-chain assembly. The comb construction drops from
     ~5 VPU ops per one-hot element to 2 (compare + convert) — the
     select-chain was measured at 333 ms of the 408 ms level cost at
-    1M×500×32 (comb='const' probe). Layout: binned block [feat_tile, T]
+    1M×500×32. Layout: binned block [feat_tile, T]
     (features on sublanes), stack [T, nvar·M]; per bin b the dot
     [feat_tile, T] @ [T, nvar·M] emits that bin's [feat_tile, nvar·M]
     plane, written at a static outermost index."""
@@ -571,249 +547,6 @@ def build_histogram_scatter(
     )
 
 
-SPLIT_FEAT_TILE = 32  # features per split-kernel program step
-
-
-def _split_kernel(
-    binned_ref, node_ref, g_ref, h_ref, fmask_ref, lam_ref, gam_ref, mcw_ref,
-    outg_ref, outf_ref, outb_ref, *, m_pad, num_bins, pack, feat_tile, lowp,
-):
-    """Fused best-split step for one (fit, feature-tile): histogram build
-    (MXU one-hot matmuls), prefix sums (block-triangular matmul), XGBoost
-    gain, and the per-tile arg-best — all while the blocks are
-    VMEM-resident. Only [M] bests leave the kernel, never [M, F, B]
-    histograms.
-
-    ``pack`` features share the 128-lane bin axis (lane = sub·S + bin with
-    S = 128 // pack), so one [T,M]ᵀ@[T,128] dot builds ``pack`` features'
-    histograms — a ``pack``× FLOP cut over one-feature-per-dot."""
-    import jax.lax as lax
-    from jax.experimental import pallas as pl
-
-    i = pl.program_id(1)
-
-    nodes = node_ref[0, 0, :]    # [T]
-    g = g_ref[0, 0, :]
-    h = h_ref[0, 0, :]
-    lam = lam_ref[0, 0, 0]
-    gam = gam_ref[0, 0, 0]
-    mcw = mcw_ref[0, 0, 0]
-    mrow = fmask_ref[0, 0, 0, :]  # [feat_tile_pad] lanes (one per feature)
-    t = nodes.shape[0]
-    s = 128 // pack  # lanes per feature group
-
-    # lowp: operands in bf16 with f32 MXU accumulation — callers assert the
-    # values are bf16-exact (RF: g ∈ {0,±1}, h = 1), so sums stay exact up
-    # to 2^24 while the dots run at the bf16 MXU rate
-    op_dtype = jnp.bfloat16 if lowp else jnp.float32
-    iota_m = lax.broadcasted_iota(jnp.int32, (t, m_pad), 1)
-    node_oh = (nodes[:, None] == iota_m).astype(jnp.float32)
-    wg = (node_oh * g[:, None]).astype(op_dtype)
-    wh = (node_oh * h[:, None]).astype(op_dtype)
-    iota_b = lax.broadcasted_iota(jnp.int32, (t, 128), 1)
-
-    # block-diagonal prefix/total matrices: lane (q·S+b) aggregates lanes of
-    # the SAME feature group only
-    r0 = lax.broadcasted_iota(jnp.int32, (128, 128), 0)
-    c0 = lax.broadcasted_iota(jnp.int32, (128, 128), 1)
-    same_grp = (r0 // s) == (c0 // s)
-    tri_bd = (same_grp & (r0 <= c0)).astype(jnp.float32)   # prefix within group
-    ones_bd = same_grp.astype(jnp.float32)                 # total within group
-
-    lane = lax.broadcasted_iota(jnp.int32, (m_pad, 128), 1)
-    lane_bin = lane % s
-    lane_sub = lane // s
-    thr_ok = lane_bin < (num_bins - 1)  # valid thresholds t = 0..B-2
-    contract = (((0,), (0,)), ((), ()))
-    mm = (((1,), (0,)), ((), ()))
-
-    best_gain = jnp.full((m_pad,), -jnp.inf, dtype=jnp.float32)
-    best_feat = jnp.full((m_pad,), -1, dtype=jnp.int32)
-    best_bin = jnp.zeros((m_pad,), dtype=jnp.int32)
-
-    hist_precision = lax.Precision.DEFAULT if lowp else lax.Precision.HIGHEST
-    for q in range(feat_tile // pack):
-        # combined (sub-feature, bin) one-hot: pack features in one dot
-        comb_oh = jnp.zeros((t, 128), dtype=op_dtype)
-        for sub in range(pack):
-            codes = binned_ref[q * pack + sub, :]
-            comb_oh = comb_oh + (
-                (codes[:, None] + sub * s) == iota_b
-            ).astype(op_dtype)
-        hg = lax.dot_general(
-            wg, comb_oh, contract,
-            preferred_element_type=jnp.float32,
-            precision=hist_precision,
-        )  # [M, 128] = pack features' histograms side by side
-        hh = lax.dot_general(
-            wh, comb_oh, contract,
-            preferred_element_type=jnp.float32,
-            precision=hist_precision,
-        )
-        gl = lax.dot_general(
-            hg, tri_bd, mm,
-            preferred_element_type=jnp.float32,
-            precision=lax.Precision.HIGHEST,
-        )  # per-feature inclusive prefix sums
-        hl = lax.dot_general(
-            hh, tri_bd, mm,
-            preferred_element_type=jnp.float32,
-            precision=lax.Precision.HIGHEST,
-        )
-        gt = lax.dot_general(
-            hg, ones_bd, mm,
-            preferred_element_type=jnp.float32,
-            precision=lax.Precision.HIGHEST,
-        )  # per-feature totals broadcast across the group
-        ht = lax.dot_general(
-            hh, ones_bd, mm,
-            preferred_element_type=jnp.float32,
-            precision=lax.Precision.HIGHEST,
-        )
-        gr = gt - gl
-        hr = ht - hl
-        gain = 0.5 * (
-            gl * gl / (hl + lam) + gr * gr / (hr + lam) - gt * gt / (ht + lam)
-        ) - gam
-        # per-lane feature mask: feature q*pack + lane_sub of this tile
-        # (static per-sub scalar selects — no gathers inside the kernel)
-        mlane = jnp.zeros((m_pad, 128), dtype=jnp.float32)
-        for sub in range(pack):
-            mlane = jnp.where(lane_sub == sub, mrow[q * pack + sub], mlane)
-        valid = thr_ok & (hl >= mcw) & (hr >= mcw) & (mlane > 0)
-        gain = jnp.where(valid, gain, -jnp.inf)
-
-        bg = jnp.max(gain, axis=1)  # [M]
-        # deterministic tie-break: smallest lane at the max
-        bl = jnp.min(
-            jnp.where(gain >= bg[:, None], lane, 128), axis=1
-        ).astype(jnp.int32)
-        better = bg > best_gain
-        best_gain = jnp.where(better, bg, best_gain)
-        best_feat = jnp.where(
-            better, i * feat_tile + q * pack + bl // s, best_feat
-        ).astype(jnp.int32)
-        best_bin = jnp.where(better, bl % s, best_bin).astype(jnp.int32)
-
-    outg_ref[0, 0, :] = best_gain
-    outf_ref[0, 0, :] = best_feat
-    outb_ref[0, 0, :] = best_bin
-
-
-@functools.partial(
-    jax.jit, static_argnames=("num_nodes", "num_bins", "lowp", "interpret")
-)
-def build_best_split_pallas(
-    binned: jax.Array,     # [N, F] int32, SHARED
-    node: jax.Array,       # [K, N] int32 compact slot per row (-1 = dead)
-    grad: jax.Array,       # [K, N] f32 (pre-masked)
-    hess: jax.Array,       # [K, N] f32
-    feat_mask: jax.Array,  # [K, F] f32 (0 disables a feature)
-    reg_lambda: jax.Array,       # [K] f32
-    gamma: jax.Array,            # [K] f32
-    min_child_weight: jax.Array, # [K] f32
-    num_nodes: int,
-    num_bins: int,
-    lowp: bool = False,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """(best_gain, best_feat, best_bin) each [K, num_nodes] — the fused
-    split search. Requires all rows to fit one VMEM tile (N ≲ 2k); callers
-    fall back to the two-phase histogram path beyond that."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    k_fits, n = node.shape
-    f = binned.shape[1]
-    m_pad = _round_up(max(num_nodes, 8), 8)
-    n_pad = _round_up(max(n, 128), 128)
-    # bin-axis packing: features per 128-lane dot (4 for ≤32 bins)
-    pack = 4 if num_bins <= 32 else (2 if num_bins <= 64 else 1)
-    feat_tile = SPLIT_FEAT_TILE
-    f_pad = _round_up(f, feat_tile)
-    n_tiles = f_pad // feat_tile
-
-    binned_t = jnp.zeros((f_pad, n_pad), dtype=jnp.int32)
-    binned_t = binned_t.at[:f, :n].set(binned.T)
-    node_p = jnp.full((k_fits, 1, n_pad), -1, dtype=jnp.int32).at[:, 0, :n].set(node)
-    g_p = jnp.zeros((k_fits, 1, n_pad), dtype=jnp.float32).at[:, 0, :n].set(grad)
-    h_p = jnp.zeros((k_fits, 1, n_pad), dtype=jnp.float32).at[:, 0, :n].set(hess)
-    # per-(fit, tile) mask rows, one lane per feature of the tile
-    ft_pad = _round_up(feat_tile, 128)
-    fm = jnp.zeros((k_fits, n_tiles, 1, ft_pad), dtype=jnp.float32)
-    fm_src = jnp.zeros((k_fits, f_pad), dtype=jnp.float32).at[:, :f].set(feat_mask)
-    fm = fm.at[:, :, 0, :feat_tile].set(
-        fm_src.reshape(k_fits, n_tiles, feat_tile)
-    )
-    scal = lambda v: jnp.asarray(v, dtype=jnp.float32).reshape(k_fits, 1, 1)  # noqa: E731
-
-    grid = (k_fits, n_tiles)
-    out_shape = jax.ShapeDtypeStruct((k_fits * n_tiles, 1, m_pad), jnp.float32)
-    out_shape_i = jax.ShapeDtypeStruct((k_fits * n_tiles, 1, m_pad), jnp.int32)
-    out_spec = pl.BlockSpec(
-        (1, 1, m_pad), lambda k, i: (k * n_tiles + i, 0, 0),
-        memory_space=pltpu.VMEM,
-    )
-
-    outg, outf, outb = pl.pallas_call(
-        functools.partial(
-            _split_kernel, m_pad=m_pad, num_bins=num_bins, pack=pack,
-            feat_tile=feat_tile, lowp=lowp,
-        ),
-        out_shape=(out_shape, out_shape_i, out_shape_i),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (feat_tile, n_pad), lambda k, i: (i, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, 1, n_pad), lambda k, i: (k, 0, 0), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec(
-                (1, 1, n_pad), lambda k, i: (k, 0, 0), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec(
-                (1, 1, n_pad), lambda k, i: (k, 0, 0), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec(
-                (1, 1, 1, ft_pad), lambda k, i: (k, i, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, 1, 1), lambda k, i: (k, 0, 0), memory_space=pltpu.SMEM
-            ),
-            pl.BlockSpec(
-                (1, 1, 1), lambda k, i: (k, 0, 0), memory_space=pltpu.SMEM
-            ),
-            pl.BlockSpec(
-                (1, 1, 1), lambda k, i: (k, 0, 0), memory_space=pltpu.SMEM
-            ),
-        ],
-        out_specs=(out_spec, out_spec, out_spec),
-        interpret=interpret,
-    )(
-        binned_t, node_p, g_p, h_p, fm,
-        scal(reg_lambda), scal(gamma), scal(min_child_weight),
-    )
-
-    # reduce the per-tile bests over tiles (tiny [K, n_tiles, M] arrays)
-    outg = outg.reshape(k_fits, n_tiles, m_pad)
-    outf = outf.reshape(k_fits, n_tiles, m_pad)
-    outb = outb.reshape(k_fits, n_tiles, m_pad)
-    ti = jnp.argmax(outg, axis=1)  # [K, M]
-    take = lambda a: jnp.take_along_axis(a, ti[:, None, :], axis=1)[:, 0, :]  # noqa: E731
-    return (
-        take(outg)[:, :num_nodes],
-        take(outf)[:, :num_nodes],
-        take(outb)[:, :num_nodes],
-    )
-
-
-#: rows must fit one VMEM tile for the fused split kernel
-FUSED_SPLIT_MAX_ROWS = 2048
-
-
 def build_histogram_scatter_batched(
     binned: jax.Array,   # [N, F] shared
     node: jax.Array,     # [K, N]
@@ -830,12 +563,146 @@ def build_histogram_scatter_batched(
     )(node, grad, hess)
 
 
+def one_hot_codes(binned: jax.Array, num_bins: int, lowp: bool) -> jax.Array:
+    """[N, F·B] one-hot of the bin codes: the GEMM builder's operand. It is
+    the same at every level of every tree of a fit, so it is made once a
+    fit, outside the level scan (XLA's loop-invariant code motion is not
+    reliable through scan+cond+fori nesting, and the temporary is small at
+    GEMM row counts)."""
+    dt = jnp.bfloat16 if lowp else jnp.float32
+    return jax.nn.one_hot(binned, num_bins, dtype=dt).reshape(
+        binned.shape[0], -1
+    )
+
+
+def build_histogram_gemm(
+    codes1h: jax.Array,  # [N, F·B] from one_hot_codes, SHARED
+    node: jax.Array,     # [K, N] int32 node slot per row per fit (-1 = dead)
+    grad: jax.Array,     # [K, N] f32 (pre-masked)
+    hess: jax.Array,     # [K, N] f32
+    num_nodes: int,
+    num_bins: int,
+    lowp: bool = False,
+) -> jax.Array:
+    """[K, num_nodes, F, num_bins, 2] histogram as TWO one-hot GEMMs — the
+    MXU-native formulation for small row counts. The pallas kernels' grid
+    economics only win at large N; at AutoML-tabular sizes (≤4k rows) the
+    whole per-level histogram is a [K·M, N] @ [N, F·B] matmul pair that XLA
+    fuses into the surrounding program (measured: the depth-12 RF group
+    fell from ~25 s of kernel passes to GEMM noise). Plain jnp, so it also
+    serves a sharded body: the caller's psum reduces the shards'
+    histograms."""
+    dt = jnp.bfloat16 if lowp else jnp.float32
+    node1h = jax.nn.one_hot(node, num_nodes, dtype=jnp.float32)  # [K, N, M]
+    gw = (node1h * grad[:, :, None]).astype(dt)
+    hw = (node1h * hess[:, :, None]).astype(dt)
+    hg = jnp.einsum(
+        "knm,nw->kmw", gw, codes1h, preferred_element_type=jnp.float32
+    )
+    hh = jnp.einsum(
+        "knm,nw->kmw", hw, codes1h, preferred_element_type=jnp.float32
+    )
+    return jnp.stack([hg, hh], axis=-1).reshape(
+        node.shape[0], num_nodes, codes1h.shape[1] // num_bins, num_bins, 2
+    )
+
+
+# --------------------------------------------------------------------------
+# which builder a fit takes, and how many node slots one build may hold
+# --------------------------------------------------------------------------
+class Builder(NamedTuple):
+    """One way to build a feature group's [K, M, F, B, 2] histograms.
+    ``prepare(binned [N, F], num_bins, lowp)`` makes the operand that is the
+    same at every level (once a fit, outside the level scan);
+    ``build(operand, node, grad, hess, num_nodes, num_bins, lowp=...)``
+    builds one level's or one chunk's histograms from it."""
+
+    prepare: Callable
+    build: Callable
+
+
+def _codes(binned, num_bins, lowp):
+    return binned
+
+
+BUILDERS: dict[str, Builder] = {
+    # XLA scatter-add: the CPU builder and the reference (f32 whatever lowp)
+    "scatter": Builder(
+        _codes,
+        lambda *operands, lowp=False: build_histogram_scatter_batched(
+            *operands
+        ),
+    ),
+    "gemm": Builder(one_hot_codes, build_histogram_gemm),
+    "binloop": Builder(_codes, build_histogram_pallas_binloop),
+    "lanepacked": Builder(_codes, build_histogram_pallas_batched),
+}
+
+# ``"pallas"`` means "whatever is fastest on the TPU": up to this many local
+# rows the GEMM builder beats the kernels outright (a level's work is two
+# matmuls that fuse into the program, while a kernel grid carries per-pass
+# costs that dominate at small N)
+_GEMM_MAX_ROWS = 4096
+# The bin-loop kernel's cost is linear in the bin count (one whole-block
+# compare and one dot per bin), so wide sketches keep the lane-packed
+# kernel; up to 64 bins the bin-loop builds the same histograms bit for bit
+# without the select chain (seconds a build by width: ``binloop_tiles``)
+_BINLOOP_MAX_BINS = 64
+# [K, chunk, Σ F·B, 2] histogram elements one build may hold in HBM (the
+# Spark maxMemoryInMB node-group equivalent): 2^25 over the lanes, and no
+# lane count shrinks it under 2^20
+_HIST_BUDGET_ELEMS = 1 << 25
+_HIST_BUDGET_FLOOR = 1 << 20
+# GEMM: the [K, N, M] weighted node one-hots bound the chunk; the ceiling
+# keeps deep levels multi-chunk, so the occupancy skip can drop the (mostly
+# dead) tail of the slot range instead of paying one [K·cap, N] GEMM a level
+_GEMM_ONEHOT_ELEMS = 1 << 24
+_GEMM_CHUNK_CEIL = 128
+# Kernels: VMEM a grid step is the [FEAT_TILE, M, b_pad] x 2 output block
+# (the feature axis is gridded, F does not multiply in) plus the [T, M]
+# one-hot temporaries (the kernels shrink their row tile as M grows)
+_KERNEL_BLOCK_ELEMS = 1 << 19
+_KERNEL_CHUNK_CEIL = 256
+
+
+class HistogramPlan(NamedTuple):
+    builders: tuple[str, ...]  # key of BUILDERS, one per feature group
+    chunk_cap: int             # most node slots one build may hold (2^j)
+
+
+def _pow2_floor(x: int) -> int:
+    return 1 << (x.bit_length() - 1)
+
+
+def histogram_plan(
+    impl: str, n: int, k_fits: int, groups: Sequence[tuple[int, int]],
+    max_slots: int,
+) -> HistogramPlan:
+    """How a fit builds its histograms, from what its trace can see:
+    ``impl`` ('pallas' = choose for the TPU; 'gemm' / 'scatter' force one
+    builder), the LOCAL rows ``n`` and lanes ``k_fits`` of one build, the
+    feature groups' ``(columns, bins)`` and ``max_slots``, the most compact
+    node slots a level can have live. This is the one place that decides;
+    it runs while a program is traced, never per call."""
+    hist_width = sum(f * b for f, b in groups)
+    budget = max(_HIST_BUDGET_ELEMS // k_fits, _HIST_BUDGET_FLOOR)
+    cap = min(_pow2_floor(max(1, budget // max(hist_width, 1))), max_slots)
+    if impl == "gemm" or (impl == "pallas" and n <= _GEMM_MAX_ROWS):
+        builders = ("gemm",) * len(groups)
+        ceil = min(_GEMM_CHUNK_CEIL, _GEMM_ONEHOT_ELEMS // max(k_fits * n, 1))
+    elif impl == "pallas":
+        builders = tuple(
+            "binloop" if b <= _BINLOOP_MAX_BINS else "lanepacked"
+            for _, b in groups
+        )
+        b_pad = _round_up(max(b for _, b in groups), 128)
+        ceil = min(_KERNEL_CHUNK_CEIL, _KERNEL_BLOCK_ELEMS // (8 * b_pad))
+    else:
+        return HistogramPlan(("scatter",) * len(groups), cap)
+    return HistogramPlan(builders, min(cap, _pow2_floor(max(8, ceil))))
+
+
 def default_impl() -> str:
     """'pallas' on real TPU backends, 'scatter' elsewhere (CPU tests run the
-    kernel via interpret mode in the dedicated unit tests only)."""
-    import os
-
-    forced = os.environ.get("TPTPU_HIST")
-    if forced:
-        return forced
+    kernels via interpret mode in the dedicated unit tests only)."""
     return "pallas" if jax.default_backend() == "tpu" else "scatter"

@@ -389,7 +389,6 @@ def grow_tree(
     min_child_weight: float | jax.Array = 1.0,
     min_info_gain: float | jax.Array = 0.0,
     hist_impl: str | None = None,
-    parallel_fits: int = 1,  # kept for API compat; K now rides the kernel grid
     feature_groups=None,
     info_gain_norm: float = 0.0,
 ) -> Tree:
@@ -508,14 +507,7 @@ def _grow_tree_impl(
     on the lane, the chunk, the compact slot, the width rung or the mesh.
     ``node_subset == F`` draws nothing; None also counts nothing
     (``HistSlots.subset_*`` stay 0)."""
-    from .hist_pallas import (
-        FUSED_SPLIT_MAX_ROWS,
-        build_best_split_pallas,
-        build_histogram_pallas_batched,
-        build_histogram_pallas_binloop,
-        build_histogram_scatter_batched,
-        default_impl,
-    )
+    from .hist_pallas import BUILDERS, default_impl, histogram_plan
 
     k_fits, n = grad.shape
     f = binned.shape[1]
@@ -577,135 +569,28 @@ def _grow_tree_impl(
             cap <<= 1
         cap = min(cap, max_nodes)
 
-    # histogram impl policy: "pallas" is AUTO — at AutoML-tabular row counts
-    # (≤4k) the one-hot GEMM histogram beats the kernels outright (per-level
-    # work is two MXU matmuls that fuse into the program; the pallas grid
-    # and the fused-split kernel carry per-pass costs that dominate at
-    # small N), while large N keeps the Mosaic kernels. "gemm"/"scatter"
-    # force their paths. The GEMM path also serves the sharded body: it is
-    # plain jnp, and the psum below reduces its per-shard histograms.
-    use_gemm = (impl == "gemm") or (impl == "pallas" and n <= 4096)
-
-    # one-hot bin codes are loop-invariant across the level scan (and the
-    # tree scan above it) — precompute ONCE per group so the GEMM
-    # histogram's per-level work is the node one-hot + two einsums. XLA's
-    # loop-invariant code motion is not reliable through scan+cond+fori
-    # nesting, and the [N, Fg·Bg] temporary is small at GEMM row counts.
-    if use_gemm:
-        dt1h = jnp.bfloat16 if lowp else jnp.float32
-        with jax.named_scope("tree/group_columns"):
-            groups = [
-                (gb_, gm, bb, gi,
-                 jax.nn.one_hot(gb_, bb, dtype=dt1h).reshape(
-                     gb_.shape[0], -1))
-                for gb_, gm, bb, gi in groups
-            ]
-    else:
-        groups = [(gb_, gm, bb, gi, None) for gb_, gm, bb, gi in groups]
-
-    # fused split search: gains + arg-best computed inside the kernel while
-    # histograms are VMEM-resident — nothing [M, F, B]-sized touches HBM.
-    # Only possible when every row fits one VMEM tile and the bins fit the
-    # kernel's 128-lane packing. The sharded path needs the raw histogram
-    # for the cross-shard psum, so it always takes the two-step path.
-    draw_subsets = node_subset is not None and node_subset < f
-    use_fused = (
-        not use_gemm
-        and impl == "pallas"
-        and not draw_subsets   # the kernel's mask is per lane, not per node
-        and not info_gain_norm  # and it returns no node weight
-        and axis_name is None
-        and n <= FUSED_SPLIT_MAX_ROWS
-        and b <= 128
+    # which builder each group takes and how many node slots one build may
+    # hold: decided in ONE place, hist_pallas.histogram_plan. A builder's
+    # loop-invariant operand (the GEMM's one-hot codes) is made here, once
+    # per group, outside the level scan and the tree scan above it.
+    plan = histogram_plan(
+        impl, n, k_fits, [(gb_.shape[1], bb) for gb_, _, bb, _ in groups],
+        cap,
     )
+    with jax.named_scope("tree/group_columns"):
+        groups = [
+            (gb_, gm, bb, gi, BUILDERS[name].build,
+             BUILDERS[name].prepare(gb_, bb, lowp))
+            for (gb_, gm, bb, gi), name in zip(groups, plan.builders)
+        ]
+    draw_subsets = node_subset is not None and node_subset < f
 
-    # per-chunk histogram memory scales with K — shrink the node chunk so
-    # [K, chunk, F, B, 2] stays inside the HBM budget (the Spark
-    # maxMemoryInMB node-group equivalent). With feature groups the total
-    # histogram width is Σ_g f_g·b_g, and VMEM kernel caps take the min
-    # over groups.
-    hist_width = sum(gb.shape[1] * bb for gb, _, bb, _, _ in groups)
-    budget_elems = max((1 << 25) // k_fits, 1 << 20)
-    chunk_cap = max(1, budget_elems // max(hist_width, 1))
-    while chunk_cap & (chunk_cap - 1):
-        chunk_cap &= chunk_cap - 1
-    chunk_cap = min(chunk_cap, cap)
-    if use_fused:
-        # the [T, M] one-hot temporaries are the only big VMEM tenants;
-        # M=512 at T=896 was measured to overflow scoped VMEM on v5e —
-        # 256 is the validated ceiling
-        n_pad = (n + 127) // 128 * 128
-        m_cap = max(8, min(256, (1 << 18) // max(n_pad, 128)))
-        while m_cap & (m_cap - 1):
-            m_cap &= m_cap - 1
-        chunk_cap = min(cap, m_cap)
-    elif use_gemm:
-        # the [K, N, M] weighted node-one-hot temporaries bound the chunk;
-        # the 128 ceiling keeps deep levels multi-chunk so the occupancy
-        # skip can drop the (mostly dead) tail of the slot range instead of
-        # paying one [K·cap, N] GEMM per level
-        import os as _os
-
-        _ceil = int(_os.environ.get("TPTPU_GEMM_MCAP", "128"))
-        m_cap = max(8, min(_ceil, (1 << 24) // max(k_fits * n, 1)))
-        while m_cap & (m_cap - 1):
-            m_cap &= m_cap - 1
-        chunk_cap = min(chunk_cap, m_cap)
-    elif impl == "pallas":
-        # VMEM per grid step: the [FEAT_TILE, M, b_pad]×2 output block (the
-        # feature axis is gridded — f does not multiply in) plus the [T, M]
-        # one-hot temporaries (the kernel shrinks its row tile as M grows)
-        b_pad = (b + 127) // 128 * 128
-        m_cap = max(8, min(256, (1 << 19) // (8 * b_pad)))
-        while m_cap & (m_cap - 1):
-            m_cap &= m_cap - 1
-        chunk_cap = min(chunk_cap, m_cap)
-
-    lam_k = jnp.broadcast_to(vec(reg_lambda), (k_fits,))
-    gam_k = jnp.broadcast_to(vec(gamma), (k_fits,))
-    mcw_k = jnp.broadcast_to(vec(min_child_weight), (k_fits,))
-
-    def build_histogram_gemm(gbinned, loc, chunk_nodes, gb, codes1h):
-        """[K, M, Fg, Bg, 2] histogram as TWO one-hot GEMMs — the MXU-native
-        formulation for small row counts. The pallas kernel's grid economics
-        only win at large N; at AutoML-tabular sizes (≤4k rows) the whole
-        per-level histogram is a [K·M, N] @ [N, Fg·Bg] matmul pair that XLA
-        fuses into the surrounding program (measured: the depth-12 RF group
-        fell from ~25 s of kernel passes to GEMM noise). ``codes1h``
-        [N, Fg·Bg] is precomputed outside the level scan (loop-invariant)."""
-        fg = gbinned.shape[1]
-        dt = jnp.bfloat16 if lowp else jnp.float32
-        node1h = jax.nn.one_hot(loc, chunk_nodes, dtype=jnp.float32)  # [K,N,M]
-        gw = (node1h * g[:, :, None]).astype(dt)
-        hw = (node1h * h[:, :, None]).astype(dt)
-        hg = jnp.einsum(
-            "knm,nw->kmw", gw, codes1h, preferred_element_type=jnp.float32
-        )
-        hh = jnp.einsum(
-            "knm,nw->kmw", hw, codes1h, preferred_element_type=jnp.float32
-        )
-        return jnp.stack([hg, hh], axis=-1).reshape(
-            loc.shape[0], chunk_nodes, fg, gb, 2
-        )
-
-    def group_stats(gbinned, gmask, gb, gidx, codes1h, loc, chunk_nodes,
-                    sel):
+    def group_stats(gbinned, gmask, gb, gidx, build, operand, loc,
+                    chunk_nodes, sel):
         """(gain, orig feat, bin, node weight) of the best split per compact
         slot for ONE feature group; ``sel`` [K, M, node_subset] are the
         slots' admissible columns (None: all). Also the (slot, feature)
         pairs of this group that ``sel`` admits, [K, M] (None: all)."""
-        if use_fused:
-            # histogram and arg-best in one kernel: the histogram's scope
-            with jax.named_scope("tree/histogram"):
-                bg, bf, bb = build_best_split_pallas(
-                    gbinned, loc, g, h, gmask,
-                    lam_k, gam_k, mcw_k,
-                    num_nodes=chunk_nodes, num_bins=gb, lowp=lowp,
-                )
-            if gidx is not None:
-                with jax.named_scope("tree/split_search"):
-                    bf = gidx[jnp.maximum(bf, 0)].astype(jnp.int32)
-            return (bg, bf, bb, None), None
         nmask = None
         if sel is not None:
             with jax.named_scope("tree/node_subset"):
@@ -715,39 +600,16 @@ def _grow_tree_impl(
                 )
                 nmask = (sel[..., None] == fid).any(axis=2)  # [K, M, Fg]
         with jax.named_scope("tree/histogram"):
-            hist = build_histogram(gbinned, gb, codes1h, loc, chunk_nodes)
+            # [K, M, Fg, Bg, 2] (grad, hess) sums of the group
+            hist = build(operand, loc, g, h, chunk_nodes, gb, lowp=lowp)
+            if axis_name is not None:
+                # the Rabit-allreduce moment: per-shard partial histograms
+                # reduce over ICI; everything after sees the global
+                # histogram
+                hist = jax.lax.psum(hist, axis_name)
         with jax.named_scope("tree/split_search"):
             best = best_split(hist, gmask, nmask, gb, gidx, chunk_nodes)
         return best, None if nmask is None else nmask.sum(axis=2)
-
-    def build_histogram(gbinned, gb, codes1h, loc, chunk_nodes):
-        """[K, M, Fg, Bg, 2] (grad, hess) sums of one feature group."""
-        if use_gemm:
-            hist = build_histogram_gemm(gbinned, loc, chunk_nodes, gb, codes1h)
-        elif impl == "pallas":
-            # bin-loop kernel for narrow bin counts: one whole-block
-            # compare per bin instead of the select-chain lane assembly,
-            # bit-identical histograms (see _hist_binloop_kernel; seconds
-            # a build by node-slot width: hist_pallas.binloop_tiles). Its
-            # cost is linear in num_bins, so wide-bin fits (e.g. 256-bin
-            # sketches) keep the lane-packed kernel.
-            if gb <= 64:
-                hist = build_histogram_pallas_binloop(
-                    gbinned, loc, g, h, chunk_nodes, gb, lowp=lowp
-                )
-            else:
-                hist = build_histogram_pallas_batched(
-                    gbinned, loc, g, h, chunk_nodes, gb, lowp=lowp
-                )
-        else:
-            hist = build_histogram_scatter_batched(
-                gbinned, loc, g, h, chunk_nodes, gb
-            )
-        if axis_name is not None:
-            # the Rabit-allreduce moment: per-shard partial histograms
-            # reduce over ICI; everything after sees the global histogram
-            hist = jax.lax.psum(hist, axis_name)
-        return hist
 
     def best_split(hist, gmask, nmask, gb, gidx, chunk_nodes):
         hg, hh = hist[..., 0], hist[..., 1]  # [K, M, Fg, Bg]
@@ -815,9 +677,10 @@ def _grow_tree_impl(
         sel = node_subsets(at, c0, chunk_nodes) if draw_subsets else None
         bg, bf, bb, bw = None, None, None, None
         admitted = None
-        for gbinned, gmask, grp_b, gidx, codes1h in groups:
+        for gbinned, gmask, grp_b, gidx, build, operand in groups:
             (gg, gf, gbin, gw), adm = group_stats(
-                gbinned, gmask, grp_b, gidx, codes1h, loc, chunk_nodes, sel
+                gbinned, gmask, grp_b, gidx, build, operand, loc,
+                chunk_nodes, sel,
             )
             if adm is not None:
                 admitted = adm if admitted is None else admitted + adm
@@ -856,7 +719,6 @@ def _grow_tree_impl(
 
     sentinel = jnp.int32(max_nodes)  # out-of-range → dropped by scatters
 
-
     if max_depth == 0:
         # root-only tree (legal Spark maxDepth=0): no splits, leaf = all rows
         with jax.named_scope("tree/leaf"):
@@ -891,7 +753,7 @@ def _grow_tree_impl(
     # builds have 128 live slots or fewer. The sharded path keeps the
     # full width: its psums may not sit under a data-dependent branch.
     n_nodes = cap
-    chunk_nodes = min(chunk_cap, n_nodes)
+    chunk_nodes = plan.chunk_cap
     num_chunks = (n_nodes + chunk_nodes - 1) // chunk_nodes
     ladder = (
         _width_ladder(chunk_nodes) if axis_name is None else (chunk_nodes,)
@@ -1135,7 +997,7 @@ def predict_tree(binned: jax.Array, tree: Tree) -> jax.Array:
 
 
 # --------------------------------------------------------------------------
-# forests (bagged, batched over the fit axis) and boosting (chunk-scanned)
+# forests (bagged, batched over the fit axis) and boosting (round-scanned)
 # --------------------------------------------------------------------------
 def fit_forest(
     binned: jax.Array,
@@ -1150,7 +1012,6 @@ def fit_forest(
     min_info_gain: float | jax.Array = 0.0,
     seed: int = 42,
     bootstrap: bool = True,
-    parallel_fits: int = 1,  # kept for API compat
     lowp: bool = False,
     feature_groups=None,
     feature_subset: int | None = None,
@@ -1700,13 +1561,6 @@ def _fit_result(trees, outputs, slots, return_outputs, return_slots):
     return (trees, *extra) if extra else trees
 
 
-@partial(
-    jax.jit,
-    static_argnames=(
-        "max_depth", "num_bins", "num_rounds", "objective", "parallel_fits",
-        "info_gain_norm",
-    ),
-)
 def fit_boosted(
     binned: jax.Array,
     y: jax.Array,          # [N] labels (0/1 binary, float regression)
@@ -1721,43 +1575,23 @@ def fit_boosted(
     min_info_gain: float | jax.Array = 0.0,
     base_score: float | jax.Array = 0.0,
     objective: str = "binary:logistic",
-    parallel_fits: int = 1,
     feature_groups=None,
     info_gain_norm: float = 0.0,
 ) -> tuple[Tree, jax.Array]:
-    """Gradient boosting (XGBoost/Spark-GBT parity): lax.scan over rounds,
-    second-order gradients, shrinkage eta. Returns stacked trees [R, ...]
-    and the training margin. ``info_gain_norm``: 0 for XGBoost's absolute
-    stop rule, 2 for Spark GBT's per-row variance decrease
-    (``_grow_tree_impl``)."""
-    n, f = binned.shape
-    feat_mask = jnp.ones(f, dtype=jnp.float32)
-
-    def grads(margin):
-        if objective == "binary:logistic":
-            p = jax.nn.sigmoid(margin)
-            return p - y, p * (1.0 - p)
-        # reg:squarederror
-        return margin - y, jnp.ones_like(margin)
-
-    def round_step(margin, _):
-        with jax.named_scope("tree/gradients"):
-            g, h = grads(margin)
-        tree = grow_tree(
-            binned, g, h, row_mask, feat_mask,
-            max_depth=max_depth, num_bins=num_bins,
-            reg_lambda=reg_lambda, gamma=gamma,
-            min_child_weight=min_child_weight, min_info_gain=min_info_gain,
-            parallel_fits=parallel_fits, feature_groups=feature_groups,
-            info_gain_norm=info_gain_norm,
-        )
-        with jax.named_scope("tree/outputs"):
-            margin = margin + eta * predict_tree(binned, tree)
-        return margin, tree
-
-    margin0 = jnp.full(n, base_score, dtype=jnp.float32)
-    margin, trees = jax.lax.scan(round_step, margin0, None, length=num_rounds)
-    return trees, margin
+    """Gradient boosting (XGBoost/Spark-GBT parity) — the K=1 case of
+    fit_boosted_batched: second-order gradients, shrinkage eta. Returns
+    stacked trees [R, ...] and the training margin [N].
+    ``info_gain_norm``: 0 for XGBoost's absolute stop rule, 2 for Spark
+    GBT's per-row variance decrease (``_grow_tree_impl``)."""
+    trees, margin = fit_boosted_batched(
+        binned, y, jnp.asarray(row_mask)[None, :],
+        num_rounds=num_rounds, max_depth=max_depth, num_bins=num_bins,
+        eta=eta, reg_lambda=reg_lambda, gamma=gamma,
+        min_child_weight=min_child_weight, min_info_gain=min_info_gain,
+        base_score=base_score, objective=objective,
+        feature_groups=feature_groups, info_gain_norm=info_gain_norm,
+    )
+    return jax.tree.map(lambda a: a[0], trees), margin[0]
 
 
 def predict_boosted(
@@ -1776,8 +1610,8 @@ def _boost_chunk_body(
     num_rounds, max_depth, num_bins, objective,
     axis_name=None, axis_size=1, hist_impl=None, info_gain_norm=0.0,
 ) -> tuple[Tree, jax.Array, HistSlots]:
-    """A chunk of boosting rounds for all K fits (lax.scan inside one
-    program) — shared by the single-device jit and the shard_map'd path
+    """The boosting rounds of all K fits (lax.scan inside one program) —
+    shared by the single-device jit and the shard_map'd path
     (axis_name set: per-level histograms psum over the mesh axis; margins,
     gradients and predictions stay row-local). Returns (trees [K, R, ...],
     margins [K, N], HistSlots [R, depth])."""
@@ -1829,22 +1663,11 @@ _boost_rounds_batched = partial(
 
 def _resolved_impl() -> str:
     """The histogram impl the trace WILL use, resolved at call time so it
-    participates in jit-cache and AOT-blob identity (the env knob is read
-    at trace time deep inside _grow_tree_impl otherwise)."""
+    participates in jit-cache and AOT-blob identity (``_grow_tree_impl``
+    would otherwise ask the backend at trace time)."""
     from .hist_pallas import default_impl
 
     return default_impl()
-
-
-def _boost_round_chunk(num_rounds: int) -> int:
-    """Boosting rounds per compiled program — DEFAULT the whole run (one
-    program; the 200-round default sweep compiles and runs as one on a
-    v5e under JAX 0.9.0). TPTPU_BOOST_CHUNK=N splits it into N-round
-    programs; not re-measured on a local chip, see ROADMAP D3."""
-    import os
-
-    env = os.environ.get("TPTPU_BOOST_CHUNK")
-    return max(1, int(env)) if env else num_rounds
 
 
 def fit_boosted_batched(
@@ -1867,8 +1690,8 @@ def fit_boosted_batched(
     info_gain_norm: float = 0.0,
 ) -> tuple[Tree, jax.Array]:
     """K boosting runs batched over the fit axis: every round grows all K
-    trees in one histogram build; rounds scan in fixed-size chunks so each
-    compiled program stays modest. Returns Tree arrays [K, R, ...] and the
+    trees in one histogram build, and all the rounds are one scan in one
+    program. Returns Tree arrays [K, R, ...] and the
     training margins [K, N]; with ``return_slots`` also the fit's
     ``HistSlots`` [R, depth] (for ``await_outputs``; None from a sharded
     fit, which has recorded them).
@@ -1903,20 +1726,16 @@ def fit_boosted_batched(
             feature_groups=feature_groups, info_gain_norm=info_gain_norm,
         )
         return _fit_result(trees, margin, None, True, return_slots)
-    # f32 numpy broadcast (no eager compile), then ONE device transfer so
-    # chunk 1 and chunks 2+ present the same leaf type to the AOT key
-    # (a numpy leaf has no .sharding; mixing host/device margins would
-    # key-split the identical chunk program under TPTPU_BOOST_CHUNK)
+    # f32 numpy broadcast (no eager compile), then ONE device transfer
     margin = jnp.asarray(np.asarray(np.broadcast_to(
         _np_f32(base_score).reshape(-1, 1), (k_fits, n)
     )))
     from ..compiler.dispatch import donating
     from ..utils.aot import aot_call
 
-    # donated-buffer pipelining: the [K, N] margin is a pure carry between
-    # chunk programs — chunk i+1 never needs chunk i's input margin again,
-    # so the executable aliases it into the output margin instead of
-    # allocating a fresh buffer per chunk (TPTPU_DONATE=0 opts out)
+    # the [K, N] margin is the scan's carry and nothing reads its initial
+    # value afterwards, so the executable aliases it into the output margin
+    # instead of allocating a second buffer (TPTPU_DONATE=0 opts out)
     boost_chunk_fn = donating(
         "boost_chunk", _boost_rounds_batched, donate_argnums=(3,),
         static_argnames=(
@@ -1924,30 +1743,14 @@ def fit_boosted_batched(
             "axis_name", "axis_size", "hist_impl", "info_gain_norm",
         ),
     )
-    chunks = []
-    slot_chunks = []
-    done = 0
-    chunk_size = _boost_round_chunk(num_rounds)
-    while done < num_rounds:
-        rc = min(chunk_size, num_rounds - done)
-        trees_c, margin, slots_c = aot_call(
-            "boost_chunk", boost_chunk_fn,
-            (binned, y, row_mask, margin, eta_v, lam, gam, mcw, mig,
-             feature_groups),
-            dict(num_rounds=rc, max_depth=max_depth, num_bins=num_bins,
-                 objective=objective, hist_impl=_resolved_impl(),
-                 info_gain_norm=float(info_gain_norm)),
-        )
-        chunks.append(trees_c)  # each [K, rc, ...] (swap happens in-jit)
-        slot_chunks.append(slots_c)
-        done += rc
-    if len(chunks) == 1:
-        return _fit_result(chunks[0], margin, slots_c, True, return_slots)
-    # multi-chunk only off the default path: concatenate on HOST (eager
-    # device concatenates cost a compile-cache round-trip per shape)
-    chunks, slot_chunks = await_outputs((chunks, slot_chunks))
-    trees = jax.tree.map(lambda *xs: np.concatenate(xs, axis=1), *chunks)
-    slots = jax.tree.map(lambda *xs: np.concatenate(xs), *slot_chunks)
+    trees, margin, slots = aot_call(
+        "boost_chunk", boost_chunk_fn,
+        (binned, y, row_mask, margin, eta_v, lam, gam, mcw, mig,
+         feature_groups),
+        dict(num_rounds=num_rounds, max_depth=max_depth, num_bins=num_bins,
+             objective=objective, hist_impl=_resolved_impl(),
+             info_gain_norm=float(info_gain_norm)),
+    )
     return _fit_result(trees, margin, slots, True, return_slots)
 
 
@@ -2123,7 +1926,7 @@ def _fit_forest_batched_sharded(
 def _sharded_boost_kernel(mesh, num_rounds, max_depth, num_bins, objective,
                           hist_impl=None, has_groups=False,
                           info_gain_norm=0.0):
-    """jit(shard_map(boost-round-chunk)): margins stay row-sharded across
+    """jit(shard_map(boosting rounds)): margins stay row-sharded across
     the scan; each round's histogram build psums over the data axis."""
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
@@ -2184,26 +1987,18 @@ def _fit_boosted_batched_sharded(
     gam = jnp.asarray(gam, jnp.float32).reshape(-1)
     mcw = jnp.asarray(mcw, jnp.float32).reshape(-1)
     mig = jnp.asarray(mig, jnp.float32).reshape(-1)
-    chunks = []
-    done = 0
-    chunk_size = _boost_round_chunk(num_rounds)
-    while done < num_rounds:
-        rc = min(chunk_size, num_rounds - done)
-        kern = _sharded_boost_kernel(mesh, rc, max_depth, num_bins, objective,
-                                     _resolved_impl(),
-                                     has_groups=feature_groups is not None,
-                                     info_gain_norm=float(info_gain_norm))
-        grp_args = tuple(feature_groups) if feature_groups is not None else ()
-        trees_c, margin, slots_c = kern(
-            binned_p, y_p, rm_p, margin, eta_v, lam, gam, mcw, mig, *grp_args
-        )
-        # host-fetch each chunk's replicated trees — eager multi-device
-        # reshapes intermittently abort the XLA:CPU async runtime; margin
-        # stays DEVICE-resident as the next chunk's carry. Chunks are
-        # [K, rc, ...] (swap happens in-jit).
-        chunks.append(await_outputs(trees_c, hist_slots=slots_c))
-        done += rc
-    trees = jax.tree.map(lambda *xs: np.concatenate(xs, axis=1), *chunks)
+    kern = _sharded_boost_kernel(
+        mesh, num_rounds, max_depth, num_bins, objective, _resolved_impl(),
+        has_groups=feature_groups is not None,
+        info_gain_norm=float(info_gain_norm),
+    )
+    grp_args = tuple(feature_groups) if feature_groups is not None else ()
+    trees, margin, slots = kern(
+        binned_p, y_p, rm_p, margin, eta_v, lam, gam, mcw, mig, *grp_args
+    )
+    # host-fetch the replicated trees — eager multi-device reshapes
+    # intermittently abort the XLA:CPU async runtime
+    trees = await_outputs(trees, hist_slots=slots)
     return trees, await_outputs(margin)[:, :n]
 
 

@@ -134,16 +134,11 @@ def _version_salt() -> str:
                     h.update(fh.read())
             except OSError:
                 h.update(rel.encode())
-        # trace-time env knobs are program identity too: a blob exported
-        # under one knob value must not be served to a process expecting
-        # another (TPTPU_HIST additionally rides the explicit statics).
-        # TPTPU_DONATE is in the list because donation is baked into the
-        # serialized executable: a donating blob served to a donate-off
-        # process would still delete the caller's buffers (and vice versa
-        # a donate-off blob would permanently disable the optimization).
-        for knob in ("TPTPU_HIST", "TPTPU_HIST_COMB", "TPTPU_GEMM_MCAP",
-                     "TPTPU_BOOST_CHUNK", "TPTPU_DONATE"):
-            h.update(f"{knob}={os.environ.get(knob, '')}".encode())
+        # TPTPU_DONATE is program identity too: donation is baked into the
+        # serialized executable, so a donating blob served to a donate-off
+        # process would still delete the caller's buffers (and a donate-off
+        # blob would permanently disable the optimization).
+        h.update(f"TPTPU_DONATE={os.environ.get('TPTPU_DONATE', '')}".encode())
         _SALT = h.hexdigest()[:16]
     return _SALT
 
