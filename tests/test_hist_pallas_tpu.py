@@ -235,3 +235,38 @@ def test_width_ladder_grows_the_scatter_trees_on_device():
     np.testing.assert_array_equal(tree.split_feat, ref.split_feat)
     np.testing.assert_array_equal(tree.split_bin, ref.split_bin)
     np.testing.assert_array_equal(tree.leaf_value, ref.leaf_value)
+
+
+@pytest.mark.parametrize("cols", [55, 302, 500])
+@pytest.mark.parametrize("lowp", [False, True])
+@pytest.mark.parametrize("slots", [8, 32, 64, 128, 256])
+@pytest.mark.parametrize("bins", [2, 32, 64])
+def test_binloop_compiles_and_matches_scatter_at_the_chosen_tiles(
+    bins, slots, lowp, cols
+):
+    """Every shape a TPU tree fit can hand ``binloop_tiles`` (both cells'
+    groups, the 64-bin sketch, a forest deep enough for the full width)
+    compiles under the limit the kernel states to Mosaic and builds the
+    scatter histograms. 5,000 rows: two or more row tiles at every chosen
+    ``row_tile``, the last one padded."""
+    from transmogrifai_tpu.models.hist_pallas import (
+        build_histogram_pallas_binloop,
+        build_histogram_scatter_batched,
+    )
+
+    n, k = 5000, 2
+    binned, _, g, h, _ = _case(n, cols, bins, k, seed=bins + slots)
+    node = np.random.default_rng(slots).integers(
+        -1, slots, size=(k, n)
+    ).astype(np.int32)
+    if lowp:
+        g = np.sign(g).astype(np.float32)  # bf16-exact indicator values
+        h = np.ones_like(h)
+    args = (jnp.asarray(binned), jnp.asarray(node), jnp.asarray(g),
+            jnp.asarray(h), slots, bins)
+    got = np.asarray(build_histogram_pallas_binloop(*args, lowp=lowp))
+    ref = np.asarray(build_histogram_scatter_batched(*args))
+    if lowp:
+        np.testing.assert_array_equal(got, ref)  # integer sums stay exact
+    else:
+        np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-3)

@@ -239,27 +239,161 @@ def test_await_outputs_lands_the_counts_on_span_and_ledger():
     assert f"tptpu_tree_hist_slots_built {now['histSlotsBuilt']}" in text
 
 
+# (columns, bins, lowp) -> {slots: (row_tile, feat_tile)}: the cells' two
+# feature groups, four value variants (boosting) and two (the forest)
+FLAGSHIP_TILES = {
+    (302, 32, False): {
+        256: (1024, 104), 128: (2048, 104), 64: (2048, 104),
+        32: (2048, 104), 8: (2048, 104),
+    },
+    (302, 32, True): {
+        256: (2048, 104), 128: (2048, 104), 64: (2048, 104),
+        32: (2048, 104), 8: (2048, 104),
+    },
+    (55, 2, False): {
+        256: (1024, 56), 128: (2048, 56), 64: (2048, 56), 32: (2048, 56),
+        8: (2048, 56),
+    },
+    (55, 2, True): {
+        256: (2048, 56), 128: (2048, 56), 64: (2048, 56), 32: (2048, 56),
+        8: (2048, 56),
+    },
+}
+
+
+@pytest.mark.parametrize("slots", [256, 128, 64, 32, 8])
 @pytest.mark.parametrize(
-    "slots,tiles",
-    [(256, (1024, 8)), (128, (2048, 16)), (64, (2048, 104)),
-     (32, (2048, 104)), (8, (2048, 104))],
+    "group", FLAGSHIP_TILES, ids=lambda g: "{}x{}{}".format(
+        g[0], g[1], "_lowp" if g[2] else ""
+    ),
 )
-def test_kernel_tiles_by_width_at_the_flagship_shape(slots, tiles):
-    """302 wide columns x 32 bins, four value variants: the full width
-    keeps the tiles it had, a narrower build gets a fuller feature tile,
-    never over the MXU's 128 rows, evenly filled (three tiles of 104 for
-    302 columns, not 128 + 128 + 46)."""
-    assert HP.binloop_tiles(302, slots, 32) == tiles
+def test_kernel_tiles_by_width_at_the_flagship_shape(group, slots):
+    """The measured table (``binloop_tiles``' docstring): the feature tile
+    fills towards the MXU's 128 rows at EVERY width, evenly (three tiles of
+    104 for 302 columns, not 128 + 128 + 46; one of 56 for the 55 narrow
+    ones), and the row tile is the stacked operand's element cap: 1,024
+    rows where it is 1,024 lanes wide (256 slots x 4 variants), else
+    2,048."""
+    f, bins, lowp = group
+    assert HP.binloop_tiles(f, slots, bins, lowp=lowp) == (
+        FLAGSHIP_TILES[group][slots]
+    )
 
 
+@pytest.mark.parametrize("lowp", [False, True])
+@pytest.mark.parametrize("bins", [2, 32, 64])
 @pytest.mark.parametrize("f", [3, 55, 302, 500])
 @pytest.mark.parametrize("slots", [8, 64, 256])
-def test_kernel_tiles_are_sublane_multiples_within_the_mxu(f, slots):
-    row_tile, feat_tile = HP.binloop_tiles(f, slots, 32)
+def test_kernel_tiles_are_sublane_multiples_within_the_mxu(
+    f, slots, bins, lowp
+):
+    row_tile, feat_tile = HP.binloop_tiles(f, slots, bins, lowp=lowp)
     assert row_tile % 128 == 0 and 128 <= row_tile <= 2048
     assert feat_tile % HP.FEAT_TILE == 0 and 8 <= feat_tile <= 128
     # evening the tiles never adds a tile or pads more than one sublane
     # group per tile
     f8 = -(-f // 8) * 8
     tiles = -(-f8 // feat_tile)
+    assert tiles == -(-f8 // 128)
     assert tiles * feat_tile - f8 < 8 * tiles
+    # what the model says the pair takes is under what Mosaic is told
+    assert HP.binloop_vmem_bytes(
+        row_tile, feat_tile, slots, bins, lowp
+    ) <= HP._BINLOOP_VMEM_BUDGET < HP._BINLOOP_VMEM_LIMIT
+
+
+# (slots, lowp, bins, row_tile, feat_tile) -> MiB of scoped VMEM Mosaic
+# asked for when compiled for a v5e (read off its refusal under a limit
+# set just over the pipelined blocks: PR 30)
+MOSAIC_ASKED_MIB = {
+    (256, False, 32, 1024, 104): 17.99,
+    (256, False, 32, 2048, 128): 26.65,
+    (256, False, 32, 128, 128): 16.65,
+    (256, False, 64, 512, 128): 34.64,
+    (256, True, 32, 2048, 128): 23.54,
+    (128, False, 32, 2048, 128): 15.81,
+    (64, False, 32, 2048, 128): 13.99,
+    (32, False, 32, 2048, 128): 12.49,
+    (32, True, 32, 2048, 128): 12.06,
+    (256, False, 2, 2048, 56): 8.55,
+}
+
+
+@pytest.mark.parametrize(
+    "point", MOSAIC_ASKED_MIB, ids=lambda p: "-".join(map(str, p))
+)
+def test_vmem_model_is_not_under_what_mosaic_asked_for(point):
+    slots, lowp, bins, row_tile, feat_tile = point
+    model = HP.binloop_vmem_bytes(row_tile, feat_tile, slots, bins, lowp)
+    asked = MOSAIC_ASKED_MIB[point] * 2**20
+    assert asked <= model <= 1.5 * asked
+
+
+# (slots, the pair binloop_tiles gave up to PR 29): the full-width pairs
+# the joint choice replaced
+OLD_TILES = {256: (1024, 8), 128: (2048, 16)}
+
+
+@pytest.mark.parametrize("values", ["half", "real"])
+@pytest.mark.parametrize("slots", [256, 128])
+def test_old_and_new_tiles_build_the_same_histograms(slots, values):
+    """The kernel at the flagship shape's old pair and at its new pair, in
+    interpret mode: another order of adding row tiles and another split of
+    the columns, the same sums. +-0.5 / 0.25 values (one round of
+    binary:logistic) are exact in float32 in any order; real values agree
+    with the scatter histograms within the kernel tests' tolerance."""
+    n, f, k = 4608, 70, 2
+    new = HP.binloop_tiles(302, slots, 32)
+    assert new != OLD_TILES[slots]
+    rng = np.random.default_rng(slots)
+    binned = jnp.asarray(rng.integers(0, BINS, size=(n, f)), jnp.int32)
+    node = jnp.asarray(rng.integers(-1, slots, size=(k, n)), jnp.int32)
+    if values == "half":
+        g = rng.choice([-0.5, 0.5], size=(k, n)).astype(np.float32)
+        h = np.full((k, n), 0.25, np.float32)
+    else:
+        g = rng.normal(size=(k, n)).astype(np.float32)
+        h = rng.uniform(0.1, 1.0, size=(k, n)).astype(np.float32)
+    g, h = jnp.asarray(g), jnp.asarray(h)
+
+    def build(tiles):
+        return np.asarray(HP.build_histogram_pallas_binloop(
+            binned, node, g, h, slots, BINS, row_tile=tiles[0],
+            feat_tile=tiles[1], interpret=True,
+        ))
+
+    old_hist, new_hist = build(OLD_TILES[slots]), build(new)
+    ref = np.asarray(HP.build_histogram_scatter_batched(
+        binned, node, g, h, slots, BINS
+    ))
+    if values == "half":
+        np.testing.assert_array_equal(old_hist, new_hist)
+        np.testing.assert_array_equal(new_hist, ref)
+    else:
+        np.testing.assert_allclose(old_hist, ref, atol=2e-4)
+        np.testing.assert_allclose(new_hist, ref, atol=2e-4)
+
+
+@pytest.mark.parametrize(
+    "kw,text",
+    [
+        # the boosted cell: four rungs, the widest group is the 302 wide
+        (dict(max_depth=10, lowp=False),
+         "32:2048/104 64:2048/104 128:2048/104 256:1024/104"),
+        # the forest cell's three depth programs (lowp)
+        (dict(max_depth=12, lowp=True),
+         "32:2048/104 64:2048/104 128:2048/104 256:2048/104"),
+        (dict(max_depth=6, lowp=True), "32:2048/104 64:2048/104"),
+        (dict(max_depth=3, lowp=True), "8:2048/104"),
+        # four shards: the local rows' plan, the full width only
+        (dict(max_depth=10, lowp=False, shards=4), "256:1024/104"),
+        (dict(max_depth=10, lowp=False, shards=1), "256:1024/104"),
+        # builders without tiles
+        (dict(max_depth=10, lowp=False, n=4096), "none"),
+        (dict(max_depth=10, lowp=False, impl="scatter"), "none"),
+    ],
+)
+def test_hist_tiles_attribute_names_the_ladder_of_the_widest_group(kw, text):
+    kw = {"n": 1_002_701, "impl": "pallas", **kw}
+    n = kw.pop("n")
+    assert TR.hist_tiles(n, 4, [(55, 2), (302, 32)], **kw) == text

@@ -13,7 +13,9 @@ mode; Mosaic refused it at every shape).
     JAX_PLATFORMS=cpu python tools/aot_v5e.py --serve-matrix
 
 Compiled at main-path shapes: the bin-loop histogram (32 bins) and the
-lane-packed histogram (256 bins) in both precisions, the serve-side
+lane-packed histogram (256 bins) in both precisions, the bin-loop kernel
+at the benchmark cells' shape and full widths (1,002,701 x 302, the tiles
+``binloop_tiles`` picks under the VMEM limit it states), the serve-side
 traversal kernel at more than one tree tile (one-byte and split codes), one
 whole ``boost_chunk`` program (65,536 x 64, depth 10) with the Pallas
 histogram inside, and the binning's column statistics (the chunked sort).
@@ -68,6 +70,19 @@ def main(argv) -> int:
             f"hist lane-packed 256 bins lowp={lowp}",
             lambda a=hist_args, lp=lowp: HP.build_histogram_pallas_batched
             .lower(*a, num_nodes=m, num_bins=256, lowp=lp),
+        ))
+    # the benchmark cells' wide group at the widths whose tiles ask for
+    # more scoped VMEM than Mosaic's default (binloop_tiles, PR 30)
+    cn, cf, ck = 1_002_701, 302, 4
+    cell_args = (sds((cn, cf), i32), sds((ck, cn), i32), sds((ck, cn), f32),
+                 sds((ck, cn), f32))
+    for slots, lowp in ((256, False), (256, True), (128, False)):
+        rt, ft = HP.binloop_tiles(cf, slots, 32, lowp=lowp)
+        jobs.append((
+            f"hist binloop cell shape {slots} slots lowp={lowp} "
+            f"(tiles {rt}/{ft})",
+            lambda s_=slots, lp=lowp: HP.build_histogram_pallas_binloop
+            .lower(*cell_args, num_nodes=s_, num_bins=32, lowp=lp),
         ))
     serve = [(200, 10), (50, 12)]
     if "--serve-matrix" in argv:

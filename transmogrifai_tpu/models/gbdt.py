@@ -146,6 +146,24 @@ def _groups_from_flags(binary: np.ndarray):
     return jnp.asarray(narrow), jnp.asarray(wide)
 
 
+def _hist_tiles_attr(binned, fgroups, lanes, depth, bins, lowp) -> str:
+    """``hist_tiles`` of a ``tree/fit_dispatch`` span: the bin-loop
+    kernel's tiles at each width the fit can build at, from the same
+    shapes the fit program is traced with (``trees.hist_tiles``)."""
+    from ..parallel.mesh import DATA_AXIS, execution_mesh
+
+    n, f = binned.shape
+    groups = [(f, bins)] if fgroups is None else [
+        (int(idx.shape[0]), b)
+        for idx, b in zip(fgroups, (2, bins)) if idx.shape[0]
+    ]
+    mesh = execution_mesh()
+    return TR.hist_tiles(
+        n, lanes, groups, depth, lowp,
+        shards=None if mesh is None else mesh.shape[DATA_AXIS],
+    )
+
+
 # Planes of at least this many values (rows x columns) take their column
 # statistics on the device (``_bin_into_cache``). The host's two passes cost
 # 51-70 ns a value, the program 1.9 ns (a v5e at 1,002,701 x 357: PERF.md
@@ -994,7 +1012,7 @@ class _TreeEstimator(PredictorEstimator):
 
     def _batched_group_fit(
         self, x, masks, group_points, run_batched, make_model, normalize=None,
-        dispatch_attrs=None,
+        dispatch_attrs=None, lowp=False,
     ):
         """Shared plumbing for the masks × points batched fit: bin once,
         merge (+ normalize) params, stack the float knobs mask-major
@@ -1007,7 +1025,8 @@ class _TreeEstimator(PredictorEstimator):
         for a param;
         ``make_model(thresholds, sliced_trees, merged_params, mask_index)``;
         ``dispatch_attrs(binned, m0)`` gives the family's own attributes of
-        the ``tree/fit_dispatch`` span.
+        the ``tree/fit_dispatch`` span; ``lowp`` says the trainer hands the
+        histogram kernel bf16-exact values (the span's ``hist_tiles``).
         The training outputs (every lane's raw model output on the full
         training matrix, computed by the fit program itself) ride the stack
         so sweep_eval_batched needs no re-traversal program.
@@ -1043,6 +1062,11 @@ class _TreeEstimator(PredictorEstimator):
             rounds=int(m0.get("num_round", m0.get("num_trees", 1))),
             depth=int(m0["max_depth"]), bins=int(m0["max_bins"]),
             hist_impl=TR._resolved_impl(),
+            hist_tiles=_hist_tiles_attr(
+                binned, fgroups, n_masks * n_pts,
+                max(int(m["max_depth"]) for m in merged),
+                int(m0["max_bins"]), lowp,
+            ),
             **(dispatch_attrs(binned, m0) if dispatch_attrs else {}),
         ):
             trees, outputs, slots = run_batched(
@@ -1564,7 +1588,7 @@ class RandomForestClassifier(_TreeEstimator):
         return self._batched_group_fit(
             x, masks, group_points, run_batched,
             lambda th, tr, m, mi: ForestClassifierModel(th, [tr]),
-            dispatch_attrs=self._dispatch_attrs,
+            dispatch_attrs=self._dispatch_attrs, lowp=True,
         )
 
     def _fit_group_masks_multiclass(self, x, y, masks, group_points,
@@ -1611,6 +1635,10 @@ class RandomForestClassifier(_TreeEstimator):
             "tree/fit_dispatch", lanes=n_masks * n_pts * c,
             rounds=int(m0["num_trees"]), depth=int(m0["max_depth"]),
             bins=int(m0["max_bins"]), hist_impl=TR._resolved_impl(),
+            hist_tiles=_hist_tiles_attr(
+                binned, fgroups, n_masks * n_pts * c, int(m0["max_depth"]),
+                int(m0["max_bins"]), True,
+            ),
             **self._dispatch_attrs(binned, m0),
         ):
             trees, outs, slots = TR.fit_forest_batched(

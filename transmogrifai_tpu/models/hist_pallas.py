@@ -338,70 +338,101 @@ def _hist_binloop_kernel(binned_ref, node_ref, g_ref, h_ref, outg_ref,
             outh_ref[0, b, :, :] = outh_ref[0, b, :, :] + hh
 
 
+# Scoped VMEM the bin-loop kernel states to Mosaic (``vmem_limit_bytes``; a
+# v5e core has 128 MiB, Mosaic's default scope is 16 MiB, the serve kernel
+# states 64 MiB too), and what ``binloop_tiles`` may plan for under it by
+# ``binloop_vmem_bytes``. The limit itself costs nothing: the pairs that fit
+# the default ran the same seconds with and without it (PR 30).
+_BINLOOP_VMEM_LIMIT = 64 << 20
+_BINLOOP_VMEM_BUDGET = 48 << 20
+# Elements of the [T, nvar·M] stacked one-hot operand one grid step builds.
+# Measured, not a memory bound: at 1,024 lanes (256 slots x 4 variants) a
+# 1,024-row tile beat 2,048 rows at every feature tile (0.5295 against
+# 0.5511 s at 104), at 512 lanes and under 2,048 rows won.
+_BINLOOP_STACK_ELEMS = 1 << 20
+
+
+def binloop_vmem_bytes(
+    row_tile: int, feat_tile: int, num_nodes: int, num_bins: int,
+    lowp: bool = False,
+) -> int:
+    """Scoped VMEM one grid step of the bin-loop kernel takes, fitted from
+    above to what Mosaic asked for at 240 (slots, bins, variants, tiles)
+    points compiled for a v5e (PR 30: 0.5% to 27% over it at 128
+    features a tile, 16% to 5x at 8). Mosaic double-buffers every block
+    the grid pipelines: the two ``[bins, feat_tile, M]`` float32 output
+    accumulators (M pads to 128 lanes: under 128 slots they cost what 128
+    cost), the ``[feat_tile, T]`` codes and the node / grad / hess rows
+    (8 sublanes each). On top, once, the step's temporaries per row of the
+    tile: the ``[T, nvar·M]`` stack and what it is selected from (3.5
+    bytes a lane), the per-bin compare of the codes (8 bytes a feature),
+    and 1 KB of row vectors."""
+    m_pad = _round_up(max(num_nodes, 8), 8)
+    stack_lanes = _round_up((2 if lowp else 4) * m_pad, 128)
+    blocks = (
+        2 * num_bins * feat_tile * _round_up(m_pad, 128) * 4
+        + feat_tile * row_tile * 4
+        + 3 * 8 * row_tile * 4
+    )
+    temps = row_tile * (stack_lanes * 7 // 2 + 8 * feat_tile + 1024)
+    return 2 * blocks + temps
+
+
 def binloop_tiles(
-    f: int, num_nodes: int, num_bins: int, lowp: bool = False,
-    row_tile: int | None = None, feat_tile: int | None = None,
+    f: int, num_nodes: int, num_bins: int, lowp: bool = False
 ) -> tuple[int, int]:
     """(row_tile, feat_tile) of the bin-loop kernel at ``num_nodes`` node
-    slots, from shapes alone. Both grow as the node axis narrows, so a
-    level built at the width of its live nodes gains twice: a narrower
-    ``[T, nvar·M]`` one-hot operand, and more features (the dot's row
-    dimension) per grid step. At 1,002,701 x 302 x 32 bins, 4 lanes and 4
-    variants, on a v5e (PR 26, tools/bench_hist_kernel.py; seconds a call):
+    slots, from shapes alone, chosen together. The kernel's dot is
+    ``[feat_tile, T] @ [T, nvar·M]``: the feature tile is the MXU's row
+    dimension, and the ``[T, nvar·M]`` operand (the VPU's work) is rebuilt
+    once per feature tile. So the feature tile comes first: as few tiles
+    as 128 features a tile allow, evenly filled (302 columns as 3 x 104,
+    padded to 312; 128 + 128 + 46 would pad them to 384, a quarter more
+    dots). Then the largest row tile the stacked operand's element cap and
+    the VMEM budget leave; only if none fits, one more feature tile (no
+    shape a fit can reach today: at most 256 slots and 64 bins need 42 of
+    the 48 MB).
 
-        slots  row_tile  feat_tile  s
-          256      1024          8  2.406
-          128      2048         16  0.648
-           64      2048        104  0.176
-           32      2048        104  0.134
+    Measured on a v5e at 1,002,701 rows x 32 bins, 4 lanes
+    (``tools/bench_hist_kernel.py --grid``, PR 30; seconds a call; ``was``:
+    the pair and seconds of the two-step choice this replaced, which fixed
+    the row tile first and had 6 MB for the rest):
 
-    The time follows slots / feat_tile, not slots alone: at 256 slots the
-    one-hot temporaries take 5.2 of the model's 6 MB and leave the dot 8
-    rows of the MXU's 128. Forced tiles say what that costs: 256 slots at
-    (256, 64) 0.803 s, 128 slots at (1024, 64) 0.325 s (ROADMAP S1)."""
+        302 columns  4 variants                lowp (2 variants)
+        slots  tiles     s       was             tiles     s       was
+          256  1024/104  0.5295  1024/8  2.4061  2048/104  0.2706  1024/8
+          128  2048/104  0.2676  2048/16 0.6486  2048/104  0.1524  2048/32
+           64  2048/104  0.1755  the same        2048/104  0.1126  the same
+           32  2048/104  0.1339  the same        2048/104  0.1128  the same
+
+        55 columns x 2 bins: 2048/56 (1024/56 at 256 slots x 4 variants)
+        0.0151 s at 256 slots, 0.0102 at 32 (was 32 features: 0.0248,
+        0.0181)
+
+    At 256 slots the best pair under Mosaic's default 16 MiB scope is
+    512/104, 0.5853 s (1024/104 asks for 18.0 MiB): the stated limit buys
+    a tenth. 128 features a tile lost to 104 at every width (0.5981 at
+    1024/128): the padding to 384 columns costs more than the fuller MXU
+    gains. Every pair of {128..2048} x {8..128} compiled under the stated
+    limit."""
     m_pad = _round_up(max(num_nodes, 8), 8)
     nvar = 2 if lowp else 4
-    if row_tile is None:
-        # the [T, nvar·M] stacked operand and the [T, M] one-hot copies are
-        # the big VMEM temporaries: T·nvar·M stays bounded, lane-aligned
-        row_tile = max(
-            128, min(2048, ((1 << 20) // (nvar * m_pad)) // 128 * 128)
-        )
-
-    if feat_tile is not None:
-        return row_tile, feat_tile
-
-    def vmem_bytes(ft: int) -> int:
-        # binned block + 2 output accumulators + stacked operand + comb
-        return (
-            ft * row_tile * 4
-            + 2 * num_bins * ft * m_pad * 4
-            + row_tile * nvar * m_pad * 2
-            + row_tile * (3 * m_pad * 4 + ft * 2)
-        )
-
-    # budget 6 MB by this model: Mosaic double-buffers grid blocks and
-    # carries dot/select temporaries the model does not count (measured
-    # ~2x) — 12 MB nominal blew the 16 MB scoped-vmem stack
-    # ... and at most 128 features, the MXU's row dimension: more gains
-    # nothing, and under 128 node slots the output blocks pad their lane
-    # axis to 128, which the model does not count (256 features at 16
-    # slots asked for 20 MB of scoped VMEM at 1M x 302 and did not compile)
-    feat_tile = FEAT_TILE
-    while (
-        feat_tile * 2 <= min(_round_up(f, FEAT_TILE), 128)
-        and vmem_bytes(feat_tile * 2) <= (6 << 20)
-    ):
-        feat_tile *= 2
-    while vmem_bytes(feat_tile) > (6 << 20) and row_tile > 512:
-        row_tile //= 2
-    # the same number of feature tiles, evenly filled: a 128-feature tile
-    # pads 302 columns to 384 (a quarter more dots, and a larger [F, N]
-    # copy of the codes), three tiles of 104 pad them to 312 (measured at
-    # 32 slots: 0.144 s a call at 128, 0.134 at 104, 0.178 at 64)
+    row_cap = max(
+        128, min(2048, _BINLOOP_STACK_ELEMS // (nvar * m_pad) // 128 * 128)
+    )
     f8 = _round_up(f, FEAT_TILE)
-    feat_tile = _round_up(-(-f8 // -(-f8 // feat_tile)), FEAT_TILE)
-    return row_tile, feat_tile
+    for tiles in range(-(-f8 // 128), f8 // FEAT_TILE + 1):
+        feat_tile = _round_up(-(-f8 // tiles), FEAT_TILE)
+        row_tile = row_cap
+        while row_tile >= 128:
+            if (
+                binloop_vmem_bytes(
+                    row_tile, feat_tile, num_nodes, num_bins, lowp
+                ) <= _BINLOOP_VMEM_BUDGET
+            ):
+                return row_tile, feat_tile
+            row_tile //= 2
+    return 128, FEAT_TILE
 
 
 @functools.partial(
@@ -432,10 +463,8 @@ def build_histogram_pallas_binloop(
     k_fits, n = node.shape
     f = binned.shape[1]
     m_pad = _round_up(max(num_nodes, 8), 8)
-    row_tile, feat_tile = binloop_tiles(
-        f, num_nodes, num_bins, lowp=lowp, row_tile=row_tile,
-        feat_tile=feat_tile,
-    )
+    tiles = binloop_tiles(f, num_nodes, num_bins, lowp=lowp)
+    row_tile, feat_tile = row_tile or tiles[0], feat_tile or tiles[1]
     n_pad = _round_up(max(n, row_tile), row_tile)
     f_pad = _round_up(f, feat_tile)
 
@@ -489,6 +518,9 @@ def build_histogram_pallas_binloop(
                 lambda k, i, j: (k, 0, i, 0),
                 memory_space=pltpu.VMEM,
             ),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_BINLOOP_VMEM_LIMIT
         ),
         interpret=interpret,
     )(binned_t, node_p, g_p, h_p)

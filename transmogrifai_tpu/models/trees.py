@@ -89,6 +89,62 @@ def _width_ladder(chunk_nodes: int) -> tuple[int, ...]:
     return tuple(rungs)
 
 
+def _slot_layout(
+    impl: str, n: int, k_fits: int, groups, max_depth: int,
+    axis_size: int = 1, sharded: bool = False,
+):
+    """(cap, plan, ladder) of one tree fit, from what its trace can see:
+    ``cap`` compact node slots a level can have live (``2^max_depth``, or
+    the power of two that holds the GLOBAL rows where there are fewer),
+    ``hist_pallas.histogram_plan``'s builders and chunk width for the
+    LOCAL rows ``n``, and the widths a level may be built at. The sharded
+    path keeps the full width: its psums may not sit under a
+    data-dependent branch."""
+    from .hist_pallas import histogram_plan
+
+    max_nodes = 1 << max_depth
+    n_global = n * axis_size
+    cap = max_nodes
+    if cap > n_global:
+        cap = 1
+        while cap < n_global:
+            cap <<= 1
+        cap = min(cap, max_nodes)
+    plan = histogram_plan(impl, n, k_fits, groups, cap)
+    ladder = (plan.chunk_cap,) if sharded else _width_ladder(plan.chunk_cap)
+    return cap, plan, ladder
+
+
+def hist_tiles(
+    n: int, k_fits: int, groups, max_depth: int, lowp: bool,
+    impl: str | None = None, shards: int | None = None,
+) -> str:
+    """The ``hist_tiles`` attribute of ``tree/fit_dispatch``: for each rung
+    of the fit's width ladder, ``slots:row_tile/feat_tile`` of the bin-loop
+    kernel over the widest feature group (the one with the most histogram
+    cells), so a trace reader can tell which table of
+    ``hist_pallas.binloop_tiles`` a run used. ``groups``: the fit's
+    ``(columns, bins)``; ``n``: the rows of the whole fit; ``shards``: the
+    data-axis size of the mesh a sharded fit runs on (None: one device).
+    ``none`` where that group takes another builder."""
+    from .hist_pallas import binloop_tiles
+
+    axis_size = shards or 1
+    _, plan, ladder = _slot_layout(
+        impl or _resolved_impl(), -(-n // axis_size), k_fits, groups,
+        max_depth, axis_size, sharded=shards is not None,
+    )
+    (f, b), builder = max(
+        zip(groups, plan.builders), key=lambda gb: gb[0][0] * gb[0][1]
+    )
+    if builder != "binloop":
+        return "none"
+    return " ".join(
+        "{}:{}/{}".format(w, *binloop_tiles(f, w, b, lowp=lowp))
+        for w in ladder
+    )
+
+
 class HistSlotStats(_tm.LedgerCore):
     """Cumulative sums of the ``HistSlots`` of the fits whose outputs
     ``await_outputs`` landed (part of the ``tree`` ledger: ``models/gbdt.py``
@@ -507,7 +563,7 @@ def _grow_tree_impl(
     on the lane, the chunk, the compact slot, the width rung or the mesh.
     ``node_subset == F`` draws nothing; None also counts nothing
     (``HistSlots.subset_*`` stay 0)."""
-    from .hist_pallas import BUILDERS, default_impl, histogram_plan
+    from .hist_pallas import BUILDERS, default_impl
 
     k_fits, n = grad.shape
     f = binned.shape[1]
@@ -561,21 +617,13 @@ def _grow_tree_impl(
     # depth-12 growth on 1k rows costs the same as depth-10 (the dominant
     # win for the deep ends of the reference's maxDepth {3,6,12} grids).
     # When sharded, the live bound is the GLOBAL row count.
-    n_global = n * axis_size
-    cap = max_nodes
-    if cap > n_global:
-        cap = 1
-        while cap < n_global:
-            cap <<= 1
-        cap = min(cap, max_nodes)
-
     # which builder each group takes and how many node slots one build may
     # hold: decided in ONE place, hist_pallas.histogram_plan. A builder's
     # loop-invariant operand (the GEMM's one-hot codes) is made here, once
     # per group, outside the level scan and the tree scan above it.
-    plan = histogram_plan(
+    cap, plan, ladder = _slot_layout(
         impl, n, k_fits, [(gb_.shape[1], bb) for gb_, _, bb, _ in groups],
-        cap,
+        max_depth, axis_size, sharded=axis_name is not None,
     )
     with jax.named_scope("tree/group_columns"):
         groups = [
@@ -745,19 +793,15 @@ def _grow_tree_impl(
     #
     # A level with few live slots does not pay for a whole chunk: its
     # histograms are built and searched at the smallest rung of `ladder`
-    # that holds them (live_level below). A build costs at least in
-    # proportion to its width (the kernel's one-hot operand is
-    # [T, nvar·width], and its feature tile grows as the width falls:
-    # 2.41 s at 256 slots, 0.65 at 128, 0.18 at 64, 0.13 at 32 on a v5e at
-    # 1M x 302 x 32 bins), and in a depth-10 fit eight of the eleven
-    # builds have 128 live slots or fewer. The sharded path keeps the
-    # full width: its psums may not sit under a data-dependent branch.
+    # that holds them (live_level below). A build costs about in
+    # proportion to its width (the kernel's one-hot operand and its dots
+    # are [T, nvar·width]: 0.53 s at 256 slots, 0.27 at 128, 0.18 at 64,
+    # 0.13 at 32 on a v5e at 1M x 302 x 32 bins, hist_pallas.binloop_tiles),
+    # and in a depth-10 fit eight of the eleven builds have 128 live slots
+    # or fewer. The sharded path keeps the full width (_slot_layout).
     n_nodes = cap
     chunk_nodes = plan.chunk_cap
     num_chunks = (n_nodes + chunk_nodes - 1) // chunk_nodes
-    ladder = (
-        _width_ladder(chunk_nodes) if axis_name is None else (chunk_nodes,)
-    )
 
     def compact_local(hist_node):
         """Dense live-slot numbering via occupancy + cumsum rank. Slot =
