@@ -45,6 +45,8 @@ def test_main_path_kernels_compile_for_v5e():
     assert p.returncode == 0, p.stdout[-4000:] + p.stderr[-2000:]
     # the serve kernel was compiled at more than one tree tile
     assert "(13 tree tiles)" in p.stdout and "boost_chunk" in p.stdout
+    # both families of objective: XGBoost's and Spark GBT's
+    assert "spark:logloss" in p.stdout
     assert "ok   bin_column_stats" in p.stdout
 
 

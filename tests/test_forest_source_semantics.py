@@ -298,7 +298,8 @@ def test_min_info_gain_is_per_row_and_bites_on_4096_rows():
 
 
 @pytest.mark.parametrize("est,stops_all", [
-    # logistic g = +-0.5, h = 0.25: the variance of g/h is at most 4
+    # Spark's boosting (PR 32): targets +-1, then |4y/(1+exp(2yF))| < 4: the
+    # variance of a node's targets is under 4.5 in both rounds
     (G.GBTClassifier(max_iter=2, max_depth=4, max_bins=BINS), 4.5),
     (G.DecisionTreeClassifier(max_depth=4, max_bins=BINS), 0.5),
     (G.DecisionTreeRegressor(max_depth=4, max_bins=BINS), 0.5),

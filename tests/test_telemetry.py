@@ -848,6 +848,7 @@ def test_fit_arrays_yields_the_sweep_span_tree(xgb_sweep):
     assert args["tree/fit_dispatch"] == {
         "lanes": 4, "rounds": 2, "depth": 3, "bins": 32,
         "hist_impl": "scatter", "hist_tiles": "none",
+        "objective": "binary:logistic", "tree_weights": "0.02 0.02",
     }
     assert args["tree/feature_groups"] == {"narrow": 3, "wide": 4}
     assert args["selector/refit"] == {"prefit": True}

@@ -110,6 +110,18 @@ def main(argv) -> int:
         ),
     ))
     jobs.append((
+        "boost_chunk 65536x64 K=4 depth 12, 2 rounds, spark:logloss "
+        "(first-order targets, per-round weights, 8 chunks a level)",
+        lambda: TR._boost_rounds_batched.lower(
+            sds((n, f), i32), sds((n,), f32), sds((4, n), f32),
+            sds((4, n), f32), sds((4,), f32), sds((), f32), sds((), f32),
+            sds((4,), f32), sds((4,), f32), None,
+            num_rounds=2, max_depth=12, num_bins=32,
+            objective="spark:logloss", hist_impl="pallas",
+            info_gain_norm=2.0,
+        ),
+    ))
+    jobs.append((
         "bin_column_stats 65536x70, 32 bins (3 sorts of 32 columns)",
         lambda: TR.bin_column_stats.lower(sds((n, 70), f32), max_bins=32),
     ))

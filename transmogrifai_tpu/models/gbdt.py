@@ -1,22 +1,34 @@
 """Tree-ensemble model stages: XGBoost / GBT / RandomForest / DecisionTree.
 
-Reference stages replaced (behavioral parity on the histogram learner in
-models/trees.py):
+Reference stages replaced (all on the histogram learner in models/trees.py):
   * OpXGBoostClassifier/Regressor (core/.../classification/OpXGBoostClassifier.scala
     — JNI libxgboost + Rabit allreduce): XLA boosting with second-order
     gradients; pass ``mesh=`` to the trees.fit_* entry points to shard rows
     over the mesh data axis with per-level histograms psum'd over ICI
     (trees._sharded_boost_kernel — the Rabit replacement, proven
     tree-identical in tests/test_trees_sharded.py).
-  * OpGBTClassifier/Regressor (Spark GBT; defaults maxIter 20, stepSize 0.1).
+  * OpGBTClassifier/Regressor: Spark ML's ``GradientBoostedTrees.boost``
+    (defaults maxIter 20, stepSize 0.1) — FIRST-order regression trees with
+    the variance gain, the first fitted to the labels (±1 for the
+    classifier) at weight 1, each later one to the loss's negative gradient
+    at weight stepSize; ``minInstancesPerNode`` counts rows;
+    P(y = 1) = σ(2F). The objectives ``spark:logloss`` /
+    ``spark:squarederror`` of ``trees.OBJECTIVES``; pinned node for node in
+    tests/test_gbt_source_semantics.py. (Up to PR 31 these two were the
+    XGBoost learner under Spark's knob names.)
   * OpRandomForestClassifier/Regressor (Spark RF; defaults numTrees 50 in
     selector grids, maxDepth 5 spark default).
   * OpDecisionTreeClassifier/Regressor: single unbagged tree.
 
 Known divergences (documented per SURVEY.md §7 hard-part 5): multiclass
-boosting is one-vs-rest rather than softmax-per-round; RF classification
-impurity is variance on per-class indicators (probability trees) rather than
-gini — both preserve the fitted-probability semantics used downstream.
+XGBoost is one-vs-rest rather than softmax-per-round; RF classification
+over more than two classes grows per-class indicator (probability) trees
+rather than one multiclass-gini tree; every tree family
+takes its split candidates from exact float64 quantiles (at most
+``max_bins`` - 1 a column) where Spark samples rows for them, and breaks
+ties between equal gains by lowest column, then lowest bin;
+``GBTClassifier`` over more than two classes is one-vs-rest (Spark's is
+binary only).
 """
 from __future__ import annotations
 
@@ -292,10 +304,16 @@ class _BinnedModel(PredictorModel):
     def _use_host(self, x) -> bool:
         """Serving-size batches predict in numpy on the host; larger ones
         dispatch on the device. The 16,384-row cutoff is not re-measured on
-        a local chip; see ROADMAP S7/D3."""
+        a local chip; see ROADMAP M6 / C5 and D3."""
         import os
 
         return len(x) <= int(os.environ.get("TPTPU_HOST_PREDICT_MAX", "16384"))
+
+    def _boost_weights(self) -> np.ndarray:
+        """What a boosted model's predict paths take as ``eta``: one
+        shrinkage for every tree (float32 scalar; XGBoost's), or, from a
+        Spark GBT model, the [R] weight of each tree."""
+        return np.float32(self.eta)
 
     def _host(self, trees):
         if self._host_cache is None:
@@ -343,7 +361,8 @@ class _BinnedModel(PredictorModel):
             if boosted:
                 outs = [
                     TR.predict_boosted_host(
-                        xu, thr_used, t, self.eta, self.base_score,
+                        xu, thr_used, t, self._boost_weights(),
+                        self.base_score,
                         binned=binned,
                     )
                     for t in hs
@@ -363,7 +382,7 @@ class _BinnedModel(PredictorModel):
             ds = self._dev(trees)
             ds = ds if many else [ds]
             if boosted:
-                eta = jnp.float32(self.eta)
+                eta = jnp.asarray(self._boost_weights())
                 base = jnp.float32(self.base_score)
                 outs = [np.asarray(_aot_predict_boosted(xj, thr, t, eta, base))
                         for t in ds]
@@ -408,7 +427,7 @@ class _BinnedModel(PredictorModel):
             "trees": tuple(ds),
         }
         if boosted:
-            params["eta"] = np.float32(self.eta)
+            params["eta"] = self._boost_weights()
             params["base"] = np.float32(self.base_score)
         # implementation is resolved HERE, at spec-build time, never inside
         # the traced core — the choice is baked into the program and salts
@@ -455,6 +474,8 @@ class _BinnedModel(PredictorModel):
             core=core, epilogue=self.predictions_from_core,
             descriptor=(
                 f"{'boost' if boosted else 'forest'}:{len(ds)}"
+                # per-tree weights are another program than one eta
+                + (":tw" if boosted and params["eta"].ndim else "")
                 + (":pl" if pallas else "")
             ),
         )
@@ -547,8 +568,12 @@ class BoostedBinaryModel(_BinnedModel):
     def sweep_lane_params(self):
         return float(self.eta), float(self.base_score)
 
+    #: P(y = 1) = sigmoid(_LINK * F): 1 for XGBoost's logistic margin, 2
+    #: for Spark's LogLoss on +-1 labels (1 / (1 + exp(-2F)))
+    _LINK = 1.0
+
     def predictions_from_sweep(self, margin):
-        p1 = _sigmoid(np.asarray(margin, dtype=np.float64))
+        p1 = _sigmoid(self._LINK * np.asarray(margin, dtype=np.float64))
         prob = np.stack([1 - p1, p1], axis=1)
         raw = np.stack([-margin, margin], axis=1)
         return (p1 > 0.5).astype(np.float64), prob, raw
@@ -584,9 +609,11 @@ class BoostedMultiModel(_BinnedModel):
     def _tree_stacks(self):
         return self.trees_per_class, True
 
+    _LINK = 1.0  # as BoostedBinaryModel's
+
     def predictions_from_core(self, core):
         margins = np.asarray(core, dtype=np.float64)
-        p = _sigmoid(margins)
+        p = _sigmoid(self._LINK * margins)
         prob = p / np.maximum(p.sum(axis=1, keepdims=True), 1e-12)
         return prob.argmax(axis=1).astype(np.float64), prob, margins
 
@@ -631,6 +658,61 @@ class BoostedRegressionModel(_BinnedModel):
     @staticmethod
     def predictions_from_sweep(margin):
         return np.asarray(margin, dtype=np.float64), None, None
+
+
+class _PerTreeWeights:
+    """Mixin of a boosted model whose trees carry a weight EACH (Spark's
+    ``treeWeights``: 1, then ``stepSize``), not one ``eta``, from a zero
+    margin. The trees are kept as fitted (a leaf is its node's mean
+    target) and the weights beside them (``get_arrays``); the predict paths
+    (the host traversal, the banked ``predict_boosted`` program, the fused
+    graph's core and the serve kernel's epilogue) take the weights where an
+    XGBoost model hands them its ``eta``. Its classifiers map the margin
+    through 1 / (1 + exp(-2F))."""
+
+    _LINK = 2.0
+
+    def __init__(self, thresholds, trees, tree_weights, uid=None):
+        tree_weights = np.asarray(tree_weights, dtype=np.float32)
+        # eta: what every tree after the first weighs (the model's params)
+        super().__init__(
+            thresholds, trees, float(tree_weights[-1]), 0.0, uid=uid
+        )
+        self.tree_weights = tree_weights
+
+    def _boost_weights(self) -> np.ndarray:
+        return self.tree_weights
+
+    def get_arrays(self):
+        return {**super().get_arrays(), "tree_weights": self.tree_weights}
+
+    @classmethod
+    def from_params(cls, params, arrays):
+        trees = (
+            _class_trees_from_arrays(arrays)
+            if "c0__split_feat" in arrays else _tree_from_arrays(arrays)
+        )
+        return cls(arrays["thresholds"], trees, arrays["tree_weights"])
+
+    def sweep_lane_params(self):
+        # asked only of a stack that lacks the fit program's own outputs;
+        # (eta, base) cannot say per-tree weights, so sweep_eval_batched
+        # falls back to predict_arrays
+        raise NotImplementedError("per-tree weights")
+
+
+class GBTClassificationModel(_PerTreeWeights, BoostedBinaryModel):
+    """Spark's ``GBTClassificationModel``: F = Σ w_r T_r over regression
+    trees, raw [-F, F], P(y = 1) = 1 / (1 + exp(-2F)), label F > 0."""
+
+
+class GBTMultiModel(_PerTreeWeights, BoostedMultiModel):
+    """One-vs-rest stack of ``GBTClassificationModel`` margins (Spark's
+    classifier is binary; more classes are this repo's one-vs-rest)."""
+
+
+class GBTRegressionModel(_PerTreeWeights, BoostedRegressionModel):
+    """Spark's ``GBTRegressionModel``: the prediction is Σ w_r T_r."""
 
 
 class ForestClassifierModel(_BinnedModel):
@@ -1157,39 +1239,56 @@ class XGBoostClassifier(_TreeEstimator):
         }
 
     _STATIC_GRID_KEYS = ("num_round", "max_depth", "max_bins")
-
-    def fit_arrays(self, x, y, row_mask):
-        thresholds, binned, fgroups = self._binned(x)
-        present = y[row_mask > 0]
-        num_classes = max(int(present.max()) + 1 if len(present) else 2, 2)
-        kwargs = dict(
-            info_gain_norm=self._INFO_GAIN_NORM,
-            num_rounds=int(self.num_round),
-            max_depth=int(self.max_depth),
-            num_bins=int(self.max_bins),
-            eta=float(self.eta),
-            reg_lambda=float(self.reg_lambda),
-            gamma=float(self.gamma),
-            min_child_weight=float(self.min_child_weight),
-            min_info_gain=float(self.min_info_gain),
-            objective="binary:logistic",
-            feature_groups=fgroups,
-        )
-        rm = jnp.asarray(row_mask, dtype=jnp.float32)
-        if num_classes == 2:
-            trees, _ = TR.fit_boosted(binned, jnp.asarray(y, dtype=jnp.float32), rm, **kwargs)
-            return BoostedBinaryModel(thresholds, trees, float(self.eta), 0.0)
-        per_class = []
-        for c in range(num_classes):
-            yc = jnp.asarray((y == c).astype(np.float32))
-            trees, _ = TR.fit_boosted(binned, yc, rm, **kwargs)
-            per_class.append(trees)
-        return BoostedMultiModel(thresholds, per_class, float(self.eta), 0.0)
+    #: the boosting objective (``trees.OBJECTIVES``), a static argument of
+    #: the fit program
+    _OBJECTIVE = "binary:logistic"
 
     def _normalize_boost(self, merged: dict) -> dict:
         """Map this family's param names onto the boosting knobs (GBT uses
         Spark names: maxIter/stepSize/minInstancesPerNode)."""
         return merged
+
+    def _boosted_model(self, thresholds, trees, m: dict, base: float = 0.0):
+        """The fitted model of one lane; ``m``: its normalized params;
+        ``trees``: one stack, or the per-class list of a one-vs-rest fit."""
+        cls = BoostedMultiModel if isinstance(trees, list) else BoostedBinaryModel
+        return cls(thresholds, trees, float(m["eta"]), base)
+
+    def _boost_kwargs(self, m: dict, knob=None) -> dict:
+        """``trees.fit_boosted[_batched]``'s arguments from the normalized
+        params ``m``; ``knob(name)`` gives a per-lane [K] array instead."""
+        knob = knob or (lambda name: float(m[name]))
+        return dict(
+            num_rounds=int(m["num_round"]), max_depth=int(m["max_depth"]),
+            num_bins=int(m["max_bins"]),
+            eta=knob("eta"), reg_lambda=knob("reg_lambda"),
+            gamma=knob("gamma"), min_child_weight=knob("min_child_weight"),
+            min_info_gain=knob("min_info_gain"),
+            objective=self._OBJECTIVE, info_gain_norm=self._INFO_GAIN_NORM,
+        )
+
+    def _dispatch_attrs(self, binned, m0: dict) -> dict:
+        first, rest = TR.boost_tree_weights(self._OBJECTIVE, 2, m0["eta"])
+        return dict(
+            objective=self._OBJECTIVE, tree_weights=f"{first:g} {rest:g}"
+        )
+
+    def fit_arrays(self, x, y, row_mask):
+        thresholds, binned, fgroups = self._binned(x)
+        present = y[row_mask > 0]
+        num_classes = max(int(present.max()) + 1 if len(present) else 2, 2)
+        m = self._normalize_boost(self.get_params())
+        kwargs = dict(self._boost_kwargs(m), feature_groups=fgroups)
+        rm = jnp.asarray(row_mask, dtype=jnp.float32)
+        if num_classes == 2:
+            trees, _ = TR.fit_boosted(binned, jnp.asarray(y, dtype=jnp.float32), rm, **kwargs)
+            return self._boosted_model(thresholds, trees, m)
+        per_class = []
+        for c in range(num_classes):
+            yc = jnp.asarray((y == c).astype(np.float32))
+            trees, _ = TR.fit_boosted(binned, yc, rm, **kwargs)
+            per_class.append(trees)
+        return self._boosted_model(thresholds, per_class, m)
 
     def _fit_group_masks(self, x, y, masks, group_points):
         present = y[masks.max(axis=0) > 0]
@@ -1199,26 +1298,17 @@ class XGBoostClassifier(_TreeEstimator):
         yj = np.asarray(y, dtype=np.float32)
 
         def run_batched(binned, m0, row_mask_k, knob, fgroups):
-            trees, margin, slots = TR.fit_boosted_batched(
-                binned, yj, row_mask_k,
-                num_rounds=int(m0["num_round"]),
-                max_depth=int(m0["max_depth"]),
-                num_bins=int(m0["max_bins"]),
-                eta=knob("eta"), reg_lambda=knob("reg_lambda"),
-                gamma=knob("gamma"),
-                min_child_weight=knob("min_child_weight"),
-                min_info_gain=knob("min_info_gain"),
-                objective="binary:logistic",
-                feature_groups=fgroups, return_slots=True,
-                info_gain_norm=self._INFO_GAIN_NORM,
-            )
             # the final margin IS each lane's raw output on every row
-            return trees, margin, slots
+            return TR.fit_boosted_batched(
+                binned, yj, row_mask_k, **self._boost_kwargs(m0, knob),
+                feature_groups=fgroups, return_slots=True,
+            )
 
         return self._batched_group_fit(
             x, masks, group_points, run_batched,
-            lambda th, tr, m, mi: BoostedBinaryModel(th, tr, float(m["eta"]), 0.0),
+            lambda th, tr, m, mi: self._boosted_model(th, tr, m),
             normalize=self._normalize_boost,
+            dispatch_attrs=self._dispatch_attrs,
         )
 
 
@@ -1247,68 +1337,68 @@ class XGBoostRegressor(_TreeEstimator):
 
     get_params = XGBoostClassifier.get_params
     _STATIC_GRID_KEYS = ("num_round", "max_depth", "max_bins")
+    _OBJECTIVE = "reg:squarederror"
     _normalize_boost = XGBoostClassifier._normalize_boost
+    _boost_kwargs = XGBoostClassifier._boost_kwargs
+    _dispatch_attrs = XGBoostClassifier._dispatch_attrs
+
+    def _boosted_model(self, thresholds, trees, m: dict, base: float = 0.0):
+        return BoostedRegressionModel(thresholds, trees, float(m["eta"]), base)
+
+    def _base_scores(self, y, masks) -> np.ndarray:
+        """[M] float64: each mask's starting margin, the mean target over
+        its rows."""
+        sums = masks @ y.astype(np.float64)
+        cnts = masks.sum(axis=1)
+        return np.where(cnts > 0, sums / np.maximum(cnts, 1), 0.0)
 
     def _fit_group_masks(self, x, y, masks, group_points):
         yj = np.asarray(y, dtype=np.float32)
-        # per-mask base score = mean target over that mask's rows
-        sums = masks @ y.astype(np.float64)
-        cnts = masks.sum(axis=1)
-        base_scores = np.where(cnts > 0, sums / np.maximum(cnts, 1), 0.0)
+        base_scores = self._base_scores(y, masks)
         n_pts = len(group_points)
 
         def run_batched(binned, m0, row_mask_k, knob, fgroups):
-            base_k = np.repeat(base_scores, n_pts).astype(np.float32)
-            trees, margin, slots = TR.fit_boosted_batched(
-                binned, yj, row_mask_k,
-                num_rounds=int(m0["num_round"]),
-                max_depth=int(m0["max_depth"]),
-                num_bins=int(m0["max_bins"]),
-                eta=knob("eta"), reg_lambda=knob("reg_lambda"),
-                gamma=knob("gamma"),
-                min_child_weight=knob("min_child_weight"),
-                min_info_gain=knob("min_info_gain"),
-                base_score=base_k,
-                objective="reg:squarederror",
+            return TR.fit_boosted_batched(
+                binned, yj, row_mask_k, **self._boost_kwargs(m0, knob),
+                base_score=np.repeat(base_scores, n_pts).astype(np.float32),
                 feature_groups=fgroups, return_slots=True,
-                info_gain_norm=self._INFO_GAIN_NORM,
             )
-            return trees, margin, slots
 
         return self._batched_group_fit(
             x, masks, group_points, run_batched,
-            lambda th, tr, m, mi: BoostedRegressionModel(
-                th, tr, float(m["eta"]), float(base_scores[mi])
+            lambda th, tr, m, mi: self._boosted_model(
+                th, tr, m, float(base_scores[mi])
             ),
             normalize=self._normalize_boost,
+            dispatch_attrs=self._dispatch_attrs,
         )
 
     def fit_arrays(self, x, y, row_mask):
         thresholds, binned, fgroups = self._binned(x)
-        base = float(np.mean(y[row_mask > 0])) if (row_mask > 0).any() else 0.0
+        mask = np.asarray(row_mask, dtype=np.float32)
+        base = float(self._base_scores(np.asarray(y), mask[None, :])[0])
+        m = self._normalize_boost(self.get_params())
+        kwargs = self._boost_kwargs(m)
         trees, _ = TR.fit_boosted(
             binned,
             jnp.asarray(y, dtype=jnp.float32),
-            jnp.asarray(row_mask, dtype=jnp.float32),
-            num_rounds=int(self.num_round),
-            max_depth=int(self.max_depth),
-            num_bins=int(self.max_bins),
-            eta=float(self.eta),
-            reg_lambda=float(self.reg_lambda),
-            gamma=float(self.gamma),
-            min_child_weight=float(self.min_child_weight),
-            min_info_gain=float(self.min_info_gain),
-            base_score=base,
-            objective="reg:squarederror",
-            feature_groups=fgroups,
-            info_gain_norm=self._INFO_GAIN_NORM,
+            jnp.asarray(mask),
+            **kwargs, base_score=base, feature_groups=fgroups,
         )
-        return BoostedRegressionModel(thresholds, trees, float(self.eta), base)
+        return self._boosted_model(thresholds, trees, m, base)
 
 
 class GBTClassifier(XGBoostClassifier):
-    """OpGBTClassifier parity: Spark GBT defaults maxIter 20, stepSize 0.1,
-    maxDepth 5, variance-style gain with no regularization."""
+    """OpGBTClassifier: Spark ML's ``GBTClassifier`` (defaults maxIter 20,
+    stepSize 0.1, maxDepth 5; more than two classes go one-vs-rest, each a
+    binary fit on its indicator). ``GradientBoostedTrees.
+    boost`` on the labels 2y - 1 under ``LogLoss`` (the objective
+    ``spark:logloss`` of ``trees.OBJECTIVES``): every tree a variance-
+    impurity regression tree over all columns and all rows; a child under
+    ``min_instances_per_node`` ROWS makes a split invalid; a node splits
+    where its best valid split's variance decrease per row reaches
+    ``min_info_gain`` and is positive; the first tree weighs 1, the rest
+    ``step_size``."""
 
     model_type = "OpGBTClassifier"
 
@@ -1349,13 +1439,7 @@ class GBTClassifier(XGBoostClassifier):
 
     _STATIC_GRID_KEYS = ("max_iter", "max_depth", "max_bins")
     _INFO_GAIN_NORM = 2.0
-
-    def fit_arrays(self, x, y, row_mask):
-        # keep the boosted knobs in sync with the Spark-named params
-        self.num_round = self.max_iter
-        self.eta = self.step_size
-        self.min_child_weight = float(self.min_instances_per_node)
-        return super().fit_arrays(x, y, row_mask)
+    _OBJECTIVE = "spark:logloss"
 
     def _normalize_boost(self, merged: dict) -> dict:
         return {
@@ -1363,17 +1447,32 @@ class GBTClassifier(XGBoostClassifier):
             "eta": merged["step_size"],
             "reg_lambda": 0.0,
             "gamma": 0.0,
+            # h = 1 under the spark:* objectives: a child's weight IS its
+            # row count
             "min_child_weight": float(merged["min_instances_per_node"]),
             "min_info_gain": merged["min_info_gain"],
             "max_depth": merged["max_depth"],
             "max_bins": merged["max_bins"],
         }
 
+    def _boosted_model(self, thresholds, trees, m: dict, base: float = 0.0):
+        cls = GBTMultiModel if isinstance(trees, list) else GBTClassificationModel
+        return cls(
+            thresholds, trees,
+            TR.boost_tree_weights(self._OBJECTIVE, m["num_round"], m["eta"]),
+        )
+
 
 class GBTRegressor(XGBoostRegressor):
+    """OpGBTRegressor: Spark ML's ``GBTRegressor`` under ``SquaredError``
+    (the objective ``spark:squarederror``): the first tree fitted to the
+    labels at weight 1 from a zero margin, each later one to 2 (y - F) at
+    weight ``step_size``; rows, gain and stop rule as ``GBTClassifier``."""
+
     model_type = "OpGBTRegressor"
     _STATIC_GRID_KEYS = ("max_iter", "max_depth", "max_bins")
     _INFO_GAIN_NORM = 2.0
+    _OBJECTIVE = "spark:squarederror"
     _normalize_boost = GBTClassifier._normalize_boost
 
     def __init__(
@@ -1403,11 +1502,14 @@ class GBTRegressor(XGBoostRegressor):
 
     get_params = GBTClassifier.get_params
 
-    def fit_arrays(self, x, y, row_mask):
-        self.num_round = self.max_iter
-        self.eta = self.step_size
-        self.min_child_weight = float(self.min_instances_per_node)
-        return super().fit_arrays(x, y, row_mask)
+    def _base_scores(self, y, masks) -> np.ndarray:
+        return np.zeros(len(masks))  # Spark boosts from F = 0
+
+    def _boosted_model(self, thresholds, trees, m: dict, base: float = 0.0):
+        return GBTRegressionModel(
+            thresholds, trees,
+            TR.boost_tree_weights(self._OBJECTIVE, m["num_round"], m["eta"]),
+        )
 
 
 FEATURE_SUBSET_STRATEGIES = ("auto", "all", "sqrt", "onethird", "log2")

@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .trees import sum_trees
+from .trees import sum_trees, weighted_tree_sum
 
 
 def _round_up(x: int, m: int) -> int:
@@ -336,12 +336,13 @@ def predict_forest_pallas(binned, trees, interpret: bool = False,
 def predict_boosted_pallas(binned, trees, eta, base_score,
                            interpret: bool = False,
                            num_bins: int | None = None):
-    """base + eta·Σ rounds -> [N] (the ``predict_boosted`` contract)."""
+    """base + the weighted sum of the rounds -> [N] (the
+    ``predict_boosted`` contract: ``eta`` a scalar or per-tree weights)."""
     per_tree = serve_trees_pallas(
         binned, trees.split_feat, trees.split_bin, trees.leaf_value,
         interpret=interpret, num_bins=num_bins,
     )
-    return base_score + eta * sum_trees(per_tree)
+    return base_score + weighted_tree_sum(per_tree, eta)
 
 
 def serve_impl() -> str:

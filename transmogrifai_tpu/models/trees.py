@@ -56,7 +56,11 @@ class HistSlots(NamedTuple):
     branch left out. ``subset_admitted`` / ``subset_pairs``: over every
     lane's live nodes, the (node, feature) pairs the split search admitted
     and all there were (both 0 from a fit that draws no node subsets:
-    boosting)."""
+    boosting). ``rounds_label`` / ``rounds_residual`` (per round, not per
+    level): the lanes whose tree of that round was fitted to the labels
+    themselves, and to a pseudo-residual of the margin so far — counted by
+    ``_boost_chunk_body`` under a ``spark:*`` objective, 0 from every other
+    fit (a Newton round is neither; a forest has no rounds)."""
 
     live: jax.Array
     built: jax.Array
@@ -64,6 +68,8 @@ class HistSlots(NamedTuple):
     chunks_skipped: jax.Array
     subset_admitted: jax.Array
     subset_pairs: jax.Array
+    rounds_label: jax.Array
+    rounds_residual: jax.Array
 
 
 # The narrowest width a level's histograms are built at. At 32 slots the
@@ -156,6 +162,8 @@ class HistSlotStats(_tm.LedgerCore):
         "chunks_run": "chunksRun", "chunks_skipped": "chunksSkipped",
         "subset_admitted": "nodeSubsetAdmitted",
         "subset_pairs": "nodeSubsetPairs",
+        "rounds_label": "boostRoundsLabel",
+        "rounds_residual": "boostRoundsResidual",
     }
 
     def __init__(self) -> None:
@@ -187,9 +195,10 @@ def await_outputs(value, hist_slots: HistSlots | None = None):
     on the host already passes through. ``hist_slots`` are the counts the
     same fit program returned: once its outputs have landed they are there
     too, and their sums go onto the span (``slots_live``, ``slots_built``,
-    ``chunks_run``, ``chunks_skipped`` and, from a fit that counts node
-    subsets, ``subset_admitted``, ``subset_pairs``) and the ``tree``
-    ledger."""
+    ``chunks_run``, ``chunks_skipped``; from a fit that counts node
+    subsets ``subset_admitted``, ``subset_pairs``; from one that boosts in
+    first order ``boost_rounds_label``, ``boost_rounds_residual``) and the
+    ``tree`` ledger."""
     leaves = jax.tree.leaves(value)
     if all(isinstance(a, np.ndarray) for a in leaves):
         return value
@@ -213,6 +222,11 @@ def await_outputs(value, hist_slots: HistSlots | None = None):
                 sp.attrs.update(
                     subset_admitted=sums["subset_admitted"],
                     subset_pairs=sums["subset_pairs"],
+                )
+            if sums["rounds_label"] or sums["rounds_residual"]:
+                sp.attrs.update(
+                    boost_rounds_label=sums["rounds_label"],
+                    boost_rounds_residual=sums["rounds_residual"],
                 )
             _HIST_SLOT_STATS.record(sums)
     return out
@@ -988,7 +1002,7 @@ def _grow_tree_impl(
             active = active & (row_feat >= 0)
         return (node, active, alive), (
             feats_d, bins_d,
-            HistSlots(n_live, built, runs, skipped, *counts),
+            HistSlots(n_live, built, runs, skipped, *counts, *zero2),
         )
 
     (node, active, _), (feats_s, bins_s, slots_s) = jax.lax.scan(
@@ -1022,7 +1036,7 @@ def predict_tree(binned: jax.Array, tree: Tree) -> jax.Array:
     (one shared gather body). An unrolled depth loop with level-sliced
     one-hot lookups grows the vmapped sweep programs ~depth×; whether its
     execution win pays for that is not re-measured on a local chip (see
-    ROADMAP S7), so the scan stays."""
+    ROADMAP M6 / C5), so the scan stays."""
     n = binned.shape[0]
 
     def level(node, sfsb):
@@ -1112,8 +1126,9 @@ def predict_boosted_raw(
     x: jax.Array, thresholds: jax.Array, trees: Tree,
     eta: jax.Array, base_score: jax.Array,
 ) -> jax.Array:
-    """Fused bin + boosted predict — one dispatch; eta/base_score are
-    traced arrays so distinct hyperparameter values share the compilation."""
+    """Fused bin + boosted predict — one dispatch; eta (a scalar, or the
+    [R] per-tree weights of a Spark GBT model) and base_score are traced
+    arrays so distinct hyperparameter values share the compilation."""
     return predict_boosted(bin_data(x, thresholds), trees, eta, base_score)
 
 
@@ -1122,7 +1137,7 @@ def predict_boosted_raw(
 # --------------------------------------------------------------------------
 # Serving-size batches skip the device: no upload, no dispatch, no result
 # sync. Where the crossover to the device sits is not re-measured on a
-# local chip (TPTPU_HOST_PREDICT_MAX; see ROADMAP S7). Semantics mirror
+# local chip (TPTPU_HOST_PREDICT_MAX; see ROADMAP M6 / C5). Semantics mirror
 # bin_data/predict_tree exactly (parity pinned in tests).
 
 
@@ -1331,11 +1346,19 @@ def predict_boosted_host(
 ) -> np.ndarray:
     """Numpy twin of predict_boosted_raw; ``trees`` must hold host arrays
     (a Tree stack or a prepared one from prepare_host_stack/
-    host_serving_plan). ``binned`` lets multi-stack callers bin x once
-    across stacks."""
+    host_serving_plan). ``eta``: a scalar, or [R] per-tree weights (then
+    the per-tree values come from the numpy traversal: the C kernel sums
+    unweighted). ``binned`` lets multi-stack callers bin x once across
+    stacks."""
     if binned is None:
         binned = bin_data_host(x, thresholds)
-    return np.float32(base_score) + np.float32(eta) * _leaf_sum(binned, trees)
+    eta = np.asarray(eta, dtype=np.float32)
+    if eta.ndim == 0:
+        return np.float32(base_score) + eta * _leaf_sum(binned, trees)
+    per_tree = _traverse_host(binned, trees)  # [R, N]
+    return np.float32(base_score) + (eta[:, None] * per_tree).sum(
+        axis=0, dtype=np.float32
+    )
 
 
 def predict_forest_host(
@@ -1605,6 +1628,30 @@ def _fit_result(trees, outputs, slots, return_outputs, return_slots):
     return (trees, *extra) if extra else trees
 
 
+#: The boosting objectives, by the program's names for them (a static
+#: argument of the fit programs; ``tree/fit_dispatch`` carries it).
+#: ``binary:logistic`` and ``reg:squarederror`` are XGBoost's: Newton trees
+#: (g and h the loss's two derivatives, leaf -G/(H + lambda)), every tree
+#: weighted ``eta``. The ``spark:*`` pair are Spark ML's
+#: ``GradientBoostedTrees.boost``: FIRST-order regression trees (h = 1, so a
+#: node's H is its row count and ``min_child_weight`` a count), the first
+#: fitted to the labels themselves at weight 1, every later one to the
+#: loss's negative gradient at weight ``eta`` (Spark's ``stepSize``):
+#: ``spark:logloss`` on labels 2y - 1 under L = 2 log(1 + exp(-2 y~ F)),
+#: whose negative gradient is 4 y~ / (1 + exp(2 y~ F));
+#: ``spark:squarederror`` under (y - F)^2, negative gradient 2 (y - F).
+SPARK_OBJECTIVES = ("spark:logloss", "spark:squarederror")
+OBJECTIVES = ("binary:logistic", "reg:squarederror") + SPARK_OBJECTIVES
+
+
+def boost_tree_weights(objective: str, num_rounds: int, eta) -> np.ndarray:
+    """[R] float32: the weight each round's tree carries in the margin."""
+    w = np.full(int(num_rounds), eta, dtype=np.float32)
+    if objective in SPARK_OBJECTIVES and len(w):
+        w[0] = 1.0
+    return w
+
+
 def fit_boosted(
     binned: jax.Array,
     y: jax.Array,          # [N] labels (0/1 binary, float regression)
@@ -1622,9 +1669,10 @@ def fit_boosted(
     feature_groups=None,
     info_gain_norm: float = 0.0,
 ) -> tuple[Tree, jax.Array]:
-    """Gradient boosting (XGBoost/Spark-GBT parity) — the K=1 case of
-    fit_boosted_batched: second-order gradients, shrinkage eta. Returns
-    stacked trees [R, ...] and the training margin [N].
+    """Gradient boosting — the K=1 case of fit_boosted_batched, under one
+    of ``OBJECTIVES`` (XGBoost's Newton trees at shrinkage eta, or Spark
+    GBT's first-order ones). Returns stacked trees [R, ...] and the
+    training margin [N].
     ``info_gain_norm``: 0 for XGBoost's absolute stop rule, 2 for Spark
     GBT's per-row variance decrease (``_grow_tree_impl``)."""
     trees, margin = fit_boosted_batched(
@@ -1638,14 +1686,27 @@ def fit_boosted(
     return jax.tree.map(lambda a: a[0], trees), margin[0]
 
 
+def weighted_tree_sum(per_tree: jax.Array, eta) -> jax.Array:
+    """[R, N] per-tree leaf values -> [N] ensemble margin (less the base
+    score). ``eta`` is one shrinkage for every tree (a scalar: XGBoost's,
+    applied to the sum) or the weight of each tree ([R]: Spark's
+    ``treeWeights``, 1 then ``stepSize``: ``boost_tree_weights``)."""
+    eta = jnp.asarray(eta, dtype=per_tree.dtype)
+    if eta.ndim == 0:
+        return eta * sum_trees(per_tree)
+    return sum_trees(eta[:, None] * per_tree)
+
+
 def predict_boosted(
     binned: jax.Array,
     trees: Tree,
-    eta: float,
+    eta: float | jax.Array,
     base_score: float = 0.0,
 ) -> jax.Array:
+    """``base_score`` + the weighted sum of the stacked trees' leaf values;
+    ``eta``: a scalar or per-tree weights (``weighted_tree_sum``)."""
     preds = jax.vmap(lambda t: predict_tree(binned, t))(trees)  # [R, N]
-    return base_score + eta * sum_trees(preds)
+    return base_score + weighted_tree_sum(preds, eta)
 
 
 def _boost_chunk_body(
@@ -1663,15 +1724,31 @@ def _boost_chunk_body(
     f = binned.shape[1]
     feat_mask = jnp.ones((k_fits, f), dtype=jnp.float32)
 
-    def grads(margin):  # [K, N_local]
+    if objective not in OBJECTIVES:
+        raise ValueError(f"objective {objective!r}: one of {OBJECTIVES}")
+    first_order = objective in SPARK_OBJECTIVES
+
+    def grads(margin, r):  # [K, N_local]; r: the round, from 0
         if objective == "binary:logistic":
             p = jax.nn.sigmoid(margin)
             return p - y[None, :], p * (1.0 - p)
+        if first_order:
+            # g = -target, h = 1: the leaf -G/H is the node's mean target
+            if objective == "spark:logloss":
+                ys = 2.0 * y[None, :] - 1.0
+                t = 4.0 * ys / (1.0 + jnp.exp(2.0 * ys * margin))
+            else:
+                ys = jnp.broadcast_to(y[None, :], margin.shape)
+                t = 2.0 * (ys - margin)
+            return -jnp.where(r == 0, ys, t), jnp.ones_like(margin)
         return margin - y[None, :], jnp.ones_like(margin)
 
-    def round_step(margin, _):
+    def round_step(margin, r):
         with jax.named_scope("tree/gradients"):
-            g, h = grads(margin)
+            g, h = grads(margin, r)
+        # no lowp under any objective: from the second round on g is a real
+        # number (a pseudo-residual, a Newton gradient), which the kernel's
+        # split-operand path carries float32-exact and one bfloat16 would not
         tree, leaf_slot, slots = _grow_tree_impl(
             binned, g, h, row_mask, feat_mask,
             max_depth=max_depth, num_bins=num_bins,
@@ -1684,11 +1761,18 @@ def _boost_chunk_body(
         # small-table lookup instead of a full predict_tree re-traversal
         with jax.named_scope("tree/outputs"):
             step = _small_table_lookup(tree.leaf_value, leaf_slot)  # [K, N]
-            margin = margin + eta_v[:, None] * step
+            weight = jnp.where(r == 0, 1.0, eta_v) if first_order else eta_v
+            margin = margin + weight[:, None] * step
+        if first_order:
+            lanes = jnp.int32(k_fits)
+            slots = slots._replace(
+                rounds_label=jnp.where(r == 0, lanes, 0),
+                rounds_residual=jnp.where(r == 0, 0, lanes),
+            )
         return margin, (tree, slots)
 
     margin, (trees, slots) = jax.lax.scan(
-        round_step, margin0, None, length=num_rounds
+        round_step, margin0, jnp.arange(num_rounds, dtype=jnp.int32)
     )
     # [R, K, ...] -> [K, R, ...] INSIDE the program: an eager transpose
     # after the fact costs a compile-cache round-trip per shape
@@ -2077,9 +2161,13 @@ def program_trace_specs():
                 s, s, s, s,                          # lam, gam, mcw, mig
                 None,                                # feature_groups
             ),
+            # traced under the objective whose body holds the most: the
+            # round index, per-round targets and weights, the round counts
+            # (XGBoost's objectives differ in the gradient line alone)
             dict(
                 num_rounds=2, max_depth=2, num_bins=4,
-                objective="binary:logistic", hist_impl=_resolved_impl(),
+                objective="spark:logloss", hist_impl=_resolved_impl(),
+                info_gain_norm=2.0,
             ),
         )
 
