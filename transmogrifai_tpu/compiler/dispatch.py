@@ -14,13 +14,17 @@ Two seams that cut hot-path dispatch cost without touching any math:
   host→device upload of a float32 view of ``arr`` while host-side work
   (layer transforms, checkpoint saves, row codecs) is still running;
   ``device_f32(arr)`` picks the in-flight buffer up at dispatch time (or
-  falls back to a plain ``jnp.asarray``). This is how layer k+1's input
+  falls back to its own upload). This is how layer k+1's input
   transfer overlaps layer k's compute. Prefetch is a
   no-op under an active execution mesh — GSPMD placement stays with the
-  sharding helpers in ``parallel/mesh.py``.
+  sharding helpers in ``parallel/mesh.py``. Both upload in the layout the
+  host array HAS (``host_layout``): a column-major plane goes up as its
+  row-major transpose view and is transposed on the device, so the host
+  never re-lays the plane out.
 """
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import threading
@@ -87,6 +91,51 @@ def _mesh_active() -> bool:
         return False
 
 
+def host_layout(arr) -> str:
+    """``"F"`` for a 2-D column-major host array (what a fancy column index
+    of a row-major plane returns: the SanityChecker's output), else
+    ``"C"``: how ``device_f32`` uploads it, and the ``layout`` attribute of
+    the ``tree/upload`` and ``compile/prefetch`` spans."""
+    if (
+        isinstance(arr, np.ndarray) and arr.ndim == 2
+        and arr.flags.f_contiguous and not arr.flags.c_contiguous
+    ):
+        return "F"
+    return "C"
+
+
+@functools.cache
+def _transpose_program():
+    """The jitted device transpose, made once (this module imports jax
+    lazily): ``aot_call`` and jax's own cache key on the object."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(jnp.transpose)  # tp: disable=TPL003 — cached
+
+
+def _upload_f32(arr: np.ndarray):
+    """``arr`` as a float32 device array of ``arr``'s shape; the dtype is
+    converted on the HOST (an eager device-side convert compiles a
+    per-process program: see gbdt._binned). The runtime needs a row-major
+    buffer, and makes one with a strided host pass when handed anything
+    else (2.2-2.9 s for a column-major 1,002,701 x 357 plane, the device
+    idle). A column-major array's transpose IS row-major, so that view
+    goes up as it lies and one device program transposes it back (2 x the
+    plane of HBM traffic): the same values in the same device layout."""
+    import jax
+
+    host = np.asarray(arr, dtype=np.float32)
+    if host_layout(host) == "C":
+        return jax.device_put(host)
+    # through the executable bank, as every program on a fit's path
+    from ..utils.aot import aot_call
+
+    return aot_call(
+        "plane_transpose", _transpose_program(), (jax.device_put(host.T),), {}
+    )
+
+
 def prefetch_f32(arr) -> None:
     """Start the async device upload of ``np.asarray(arr, float32)``;
     ``device_f32`` on the SAME object (by identity) picks it up. Errors are
@@ -99,15 +148,15 @@ def prefetch_f32(arr) -> None:
         with _PREFETCH_LOCK:
             if key in _PREFETCH:
                 return
-        import jax
-
         from ..telemetry import runlog as _runlog
         from ..telemetry import spans as _tspans
 
         nbytes = int(getattr(arr, "nbytes", 0))
-        with _tspans.span("compile/prefetch", bytes=nbytes):
+        with _tspans.span(
+            "compile/prefetch", bytes=nbytes, layout=host_layout(arr)
+        ):
             t0 = _tspans.clock()
-            buf = jax.device_put(np.asarray(arr, dtype=np.float32))
+            buf = _upload_f32(arr)
             # runtime transfer census (telemetry/runlog.py): every upload
             # through this seam is one host->device crossing the run
             # ledger counts — the live counterpart of the static TPX
@@ -139,9 +188,10 @@ def prefetch_pending(arr) -> bool:
 def device_f32(arr):
     """The prefetched device buffer for ``arr`` if one is in flight (and
     the source object is still alive — a dead ref means the id may have
-    been recycled), else a plain float32 ``jnp.asarray``. Entries are NOT
-    consumed: several model families dispatch on the same training matrix.
-    Callers must not mutate ``arr`` between prefetch and dispatch."""
+    been recycled), else a float32 upload in the layout ``arr`` has
+    (``_upload_f32``). Entries are NOT consumed: several model families
+    dispatch on the same training matrix. Callers must not mutate ``arr``
+    between prefetch and dispatch."""
     import jax.numpy as jnp
 
     key = id(arr)
@@ -168,9 +218,7 @@ def device_f32(arr):
 
     t0 = _tspans.clock()
     if isinstance(arr, np.ndarray):
-        # dtype-convert on HOST: an eager device-side convert compiles a
-        # per-process program (see gbdt._binned)
-        out = jnp.asarray(np.asarray(arr, dtype=np.float32))
+        out = _upload_f32(arr)
     else:
         out = jnp.asarray(arr, dtype=jnp.float32)
     # fresh upload (no prefetch in flight): one host->device crossing

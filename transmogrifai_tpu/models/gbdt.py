@@ -860,15 +860,18 @@ class _TreeEstimator(PredictorEstimator):
         # acquire its program on the sweep's critical path
         from ..utils.aot import aot_call
 
-        from ..compiler.dispatch import device_f32, prefetch_pending
+        from ..compiler.dispatch import (
+            device_f32, host_layout, prefetch_pending,
+        )
 
         rows, cols, bins = int(x.shape[0]), int(x.shape[1]), int(self.max_bins)
         # device_f32 picks up the async upload the DAG fit prefetched for
-        # this matrix, when one is in flight (compiler.dispatch)
+        # this matrix, when one is in flight (compiler.dispatch); either
+        # uploads the plane in the layout it has
         with _tspans.span(
             "tree/upload",
             bytes=4 * int(x.size) + 4 * cols * (bins - 1),
-            prefetched=prefetch_pending(x),
+            prefetched=prefetch_pending(x), layout=host_layout(x),
         ):
             xj = device_f32(x)
         with _tspans.span(
@@ -1052,7 +1055,9 @@ class _TreeEstimator(PredictorEstimator):
                     else TR.sweep_forest_outputs
                 )
                 if xj is None:
-                    xj = jnp.asarray(x, dtype=jnp.float32)
+                    from ..compiler.dispatch import device_f32
+
+                    xj = device_f32(x)
                 out = aot_call(
                     f"sweep_{mode}_outputs", fn,
                     (

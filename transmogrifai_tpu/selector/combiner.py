@@ -15,7 +15,7 @@ import numpy as np
 
 from ..evaluators import Evaluator
 from ..models.base import PredictorModel
-from .model_selector import ModelSelector, SelectedModel
+from .model_selector import ModelSelector, SelectedModel, keep_rows
 from .validators import Validator
 
 
@@ -123,7 +123,10 @@ class SelectedModelCombiner(ModelSelector):
         return {"strategy": self.strategy.value, "problem_kind": self.problem_kind}
 
     def fit_arrays(self, x, y, row_mask) -> SelectedModel:
-        # fit both selectors on the same data; each runs its own validation
+        # fit both selectors on the same data; each runs its own validation.
+        # Where the mask keeps every row both sweep ``x`` itself, so their
+        # tree families share ONE bin-cache entry (the second selector's
+        # ``tree/bin_prepare`` is a hit)
         self.selector1.set_input(*self.input_features)
         self.selector2.set_input(*self.input_features)
         if self.precomputed_results is not None:
@@ -183,8 +186,8 @@ class SelectedModelCombiner(ModelSelector):
             "holdoutEvaluation": None,
             "splitterSummary": None,
         }
-        pred, prob, _ = combined.predict_arrays(x[np.nonzero(row_mask > 0)[0]])
-        yt = y[np.nonzero(row_mask > 0)[0]]
+        xt, yt, _ = keep_rows(x, y, np.asarray(row_mask) > 0)
+        pred, prob, _ = combined.predict_arrays(xt)
         summary["trainEvaluation"] = self.evaluator.evaluate_arrays(yt, pred, prob)
         self.metadata["modelSelectorSummary"] = summary
         return SelectedModel(combined, summary)

@@ -260,6 +260,20 @@ class SelectedModel(PredictorModel):
         return metrics
 
 
+def keep_rows(x, y, keep):
+    """``(x, y)`` at the rows the boolean ``keep`` keeps, and the bytes
+    copied to get them. A mask that keeps every row (the product path's:
+    ``PredictorEstimator.fit_model``) returns ``x`` and ``y`` themselves:
+    no index, no copy, the caller's layout. A mask that drops rows gathers
+    them into new row-major arrays: tree fit's thresholds are quantiles of
+    the KEPT rows."""
+    if keep.all():
+        return x, y, 0
+    idx = np.nonzero(keep)[0]
+    xk, yk = x[idx], y[idx]
+    return xk, yk, int(xk.nbytes + yk.nbytes)
+
+
 class ModelSelector(PredictorEstimator):
     """Estimator[(RealNN, OPVector)] -> Prediction that finds, refits, and
     wraps the best model family × grid point."""
@@ -300,6 +314,18 @@ class ModelSelector(PredictorEstimator):
         }
 
     def fit_arrays(self, x, y, row_mask) -> SelectedModel:
+        """One sweep over ``x``'s rows that ``row_mask`` keeps.
+
+        Where the mask (and a ``DataCutter``) keeps every row, the
+        families receive ``x`` and ``y`` THEMSELVES, in the layout the
+        caller gave them (``keep_rows``), so tree fit's bin cache
+        (``gbdt._TreeEstimator._binned``) reaches this method's callers:
+        it is keyed on ``x``'s buffer (address, shape, strides) and holds a
+        strong reference to it, so a second call on the same unmutated
+        array takes the first call's thresholds and bin codes, and a
+        caller that writes into ``x`` between calls must hand in a new
+        array instead. No estimator, validator or evaluator writes into
+        ``x`` or ``y``."""
         # one trace per sweep: every span below (and the fits the candidate
         # pool runs on its threads) carries this root's id as ``trace``
         with _tspans.span(
@@ -319,15 +345,12 @@ class ModelSelector(PredictorEstimator):
         compile_baseline = cstats.snapshot()
         featurize_baseline = fstats.snapshot()
         with _tspans.span("selector/row_select", rows_in=len(row_mask)) as sp:
-            train_idx = np.nonzero(row_mask > 0)[0]
-            xt, yt = x[train_idx], y[train_idx]
-            copied = xt.nbytes + yt.nbytes
+            xt, yt, copied = keep_rows(x, y, np.asarray(row_mask) > 0)
 
             # pre-validation prepare (DataCutter removes rare labels up front)
             if isinstance(self.splitter, DataCutter):
-                keep = self.splitter.prepare(yt)
-                xt, yt = xt[keep], yt[keep]
-                copied += xt.nbytes + yt.nbytes
+                xt, yt, cut = keep_rows(xt, yt, self.splitter.prepare(yt))
+                copied += cut
 
             # validation prepare (balancing / down-sampling) is a
             # deterministic seeded function of yt, so the refit mask is
