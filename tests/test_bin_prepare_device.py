@@ -134,7 +134,7 @@ def test_binned_and_the_fits_are_the_same_by_either_route(route):
         got[which] = (
             thresholds, np.asarray(codes), [np.asarray(g) for g in groups],
             [np.asarray(a) for a in jax.tree.leaves(boosted.trees)],
-            [np.asarray(a) for a in jax.tree.leaves(forest.forests_per_class)],
+            [np.asarray(a) for a in jax.tree.leaves(forest.trees)],
         )
     dev, host = got["device"], got["host"]
     assert np.array_equal(_bits(dev[0]), _bits(host[0]))

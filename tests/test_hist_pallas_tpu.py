@@ -270,3 +270,42 @@ def test_binloop_compiles_and_matches_scatter_at_the_chosen_tiles(
         np.testing.assert_array_equal(got, ref)  # integer sums stay exact
     else:
         np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-3)
+
+
+@pytest.mark.parametrize("cols,bins", [(55, 2), (302, 32)])
+@pytest.mark.parametrize("slots", [8, 32, 64, 128])
+@pytest.mark.parametrize("channels,lowp", [(7, True), (3, True), (3, False)])
+def test_binloop_builds_every_statistic_channel_on_device(
+    channels, lowp, slots, cols, bins
+):
+    """The statistic axis on real Mosaic: a K-class forest's K channels
+    (the K - 1 class indicators times w, and w; bfloat16-exact) and a
+    three-channel real-valued fit, at every width a fit of that many
+    channels can build at (``histogram_plan``: 128 slots at most for
+    seven one-variant channels)."""
+    from transmogrifai_tpu.models.hist_pallas import (
+        build_histogram_pallas_binloop,
+        build_histogram_scatter_batched,
+    )
+
+    n, k = 5000, 2
+    rng = np.random.default_rng(channels * 1000 + slots + cols)
+    binned = rng.integers(0, bins, size=(n, cols)).astype(np.int32)
+    node = rng.integers(-1, slots, size=(k, n)).astype(np.int32)
+    w = rng.poisson(1.0, size=(k, n)).astype(np.float32)
+    if lowp:
+        cls = rng.integers(0, channels, size=n)
+        grad = np.stack(
+            [-((cls == c) * w) for c in range(1, channels)], axis=1
+        ).astype(np.float32)
+    else:
+        grad = rng.normal(size=(k, channels - 1, n)).astype(np.float32)
+    args = (jnp.asarray(binned), jnp.asarray(node), jnp.asarray(grad),
+            jnp.asarray(w), slots, bins)
+    got = np.asarray(build_histogram_pallas_binloop(*args, lowp=lowp))
+    ref = np.asarray(build_histogram_scatter_batched(*args))
+    assert got.shape == ref.shape == (k, slots, cols, bins, channels)
+    if lowp:
+        np.testing.assert_array_equal(got, ref)  # integer sums stay exact
+    else:
+        np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-3)

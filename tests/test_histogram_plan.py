@@ -84,7 +84,76 @@ PLAN_TABLE = {
         ("scatter", 891, 256, CELL_GROUPS, 1 << 10),
         (("scatter", "scatter"), 64),
     ),
+    # ---- the statistic axis (args: ..., stat_channels, lowp). Two channels
+    # read the same whatever ``lowp``; more are held by the stacked
+    # operand's 1,024 lanes: 7 one-variant channels -> 146 -> 128 slots,
+    # 14 variants -> 73 -> 64; the HBM budget scales too (2^23 / (9,774 *
+    # 7 / 2) -> 245 -> 128)
+    "cell_depth_12_lowp": (
+        ("pallas", CELL_ROWS, 4, CELL_GROUPS, 1 << 12, 2, True),
+        (("binloop", "binloop"), 256),
+    ),
+    "seven_classes_depth_12": (
+        ("pallas", CELL_ROWS, 4, CELL_GROUPS, 1 << 12, 7, True),
+        (("binloop", "binloop"), 128),
+    ),
+    "seven_channels_two_variants": (
+        ("pallas", CELL_ROWS, 4, CELL_GROUPS, 1 << 12, 7, False),
+        (("binloop", "binloop"), 64),
+    ),
+    "seven_classes_depth_3": (
+        ("pallas", CELL_ROWS, 4, CELL_GROUPS, 1 << 3, 7, True),
+        (("binloop", "binloop"), 8),
+    ),
+    # 3 one-variant channels: 1,024 / 3 -> 341 -> 256, the ceiling's
+    "three_classes_depth_12": (
+        ("pallas", CELL_ROWS, 4, CELL_GROUPS, 1 << 12, 3, True),
+        (("binloop", "binloop"), 256),
+    ),
+    # the lane-packed kernel has two accumulators: more channels are
+    # planned onto the bin-loop kernel whatever the bins
+    "seven_classes_at_256_bins": (
+        ("pallas", CELL_ROWS, 4, [(20, 256)], 1 << 10, 7, True),
+        (("binloop",), 128),
+    ),
+    "seven_classes_gemm": (
+        ("pallas", 4096, 4, CELL_GROUPS, 1 << 10, 7, True),
+        (("gemm", "gemm"), 128),
+    ),
+    # scatter: the HBM budget alone, 2^23 / (9,774 * 7 / 2) -> 245 -> 128
+    "seven_classes_scatter": (
+        ("scatter", CELL_ROWS, 4, CELL_GROUPS, 1 << 10, 7, True),
+        (("scatter", "scatter"), 128),
+    ),
 }
+
+# (slots, lowp, channels) -> (row_tile, feat_tile) over the cell's 302 wide
+# columns at 32 bins, and the channels its operand has lanes for. Seven
+# channels: the 128-slot chunk's 896 lanes halve the row tile, as four
+# variants at 256 slots do; derived from the VMEM model, compiled for a
+# v5e (tools/aot_v5e.py), timed in ``binloop_tiles``' docstring
+CHANNEL_TILES = {
+    (8, True, 7): ((2048, 104), 16),
+    (32, True, 7): ((2048, 104), 8),
+    (64, True, 7): ((2048, 104), 8),
+    (128, True, 7): ((1024, 104), 7),
+    (32, True, 2): ((2048, 104), 4),
+    (32, False, 2): ((2048, 104), 2),
+    (256, False, 2): ((1024, 104), 2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CHANNEL_TILES))
+def test_tiles_and_built_channels_by_channel_count(case):
+    slots, lowp, channels = case
+    tiles, built = CHANNEL_TILES[case]
+    assert HP.binloop_tiles(
+        302, slots, 32, lowp=lowp, stat_channels=channels) == tiles
+    assert HP.stat_channels_built(channels, lowp, slots) == built
+    assert HP.binloop_vmem_bytes(
+        *tiles, slots, 32, lowp, channels) <= HP._BINLOOP_VMEM_BUDGET
+    if channels == 2:  # the default is the two-channel fit's
+        assert HP.binloop_tiles(302, slots, 32, lowp=lowp) == tiles
 
 
 @pytest.mark.parametrize("case", sorted(PLAN_TABLE))

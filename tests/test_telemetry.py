@@ -834,8 +834,11 @@ def test_fit_arrays_yields_the_sweep_span_tree(xgb_sweep):
         "rows": 600, "cols": 7, "families": 1, "points": 2, "lanes": 4,
     }
     evaluated = [r["args"] for r in recs if r["name"] == "selector/evaluate"]
+    # bytes: the [4, 600] float32 margins the fold lanes are scored from,
+    # and the refit lane's row of them
     assert evaluated == [
-        {"lanes": 2, "rows": 600}, {"lanes": 1, "rows": 600},
+        {"lanes": 2, "rows": 600, "classes": 2, "bytes": 9600},
+        {"lanes": 1, "rows": 600, "classes": 2, "bytes": 2400},
     ]
     args = {r["name"]: r.get("args", {}) for r in recs}
     assert args["selector/row_select"] == {
@@ -849,6 +852,9 @@ def test_fit_arrays_yields_the_sweep_span_tree(xgb_sweep):
     assert args["tree/fit_dispatch"] == {
         "lanes": 4, "rounds": 2, "depth": 3, "bins": 32,
         "hist_impl": "scatter", "hist_tiles": "none",
+        # (g, h); at 8 slots the kernel's operand (4 variants x 8 lanes)
+        # pads to one 128-lane tile: room for 8 statistics
+        "stat_channels": 2, "stat_channels_built": 8,
         "objective": "binary:logistic", "tree_weights": "0.02 0.02",
     }
     assert args["tree/feature_groups"] == {"narrow": 3, "wide": 4}

@@ -84,6 +84,18 @@ def main(argv) -> int:
             lambda s_=slots, lp=lowp: HP.build_histogram_pallas_binloop
             .lower(*cell_args, num_nodes=s_, num_bins=32, lowp=lp),
         ))
+    # a seven-class forest's statistic axis (w and six class indicators,
+    # one bfloat16 variant each) at the widths its plan allows
+    class_args = (sds((cn, cf), i32), sds((ck, cn), i32),
+                  sds((ck, 6, cn), f32), sds((ck, cn), f32))
+    for slots in (32, 128):
+        rt, ft = HP.binloop_tiles(cf, slots, 32, lowp=True, stat_channels=7)
+        jobs.append((
+            f"hist binloop cell shape {slots} slots, 7 channels lowp "
+            f"(tiles {rt}/{ft})",
+            lambda s_=slots: HP.build_histogram_pallas_binloop
+            .lower(*class_args, num_nodes=s_, num_bins=32, lowp=True),
+        ))
     serve = [(200, 10), (50, 12)]
     if "--serve-matrix" in argv:
         serve = list(itertools.product((8, 50, 200, 1000), (3, 6, 10, 12)))

@@ -44,6 +44,10 @@ def main(argv) -> int:
     ap.add_argument("--lanes", type=int, default=4)
     ap.add_argument("--widths", type=_ints, default=[32, 64, 128, 256])
     ap.add_argument("--lowp", action="store_true")
+    ap.add_argument(
+        "--channels", type=int, default=2,
+        help="statistic channels (2: grad and hess; K: a K-class forest's)",
+    )
     ap.add_argument("--row-tile", type=int, default=None)
     ap.add_argument("--feat-tile", type=int, default=None)
     ap.add_argument("--repeat", type=int, default=3)
@@ -78,11 +82,21 @@ def main(argv) -> int:
     if args.lowp:
         g = jnp.sign(g)  # bf16-exact values, as the forest's indicators
     h = jnp.ones((k, n), dtype=jnp.float32)
+    ch = args.channels
+    if ch > 2:
+        # a K-class forest's value channels: the indicators of classes
+        # 1 … K - 1 (bf16-exact), or real numbers without --lowp
+        cls = jax.random.randint(k3, (n,), 0, ch, dtype=jnp.int32)
+        g = jnp.stack(
+            [-(cls == c).astype(jnp.float32) for c in range(1, ch)]
+        )[None] * jnp.ones((k, 1, 1), jnp.float32)
+        if not args.lowp:
+            g = g * jax.random.normal(k3, (k, ch - 1, n), dtype=jnp.float32)
     np.asarray(jnp.sum(binned))  # force inputs
     dev = jax.devices()[0]
     limit = HP._BINLOOP_VMEM_LIMIT
     print(f"device {dev.platform} {dev.device_kind}; {n} x {f} x {b} bins, "
-          f"{k} lanes, lowp {args.lowp}, vmem limit "
+          f"{k} lanes, lowp {args.lowp}, {ch} channels, vmem limit "
           f"{'default' if limit is None else limit >> 20} MB", flush=True)
 
     def timed(fn, node, m, **kw):
@@ -98,7 +112,7 @@ def main(argv) -> int:
 
     def grid(node, m):
         """One table: rows row_tile, columns feat_tile, best s a call."""
-        picked = HP.binloop_tiles(f, m, b, lowp=args.lowp)
+        picked = HP.binloop_tiles(f, m, b, lowp=args.lowp, stat_channels=ch)
         print(f"width {m}: best s a call; row_tile down, feat_tile across; "
               f"* = binloop_tiles {picked}", flush=True)
         print("       " + "".join(f"{ft:>9d}" for ft in args.feat_tiles))
@@ -124,7 +138,7 @@ def main(argv) -> int:
         if args.grid:
             grid(node, m)
             continue
-        rt, ft = HP.binloop_tiles(f, m, b, lowp=args.lowp)
+        rt, ft = HP.binloop_tiles(f, m, b, lowp=args.lowp, stat_channels=ch)
         rt, ft = args.row_tile or rt, args.feat_tile or ft
         total, best, med = timed(
             HP.build_histogram_pallas_binloop, node, m, lowp=args.lowp,

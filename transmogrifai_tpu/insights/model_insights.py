@@ -51,15 +51,12 @@ def feature_contributions(model: PredictorModel, dim: int) -> np.ndarray:
         return w if w.ndim == 1 else w.mean(axis=1)
     if isinstance(model, MLPClassifierModel):
         return np.linalg.norm(model.params[0]["w"], axis=1)
-    if isinstance(model, (BoostedBinaryModel, BoostedRegressionModel, ForestRegressionModel)):
+    if isinstance(model, (BoostedBinaryModel, BoostedRegressionModel,
+                          ForestRegressionModel, ForestClassifierModel)):
         return _tree_split_importance([model.trees.split_feat], dim)
     if isinstance(model, BoostedMultiModel):
         return _tree_split_importance(
             [t.split_feat for t in model.trees_per_class], dim
-        )
-    if isinstance(model, ForestClassifierModel):
-        return _tree_split_importance(
-            [t.split_feat for t in model.forests_per_class], dim
         )
     return np.zeros(dim)
 

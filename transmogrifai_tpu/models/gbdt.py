@@ -17,13 +17,19 @@ Reference stages replaced (all on the histogram learner in models/trees.py):
     tests/test_gbt_source_semantics.py. (Up to PR 31 these two were the
     XGBoost learner under Spark's knob names.)
   * OpRandomForestClassifier/Regressor (Spark RF; defaults numTrees 50 in
-    selector grids, maxDepth 5 spark default).
+    selector grids, maxDepth 5 spark default). Over K classes the
+    classifier grows ONE forest, as Spark's does: a node holds its K
+    class-weight sums (the histogram kernel's statistic axis: the
+    indicators of classes 1 … K - 1 and w), its impurity is the K-class
+    Gini 1 - Σ p_k², a leaf is the class distribution and the forest's
+    probability the mean of its trees' leaf distributions
+    (``FOREST_MULTICLASS``; K = 2 is the same code; pinned node for node
+    in tests/test_forest_multiclass.py). Up to PR 33 more than two
+    classes were K one-vs-rest indicator forests.
   * OpDecisionTreeClassifier/Regressor: single unbagged tree.
 
 Known divergences (documented per SURVEY.md §7 hard-part 5): multiclass
-XGBoost is one-vs-rest rather than softmax-per-round; RF classification
-over more than two classes grows per-class indicator (probability) trees
-rather than one multiclass-gini tree; every tree family
+XGBoost is one-vs-rest rather than softmax-per-round; every tree family
 takes its split candidates from exact float64 quantiles (at most
 ``max_bins`` - 1 a column) where Spark samples rows for them, and breaks
 ties between equal gains by lowest column, then lowest bin;
@@ -113,6 +119,14 @@ def _bin_cache_census() -> dict:
     }
 
 
+#: How the random-forest classifier treats a label of more than two classes,
+#: stated as ``hist_pallas.default_impl`` states the histogram builder: ONE
+#: forest a fit whose nodes hold the K class counts (Spark's learner). A
+#: program from before PR 34 has no such statement (it grew K one-vs-rest
+#: indicator forests).
+FOREST_MULTICLASS = "one forest: K class counts a node"
+
+
 def _sigmoid(m: np.ndarray) -> np.ndarray:
     return 1.0 / (1.0 + np.exp(-m))
 
@@ -158,10 +172,15 @@ def _groups_from_flags(binary: np.ndarray):
     return jnp.asarray(narrow), jnp.asarray(wide)
 
 
-def _hist_tiles_attr(binned, fgroups, lanes, depth, bins, lowp) -> str:
-    """``hist_tiles`` of a ``tree/fit_dispatch`` span: the bin-loop
-    kernel's tiles at each width the fit can build at, from the same
-    shapes the fit program is traced with (``trees.hist_tiles``)."""
+def _hist_shape_attrs(
+    binned, fgroups, lanes, depth, bins, lowp, stat_channels
+) -> dict:
+    """What a ``tree/fit_dispatch`` span says of the histogram kernel, from
+    the same shapes the fit program is traced with: ``hist_tiles`` (the
+    bin-loop kernel's tiles at each width the fit can build at:
+    ``trees.hist_tiles``), ``stat_channels`` (the statistics a node holds)
+    and ``stat_channels_built`` (those the kernel's operand has lanes for:
+    ``trees.stat_channels_built``)."""
     from ..parallel.mesh import DATA_AXIS, execution_mesh
 
     n, f = binned.shape
@@ -170,9 +189,16 @@ def _hist_tiles_attr(binned, fgroups, lanes, depth, bins, lowp) -> str:
         for idx, b in zip(fgroups, (2, bins)) if idx.shape[0]
     ]
     mesh = execution_mesh()
-    return TR.hist_tiles(
-        n, lanes, groups, depth, lowp,
+    shape = dict(
         shards=None if mesh is None else mesh.shape[DATA_AXIS],
+        stat_channels=stat_channels,
+    )
+    return dict(
+        hist_tiles=TR.hist_tiles(n, lanes, groups, depth, lowp, **shape),
+        stat_channels=stat_channels,
+        stat_channels_built=TR.stat_channels_built(
+            n, lanes, groups, depth, lowp, **shape
+        ),
     )
 
 
@@ -334,7 +360,8 @@ class _BinnedModel(PredictorModel):
 
     def _predict_stacks(self, x, trees, boosted: bool) -> np.ndarray:
         """float64 [N, k] of margins (boosted) or mean-leaf values (forest)
-        — k=1 for a single stacked-tree pytree, one column per class for a
+        — k=1 for a single stacked-tree pytree (k = V where its leaves hold
+        V value channels: a class forest), one column per class for a
         list. The ONLY host-vs-device dispatch point for scoring."""
         many = isinstance(trees, list)
         if self._use_host(x):
@@ -346,7 +373,7 @@ class _BinnedModel(PredictorModel):
             if plan is None:
                 hs0 = self._host(trees)
                 plan = TR.host_serving_plan(
-                    self.thresholds, hs0 if many else [hs0]
+                    self.thresholds, hs0 if many else TR.leaf_channels(hs0)
                 )
                 self._serve_plan = plan
                 # the full-width host stacks are only needed to build the
@@ -389,7 +416,10 @@ class _BinnedModel(PredictorModel):
             else:
                 outs = [np.asarray(_aot_predict_forest(xj, thr, t))
                         for t in ds]
-        return np.stack(outs, axis=1).astype(np.float64)
+        # one column a stack; a class forest's [V, N] gives V
+        return np.concatenate(
+            [np.atleast_2d(o) for o in outs], axis=0
+        ).T.astype(np.float64)
 
     # ---- shared predict entry: family-specific stacks + HOST epilogue ----
     def _tree_stacks(self):
@@ -421,7 +451,8 @@ class _BinnedModel(PredictorModel):
 
         trees, boosted = self._tree_stacks()
         ds = self._dev(trees)
-        ds = ds if isinstance(trees, list) else [ds]
+        # one scalar-leaf stack a column of the core
+        ds = ds if isinstance(trees, list) else TR.leaf_channels(ds)
         params: dict = {
             "thr": np.asarray(self.thresholds, dtype=np.float32),
             "trees": tuple(ds),
@@ -517,13 +548,13 @@ class _BinnedModel(PredictorModel):
         self._dev_cache = None
         self._host_cache = None
         self._serve_plan = None
-        for attr in ("trees", "trees_per_class", "forests_per_class"):
+        for attr in ("trees", "trees_per_class"):
             t = getattr(self, attr, None)
             if isinstance(t, _LazySlice):
                 setattr(self, attr, own(t))
             elif isinstance(t, list):
                 setattr(self, attr, [own(x) for x in t])
-        for attr in ("_sweep_stack", "_sweep_lane", "_sweep_lanes"):
+        for attr in ("_sweep_stack", "_sweep_lane"):
             if hasattr(self, attr):
                 delattr(self, attr)
 
@@ -716,58 +747,63 @@ class GBTRegressionModel(_PerTreeWeights, BoostedRegressionModel):
 
 
 class ForestClassifierModel(_BinnedModel):
-    """Per-class probability forests (leaf value = class fraction)."""
+    """ONE forest over the label's K classes (``FOREST_MULTICLASS``): a
+    leaf holds the class distribution C_k / W (``leaf_value``
+    [T, leaves, K]; at two classes the class-1 share alone, [T, leaves]);
+    the forest's probability is the mean of its trees' leaf
+    distributions."""
 
-    def __init__(self, thresholds, forests_per_class: list[TR.Tree], uid=None):
+    def __init__(self, thresholds, trees: TR.Tree, uid=None):
         super().__init__("rfClassifier", thresholds, uid=uid)
-        self.forests_per_class = forests_per_class
+        self.trees = trees
 
     def get_arrays(self):
-        out = {"thresholds": self.thresholds}
-        for c, t in enumerate(map(_host_trees, self.forests_per_class)):
-            out[f"c{c}__split_feat"] = t.split_feat
-            out[f"c{c}__split_bin"] = t.split_bin
-            out[f"c{c}__leaf_value"] = t.leaf_value
-        return out
+        # ``c0__``: the one forest, under the prefix it always had
+        t = _host_trees(self.trees)
+        return {
+            "thresholds": self.thresholds,
+            "c0__split_feat": t.split_feat,
+            "c0__split_bin": t.split_bin,
+            "c0__leaf_value": t.leaf_value,
+        }
 
     @classmethod
     def from_params(cls, params, arrays):
-        return cls(arrays["thresholds"], _class_trees_from_arrays(arrays))
+        if "c1__split_feat" in arrays:
+            raise ValueError(
+                "a saved one-vs-rest forest (one forest a class, from "
+                "before the K-class learner) cannot be loaded: refit it"
+            )
+        return cls(arrays["thresholds"], _tree_from_arrays(arrays, "c0__"))
 
     def _tree_stacks(self):
-        return self.forests_per_class, False
+        return self.trees, False
 
     def predictions_from_core(self, core):
         return self._probs_to_predictions(np.asarray(core, dtype=np.float64))
 
     @staticmethod
     def _probs_to_predictions(probs):
+        """(pred, prob, raw) from the [N, K] mean leaf distributions ([N, 1]
+        at two classes: class 1's share); ties go to the lowest class."""
         probs = np.clip(probs, 0.0, 1.0)
-        if probs.shape[1] == 1:  # binary trained on the positive indicator
+        if probs.shape[1] == 1:  # two classes: the leaf is class 1's share
             probs = np.concatenate([1 - probs, probs], axis=1)
         raw = probs.copy()
         prob = probs / np.maximum(probs.sum(axis=1, keepdims=True), 1e-12)
         return prob.argmax(axis=1).astype(np.float64), prob, raw
 
-    # sweep-eval protocol: only single-forest (binary) stacks batch — the
-    # one-vs-rest multiclass loop stays on the per-model path
+    # sweep-eval protocol: a lane's outputs are its forest's
     sweep_mode = "forest"
 
     def sweep_lane_params(self):
         return 1.0, 0.0
 
     def predictions_from_sweep(self, preds):
-        if len(self.forests_per_class) != 1:
-            raise ValueError("sweep path is single-forest only")
+        """``preds``: one lane of the fit program's outputs, [N] at two
+        classes, [K, N] at more."""
         return self._probs_to_predictions(
-            np.asarray(preds, dtype=np.float64)[:, None]
-        )
-
-    def predictions_from_sweep_multi(self, rows):
-        """[C, N] per-class mean-leaf outputs (one sweep lane per class) →
-        (pred, prob, raw)."""
-        return self._probs_to_predictions(
-            np.asarray(rows, dtype=np.float64).T
+            np.atleast_2d(np.asarray(preds, dtype=np.float64)).T
         )
 
 
@@ -812,10 +848,11 @@ class _TreeEstimator(PredictorEstimator):
     #: grid params that are STATIC in the jitted fit (shape-affecting);
     #: points sharing them batch into one vmapped fit
     _STATIC_GRID_KEYS: tuple = ()
-    #: the stop rule (``trees._grow_tree_impl``). 0: ``min_info_gain`` (and
-    #: XGBoost's ``gamma``) are absolute, the XGBoost families. Spark's
-    #: families compare the impurity decrease PER ROW: 4·bg/W for the Gini
-    #: of a 0/1 (one-vs-rest) target, 2·bg/W for the variance
+    #: the impurity and its stop rule (``trees._grow_tree_impl``). 0:
+    #: ``min_info_gain`` (and XGBoost's ``gamma``) are absolute, the XGBoost
+    #: families. Spark's families compare the impurity decrease PER ROW:
+    #: ``trees.GINI`` over a class label's K class counts,
+    #: ``trees.VARIANCE`` of a real target
     _INFO_GAIN_NORM = 0.0
 
     def __init__(self, operation_name: str, max_depth: int, max_bins: int, uid=None):
@@ -1015,18 +1052,6 @@ class _TreeEstimator(PredictorEstimator):
         ):
             return None
         try:
-            for m in flat:
-                # multiclass stacks batch only via the per-class output
-                # lanes set by _fit_group_masks_multiclass
-                if (
-                    getattr(m, "forests_per_class", None) is not None
-                    and len(m.forests_per_class) != 1
-                    and (
-                        getattr(m, "_sweep_lanes", None) is None
-                        or m._sweep_stack.get("outputs") is None
-                    )
-                ):
-                    return None
             xj = None
             outputs: dict[int, np.ndarray] = {}
             for m in flat:
@@ -1067,31 +1092,32 @@ class _TreeEstimator(PredictorEstimator):
                     ),
                     {},
                 )
-                outputs[sid] = TR.await_outputs(out)  # [K, N]
+                outputs[sid] = TR.await_outputs(out)  # [K, (V,) N]
             values: list[list[float]] = [
                 [] for _ in range(len(models_by_fold[0]))
             ]
             with _tspans.span(
-                "selector/evaluate", lanes=len(flat), rows=len(y)
-            ):
+                "selector/evaluate", lanes=len(flat), rows=len(y),
+                # what the lanes are scored from: the outputs pulled to
+                # the host, one row of them a class where the model has
+                # classes
+                bytes=sum(int(o.nbytes) for o in outputs.values()),
+            ) as sp:
+                prob = None
                 for fi, (_train_mask, val_mask) in enumerate(folds):
                     val_idx = np.nonzero(val_mask)[0]
                     for gi, m in enumerate(models_by_fold[fi]):
-                        lanes = getattr(m, "_sweep_lanes", None)
-                        out_m = outputs[id(m._sweep_stack)]
-                        if lanes is not None:
-                            rows = out_m[lanes][:, val_idx]  # [C, n_val]
-                            pred, prob, _ = (
-                                m.predictions_from_sweep_multi(rows)
-                            )
-                        else:
-                            pred, prob, _ = m.predictions_from_sweep(
-                                out_m[m._sweep_lane][val_idx]
-                            )
+                        # a lane's outputs, the rows last: [N] or [V, N]
+                        pred, prob, _ = m.predictions_from_sweep(
+                            outputs[id(m._sweep_stack)][m._sweep_lane][
+                                ..., val_idx
+                            ]
+                        )
                         metrics = evaluator.evaluate_arrays(
                             y[val_idx], pred, prob
                         )
                         values[gi].append(evaluator.metric_of(metrics))
+                sp.attrs["classes"] = 0 if prob is None else prob.shape[1]
             return values
         except Exception:
             log.warning("batched sweep-eval failed; falling back", exc_info=True)
@@ -1099,7 +1125,7 @@ class _TreeEstimator(PredictorEstimator):
 
     def _batched_group_fit(
         self, x, masks, group_points, run_batched, make_model, normalize=None,
-        dispatch_attrs=None, lowp=False,
+        dispatch_attrs=None, lowp=False, stat_channels=2,
     ):
         """Shared plumbing for the masks × points batched fit: bin once,
         merge (+ normalize) params, stack the float knobs mask-major
@@ -1113,7 +1139,9 @@ class _TreeEstimator(PredictorEstimator):
         ``make_model(thresholds, sliced_trees, merged_params, mask_index)``;
         ``dispatch_attrs(binned, m0)`` gives the family's own attributes of
         the ``tree/fit_dispatch`` span; ``lowp`` says the trainer hands the
-        histogram kernel bf16-exact values (the span's ``hist_tiles``).
+        histogram kernel bf16-exact values and ``stat_channels`` how many
+        statistics a node holds (the span's ``hist_tiles``,
+        ``stat_channels``, ``stat_channels_built``).
         The training outputs (every lane's raw model output on the full
         training matrix, computed by the fit program itself) ride the stack
         so sweep_eval_batched needs no re-traversal program.
@@ -1143,17 +1171,21 @@ class _TreeEstimator(PredictorEstimator):
             )
 
         m0 = merged[0]
+        shape_attrs = _hist_shape_attrs(
+            binned, fgroups, n_masks * n_pts,
+            max(int(m["max_depth"]) for m in merged),
+            int(m0["max_bins"]), lowp, stat_channels,
+        )
+        if _tspans.enabled():
+            TR.hist_slot_stats().record_channels(
+                stat_channels, shape_attrs["stat_channels_built"]
+            )
         # the asynchronous dispatch of boost_chunk / forest_scan
         with _tspans.span(
             "tree/fit_dispatch", lanes=n_masks * n_pts,
             rounds=int(m0.get("num_round", m0.get("num_trees", 1))),
             depth=int(m0["max_depth"]), bins=int(m0["max_bins"]),
-            hist_impl=TR._resolved_impl(),
-            hist_tiles=_hist_tiles_attr(
-                binned, fgroups, n_masks * n_pts,
-                max(int(m["max_depth"]) for m in merged),
-                int(m0["max_bins"]), lowp,
-            ),
+            hist_impl=TR._resolved_impl(), **shape_attrs,
             **(dispatch_attrs(binned, m0) if dispatch_attrs else {}),
         ):
             trees, outputs, slots = run_batched(
@@ -1443,7 +1475,7 @@ class GBTClassifier(XGBoostClassifier):
         }
 
     _STATIC_GRID_KEYS = ("max_iter", "max_depth", "max_bins")
-    _INFO_GAIN_NORM = 2.0
+    _INFO_GAIN_NORM = TR.VARIANCE
     _OBJECTIVE = "spark:logloss"
 
     def _normalize_boost(self, merged: dict) -> dict:
@@ -1476,7 +1508,7 @@ class GBTRegressor(XGBoostRegressor):
 
     model_type = "OpGBTRegressor"
     _STATIC_GRID_KEYS = ("max_iter", "max_depth", "max_bins")
-    _INFO_GAIN_NORM = 2.0
+    _INFO_GAIN_NORM = TR.VARIANCE
     _OBJECTIVE = "spark:squarederror"
     _normalize_boost = GBTClassifier._normalize_boost
 
@@ -1555,7 +1587,7 @@ class RandomForestClassifier(_TreeEstimator):
     Poisson bootstrap when there is more than one tree)."""
 
     model_type = "OpRandomForestClassifier"
-    _INFO_GAIN_NORM = 4.0
+    _INFO_GAIN_NORM = TR.GINI
     _CLASSIFICATION = True
 
     def __init__(
@@ -1615,22 +1647,18 @@ class RandomForestClassifier(_TreeEstimator):
             info_gain_norm=self._INFO_GAIN_NORM,
         )
 
-    def _dispatch_attrs(self, binned, m0: dict) -> dict:
-        st = self._forest_statics(
-            m0, int(binned.shape[1]), m0.get("subsampling_rate", 1.0)
-        )
-        return dict(
-            trees=int(m0.get("num_trees", 1)),
-            feature_subset=str(m0.get("feature_subset_strategy", "auto")),
-            n_sub=st["feature_subset"], bootstrap=st["bootstrap"],
-        )
+    @staticmethod
+    def _num_classes(y, row_mask) -> int:
+        """K of a fit: the label's largest class among the rows it may
+        see, plus one (at least two)."""
+        present = y[row_mask > 0]
+        return max(int(present.max()) + 1 if len(present) else 2, 2)
 
     def fit_arrays(self, x, y, row_mask):
         thresholds, binned, fgroups = self._binned(x)
-        present = y[row_mask > 0]
-        num_classes = max(int(present.max()) + 1 if len(present) else 2, 2)
-        rm = jnp.asarray(row_mask, dtype=jnp.float32)
-        kwargs = dict(
+        trees = TR.fit_forest(
+            binned, jnp.asarray(y, dtype=jnp.float32),
+            jnp.asarray(row_mask, dtype=jnp.float32),
             num_trees=int(self.num_trees),
             max_depth=int(self.max_depth),
             num_bins=int(self.max_bins),
@@ -1638,33 +1666,35 @@ class RandomForestClassifier(_TreeEstimator):
             min_instances=float(self.min_instances_per_node),
             min_info_gain=float(self.min_info_gain),
             seed=int(self.seed),
-            lowp=True,  # one-vs-rest indicators are bf16-exact
+            lowp=True,  # w and the class indicators are bf16-exact
             feature_groups=fgroups,
+            num_classes=self._num_classes(y, row_mask),
             # the forest's params, whatever a subclass exposes of them
             **self._forest_statics(
                 RandomForestClassifier.get_params(self), x.shape[1],
                 self.subsampling_rate,
             ),
         )
-        if num_classes == 2:
-            forests = [
-                TR.fit_forest(binned, jnp.asarray((y == 1).astype(np.float32)), rm, **kwargs)
-            ]
-        else:
-            forests = [
-                TR.fit_forest(binned, jnp.asarray((y == c).astype(np.float32)), rm, **kwargs)
-                for c in range(num_classes)
-            ]
-        return ForestClassifierModel(thresholds, forests)
+        return ForestClassifierModel(thresholds, trees)
+
+    def _dispatch_attrs(self, binned, m0: dict, classes: int = 0) -> dict:
+        st = self._forest_statics(
+            m0, int(binned.shape[1]), m0.get("subsampling_rate", 1.0)
+        )
+        attrs = dict(
+            trees=int(m0.get("num_trees", 1)),
+            feature_subset=str(m0.get("feature_subset_strategy", "auto")),
+            n_sub=st["feature_subset"], bootstrap=st["bootstrap"],
+        )
+        if classes:
+            attrs["classes"] = classes
+        return attrs
 
     def _fit_group_masks(self, x, y, masks, group_points):
-        present = y[masks.max(axis=0) > 0]
-        num_classes = max(int(present.max()) + 1 if len(present) else 2, 2)
-        if num_classes != 2:
-            return self._fit_group_masks_multiclass(
-                x, y, masks, group_points, num_classes
-            )
-        yj = np.asarray((y == 1), dtype=np.float32)
+        # ONE forest a (mask, point) whatever K: the class count is the
+        # fit's statistic channels (K = 2: w*y and w, as ever)
+        num_classes = self._num_classes(y, masks.max(axis=0))
+        yj = np.asarray(y, dtype=np.float32)
 
         def run_batched(binned, m0, row_mask_k, knob, fgroups):
             # depth rides the lane axis: ONE program at the grid's max
@@ -1683,8 +1713,9 @@ class RandomForestClassifier(_TreeEstimator):
                 min_instances=knob("min_instances_per_node"),
                 min_info_gain=knob("min_info_gain"),
                 seed=int(m0["seed"]),
-                lowp=True,  # one-vs-rest indicators are bf16-exact
+                lowp=True,  # w and the class indicators are bf16-exact
                 feature_groups=fgroups,
+                num_classes=num_classes,
                 max_depth_v=(
                     None if uniform
                     else depth_arr.astype(np.int32)
@@ -1694,110 +1725,17 @@ class RandomForestClassifier(_TreeEstimator):
 
         return self._batched_group_fit(
             x, masks, group_points, run_batched,
-            lambda th, tr, m, mi: ForestClassifierModel(th, [tr]),
-            dispatch_attrs=self._dispatch_attrs, lowp=True,
-        )
-
-    def _fit_group_masks_multiclass(self, x, y, masks, group_points,
-                                    num_classes):
-        """One-vs-rest multiclass sweep as ONE batched program per static
-        group: lane (mask_i·n_pts + point_j)·C + c trains class c's
-        indicator forest (per-lane targets — trees._forest_trees_scan).
-        The sequential fallback paid masks × points × classes separate
-        forest programs (the 143 s iris bench of round 5's first cut)."""
-        from ..parallel.mesh import execution_mesh
-
-        if execution_mesh() is not None:
-            # per-lane targets are single-device only (trees.py raises);
-            # a raise here would trip the validator's candidate isolation
-            # and silently drop the whole RF family — keep the sequential
-            # sharded-safe fallback instead
-            return None
-        thresholds, binned, fgroups = self._binned(x)
-        self._last_feature_groups = fgroups
-        merged = [{**self.get_params(), **p} for p in group_points]
-        n_masks, n_pts = masks.shape[0], len(merged)
-        c = num_classes
-        from ..compiler import stats as cstats
-
-        # one program serves masks × points × classes lanes (dedup ledger)
-        cstats.stats().record_sweep(lanes=n_masks * n_pts * c)
-        ind = np.stack(
-            [(y == cls) for cls in range(c)]
-        ).astype(np.float32)                         # [C, N]
-        rm = np.repeat(np.repeat(masks, n_pts, axis=0), c, axis=0)
-        tg = np.tile(ind, (n_masks * n_pts, 1))      # [K·C, N]
-
-        def knob(name):
-            base = np.asarray(
-                [float(m[name]) for m in merged] * n_masks, dtype=np.float32
-            )
-            return np.repeat(base, c)
-
-        # max_depth is in _STATIC_GRID_KEYS, so every point of this group
-        # shares one depth — no per-lane depth caps needed here
-        m0 = merged[0]
-        rates = knob("subsampling_rate")
-        with _tspans.span(
-            "tree/fit_dispatch", lanes=n_masks * n_pts * c,
-            rounds=int(m0["num_trees"]), depth=int(m0["max_depth"]),
-            bins=int(m0["max_bins"]), hist_impl=TR._resolved_impl(),
-            hist_tiles=_hist_tiles_attr(
-                binned, fgroups, n_masks * n_pts * c, int(m0["max_depth"]),
-                int(m0["max_bins"]), True,
+            lambda th, tr, m, mi: ForestClassifierModel(th, tr),
+            dispatch_attrs=lambda binned, m0: self._dispatch_attrs(
+                binned, m0, classes=num_classes
             ),
-            **self._dispatch_attrs(binned, m0),
-        ):
-            trees, outs, slots = TR.fit_forest_batched(
-                binned, tg, rm,
-                num_trees=int(m0["num_trees"]),
-                max_depth=int(m0["max_depth"]),
-                num_bins=int(m0["max_bins"]),
-                subsample_rate=rates,
-                **self._forest_statics(m0, binned.shape[1], rates),
-                min_instances=knob("min_instances_per_node"),
-                min_info_gain=knob("min_info_gain"),
-                seed=int(m0["seed"]),
-                lowp=True,
-                feature_groups=fgroups,
-                return_outputs=True, return_slots=True,
-            )
-        leaves = jax.tree.leaves(trees)
-        is_dev = bool(leaves) and hasattr(leaves[0], "devices")
-        if (is_dev and len(leaves[0].devices()) > 1) or not is_dev:
-            trees = TR.await_outputs(trees)
-        stack = {"trees": trees, "thresholds": thresholds,
-                 "k": n_masks * n_pts * c, "outputs": outs,
-                 "hist_slots": slots}
-        models = [
-            [
-                ForestClassifierModel(
-                    thresholds,
-                    [
-                        _LazySlice(stack, (mi * n_pts + j) * c + cls)
-                        for cls in range(c)
-                    ],
-                )
-                for j in range(n_pts)
-            ]
-            for mi in range(n_masks)
-        ]
-        # C output lanes per model: sweep_eval_batched evaluates from the
-        # fit program's own per-class probabilities (the per-model predict
-        # fallback materializes C device lane slices per model)
-        for mi in range(n_masks):
-            for j in range(n_pts):
-                m = models[mi][j]
-                m._sweep_stack = stack
-                m._sweep_lanes = [
-                    (mi * n_pts + j) * c + cls for cls in range(c)
-                ]
-        return models
+            lowp=True, stat_channels=num_classes,
+        )
 
 
 class RandomForestRegressor(_TreeEstimator):
     model_type = "OpRandomForestRegressor"
-    _INFO_GAIN_NORM = 2.0
+    _INFO_GAIN_NORM = TR.VARIANCE
     _CLASSIFICATION = False
 
     def __init__(

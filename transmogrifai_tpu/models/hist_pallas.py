@@ -270,8 +270,14 @@ def build_histogram_pallas_batched(
     return jnp.transpose(out[:, :f, :num_nodes, :num_bins, :], (0, 2, 1, 3, 4))
 
 
-def _hist_binloop_kernel(binned_ref, node_ref, g_ref, h_ref, outg_ref,
-                         outh_ref, *, m_pad, num_bins, lowp):
+def _stat_rows(grad, hess) -> list:
+    """The statistic channels as ``[K, N]`` rows, the hessian last."""
+    if grad.ndim == 2:
+        return [grad, hess]
+    return [grad[:, v] for v in range(grad.shape[1])] + [hess]
+
+
+def _hist_binloop_kernel(binned_ref, node_ref, *refs, m_pad, num_bins, lowp):
     """Bin-loop histogram step: one whole-block compare per bin instead of
     the per-group select-chain assembly. The comb construction drops from
     ~5 VPU ops per one-hot element to 2 (compare + convert) — the
@@ -279,37 +285,46 @@ def _hist_binloop_kernel(binned_ref, node_ref, g_ref, h_ref, outg_ref,
     1M×500×32. Layout: binned block [feat_tile, T]
     (features on sublanes), stack [T, nvar·M]; per bin b the dot
     [feat_tile, T] @ [T, nvar·M] emits that bin's [feat_tile, nvar·M]
-    plane, written at a static outermost index."""
+    plane, written at a static outermost index.
+
+    ``refs``: the S statistic rows of the fit (its value channels, then
+    the hessian / weight: ``(g, h)`` of a boosted or a two-class fit, the
+    K - 1 class indicators and w of a K-class forest), then their S
+    output accumulators. The stack holds one variant a statistic under
+    ``lowp`` and two (hi, lo) without, statistic-major."""
     import jax.lax as lax
     from jax.experimental import pallas as pl
 
     j = pl.program_id(2)
+    nstat = len(refs) // 2
+    val_refs, out_refs = refs[:nstat], refs[nstat:]
 
     nodes = node_ref[0, 0, :]
-    g = g_ref[0, 0, :]
-    h = h_ref[0, 0, :]
+    vals = [r[0, 0, :] for r in val_refs]
     t = nodes.shape[0]
 
-    nvar = 2 if lowp else 4
+    nvar = stack_variants(nstat, lowp)
     iota_s = lax.broadcasted_iota(jnp.int32, (t, nvar * m_pad), 1)
     m_lane = iota_s % m_pad
     variant = iota_s // m_pad
     oh = nodes[:, None] == m_lane
     if lowp:
-        val = jnp.where(variant == 0, g[:, None], h[:, None])
+        pieces = vals
     else:
-        g_hi = g.astype(jnp.bfloat16).astype(jnp.float32)
-        g_lo = g - g_hi
-        h_hi = h.astype(jnp.bfloat16).astype(jnp.float32)
-        h_lo = h - h_hi
-        val = jnp.where(
-            variant == 0, g_hi[:, None],
-            jnp.where(
-                variant == 1, g_lo[:, None],
-                jnp.where(variant == 2, h_hi[:, None], h_lo[:, None]),
-            ),
-        )
-    stack = jnp.where(oh, val, 0.0).astype(jnp.bfloat16)
+        pieces = []
+        for v in vals:
+            hi = v.astype(jnp.bfloat16).astype(jnp.float32)
+            pieces += [hi, v - hi]
+
+    def select(i):
+        """Variant i's values on its lanes, the later variants' on theirs
+        (traced in the order the two-statistic kernel always had: its
+        compiled form is part of what the cells measure)."""
+        if i == nvar - 1:
+            return pieces[i][:, None]
+        return jnp.where(variant == i, pieces[i][:, None], select(i + 1))
+
+    stack = jnp.where(oh, select(0), 0.0).astype(jnp.bfloat16)
     codes = binned_ref[...]  # [feat_tile, T] int32
     contract = (((1,), (0,)), ((), ()))  # contract the row-tile axis
 
@@ -321,21 +336,23 @@ def _hist_binloop_kernel(binned_ref, node_ref, g_ref, h_ref, outg_ref,
             precision=lax.Precision.DEFAULT,
         )  # [feat_tile, nvar·M]
         if lowp:
-            hg = out[:, :m_pad]
-            hh = out[:, m_pad:]
+            sums = [out[:, s * m_pad:(s + 1) * m_pad] for s in range(nstat)]
         else:
-            hg = out[:, :m_pad] + out[:, m_pad:2 * m_pad]
-            hh = out[:, 2 * m_pad:3 * m_pad] + out[:, 3 * m_pad:]
+            sums = [
+                out[:, 2 * s * m_pad:(2 * s + 1) * m_pad]
+                + out[:, (2 * s + 1) * m_pad:(2 * s + 2) * m_pad]
+                for s in range(nstat)
+            ]
 
         @pl.when(j == 0)
-        def _(b=b, hg=hg, hh=hh):
-            outg_ref[0, b, :, :] = hg
-            outh_ref[0, b, :, :] = hh
+        def _(b=b, sums=sums):
+            for ref, hs in zip(out_refs, sums):
+                ref[0, b, :, :] = hs
 
         @pl.when(j > 0)
-        def _(b=b, hg=hg, hh=hh):
-            outg_ref[0, b, :, :] = outg_ref[0, b, :, :] + hg
-            outh_ref[0, b, :, :] = outh_ref[0, b, :, :] + hh
+        def _(b=b, sums=sums):
+            for ref, hs in zip(out_refs, sums):
+                ref[0, b, :, :] = ref[0, b, :, :] + hs
 
 
 # Scoped VMEM the bin-loop kernel states to Mosaic (``vmem_limit_bytes``; a
@@ -350,11 +367,32 @@ _BINLOOP_VMEM_BUDGET = 48 << 20
 # 1,024-row tile beat 2,048 rows at every feature tile (0.5295 against
 # 0.5511 s at 104), at 512 lanes and under 2,048 rows won.
 _BINLOOP_STACK_ELEMS = 1 << 20
+# Lanes of that operand one build may have: 1,024 is the widest measured
+# (256 slots x 4 variants), so a fit of more variants takes fewer slots a
+# chunk (``histogram_plan``): 7 statistics at ``lowp`` 128, two 256.
+_BINLOOP_STACK_LANES = 1024
+
+
+def stack_variants(stat_channels: int, lowp: bool) -> int:
+    """Value variants of the bin-loop kernel's stacked operand: one a
+    statistic where the values are bfloat16-exact (``lowp``), else a
+    (hi, lo) pair."""
+    return stat_channels * (1 if lowp else 2)
+
+
+def stat_channels_built(stat_channels: int, lowp: bool, slots: int) -> int:
+    """Statistic channels the ``[T, nvar·M]`` operand of a ``slots``-slot
+    build has lanes for: the operand pads to whole 128-lane tiles, so 7
+    statistics at 32 slots (224 lanes) are built as 8, two at ``lowp`` (64
+    lanes) as 4."""
+    m_pad = _round_up(max(slots, 8), 8)
+    per = stack_variants(stat_channels, lowp) // stat_channels
+    return _round_up(per * stat_channels * m_pad, 128) // (per * m_pad)
 
 
 def binloop_vmem_bytes(
     row_tile: int, feat_tile: int, num_nodes: int, num_bins: int,
-    lowp: bool = False,
+    lowp: bool = False, stat_channels: int = 2,
 ) -> int:
     """Scoped VMEM one grid step of the bin-loop kernel takes, fitted from
     above to what Mosaic asked for at 240 (slots, bins, variants, tiles)
@@ -366,20 +404,24 @@ def binloop_vmem_bytes(
     (8 sublanes each). On top, once, the step's temporaries per row of the
     tile: the ``[T, nvar·M]`` stack and what it is selected from (3.5
     bytes a lane), the per-bin compare of the codes (8 bytes a feature),
-    and 1 KB of row vectors."""
+    and 1 KB of row vectors. ``stat_channels`` (S: two up to PR 33) scales
+    what there is one of a statistic: S accumulators, S + 1 rows, the
+    stack's variants (derived from the two-channel fit, not re-fitted:
+    ``binloop_tiles``)."""
     m_pad = _round_up(max(num_nodes, 8), 8)
-    stack_lanes = _round_up((2 if lowp else 4) * m_pad, 128)
+    stack_lanes = _round_up(stack_variants(stat_channels, lowp) * m_pad, 128)
     blocks = (
-        2 * num_bins * feat_tile * _round_up(m_pad, 128) * 4
+        stat_channels * num_bins * feat_tile * _round_up(m_pad, 128) * 4
         + feat_tile * row_tile * 4
-        + 3 * 8 * row_tile * 4
+        + (stat_channels + 1) * 8 * row_tile * 4
     )
     temps = row_tile * (stack_lanes * 7 // 2 + 8 * feat_tile + 1024)
     return 2 * blocks + temps
 
 
 def binloop_tiles(
-    f: int, num_nodes: int, num_bins: int, lowp: bool = False
+    f: int, num_nodes: int, num_bins: int, lowp: bool = False,
+    stat_channels: int = 2,
 ) -> tuple[int, int]:
     """(row_tile, feat_tile) of the bin-loop kernel at ``num_nodes`` node
     slots, from shapes alone, chosen together. The kernel's dot is
@@ -414,11 +456,32 @@ def binloop_tiles(
     a tenth. 128 features a tile lost to 104 at every width (0.5981 at
     1024/128): the padding to 384 columns costs more than the fuller MXU
     gains. Every pair of {128..2048} x {8..128} compiled under the stated
-    limit."""
+    limit.
+
+    ``stat_channels`` (S; PR 34). MEASURED at S = 7 (a seven-class
+    forest: 7 one-variant channels at ``lowp``), same shape, same tool
+    with ``--channels 7``, at the pairs this function gives:
+
+        302 columns  7 channels, lowp
+        slots  tiles     s        lanes of the stacked operand
+          128  1024/104  0.4933   896
+           64  2048/104  0.3623   448 (in 512)
+           32  2048/104  0.2055   224 (in 256); two channels: 0.1128
+
+    DERIVED, not measured: the pairs themselves. The VMEM model scales
+    what there is one of a statistic (S accumulators, S + 1 rows, the
+    stack's variants) from the two-channel fit and was not re-fitted;
+    the row cap is the same element cap over ``S x slots`` lanes, floored
+    to a power of two (7 x 128 = 896 lanes: 1,024 rows, as 4 x 256); no
+    grid of pairs was timed at S = 7, and the v5e compiler took every
+    pair of the table (32 MB at most of the 48 MB budget:
+    ``tools/aot_v5e.py``). ``histogram_plan`` holds such a fit to
+    128-slot chunks: no operand wider than the 1,024 lanes measured."""
     m_pad = _round_up(max(num_nodes, 8), 8)
-    nvar = 2 if lowp else 4
+    nvar = stack_variants(stat_channels, lowp)
+    # a power of two, so that its halvings stay multiples of 128 lanes
     row_cap = max(
-        128, min(2048, _BINLOOP_STACK_ELEMS // (nvar * m_pad) // 128 * 128)
+        128, min(2048, _pow2_floor(_BINLOOP_STACK_ELEMS // (nvar * m_pad)))
     )
     f8 = _round_up(f, FEAT_TILE)
     for tiles in range(-(-f8 // 128), f8 // FEAT_TILE + 1):
@@ -427,7 +490,8 @@ def binloop_tiles(
         while row_tile >= 128:
             if (
                 binloop_vmem_bytes(
-                    row_tile, feat_tile, num_nodes, num_bins, lowp
+                    row_tile, feat_tile, num_nodes, num_bins, lowp,
+                    stat_channels,
                 ) <= _BINLOOP_VMEM_BUDGET
             ):
                 return row_tile, feat_tile
@@ -444,7 +508,7 @@ def binloop_tiles(
 def build_histogram_pallas_binloop(
     binned: jax.Array,   # [N, F] int32 codes in [0, num_bins), SHARED
     node: jax.Array,     # [K, N] int32 node slot per row per fit (-1 = dead)
-    grad: jax.Array,     # [K, N] f32 (pre-masked)
+    grad: jax.Array,     # [K, N] f32 (pre-masked), or [K, V, N]: V channels
     hess: jax.Array,     # [K, N] f32
     num_nodes: int,
     num_bins: int,
@@ -453,17 +517,21 @@ def build_histogram_pallas_binloop(
     interpret: bool = False,
     feat_tile: int | None = None,
 ) -> jax.Array:
-    """hist [K, num_nodes, F, num_bins, 2] via the bin-loop kernel (see
-    _hist_binloop_kernel). Same contract as build_histogram_pallas_batched;
-    ``row_tile`` / ``feat_tile`` override ``binloop_tiles`` (timing probes:
-    tools/bench_hist_kernel.py)."""
+    """hist [K, num_nodes, F, num_bins, S] via the bin-loop kernel (see
+    _hist_binloop_kernel): S = 2 (grad, hess) from a ``[K, N]`` grad, V + 1
+    (the value channels, then hess) from ``[K, V, N]``. Same contract as
+    build_histogram_pallas_batched; ``row_tile`` / ``feat_tile`` override
+    ``binloop_tiles`` (timing probes: tools/bench_hist_kernel.py)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     k_fits, n = node.shape
     f = binned.shape[1]
+    stats = _stat_rows(grad, hess)
     m_pad = _round_up(max(num_nodes, 8), 8)
-    tiles = binloop_tiles(f, num_nodes, num_bins, lowp=lowp)
+    tiles = binloop_tiles(
+        f, num_nodes, num_bins, lowp=lowp, stat_channels=len(stats)
+    )
     row_tile, feat_tile = row_tile or tiles[0], feat_tile or tiles[1]
     n_pad = _round_up(max(n, row_tile), row_tile)
     f_pad = _round_up(f, feat_tile)
@@ -471,22 +539,25 @@ def build_histogram_pallas_binloop(
     binned_t = jnp.full((f_pad, n_pad), -1, dtype=jnp.int32)
     binned_t = binned_t.at[:f, :n].set(binned.T)
     node_p = jnp.full((k_fits, 1, n_pad), -1, dtype=jnp.int32).at[:, 0, :n].set(node)
-    g_p = jnp.zeros((k_fits, 1, n_pad), dtype=jnp.float32).at[:, 0, :n].set(grad)
-    h_p = jnp.zeros((k_fits, 1, n_pad), dtype=jnp.float32).at[:, 0, :n].set(hess)
+    stats_p = [
+        jnp.zeros((k_fits, 1, n_pad), dtype=jnp.float32).at[:, 0, :n].set(v)
+        for v in stats
+    ]
 
     grid = (k_fits, f_pad // feat_tile, n_pad // row_tile)
+    row_spec = pl.BlockSpec(
+        (1, 1, row_tile), lambda k, i, j: (k, 0, j), memory_space=pltpu.VMEM,
+    )
 
-    out_g, out_h = pl.pallas_call(
+    outs = pl.pallas_call(
         functools.partial(
             _hist_binloop_kernel, m_pad=m_pad, num_bins=num_bins, lowp=lowp,
         ),
-        out_shape=(
+        out_shape=tuple(
             jax.ShapeDtypeStruct(
                 (k_fits, num_bins, f_pad, m_pad), jnp.float32
-            ),
-            jax.ShapeDtypeStruct(
-                (k_fits, num_bins, f_pad, m_pad), jnp.float32
-            ),
+            )
+            for _ in stats
         ),
         grid=grid,
         in_specs=[
@@ -494,39 +565,24 @@ def build_histogram_pallas_binloop(
                 (feat_tile, row_tile), lambda k, i, j: (i, j),
                 memory_space=pltpu.VMEM,
             ),
-            pl.BlockSpec(
-                (1, 1, row_tile), lambda k, i, j: (k, 0, j),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, 1, row_tile), lambda k, i, j: (k, 0, j),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, 1, row_tile), lambda k, i, j: (k, 0, j),
-                memory_space=pltpu.VMEM,
-            ),
-        ],
-        out_specs=(
+            row_spec,
+        ] + [row_spec] * len(stats),
+        out_specs=tuple(
             pl.BlockSpec(
                 (1, num_bins, feat_tile, m_pad),
                 lambda k, i, j: (k, 0, i, 0),
                 memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, num_bins, feat_tile, m_pad),
-                lambda k, i, j: (k, 0, i, 0),
-                memory_space=pltpu.VMEM,
-            ),
+            )
+            for _ in stats
         ),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_BINLOOP_VMEM_LIMIT
         ),
         interpret=interpret,
-    )(binned_t, node_p, g_p, h_p)
+    )(binned_t, node_p, *stats_p)
 
-    # [K, B, F, M] -> [K, M, F, B, 2]
-    out = jnp.stack([out_g, out_h], axis=-1)
+    # S x [K, B, F, M] -> [K, M, F, B, S]
+    out = jnp.stack(outs, axis=-1)
     out = jnp.transpose(out, (0, 3, 2, 1, 4))
     return out[:, :num_nodes, :f, :, :]
 
@@ -558,23 +614,27 @@ def build_histogram_scatter(
     num_nodes: int,
     num_bins: int,
 ) -> jax.Array:
-    """XLA scatter-add reference implementation (CPU / correctness).
+    """XLA scatter-add reference implementation (CPU / correctness):
+    [num_nodes, F, num_bins, S] from ``grad`` [N] (S = 2) or [V, N]
+    (S = V + 1, hess last).
 
-    Grad and hess scatter as separate flat [N·F] vectors — a trailing
-    length-2 axis would be tile-padded 64× on TPU (catastrophic under the
+    Every statistic scatters as its own flat [N·F] vector — a trailing
+    length-S axis would be tile-padded 64× on TPU (catastrophic under the
     forest vmap)."""
     n, f = binned.shape
     col_ids = jnp.arange(f, dtype=jnp.int32)[None, :]
     safe_node = jnp.maximum(node, 0)
     alive = (node >= 0).astype(jnp.float32)
     flat = ((safe_node[:, None] * f + col_ids) * num_bins + binned).reshape(-1)
-    gv = jnp.repeat(grad * alive, f)
-    hv = jnp.repeat(hess * alive, f)
     size = num_nodes * f * num_bins
-    hg = jnp.zeros(size, dtype=jnp.float32).at[flat].add(gv)
-    hh = jnp.zeros(size, dtype=jnp.float32).at[flat].add(hv)
+    stats = ([grad] if grad.ndim == 1 else list(grad)) + [hess]
     return jnp.stack(
-        [hg.reshape(num_nodes, f, num_bins), hh.reshape(num_nodes, f, num_bins)],
+        [
+            jnp.zeros(size, dtype=jnp.float32).at[flat].add(
+                jnp.repeat(v * alive, f)
+            ).reshape(num_nodes, f, num_bins)
+            for v in stats
+        ],
         axis=-1,
     )
 
@@ -582,12 +642,12 @@ def build_histogram_scatter(
 def build_histogram_scatter_batched(
     binned: jax.Array,   # [N, F] shared
     node: jax.Array,     # [K, N]
-    grad: jax.Array,     # [K, N]
+    grad: jax.Array,     # [K, N] or [K, V, N]
     hess: jax.Array,     # [K, N]
     num_nodes: int,
     num_bins: int,
 ) -> jax.Array:
-    """[K, num_nodes, F, num_bins, 2] scatter-add fallback (CPU / non-TPU)."""
+    """[K, num_nodes, F, num_bins, S] scatter-add fallback (CPU / non-TPU)."""
     return jax.vmap(
         lambda nd, g, h: build_histogram_scatter(
             binned, nd, g, h, num_nodes, num_bins
@@ -610,14 +670,15 @@ def one_hot_codes(binned: jax.Array, num_bins: int, lowp: bool) -> jax.Array:
 def build_histogram_gemm(
     codes1h: jax.Array,  # [N, F·B] from one_hot_codes, SHARED
     node: jax.Array,     # [K, N] int32 node slot per row per fit (-1 = dead)
-    grad: jax.Array,     # [K, N] f32 (pre-masked)
+    grad: jax.Array,     # [K, N] f32 (pre-masked), or [K, V, N]
     hess: jax.Array,     # [K, N] f32
     num_nodes: int,
     num_bins: int,
     lowp: bool = False,
 ) -> jax.Array:
-    """[K, num_nodes, F, num_bins, 2] histogram as TWO one-hot GEMMs — the
-    MXU-native formulation for small row counts. The pallas kernels' grid
+    """[K, num_nodes, F, num_bins, S] histogram as one one-hot GEMM a
+    statistic — the MXU-native formulation for small row counts. The
+    pallas kernels' grid
     economics only win at large N; at AutoML-tabular sizes (≤4k rows) the
     whole per-level histogram is a [K·M, N] @ [N, F·B] matmul pair that XLA
     fuses into the surrounding program (measured: the depth-12 RF group
@@ -626,16 +687,16 @@ def build_histogram_gemm(
     histograms."""
     dt = jnp.bfloat16 if lowp else jnp.float32
     node1h = jax.nn.one_hot(node, num_nodes, dtype=jnp.float32)  # [K, N, M]
-    gw = (node1h * grad[:, :, None]).astype(dt)
-    hw = (node1h * hess[:, :, None]).astype(dt)
-    hg = jnp.einsum(
-        "knm,nw->kmw", gw, codes1h, preferred_element_type=jnp.float32
-    )
-    hh = jnp.einsum(
-        "knm,nw->kmw", hw, codes1h, preferred_element_type=jnp.float32
-    )
-    return jnp.stack([hg, hh], axis=-1).reshape(
-        node.shape[0], num_nodes, codes1h.shape[1] // num_bins, num_bins, 2
+    sums = [
+        jnp.einsum(
+            "knm,nw->kmw", (node1h * v[:, :, None]).astype(dt), codes1h,
+            preferred_element_type=jnp.float32,
+        )
+        for v in _stat_rows(grad, hess)
+    ]
+    return jnp.stack(sums, axis=-1).reshape(
+        node.shape[0], num_nodes, codes1h.shape[1] // num_bins, num_bins,
+        len(sums),
     )
 
 
@@ -643,7 +704,7 @@ def build_histogram_gemm(
 # which builder a fit takes, and how many node slots one build may hold
 # --------------------------------------------------------------------------
 class Builder(NamedTuple):
-    """One way to build a feature group's [K, M, F, B, 2] histograms.
+    """One way to build a feature group's [K, M, F, B, S] histograms.
     ``prepare(binned [N, F], num_bins, lowp)`` makes the operand that is the
     same at every level (once a fit, outside the level scan);
     ``build(operand, node, grad, hess, num_nodes, num_bins, lowp=...)``
@@ -708,15 +769,24 @@ def _pow2_floor(x: int) -> int:
 
 def histogram_plan(
     impl: str, n: int, k_fits: int, groups: Sequence[tuple[int, int]],
-    max_slots: int,
+    max_slots: int, stat_channels: int = 2, lowp: bool = False,
 ) -> HistogramPlan:
     """How a fit builds its histograms, from what its trace can see:
     ``impl`` ('pallas' = choose for the TPU; 'gemm' / 'scatter' force one
     builder), the LOCAL rows ``n`` and lanes ``k_fits`` of one build, the
-    feature groups' ``(columns, bins)`` and ``max_slots``, the most compact
-    node slots a level can have live. This is the one place that decides;
-    it runs while a program is traced, never per call."""
-    hist_width = sum(f * b for f, b in groups)
+    feature groups' ``(columns, bins)``, ``max_slots``, the most compact
+    node slots a level can have live, and the fit's statistic channels
+    (two: grad and hess, or w*y and w; K for a K-class forest) with
+    whether their values are bfloat16-exact (``lowp``). This is the one
+    place that decides; it runs while a program is traced, never per
+    call.
+
+    Every builder takes the statistic axis but the lane-packed kernel,
+    whose two accumulators are its layout: a fit of more channels is
+    planned onto the bin-loop kernel whatever its bins. The kernels' chunk
+    also holds the stacked operand to ``_BINLOOP_STACK_LANES`` (two
+    channels: 256 slots as before; 7 at ``lowp``: 128)."""
+    hist_width = sum(f * b for f, b in groups) * stat_channels // 2
     budget = max(_HIST_BUDGET_ELEMS // k_fits, _HIST_BUDGET_FLOOR)
     cap = min(_pow2_floor(max(1, budget // max(hist_width, 1))), max_slots)
     if impl == "gemm" or (impl == "pallas" and n <= _GEMM_MAX_ROWS):
@@ -724,11 +794,15 @@ def histogram_plan(
         ceil = min(_GEMM_CHUNK_CEIL, _GEMM_ONEHOT_ELEMS // max(k_fits * n, 1))
     elif impl == "pallas":
         builders = tuple(
-            "binloop" if b <= _BINLOOP_MAX_BINS else "lanepacked"
+            "binloop" if b <= _BINLOOP_MAX_BINS or stat_channels > 2
+            else "lanepacked"
             for _, b in groups
         )
         b_pad = _round_up(max(b for _, b in groups), 128)
-        ceil = min(_KERNEL_CHUNK_CEIL, _KERNEL_BLOCK_ELEMS // (8 * b_pad))
+        ceil = min(
+            _KERNEL_CHUNK_CEIL, _KERNEL_BLOCK_ELEMS // (8 * b_pad),
+            _BINLOOP_STACK_LANES // stack_variants(stat_channels, lowp),
+        )
     else:
         return HistogramPlan(("scatter",) * len(groups), cap)
     return HistogramPlan(builders, min(cap, _pow2_floor(max(8, ceil))))

@@ -22,6 +22,15 @@ Design (TPU-first, static shapes throughout — SURVEY.md §7 hard-part 1):
 
 Leaf values are -G/(H+λ) (Newton step). For plain mean-target trees (random
 forest leaves) pass g = -target, h = 1: the leaf value becomes mean(target).
+
+The statistic a node holds has S channels: V value channels and the hessian
+/ weight. V is 1 for every boosted fit, every regression forest and the
+two-class forest (g, h); a K-class forest has V = K - 1, the (negated)
+indicators of classes 1 … K - 1, so that a node holds its K class counts
+(class 0's is W less the others), its impurity is the K-class Gini and its
+leaf the class distribution C_k / W, k = 0 … K - 1 (``GINI``). K = 2 is the
+(w·y, w) pair the forest always had, its leaf the class-1 share: one code
+for every K.
 """
 from __future__ import annotations
 
@@ -42,7 +51,7 @@ class Tree(NamedTuple):
 
     split_feat: jax.Array  # [depth, 2^depth] int32, -1 = leaf (route left)
     split_bin: jax.Array   # [depth, 2^depth] int32, go right when bin > split_bin
-    leaf_value: jax.Array  # [2^depth] float32
+    leaf_value: jax.Array  # [2^depth] float32; [2^depth, V] of V values a leaf
 
 
 class HistSlots(NamedTuple):
@@ -72,6 +81,12 @@ class HistSlots(NamedTuple):
     rounds_residual: jax.Array
 
 
+#: ``info_gain_norm`` of the Spark families (``_grow_tree_impl``): the
+#: variance of a real target, and the Gini impurity of a class label whose
+#: indicators are the fit's value channels
+VARIANCE = 2.0
+GINI = 4.0
+
 # The narrowest width a level's histograms are built at. At 32 slots the
 # bin-loop kernel's [T, nvar·M] operand is exactly one 128-lane tile
 # (nvar 4); below it the lanes only pad (on a v5e at 1M x 302 x 32 bins a
@@ -97,13 +112,15 @@ def _width_ladder(chunk_nodes: int) -> tuple[int, ...]:
 
 def _slot_layout(
     impl: str, n: int, k_fits: int, groups, max_depth: int,
-    axis_size: int = 1, sharded: bool = False,
+    axis_size: int = 1, sharded: bool = False, stat_channels: int = 2,
+    lowp: bool = False,
 ):
     """(cap, plan, ladder) of one tree fit, from what its trace can see:
     ``cap`` compact node slots a level can have live (``2^max_depth``, or
     the power of two that holds the GLOBAL rows where there are fewer),
     ``hist_pallas.histogram_plan``'s builders and chunk width for the
-    LOCAL rows ``n``, and the widths a level may be built at. The sharded
+    LOCAL rows ``n`` and the fit's ``stat_channels`` (bfloat16-exact where
+    ``lowp``), and the widths a level may be built at. The sharded
     path keeps the full width: its psums may not sit under a
     data-dependent branch."""
     from .hist_pallas import histogram_plan
@@ -116,14 +133,31 @@ def _slot_layout(
         while cap < n_global:
             cap <<= 1
         cap = min(cap, max_nodes)
-    plan = histogram_plan(impl, n, k_fits, groups, cap)
+    plan = histogram_plan(
+        impl, n, k_fits, groups, cap, stat_channels=stat_channels, lowp=lowp
+    )
     ladder = (plan.chunk_cap,) if sharded else _width_ladder(plan.chunk_cap)
     return cap, plan, ladder
+
+
+def _dispatch_layout(n, k_fits, groups, max_depth, lowp, impl, shards,
+                     stat_channels):
+    """(plan, ladder) of a fit as its program will be traced, from what a
+    ``tree/fit_dispatch`` span knows: the rows of the whole fit and the
+    data-axis size of the mesh it runs on (``shards``; None: one device)."""
+    axis_size = shards or 1
+    _, plan, ladder = _slot_layout(
+        impl or _resolved_impl(), -(-n // axis_size), k_fits, groups,
+        max_depth, axis_size, sharded=shards is not None,
+        stat_channels=stat_channels, lowp=lowp,
+    )
+    return plan, ladder
 
 
 def hist_tiles(
     n: int, k_fits: int, groups, max_depth: int, lowp: bool,
     impl: str | None = None, shards: int | None = None,
+    stat_channels: int = 2,
 ) -> str:
     """The ``hist_tiles`` attribute of ``tree/fit_dispatch``: for each rung
     of the fit's width ladder, ``slots:row_tile/feat_tile`` of the bin-loop
@@ -131,14 +165,13 @@ def hist_tiles(
     cells), so a trace reader can tell which table of
     ``hist_pallas.binloop_tiles`` a run used. ``groups``: the fit's
     ``(columns, bins)``; ``n``: the rows of the whole fit; ``shards``: the
-    data-axis size of the mesh a sharded fit runs on (None: one device).
-    ``none`` where that group takes another builder."""
+    data-axis size of the mesh a sharded fit runs on (None: one device);
+    ``stat_channels``: the fit's statistic channels (K for a K-class
+    forest). ``none`` where that group takes another builder."""
     from .hist_pallas import binloop_tiles
 
-    axis_size = shards or 1
-    _, plan, ladder = _slot_layout(
-        impl or _resolved_impl(), -(-n // axis_size), k_fits, groups,
-        max_depth, axis_size, sharded=shards is not None,
+    plan, ladder = _dispatch_layout(
+        n, k_fits, groups, max_depth, lowp, impl, shards, stat_channels
     )
     (f, b), builder = max(
         zip(groups, plan.builders), key=lambda gb: gb[0][0] * gb[0][1]
@@ -146,9 +179,29 @@ def hist_tiles(
     if builder != "binloop":
         return "none"
     return " ".join(
-        "{}:{}/{}".format(w, *binloop_tiles(f, w, b, lowp=lowp))
+        "{}:{}/{}".format(
+            w, *binloop_tiles(f, w, b, lowp=lowp, stat_channels=stat_channels)
+        )
         for w in ladder
     )
+
+
+def stat_channels_built(
+    n: int, k_fits: int, groups, max_depth: int, lowp: bool,
+    stat_channels: int, impl: str | None = None, shards: int | None = None,
+) -> int:
+    """The ``stat_channels_built`` attribute of ``tree/fit_dispatch``: the
+    statistic channels the bin-loop kernel's stacked operand has lanes for
+    at the narrowest rung of the fit's width ladder
+    (``hist_pallas.stat_channels_built``), from the shapes ``hist_tiles``
+    takes. Reckoned whichever builder the fit takes: it is what the
+    channel count costs on the TPU's kernel."""
+    from .hist_pallas import stat_channels_built as built
+
+    _, ladder = _dispatch_layout(
+        n, k_fits, groups, max_depth, lowp, impl, shards, stat_channels
+    )
+    return built(stat_channels, lowp, ladder[0])
 
 
 class HistSlotStats(_tm.LedgerCore):
@@ -166,8 +219,17 @@ class HistSlotStats(_tm.LedgerCore):
         "rounds_residual": "boostRoundsResidual",
     }
 
+    #: per ``tree/fit_dispatch``: the fit's statistic channels and those
+    #: its kernel operand has lanes for (``stat_channels_built``)
+    CHANNEL_KEYS = ("statChannels", "statChannelsBuilt")
+
     def __init__(self) -> None:
-        super().__init__(tuple(self.KEYS.values()))
+        super().__init__(tuple(self.KEYS.values()) + self.CHANNEL_KEYS)
+
+    def record_channels(self, channels: int, built: int) -> None:
+        with self._lock:
+            self._counts["statChannels"] += channels
+            self._counts["statChannelsBuilt"] += built
 
     def record(self, sums: dict) -> None:
         """``sums``: field of ``HistSlots`` -> its sum over one fit."""
@@ -516,7 +578,7 @@ def grow_tree_batched(
 
 def _grow_tree_impl(
     binned: jax.Array,     # [N_local, F] int32 codes, SHARED across fits
-    grad: jax.Array,       # [K, N_local] float32
+    grad: jax.Array,       # [K, N_local] float32, or [K, V, N_local]: V channels
     hess: jax.Array,       # [K, N_local] float32
     row_mask: jax.Array,   # [K, N_local] float32
     feat_mask: jax.Array,  # [K, F] float32
@@ -561,13 +623,25 @@ def _grow_tree_impl(
     finds the SAME splits as ungrouped (tie-break by original feature id
     preserved across the group merge).
 
-    ``info_gain_norm`` (static) chooses the stop rule. 0: a node splits when
-    its best gain ``bg`` (the formula above, a SUM over the node's rows) is
-    over ``min_info_gain`` — XGBoost's absolute ``gamma`` semantics. 4 (Gini
-    of a 0/1 target) or 2 (variance): Spark's rule — the impurity decrease
-    per row ``norm·bg/W`` (W the node's hessian sum, its weighted row count
-    in a forest) must reach ``min_info_gain`` and be positive. The arg-max
-    within a node is the same either way.
+    ``grad`` with V value channels (``[K, V, N]``) grows ONE tree a lane on
+    a vector statistic: every histogram has V + 1 channels, the gain is the
+    sum of the channels' gains and a leaf holds V values (``[2^depth, V]``;
+    V + 1 under ``GINI``: the class distribution). ``[K, N]`` is the V = 1
+    case and keeps its shapes.
+
+    ``info_gain_norm`` (static) chooses the impurity and the stop rule. 0:
+    a node splits when its best gain ``bg`` (the formula above, a SUM over
+    the node's rows) is over ``min_info_gain`` — XGBoost's absolute
+    ``gamma`` semantics. ``VARIANCE`` (2) or ``GINI`` (4): Spark's rule —
+    the impurity decrease per row ``2·bg/W`` (W the node's hessian sum, its
+    weighted row count in a forest) must reach ``min_info_gain`` and be
+    positive. Under ``GINI`` the value channels are the negated indicators
+    of classes 1 … V of a (V + 1)-class label: the Gini decrease is the sum
+    over ALL classes of the variance decrease of the class's indicator, and
+    class 0's indicator is 1 less the others', whose decrease is that of
+    their sum: ``bg`` adds that one term (at V = 1 it doubles ``bg``
+    exactly, the factor 4 = 2·2 this rule had for a 0/1 target). The
+    arg-max within a node is the same either way.
 
     ``node_subset`` (static; forests) is Spark's ``featureSubsetStrategy``
     count: every NODE searches ``node_subset`` distinct columns of the F,
@@ -579,12 +653,16 @@ def _grow_tree_impl(
     (``HistSlots.subset_*`` stay 0)."""
     from .hist_pallas import BUILDERS, default_impl
 
-    k_fits, n = grad.shape
+    k_fits, n = hess.shape
     f = binned.shape[1]
     b = num_bins
     max_nodes = 1 << max_depth
-    g = grad * row_mask
+    # [K, N], or [K, V, N]: the builders take either (hist_pallas)
+    g = grad * (row_mask if grad.ndim == 2 else row_mask[:, None, :])
     h = hess * row_mask
+    g_rows = [g] if g.ndim == 2 else [g[:, v] for v in range(g.shape[1])]
+    n_values = len(g_rows)
+    gini = info_gain_norm == GINI
     impl = hist_impl or default_impl()
 
     if feature_groups is not None:
@@ -638,6 +716,7 @@ def _grow_tree_impl(
     cap, plan, ladder = _slot_layout(
         impl, n, k_fits, [(gb_.shape[1], bb) for gb_, _, bb, _ in groups],
         max_depth, axis_size, sharded=axis_name is not None,
+        stat_channels=n_values + 1, lowp=lowp,
     )
     with jax.named_scope("tree/group_columns"):
         groups = [
@@ -662,7 +741,7 @@ def _grow_tree_impl(
                 )
                 nmask = (sel[..., None] == fid).any(axis=2)  # [K, M, Fg]
         with jax.named_scope("tree/histogram"):
-            # [K, M, Fg, Bg, 2] (grad, hess) sums of the group
+            # [K, M, Fg, Bg, V + 1] (value channels, hess) sums of the group
             hist = build(operand, loc, g, h, chunk_nodes, gb, lowp=lowp)
             if axis_name is not None:
                 # the Rabit-allreduce moment: per-shard partial histograms
@@ -674,16 +753,26 @@ def _grow_tree_impl(
         return best, None if nmask is None else nmask.sum(axis=2)
 
     def best_split(hist, gmask, nmask, gb, gidx, chunk_nodes):
-        hg, hh = hist[..., 0], hist[..., 1]  # [K, M, Fg, Bg]
-
-        gl = jnp.cumsum(hg, axis=3)[..., :-1]
+        hh = hist[..., n_values]  # [K, M, Fg, Bg]
         hl = jnp.cumsum(hh, axis=3)[..., :-1]
-        gt = hg.sum(axis=3, keepdims=True)
         ht = hh.sum(axis=3, keepdims=True)
-        gr = gt - gl
         hr = ht - hl
-        parent = (gt**2) / (ht + lam)
-        gain = 0.5 * (gl**2 / (hl + lam) + gr**2 / (hr + lam) - parent) - gam
+
+        def decrease(hg):
+            """GL²/(HL+λ) + GR²/(HR+λ) − G²/(H+λ) of one value channel."""
+            gl = jnp.cumsum(hg, axis=3)[..., :-1]
+            gt = hg.sum(axis=3, keepdims=True)
+            gr = gt - gl
+            parent = (gt**2) / (ht + lam)
+            return gl**2 / (hl + lam) + gr**2 / (hr + lam) - parent
+
+        total = decrease(hist[..., 0])
+        for v in range(1, n_values):
+            total = total + decrease(hist[..., v])
+        if gini:
+            # class 0: the decrease of the other classes' summed indicator
+            total = total + decrease(hist[..., :n_values].sum(axis=-1))
+        gain = 0.5 * total - gam
         valid = (
             (hl >= mcw)
             & (hr >= mcw)
@@ -756,7 +845,7 @@ def _grow_tree_impl(
                     bb = jnp.where(take, gbin, bb)
         with jax.named_scope("tree/split_search"):
             if info_gain_norm:
-                do_split = (bg > 0.0) & (info_gain_norm * bg / bw >= mig)
+                do_split = (bg > 0.0) & (2.0 * bg / bw >= mig)
             else:
                 do_split = bg > jnp.maximum(mig, 0.0)
             counts = (jnp.int32(0), jnp.int32(0))
@@ -781,15 +870,29 @@ def _grow_tree_impl(
 
     sentinel = jnp.int32(max_nodes)  # out-of-range → dropped by scatters
 
+    def leaf_values(sums_g, sum_h):
+        """-G/(H+λ) of each value channel from the leaves' [K, leaves]
+        sums: [K, leaves], or [K, leaves, V] at V > 1. Under ``GINI`` with
+        V > 1 the leaf is the whole class distribution, [K, leaves, V + 1]:
+        class 0's count is W less the others' (exact: they are integers),
+        divided like theirs, so that equal counts give equal shares and an
+        arg max can break ties by the lowest class. (A two-class leaf stays
+        its class-1 share: 1 - p is exact there.)"""
+        if axis_name is not None:
+            sums_g = [jax.lax.psum(sg, axis_name) for sg in sums_g]
+            sum_h = jax.lax.psum(sum_h, axis_name)
+        if gini and n_values > 1:
+            sums_g = [-(sum_h + sum(sums_g))] + sums_g
+        leaves = [-sg / (sum_h + vec(reg_lambda)[:, None]) for sg in sums_g]
+        return leaves[0] if len(leaves) == 1 else jnp.stack(leaves, axis=-1)
+
     if max_depth == 0:
         # root-only tree (legal Spark maxDepth=0): no splits, leaf = all rows
         with jax.named_scope("tree/leaf"):
-            leaf_g0 = (g).sum(axis=1, keepdims=True)
-            leaf_h0 = (h).sum(axis=1, keepdims=True)
-            if axis_name is not None:
-                leaf_g0 = jax.lax.psum(leaf_g0, axis_name)
-                leaf_h0 = jax.lax.psum(leaf_h0, axis_name)
-            leaf_value0 = -leaf_g0 / (leaf_h0 + vec(reg_lambda)[:, None])
+            leaf_value0 = leaf_values(
+                [gv.sum(axis=1, keepdims=True) for gv in g_rows],
+                h.sum(axis=1, keepdims=True),
+            )
         return Tree(
             split_feat=jnp.full((k_fits, 0, 1), -1, dtype=jnp.int32),
             split_bin=jnp.zeros((k_fits, 0, 1), dtype=jnp.int32),
@@ -1018,12 +1121,10 @@ def _grow_tree_impl(
     bins = jnp.swapaxes(bins_s, 0, 1)
 
     with jax.named_scope("tree/leaf"):
-        leaf_g = _segment_sum_small(g, node, max_nodes)
-        leaf_h = _segment_sum_small(h, node, max_nodes)
-        if axis_name is not None:
-            leaf_g = jax.lax.psum(leaf_g, axis_name)
-            leaf_h = jax.lax.psum(leaf_h, axis_name)
-        leaf_value = -leaf_g / (leaf_h + vec(reg_lambda)[:, None])
+        leaf_value = leaf_values(
+            [_segment_sum_small(gv, node, max_nodes) for gv in g_rows],
+            _segment_sum_small(h, node, max_nodes),
+        )
     tree = Tree(split_feat=feats, split_bin=bins, leaf_value=leaf_value)
     # `node` is each row's final leaf slot — boosting's margin update reuses
     # it (leaf_value lookup) instead of re-traversing the tree (measured
@@ -1031,8 +1132,35 @@ def _grow_tree_impl(
     return tree, node, slots_s
 
 
+def leaf_lookup(leaf_value: jax.Array, node: jax.Array) -> jax.Array:
+    """Each row's leaf value: ``leaf_value`` [K, leaves] at ``node`` [K, N]
+    -> [K, N]; with V value channels ([K, leaves, V]) -> [K, V, N] (rows
+    last: a trailing axis of V would pad to 128 lanes on the TPU)."""
+    if leaf_value.ndim == 2:
+        return _small_table_lookup(leaf_value, node)
+    return jnp.stack(
+        [
+            _small_table_lookup(leaf_value[..., v], node)
+            for v in range(leaf_value.shape[-1])
+        ],
+        axis=1,
+    )
+
+
+def leaf_channels(trees: Tree) -> list[Tree]:
+    """A tree stack whose leaves hold V value channels as V stacks of
+    scalar leaves over the same splits (one for a scalar-leaf stack): what
+    the traversals that sum one value a tree take (the host's C kernel,
+    the serve kernel)."""
+    lv = trees.leaf_value
+    if lv.ndim == trees.split_feat.ndim - 1:
+        return [trees]
+    return [trees._replace(leaf_value=lv[..., v]) for v in range(lv.shape[-1])]
+
+
 def predict_tree(binned: jax.Array, tree: Tree) -> jax.Array:
-    """Leaf value per row — lax.scan over the [depth, ...] level arrays
+    """Leaf value per row ([N]; [V, N] from leaves of V channels) —
+    lax.scan over the [depth, ...] level arrays
     (one shared gather body). An unrolled depth loop with level-sliced
     one-hot lookups grows the vmapped sweep programs ~depth×; whether its
     execution win pays for that is not re-measured on a local chip (see
@@ -1051,7 +1179,7 @@ def predict_tree(binned: jax.Array, tree: Tree) -> jax.Array:
         level, jnp.zeros(n, dtype=jnp.int32),
         (tree.split_feat, tree.split_bin),
     )
-    return _small_table_lookup(tree.leaf_value[None, :], node[None, :])[0]
+    return leaf_lookup(tree.leaf_value[None], node[None, :])[0]
 
 
 # --------------------------------------------------------------------------
@@ -1059,7 +1187,7 @@ def predict_tree(binned: jax.Array, tree: Tree) -> jax.Array:
 # --------------------------------------------------------------------------
 def fit_forest(
     binned: jax.Array,
-    target: jax.Array,      # [N] regression target (or one-vs-rest indicator)
+    target: jax.Array,      # [N] regression target, or class ids (num_classes)
     row_mask: jax.Array,    # [N]
     num_trees: int,
     max_depth: int,
@@ -1074,6 +1202,7 @@ def fit_forest(
     feature_groups=None,
     feature_subset: int | None = None,
     info_gain_norm: float = 2.0,
+    num_classes: int = 0,
 ) -> Tree:
     """Random forest of mean-target trees — the K=1 case of
     fit_forest_batched (Spark RandomForest parity: variance impurity ==
@@ -1086,7 +1215,7 @@ def fit_forest(
         min_instances=min_instances, min_info_gain=min_info_gain,
         seed=int(seed), bootstrap=bootstrap, lowp=lowp,
         feature_groups=feature_groups, feature_subset=feature_subset,
-        info_gain_norm=info_gain_norm,
+        info_gain_norm=info_gain_norm, num_classes=num_classes,
     )
     return jax.tree.map(lambda a: a[0], trees)
 
@@ -1108,7 +1237,8 @@ def sum_trees(per_tree: jax.Array) -> jax.Array:
 
 
 def predict_forest(binned: jax.Array, trees: Tree) -> jax.Array:
-    """Mean leaf value across the stacked forest -> [N]."""
+    """Mean leaf value across the stacked forest -> [N] ([V, N] from
+    leaves of V channels: a class forest's mean vote)."""
     preds = jax.vmap(lambda t: predict_tree(binned, t))(trees)  # [T, N]
     return sum_trees(preds) / preds.shape[0]
 
@@ -1396,8 +1526,9 @@ def sweep_forest_outputs(
     eta_v: jax.Array, base_v: jax.Array,
 ) -> jax.Array:
     """Forest mean-leaf outputs for a sweep stack: trees [K, T, ...] →
-    [K, N]. eta_v/base_v are accepted (and ignored) so both sweep entry
-    points share a call signature."""
+    [K, N] ([K, V, N] from leaves of V channels). eta_v/base_v are
+    accepted (and ignored) so both sweep entry points share a call
+    signature."""
     binned = bin_data(x, thresholds)
     return jax.vmap(lambda t: predict_forest(binned, t))(trees)
 
@@ -1438,7 +1569,7 @@ def _bag_masks(tkey, sub, col, row_mask, n, f, bootstrap):
     jax.jit,
     static_argnames=(
         "num_trees", "max_depth", "num_bins", "bootstrap", "lowp", "hist_impl",
-        "feature_subset", "info_gain_norm",
+        "feature_subset", "info_gain_norm", "num_classes",
     ),
 )
 def _forest_trees_scan(
@@ -1446,7 +1577,7 @@ def _forest_trees_scan(
     min_info_gain,
     feature_groups=None, max_depth_v=None, *,
     num_trees, max_depth, num_bins, bootstrap, lowp, hist_impl=None,
-    feature_subset, info_gain_norm,
+    feature_subset, info_gain_norm, num_classes=0,
 ) -> tuple[Tree, jax.Array, HistSlots]:
     """The whole bagged forest as ONE program: ``lax.scan`` over the
     per-tree PRNG keys with a single tree-growth body (the same shape as
@@ -1462,21 +1593,22 @@ def _forest_trees_scan(
     (``_grow_tree_impl``): every tree builds its histograms over every
     column, as the source's algorithm does.
 
-    Returns (Tree arrays [K, T, ...], training outputs [K, N], HistSlots
-    [T, depth]) — the outputs are each lane's mean-leaf prediction over
-    ALL rows, read from the grower's own final routing, so the CV sweep
-    needs no separate eval traversal program."""
+    ``num_classes`` (static): 0 for a real ``target`` (one value channel,
+    a leaf its mean); K >= 2 where ``target`` holds class ids 0 … K - 1:
+    the value channels are then the indicators of classes 1 … K - 1
+    (``forest_gradients``), so every lane grows ONE forest whose nodes
+    hold K class counts, whatever K.
+
+    Returns (Tree arrays [K, T, ...], training outputs [K, N] (over C > 2
+    classes [K, C, N]: each lane's mean class distribution), HistSlots
+    [T, depth]) — the outputs
+    are each lane's mean-leaf prediction over ALL rows, read from the
+    grower's own final routing, so the CV sweep needs no separate eval
+    traversal program."""
     k_fits, n = row_mask.shape
     f = binned.shape[1]
-    # target: [N] shared, or [K, N] per-lane (one-vs-rest class indicators
-    # ride the fit axis — the multiclass RF sweep trains every
-    # class × fold × grid-point forest in this one program)
-    target = jnp.asarray(target)
     with jax.named_scope("tree/gradients"):
-        if target.ndim == 1:
-            gb = jnp.broadcast_to(-target[None, :], (k_fits, n))
-        else:
-            gb = -target
+        gb = forest_gradients(jnp.asarray(target), k_fits, num_classes)
     ones = jnp.ones((k_fits, n), dtype=jnp.float32)
     mi_k = jnp.broadcast_to(
         jnp.asarray(min_instances, dtype=jnp.float32).reshape(-1), (k_fits,)
@@ -1505,20 +1637,37 @@ def _forest_trees_scan(
         # this tree's prediction for EVERY row from the grower's own final
         # routing (leaf lookup — no re-traversal)
         with jax.named_scope("tree/outputs"):
-            pred_t = _small_table_lookup(tree.leaf_value, node)
+            pred_t = leaf_lookup(tree.leaf_value, node)
         return None, (tree, pred_t, slots)
 
     _, (trees, preds, slots) = jax.lax.scan(body, None, tkeys)  # [T, K, ...]
     with jax.named_scope("tree/outputs"):
-        outs = preds.mean(axis=0)  # [K, N] forest mean-leaf outputs
+        outs = preds.mean(axis=0)  # [K, (V,) N] forest mean-leaf outputs
     return (
         jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), trees), outs, slots
     )
 
 
+def forest_gradients(target: jax.Array, k_fits: int, num_classes: int):
+    """A forest's ``grad`` for ``_grow_tree_impl`` (h is 1) from the [N]
+    ``target`` every lane shares: -target, [K, N], where it is a real
+    number (``num_classes`` 0); where it holds class ids, the negated
+    indicators of classes 1 … K - 1, [K, K - 1, N] ([K, N] at two classes:
+    one value channel keeps its shapes)."""
+    n = target.shape[0]
+    if not num_classes:
+        return jnp.broadcast_to(-target[None, :], (k_fits, n))
+    ind = jnp.stack(
+        [-(target == c).astype(jnp.float32) for c in range(1, num_classes)]
+    )  # [K - 1, N]
+    if ind.shape[0] == 1:
+        return jnp.broadcast_to(ind, (k_fits, n))
+    return jnp.broadcast_to(ind[None], (k_fits, *ind.shape))
+
+
 def fit_forest_batched(
     binned: jax.Array,      # [N, F] shared
-    target: jax.Array,      # [N] shared regression target / indicator
+    target: jax.Array,      # [N] shared regression target / class ids
     row_mask: jax.Array,    # [K, N] per-fit row masks (folds × resamples)
     num_trees: int,
     max_depth: int,
@@ -1537,6 +1686,7 @@ def fit_forest_batched(
     return_slots: bool = False,
     feature_subset: int | None = None,
     info_gain_norm: float = 2.0,
+    num_classes: int = 0,
 ) -> Tree:
     """K random forests batched over the fit axis, the whole bagged forest
     as ONE scan-over-trees program (_forest_trees_scan — one tree-growth
@@ -1551,8 +1701,10 @@ def fit_forest_batched(
     may split on (Spark's featureSubsetStrategy, resolved by the caller:
     ``models/gbdt.py``); ``colsample_rate`` is a per-LANE Bernoulli column
     mask a tree (XGBoost's colsample_bytree), and the node's subset
-    multiplies it. ``info_gain_norm``: 4 for the Gini impurity of a 0/1
-    target, 2 for the variance (see ``_grow_tree_impl``).
+    multiplies it. ``info_gain_norm``: ``GINI`` for a class label
+    (``num_classes`` >= 2, ``target`` the class ids: one forest whose
+    nodes hold the K class counts), ``VARIANCE`` for a real target (see
+    ``_grow_tree_impl``, ``_forest_trees_scan``).
 
     With ``mesh`` set, rows shard over the mesh's data axis and each level's
     histogram psums over it (grows the same trees as the unsharded path —
@@ -1584,11 +1736,6 @@ def fit_forest_batched(
             raise NotImplementedError(
                 "per-lane depth caps are single-device only (the sweep path)"
             )
-        if getattr(target, "ndim", 1) != 1:
-            raise NotImplementedError(
-                "per-lane targets are single-device only (the multiclass "
-                "sweep path); shard multiclass one class at a time"
-            )
         key = jax.random.PRNGKey(seed)
         tkeys = jax.random.split(key, num_trees)
         trees, outs = _fit_forest_batched_sharded(
@@ -1597,6 +1744,7 @@ def fit_forest_batched(
             num_trees=num_trees, max_depth=max_depth, num_bins=num_bins,
             bootstrap=bootstrap, lowp=lowp, feature_groups=feature_groups,
             feature_subset=feature_subset, info_gain_norm=info_gain_norm,
+            num_classes=num_classes,
         )
         # the sharded fit has pulled its results, and recorded its slots
         return _fit_result(trees, outs, None, return_outputs, return_slots)
@@ -1609,6 +1757,7 @@ def fit_forest_batched(
         dict(num_trees=num_trees,
              feature_subset=int(feature_subset),
              info_gain_norm=float(info_gain_norm),
+             num_classes=int(num_classes),
              max_depth=max_depth, num_bins=num_bins, bootstrap=bootstrap,
              # lowp is only sound when target values are bf16-exact
              # (classification indicators); regression keeps f32
@@ -1944,7 +2093,7 @@ def _sharded_grow_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
 @lru_cache(maxsize=None)
 def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
                                 has_groups=False, feature_subset=None,
-                                info_gain_norm=2.0):
+                                info_gain_norm=2.0, num_classes=0):
     """jit(shard_map(scan-over-trees)): the sharded counterpart of
     _forest_trees_scan. Per-tree masks are drawn OUTSIDE (global-row
     semantics) and enter sharded on the row axis; the scan carries the
@@ -1963,7 +2112,7 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
         k_fits = rmasks.shape[1]
         n_local = binned.shape[0]
         with jax.named_scope("tree/gradients"):
-            gb = jnp.broadcast_to(-target[None, :], (k_fits, n_local))
+            gb = forest_gradients(target, k_fits, num_classes)
         ones = jnp.ones((k_fits, n_local), dtype=jnp.float32)
 
         def one_tree(_, xs):
@@ -1980,14 +2129,14 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
                 node_subset=feature_subset, node_key=nk,
             )
             with jax.named_scope("tree/outputs"):
-                pred_t = _small_table_lookup(tree.leaf_value, node)
+                pred_t = leaf_lookup(tree.leaf_value, node)
             return None, (tree, pred_t, slots)
 
         _, (trees, preds, slots) = jax.lax.scan(
             one_tree, None, (rmasks, fmasks, nkeys)
         )
         with jax.named_scope("tree/outputs"):
-            outs = preds.mean(axis=0)  # [K, n_local]
+            outs = preds.mean(axis=0)  # [K, (V,) n_local]
         return (
             jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), trees), outs,
             slots,
@@ -2007,7 +2156,9 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
         ) + ((rep, rep) if has_groups else ()),
         out_specs=(
             Tree(split_feat=rep, split_bin=rep, leaf_value=rep),
-            P(None, DATA_AXIS),
+            # the rows are the outputs' last axis
+            P(None, DATA_AXIS) if num_classes <= 2
+            else P(None, None, DATA_AXIS),
             HistSlots(*(rep for _ in HistSlots._fields)),
         ),
         check_vma=False,
@@ -2018,7 +2169,7 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
 def _fit_forest_batched_sharded(
     mesh, binned, target, row_mask, tkeys, sub, col, mi, mg,
     num_trees, max_depth, num_bins, bootstrap, lowp, feature_groups=None,
-    feature_subset=None, info_gain_norm=2.0,
+    feature_subset=None, info_gain_norm=2.0, num_classes=0,
 ) -> tuple[Tree, np.ndarray]:
     from ..parallel.mesh import DATA_AXIS
 
@@ -2040,6 +2191,7 @@ def _fit_forest_batched_sharded(
         mesh, max_depth, num_bins, _resolved_impl(), lowp,
         has_groups=feature_groups is not None,
         feature_subset=feature_subset, info_gain_norm=info_gain_norm,
+        num_classes=num_classes,
     )
     grp_args = tuple(feature_groups) if feature_groups is not None else ()
     nkeys = jax.vmap(_node_key)(tkeys)
@@ -2047,7 +2199,7 @@ def _fit_forest_batched_sharded(
                               mi_k, mg_k, *grp_args)
     # pull replicated trees to HOST once
     trees, outs = await_outputs((trees, outs), hist_slots=slots)
-    return trees, outs[:, :n]
+    return trees, outs[..., :n]
 
 
 @lru_cache(maxsize=None)

@@ -431,19 +431,14 @@ class ModelSelector(PredictorEstimator):
                     # fit program itself — grab them BEFORE detach frees the
                     # stack, so train evaluation needs no re-predict
                     stack = getattr(best_model, "_sweep_stack", None)
-                    if stack is not None and stack.get("outputs") is not None:
-                        lanes = getattr(best_model, "_sweep_lanes", None)
-                        if lanes is not None:
-                            refit_raw = (
-                                "multi", await_stack_outputs(stack)[lanes]
-                            )
-                        elif hasattr(best_model, "predictions_from_sweep"):
-                            refit_raw = (
-                                "single",
-                                await_stack_outputs(stack)[
-                                    best_model._sweep_lane
-                                ],
-                            )
+                    if (
+                        stack is not None
+                        and stack.get("outputs") is not None
+                        and hasattr(best_model, "predictions_from_sweep")
+                    ):
+                        refit_raw = await_stack_outputs(stack)[
+                            best_model._sweep_lane
+                        ]
                     # free the sweep stacks: keep only the winner's own lane
                     detach = getattr(best_model, "detach_from_sweep", None)
                     if detach is not None:
@@ -460,14 +455,14 @@ class ModelSelector(PredictorEstimator):
                     best_model = final_est.fit_arrays(xt, yt, final_mask)
 
             if refit_raw is not None:
-                kind, raw = refit_raw
-                if kind == "multi":
-                    pred, prob, _ = best_model.predictions_from_sweep_multi(raw)
-                else:
-                    pred, prob, _ = best_model.predictions_from_sweep(raw)
+                pred, prob, _ = best_model.predictions_from_sweep(refit_raw)
             else:
                 pred, prob, _ = best_model.predict_arrays(xt)
-            with _tspans.span("selector/evaluate", lanes=1, rows=len(yt)):
+            with _tspans.span(
+                "selector/evaluate", lanes=1, rows=len(yt),
+                classes=0 if prob is None else int(np.shape(prob)[1]),
+                bytes=0 if refit_raw is None else int(refit_raw.nbytes),
+            ):
                 train_metrics = self.evaluator.evaluate_arrays(yt, pred, prob)
                 extra_train = {
                     ev.name: ev.evaluate_arrays(yt, pred, prob)
