@@ -237,10 +237,12 @@ def test_chunked_levels_count_their_chunks(monkeypatch):
     s = {k: np.asarray(v) for k, v in slots._asdict().items()}
     assert (s["built"] == 32 * s["chunks_run"]).all()
     assert s["chunks_run"].max() > 1 and s["chunks_skipped"].max() >= 1
-    assert ((s["chunks_run"] + s["chunks_skipped"])[s["built"] > 0] == 8).all()
-    np.testing.assert_array_equal(s["subset_pairs"],
-                                  s["live"] * codes.shape[1])
-    np.testing.assert_array_equal(s["subset_admitted"], s["live"] * n_sub)
+    # a chunk's 32 slots hold sibling PAIRS: 4 chunks serve the 256 nodes
+    assert ((s["chunks_run"] + s["chunks_skipped"])[s["built"] > 0] == 4).all()
+    nodes = s["nodes_built"] + s["nodes_derived"]  # one lane: its live nodes
+    assert (s["live"][:, 1:] * 2 == nodes[:, 1:]).all()
+    np.testing.assert_array_equal(s["subset_pairs"], nodes * codes.shape[1])
+    np.testing.assert_array_equal(s["subset_admitted"], nodes * n_sub)
 
 
 def test_sharded_forest_draws_the_same_subsets():

@@ -237,6 +237,86 @@ def test_width_ladder_grows_the_scatter_trees_on_device():
     np.testing.assert_array_equal(tree.leaf_value, ref.leaf_value)
 
 
+@pytest.mark.parametrize("values", ["half", "real"])
+@pytest.mark.parametrize("depth", [10, 12])
+def test_sibling_subtraction_grows_the_direct_trees_on_device(
+    depth, values, monkeypatch
+):
+    """The twin of tests/test_hist_sibling_subtraction.py on the chip: at
+    65,536 rows the bin-loop kernel builds one child of every sibling pair
+    (256 pair slots a chunk) and the other is its parent less it. With
+    g = +-0.5, h = 0.25 every sum is exact and the trees are bit for bit
+    those the scatter histograms grow with EVERY node built; with real
+    targets (against the kernel's own direct builds) a derived histogram
+    differs in its last bits, so a near tie may turn: nearly every node
+    agrees, and the counts say how many."""
+    import functools
+
+    from transmogrifai_tpu.models import hist_pallas as HP
+    from transmogrifai_tpu.models import trees as TR
+
+    n, f, bins, k = 65536, 24, 32, 2
+    rng = np.random.default_rng(depth)
+    x = rng.normal(size=(n, f)).astype(np.float32)
+    t = x[:, 0] + x[:, 1] * x[:, 2] + np.sin(3 * x[:, 3]) + rng.normal(size=n)
+    binned = TR.bin_data(
+        jnp.asarray(x), jnp.asarray(TR.quantile_thresholds(x, bins))
+    )
+    if values == "half":
+        grad = np.where(t > np.median(t), -0.5, 0.5).astype(np.float32)
+        hess = np.full(n, 0.25, np.float32)
+    else:
+        grad, hess = (-t).astype(np.float32), np.ones(n, np.float32)
+    args = (
+        binned, jnp.asarray(np.stack([grad] * k)),
+        jnp.asarray(np.stack([hess] * k)), jnp.ones((k, n), jnp.float32),
+        jnp.ones((k, f), jnp.float32),
+    )
+
+    def grow(impl):
+        tree, _node, slots = jax.jit(functools.partial(
+            TR._grow_tree_impl, max_depth=depth, num_bins=bins,
+            reg_lambda=1.0, gamma=0.0,
+            min_child_weight=np.asarray([1.0, 10.0], np.float32),
+            hist_impl=impl,
+        ))(*args)
+        return jax.tree.map(np.asarray, (tree, slots))
+
+    tree, slots = grow("pallas")
+    assert slots.nodes_derived.sum() > 0
+    nodes = slots.nodes_built + slots.nodes_derived
+    np.testing.assert_array_equal(slots.nodes_derived[1:] * 2, nodes[1:])
+    # the deep levels run 256-slot chunks of PAIRS, half as many as nodes
+    assert slots.chunks_run.max() > 1 or depth == 10
+    assert (slots.built[slots.chunks_run > 1] % 256 == 0).all()
+    assert slots.built.max() >= 256 and (slots.live <= slots.built).all()
+    monkeypatch.setattr(HP, "_PARENT_HIST_BUDGET_ELEMS", 0)
+    # real values: the kernel rounds its inputs to a (hi, lo) bfloat16 pair,
+    # so only the kernel's own direct builds tell what the subtraction did
+    ref, ref_slots = grow("scatter" if values == "half" else "pallas")
+    assert ref_slots.nodes_derived.sum() == 0
+    if values == "half":
+        np.testing.assert_array_equal(ref_slots.nodes_built, nodes)
+        np.testing.assert_array_equal(tree.split_feat, ref.split_feat)
+        np.testing.assert_array_equal(tree.split_bin, ref.split_bin)
+        np.testing.assert_array_equal(tree.leaf_value, ref.leaf_value)
+    else:
+        same = (tree.split_feat == ref.split_feat) & (
+            tree.split_bin == ref.split_bin
+        )
+        split = ref.split_feat >= 0
+        print(
+            f"depth {depth}: {int((~same & split).sum())} of "
+            f"{int(split.sum())} split nodes differ; nodes a level "
+            f"{nodes.tolist()}; built {slots.built.tolist()}"
+        )
+        assert same[split].mean() > 0.99
+        # the top of the tree, where a node holds thousands of rows
+        np.testing.assert_array_equal(
+            tree.split_feat[:, :6], ref.split_feat[:, :6]
+        )
+
+
 @pytest.mark.parametrize("cols", [55, 302, 500])
 @pytest.mark.parametrize("lowp", [False, True])
 @pytest.mark.parametrize("slots", [8, 32, 64, 128, 256])

@@ -1,8 +1,10 @@
 """The histogram width ladder (models/trees.py): a tree level's histograms
-are built at the smallest rung that holds its live node slots. Trees grown
-with the ladder must equal trees grown at the full chunk width, on every
-histogram implementation, and the counts the fit hands back must say what
-was built."""
+are built at the smallest rung that holds its live node slots — its sibling
+PAIRS where the fit keeps the level above's histograms and builds one child
+of each pair (tests/test_hist_sibling_subtraction.py), its nodes where it
+builds every node. Trees grown with the ladder must equal trees grown at
+the full chunk width, on every histogram implementation, and the counts the
+fit hands back must say what was built."""
 import functools
 
 import numpy as np
@@ -55,6 +57,15 @@ def _grow(binned, grad, hess, row_mask, impl):
     return jax.tree.map(np.asarray, (tree, slots))
 
 
+@pytest.fixture(params=["pairs", "nodes"])
+def slots_hold(request, monkeypatch):
+    """What a build's slots hold: sibling pairs (the fit keeps its parents'
+    histograms), or nodes (no room for them: every node is built)."""
+    if request.param == "nodes":
+        monkeypatch.setattr(HP, "_PARENT_HIST_BUDGET_ELEMS", 0)
+    return request.param
+
+
 @pytest.fixture()
 def interpret_kernels(monkeypatch):
     """The bin-loop builder in interpret mode, where the tree grower looks
@@ -92,7 +103,10 @@ def test_ladder_grows_the_full_width_trees(
 
     assert (full_slots.built[full_slots.built > 0] >= 128).all()
     if kind == "balanced" and values == "real":
-        assert len(set(slots.built.tolist())) >= 3, "three rungs engage"
+        # (the scatter builder's chunk is 512 slots and a level of this
+        # depth has 256 pairs at most: two of its rungs)
+        rungs = 2 if impl == "scatter" else 3
+        assert len(set(slots.built.tolist())) >= rungs, "the rungs engage"
     np.testing.assert_array_equal(slots.live, full_slots.live)
     assert (slots.built <= full_slots.built).all()
     assert (slots.live <= slots.built).all()
@@ -112,11 +126,12 @@ def test_ladder_grows_the_full_width_trees(
         )
 
 
-def test_slot_counts_of_a_hand_checkable_tree():
+def test_slot_counts_of_a_hand_checkable_tree(slots_hold):
     """Four equal groups of rows told apart by two 2-bin columns: the root
     splits, both children split, then nothing is left to gain. Level 0
-    (1 live slot) and level 1 (2) are built at the floor, level 2 (4 live,
-    no split) too, and the early exit skips the rest."""
+    (1 live slot) and level 1 (2 nodes: one pair) are built at the floor,
+    level 2 (4 nodes in 2 pairs, no split) too, and the early exit skips
+    the rest."""
     n, depth = 4096, 7
     rng = np.random.default_rng(5)
     a = rng.integers(0, 2, size=n)
@@ -135,19 +150,29 @@ def test_slot_counts_of_a_hand_checkable_tree():
     # cap = 2**7 = 128 slots in one chunk: rungs 32, 64, 128
     assert TR._width_ladder(128) == (32, 64, 128)
     floor = TR._HIST_WIDTH_FLOOR
+    live = {"pairs": [1, 1, 2], "nodes": [1, 2, 4]}[slots_hold]
+    derived = {"pairs": [0, 1, 2], "nodes": [0, 0, 0]}[slots_hold]
     np.testing.assert_array_equal(
-        np.asarray(slots.live), [1, 2, 4, 0, 0, 0, 0]
+        np.asarray(slots.live), live + [0, 0, 0, 0]
     )
     np.testing.assert_array_equal(
         np.asarray(slots.built), [floor, floor, floor, 0, 0, 0, 0]
     )
+    np.testing.assert_array_equal(
+        np.asarray(slots.nodes_derived), derived + [0, 0, 0, 0]
+    )
+    np.testing.assert_array_equal(
+        np.asarray(slots.nodes_built + slots.nodes_derived),
+        [1, 2, 4, 0, 0, 0, 0],
+    )
     assert (np.asarray(tree.split_feat)[0, 2] == -1).all()
 
 
-def test_a_full_level_is_built_at_its_own_width():
+def test_a_full_level_is_built_at_its_own_width(slots_hold):
     """Every row its own leaf candidate: 256 distinct codes on one column
-    keep every level full, so level d has 2**d live slots and is built at
-    the smallest rung that holds them; the last level needs both chunks."""
+    keep every level full, so level d has 2**d live nodes and is built at
+    the smallest rung that holds them (or their 2**(d-1) pairs); built by
+    node, the last level needs both chunks, by pair one."""
     n, depth, bins = 4096, 9, 512
     code = (np.arange(n) % 512).astype(np.int32)
     binned = jnp.asarray(code[:, None])
@@ -162,11 +187,23 @@ def test_a_full_level_is_built_at_its_own_width():
         jnp.ones((1, n), jnp.float32), jnp.ones((1, 1), jnp.float32),
     )
     # the GEMM caps a chunk at 128 slots: rungs 32, 64, 128; cap 512
+    nodes = [1, 2, 4, 8, 16, 32, 64, 128, 256]
+    if slots_hold == "pairs":
+        live = [1, 1, 2, 4, 8, 16, 32, 64, 128]
+        built = [32, 32, 32, 32, 32, 32, 32, 64, 128]
+        # (the widest rung IS the chunk: a level of 65-128 slots runs one
+        # chunk of the loop's and leaves the others out)
+        runs, skipped = [1] * 9, [0] * 8 + [1]
+    else:
+        live = nodes
+        built = [32, 32, 32, 32, 32, 32, 64, 128, 256]
+        runs, skipped = [1] * 8 + [2], [0] * 7 + [3, 2]
+    np.testing.assert_array_equal(np.asarray(slots.live), live)
+    np.testing.assert_array_equal(np.asarray(slots.built), built)
+    np.testing.assert_array_equal(np.asarray(slots.chunks_run), runs)
+    np.testing.assert_array_equal(np.asarray(slots.chunks_skipped), skipped)
     np.testing.assert_array_equal(
-        np.asarray(slots.live), [1, 2, 4, 8, 16, 32, 64, 128, 256]
-    )
-    np.testing.assert_array_equal(
-        np.asarray(slots.built), [32, 32, 32, 32, 32, 32, 64, 128, 256]
+        np.asarray(slots.nodes_built + slots.nodes_derived), nodes
     )
 
 
@@ -183,9 +220,11 @@ def test_ladder_is_a_function_of_the_chunk_width(chunk, ladder):
     assert len(ladder) <= 4 and ladder[-1] == chunk
 
 
-def test_small_programs_lower_to_one_level_body():
+def test_small_programs_lower_to_one_level_body(slots_hold):
     """A chunk at or under the floor has one rung: no branch over widths in
-    the program (the level scan holds one histogram build per group)."""
+    the program (the level scan holds one histogram build per group; where
+    a level's slots hold pairs, one more for the level that must build
+    every node, in chunks, because a child of some split holds no row)."""
     n = 512
     binned, t = _table("balanced", n)
     args = (
@@ -201,7 +240,8 @@ def test_small_programs_lower_to_one_level_body():
         return text.count("stablehlo.scatter")
 
     # depth 5: 32 slots, the floor; depth 7: 128 slots, three rungs
-    assert scatters(7) == 3 * scatters(5)
+    bodies = {"nodes": (1, 3), "pairs": (1 + 1, 3 + 1)}[slots_hold]
+    assert scatters(7) * bodies[0] == scatters(5) * bodies[1]
 
 
 def test_await_outputs_lands_the_counts_on_span_and_ledger():
@@ -226,15 +266,25 @@ def test_await_outputs_lands_the_counts_on_span_and_ledger():
     live = int(np.asarray(slots.live).sum())
     built = int(np.asarray(slots.built).sum())
     assert 0 < live <= built
+    nodes_built = int(np.asarray(slots.nodes_built).sum())
+    nodes_derived = int(np.asarray(slots.nodes_derived).sum())
+    # both lanes' roots are built; every node under them is one of a pair
+    assert nodes_built - nodes_derived == 2 * 2 and nodes_derived > 0
     recs = [
         r["args"] for r in tspans.snapshot_events()
         if r["name"] == "tree/await_outputs"
     ]
     assert [a.get("slots_live") for a in recs] == [live, None]
     assert recs[0]["slots_built"] == built
+    assert recs[0]["nodes_built"] == nodes_built
+    assert recs[0]["nodes_derived"] == nodes_derived
     now = TR.hist_slot_stats().snapshot()
     assert now["histSlotsLive"] - before["histSlotsLive"] == live
     assert now["histSlotsBuilt"] - before["histSlotsBuilt"] == built
+    assert now["histNodesBuilt"] - before["histNodesBuilt"] == nodes_built
+    assert (
+        now["histNodesDerived"] - before["histNodesDerived"] == nodes_derived
+    )
     text = texport.render_prometheus()
     assert f"tptpu_tree_hist_slots_built {now['histSlotsBuilt']}" in text
 

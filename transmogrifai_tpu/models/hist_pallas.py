@@ -756,11 +756,25 @@ _GEMM_CHUNK_CEIL = 128
 # one-hot temporaries (the kernels shrink their row tile as M grows)
 _KERNEL_BLOCK_ELEMS = 1 << 19
 _KERNEL_CHUNK_CEIL = 256
+# [K, parents, Σ F·B, S] histogram elements a fit may KEEP from one level to
+# the next so that a split's two children share one build (the lighter child
+# is built, the heavier is parent − sibling: ``trees._grow_tree_impl``). Its
+# own literal, not ``_HIST_BUDGET_ELEMS``: that one bounds a single build's
+# output (128 MB), this one a buffer that lives across the level scan, twice
+# while a level writes its own beside its parents'. Sized on the cells'
+# depth-12 programs at 4 lanes x 1,024 parents x 9,774 cells: two channels
+# 0.8e8 elements (317 MB), the seven-class forest's seven 2.8e8 (1.11 GB,
+# beside 4.5 GiB of temporaries on a 16 GB chip); 2^29 (2 GiB of float32)
+# leaves that one twice its size and refuses 8 such lanes.
+_PARENT_HIST_BUDGET_ELEMS = 1 << 29
 
 
 class HistogramPlan(NamedTuple):
     builders: tuple[str, ...]  # key of BUILDERS, one per feature group
     chunk_cap: int             # most node slots one build may hold (2^j)
+    # nodes whose histograms the fit keeps for the next level's subtraction
+    # (0: none, every node is built directly)
+    parent_slots: int = 0
 
 
 def _pow2_floor(x: int) -> int:
@@ -770,6 +784,7 @@ def _pow2_floor(x: int) -> int:
 def histogram_plan(
     impl: str, n: int, k_fits: int, groups: Sequence[tuple[int, int]],
     max_slots: int, stat_channels: int = 2, lowp: bool = False,
+    max_parents: int = 0,
 ) -> HistogramPlan:
     """How a fit builds its histograms, from what its trace can see:
     ``impl`` ('pallas' = choose for the TPU; 'gemm' / 'scatter' force one
@@ -785,8 +800,17 @@ def histogram_plan(
     whose two accumulators are its layout: a fit of more channels is
     planned onto the bin-loop kernel whatever its bins. The kernels' chunk
     also holds the stacked operand to ``_BINLOOP_STACK_LANES`` (two
-    channels: 256 slots as before; 7 at ``lowp``: 128)."""
-    hist_width = sum(f * b for f, b in groups) * stat_channels // 2
+    channels: 256 slots as before; 7 at ``lowp``: 128).
+
+    ``max_parents``: the most nodes a level that is not the last can hold
+    (0 from a fit with no such level, or one that may not subtract: the
+    sharded path). The fit keeps that many histograms a lane from level to
+    level (``parent_slots``) if they fit ``_PARENT_HIST_BUDGET_ELEMS``,
+    whichever builder it takes; else none, and it builds every node."""
+    cells = sum(f * b for f, b in groups)
+    kept = k_fits * max_parents * cells * stat_channels
+    parent_slots = max_parents if kept <= _PARENT_HIST_BUDGET_ELEMS else 0
+    hist_width = cells * stat_channels // 2
     budget = max(_HIST_BUDGET_ELEMS // k_fits, _HIST_BUDGET_FLOOR)
     cap = min(_pow2_floor(max(1, budget // max(hist_width, 1))), max_slots)
     if impl == "gemm" or (impl == "pallas" and n <= _GEMM_MAX_ROWS):
@@ -804,8 +828,10 @@ def histogram_plan(
             _BINLOOP_STACK_LANES // stack_variants(stat_channels, lowp),
         )
     else:
-        return HistogramPlan(("scatter",) * len(groups), cap)
-    return HistogramPlan(builders, min(cap, _pow2_floor(max(8, ceil))))
+        return HistogramPlan(("scatter",) * len(groups), cap, parent_slots)
+    return HistogramPlan(
+        builders, min(cap, _pow2_floor(max(8, ceil))), parent_slots
+    )
 
 
 def default_impl() -> str:

@@ -56,13 +56,18 @@ class Tree(NamedTuple):
 
 class HistSlots(NamedTuple):
     """What a fit counted of its own work, per tree and level (``[...,
-    depth]`` int32). ``live``: the level's live compact node slots (the
-    widest lane's); ``built``: the slots its histogram builds were made at
-    (the rung of ``_width_ladder`` taken, or ``chunk_nodes`` per chunk that
-    ran; 0 for a level the early exit skipped), so ``live / built`` is the
-    occupancy of the histogram kernel's node axis. ``chunks_run`` /
-    ``chunks_skipped``: the builds made, and the chunks the occupancy
-    branch left out. ``subset_admitted`` / ``subset_pairs``: over every
+    depth]`` int32). ``live``: the slots of the level's histogram builds
+    that held a node (the widest lane's): its live compact nodes where
+    every node is built, its sibling PAIRS at a level that builds one child
+    of each and subtracts for the other; ``built``: the slots those builds
+    were made at (the rung of ``_width_ladder`` taken, or ``chunk_nodes``
+    per chunk that ran; 0 for a level the early exit skipped), so ``live /
+    built`` is the occupancy of the histogram kernel's node axis.
+    ``chunks_run`` / ``chunks_skipped``: the builds made, and the chunks
+    the occupancy branch left out. ``nodes_built`` / ``nodes_derived``:
+    over every lane, the live nodes whose histogram came from a build, and
+    from ``parent - sibling`` (their sum is the level's live nodes).
+    ``subset_admitted`` / ``subset_pairs``: over every
     lane's live nodes, the (node, feature) pairs the split search admitted
     and all there were (both 0 from a fit that draws no node subsets:
     boosting). ``rounds_label`` / ``rounds_residual`` (per round, not per
@@ -79,6 +84,44 @@ class HistSlots(NamedTuple):
     subset_pairs: jax.Array
     rounds_label: jax.Array
     rounds_residual: jax.Array
+    nodes_built: jax.Array
+    nodes_derived: jax.Array
+
+
+class _Siblings(NamedTuple):
+    """What a level of ``_grow_tree_impl`` hands the next for its sibling
+    subtraction. The next level's pair j is the j-th node (in compact slot
+    order) that split here; its children get the compact slots 2j, 2j + 1."""
+
+    takes_part: jax.Array   # [K, N] bool: the row is in the child to build
+    n_pairs: jax.Array      # [K] int32: nodes that split, a lane
+    parent_slot: jax.Array  # [K, pairs] int32: pair -> its parent's slot here
+    built_right: jax.Array  # [K, pairs] bool: the child to build is the right
+    hists: tuple            # per feature group [K·keep, Fg·Bg·S] float32
+
+
+def sibling_rows(built, parent, built_right, slot_live):
+    """The histograms of a level's nodes from those of ONE child of each
+    sibling pair. Rows are (lane, slot), a row one node's histogram:
+    ``built`` [K·P, X] the P pairs' built children, ``parent`` [K·P, X]
+    their parents, ``built_right`` [K, P] whether the built child is the
+    right one, ``slot_live`` [K, 2P] the compact slots a node lives in.
+    Pair j's children are the compact slots 2j and 2j + 1: [K·2P, X], the
+    other child its parent less the one built, in float32. A slot no node
+    lives in (the root's sibling; past a lane's last pair, whose parent row
+    is whatever slot 0 held) is empty."""
+    other = parent - built
+    right = built_right.reshape(-1, 1)
+    rows = jnp.stack(
+        [jnp.where(right, other, built), jnp.where(right, built, other)],
+        axis=1,
+    ).reshape(2 * built.shape[0], -1)
+    return jnp.where(slot_live.reshape(-1, 1), rows, 0.0)
+
+
+# Bit of the routing table's threshold entries that says which child of the
+# split the next level builds (a bin code stays far under it)
+_BUILT_BIT = 16
 
 
 #: ``info_gain_norm`` of the Spark families (``_grow_tree_impl``): the
@@ -120,9 +163,10 @@ def _slot_layout(
     the power of two that holds the GLOBAL rows where there are fewer),
     ``hist_pallas.histogram_plan``'s builders and chunk width for the
     LOCAL rows ``n`` and the fit's ``stat_channels`` (bfloat16-exact where
-    ``lowp``), and the widths a level may be built at. The sharded
-    path keeps the full width: its psums may not sit under a
-    data-dependent branch."""
+    ``lowp``), and the widths a level may be built at; ``plan.parent_slots``
+    are the nodes a lane whose histograms a level keeps for the next one's
+    sibling subtraction. The sharded path keeps the full width and builds
+    every node: its psums may not sit under a data-dependent branch."""
     from .hist_pallas import histogram_plan
 
     max_nodes = 1 << max_depth
@@ -133,8 +177,13 @@ def _slot_layout(
         while cap < n_global:
             cap <<= 1
         cap = min(cap, max_nodes)
+    # the most nodes a level that is not the last can hold: what the next
+    # level's sibling subtraction can ask for (the sharded path builds every
+    # node: its psums stay at one width, outside every branch)
+    max_parents = 0 if sharded or max_depth < 2 else min(cap, max_nodes // 4)
     plan = histogram_plan(
-        impl, n, k_fits, groups, cap, stat_channels=stat_channels, lowp=lowp
+        impl, n, k_fits, groups, cap, stat_channels=stat_channels, lowp=lowp,
+        max_parents=max_parents,
     )
     ladder = (plan.chunk_cap,) if sharded else _width_ladder(plan.chunk_cap)
     return cap, plan, ladder
@@ -217,6 +266,7 @@ class HistSlotStats(_tm.LedgerCore):
         "subset_pairs": "nodeSubsetPairs",
         "rounds_label": "boostRoundsLabel",
         "rounds_residual": "boostRoundsResidual",
+        "nodes_built": "histNodesBuilt", "nodes_derived": "histNodesDerived",
     }
 
     #: per ``tree/fit_dispatch``: the fit's statistic channels and those
@@ -257,7 +307,8 @@ def await_outputs(value, hist_slots: HistSlots | None = None):
     on the host already passes through. ``hist_slots`` are the counts the
     same fit program returned: once its outputs have landed they are there
     too, and their sums go onto the span (``slots_live``, ``slots_built``,
-    ``chunks_run``, ``chunks_skipped``; from a fit that counts node
+    ``chunks_run``, ``chunks_skipped``, ``nodes_built``, ``nodes_derived``;
+    from a fit that counts node
     subsets ``subset_admitted``, ``subset_pairs``; from one that boosts in
     first order ``boost_rounds_label``, ``boost_rounds_residual``) and the
     ``tree`` ledger."""
@@ -279,6 +330,8 @@ def await_outputs(value, hist_slots: HistSlots | None = None):
                 slots_live=sums["live"], slots_built=sums["built"],
                 chunks_run=sums["chunks_run"],
                 chunks_skipped=sums["chunks_skipped"],
+                nodes_built=sums["nodes_built"],
+                nodes_derived=sums["nodes_derived"],
             )
             if sums["subset_pairs"]:
                 sp.attrs.update(
@@ -718,6 +771,9 @@ def _grow_tree_impl(
         max_depth, axis_size, sharded=axis_name is not None,
         stat_channels=n_values + 1, lowp=lowp,
     )
+    # nodes a lane whose histograms a level keeps for the next (0: none)
+    keep = plan.parent_slots
+    assert b <= 1 << _BUILT_BIT, "a bin code shares its table entry's low bits"
     with jax.named_scope("tree/group_columns"):
         groups = [
             (gb_, gm, bb, gi, BUILDERS[name].build,
@@ -726,12 +782,34 @@ def _grow_tree_impl(
         ]
     draw_subsets = node_subset is not None and node_subset < f
 
+    # A level's histograms as rows, one a (lane, node): [K·M, S·B·F] from
+    # the builders' [K, M, F, B, S], and back. The orders are the TPU
+    # kernel's, whose output lies [S][K][B][F][M] in memory under that
+    # logical shape: each way is ONE transpose of the node axis against the
+    # rest, between shapes whose last two axes are both wide (a trailing
+    # axis of S = 2 or B = 2 pads 64-fold to the chip's (8, 128) tiles).
+    def node_rows(hist):
+        return jnp.transpose(hist, (0, 1, 4, 3, 2)).reshape(
+            hist.shape[0] * hist.shape[1], -1
+        )
+
+    def node_hists(rows, nodes, fg, bg):
+        by_cell = jnp.transpose(rows.reshape(k_fits, nodes, -1), (0, 2, 1))
+        return jnp.transpose(
+            by_cell.reshape(k_fits, n_values + 1, bg, fg, nodes),
+            (0, 4, 3, 2, 1),
+        )
+
     def group_stats(gbinned, gmask, gb, gidx, build, operand, loc,
-                    chunk_nodes, sel):
-        """(gain, orig feat, bin, node weight) of the best split per compact
-        slot for ONE feature group; ``sel`` [K, M, node_subset] are the
-        slots' admissible columns (None: all). Also the (slot, feature)
-        pairs of this group that ``sel`` admits, [K, M] (None: all)."""
+                    chunk_nodes, sel, pairs):
+        """(gain, orig feat, bin, right child lighter) of the best split per
+        compact slot for ONE feature group; ``sel`` [K, M, node_subset] are
+        the slots' admissible columns (None: all). Also the (slot, feature)
+        pairs of this group that ``sel`` admits, [K, M] (None: all), and the
+        slots' histograms [K·M, Fg·Bg·S] for the next level to subtract
+        from. ``pairs`` (None: every slot is built): ``loc`` numbers sibling
+        PAIRS, M/2 of them, and holds the rows of one child of each; the
+        other child is its parent less the one built."""
         nmask = None
         if sel is not None:
             with jax.named_scope("tree/node_subset"):
@@ -742,15 +820,29 @@ def _grow_tree_impl(
                 nmask = (sel[..., None] == fid).any(axis=2)  # [K, M, Fg]
         with jax.named_scope("tree/histogram"):
             # [K, M, Fg, Bg, V + 1] (value channels, hess) sums of the group
-            hist = build(operand, loc, g, h, chunk_nodes, gb, lowp=lowp)
-            if axis_name is not None:
-                # the Rabit-allreduce moment: per-shard partial histograms
-                # reduce over ICI; everything after sees the global
-                # histogram
-                hist = jax.lax.psum(hist, axis_name)
+            if pairs is None:
+                hist = build(operand, loc, g, h, chunk_nodes, gb, lowp=lowp)
+                if axis_name is not None:
+                    # the Rabit-allreduce moment: per-shard partial
+                    # histograms reduce over ICI; everything after sees the
+                    # global histogram
+                    hist = jax.lax.psum(hist, axis_name)
+            else:
+                built_right, parent, slot_live = pairs
+                one = build(
+                    operand, loc, g, h, chunk_nodes // 2, gb, lowp=lowp
+                )
+                rows = sibling_rows(
+                    node_rows(one), parent, built_right, slot_live
+                )
+                hist = node_hists(rows, chunk_nodes, gbinned.shape[1], gb)
         with jax.named_scope("tree/split_search"):
             best = best_split(hist, gmask, nmask, gb, gidx, chunk_nodes)
-        return best, None if nmask is None else nmask.sum(axis=2)
+        if not keep:
+            rows = None
+        elif pairs is None:
+            rows = node_rows(hist)
+        return best, None if nmask is None else nmask.sum(axis=2), rows
 
     def best_split(hist, gmask, nmask, gb, gidx, chunk_nodes):
         hh = hist[..., n_values]  # [K, M, Fg, Bg]
@@ -791,7 +883,16 @@ def _grow_tree_impl(
             best_feat = gidx[best_feat].astype(jnp.int32)
         # every row of a node lies in one bin of each feature: any
         # feature's total is the node's weight
-        return best_gain, best_feat, best_bin, ht[:, :, 0, 0]
+        # the hessian sums of the two children at the split taken: the
+        # LIGHTER child is the one the next level builds (ties: the left)
+        def at_best(a):
+            return jnp.take_along_axis(
+                a.reshape(a.shape[0], chunk_nodes, -1), best[..., None],
+                axis=2,
+            )[..., 0]
+
+        right_lighter = at_best(hr) < at_best(hl)
+        return best_gain, best_feat, best_bin, ht[:, :, 0, 0], right_lighter
 
     def node_subsets(at, c0, chunk_nodes):
         """[K, M, node_subset] int32: the admissible columns of the nodes
@@ -817,32 +918,91 @@ def _grow_tree_impl(
 
             return jax.vmap(jax.vmap(draw))(heap)
 
-    def chunk_stats(local, c0, chunk_nodes, at):
+    def build_slots(local, c0, chunk_nodes, sib):
+        """[K, N] int32: the slot of the build over the compact node slots
+        [c0, c0 + chunk_nodes) each row goes to, -1 for a row that takes no
+        part. Every node is built (``sib`` None): the row's node, from 0.
+        A subtracting level: the row's sibling PAIR (nodes 2j and 2j + 1
+        are pair j), from 0 at c0 / 2, for the rows of the child the level
+        above chose to build."""
+        with jax.named_scope("tree/partition"):
+            if sib is None:
+                part = (local >= c0) & (local < c0 + chunk_nodes)
+                return jnp.where(part, local - c0, -1)
+            pair, p0 = local >> 1, c0 // 2
+            part = sib.takes_part & (pair >= p0) & (
+                pair < p0 + chunk_nodes // 2
+            )
+            return jnp.where(part, pair - p0, -1)
+
+    def chunk_stats(loc, c0, chunk_nodes, at, sib, hists):
         """Best (feat, bin) per compact slot in [c0, c0 + chunk_nodes),
         merged across feature groups (tie-break: lowest original feature
-        id — matches the single-group argmax order), and the (live node,
-        feature) pairs admitted and possible there."""
-        with jax.named_scope("tree/partition"):
-            active = (local >= c0) & (local < c0 + chunk_nodes)
-            loc = jnp.where(active, local - c0, -1)  # [K, N]
+        id — matches the single-group argmax order), whether the right
+        child of that split is the lighter, the (live node, feature)
+        pairs admitted and possible there, and ``hists`` (per group
+        [K·keep, Fg·Bg·S]: the level's histograms so far) with these
+        slots' written in. ``loc``: ``build_slots``' of the same range."""
+        halves = [None] * len(groups)
+        # dense numbering: a lane's live slots are those below its live count
+        slot_live = (
+            c0 + jnp.arange(chunk_nodes, dtype=jnp.int32)
+        ) < at[0].sum(axis=1, dtype=jnp.int32)[:, None]
+        if sib is not None:
+            def of_chunk(a):
+                return jax.lax.dynamic_slice_in_dim(
+                    a, c0 // 2, chunk_nodes // 2, axis=1
+                )
+
+            # rows of the kept histograms ([K·keep, Fg·Bg·S], lane-major)
+            parent_row = (
+                jnp.arange(k_fits, dtype=jnp.int32)[:, None] * keep
+                + of_chunk(sib.parent_slot)
+            ).reshape(-1)
+            with jax.named_scope("tree/histogram"):
+                halves = [
+                    (
+                        of_chunk(sib.built_right),
+                        jnp.take(kept, parent_row, axis=0, mode="clip"),
+                        slot_live,
+                    )
+                    for kept in sib.hists
+                ]
         sel = node_subsets(at, c0, chunk_nodes) if draw_subsets else None
-        bg, bf, bb, bw = None, None, None, None
+        bg, bf, bb, bw, br = None, None, None, None, None
         admitted = None
-        for gbinned, gmask, grp_b, gidx, build, operand in groups:
-            (gg, gf, gbin, gw), adm = group_stats(
+        new_hists = []
+        for grp, half, kept in zip(
+            groups, halves, hists or [None] * len(groups)
+        ):
+            gbinned, gmask, grp_b, gidx, build, operand = grp
+            (gg, gf, gbin, gw, gr), adm, hist = group_stats(
                 gbinned, gmask, grp_b, gidx, build, operand, loc,
-                chunk_nodes, sel,
+                chunk_nodes, sel, half,
             )
+            if kept is not None:
+                # compact slots [c0, c0 + chunk) of this level: the next
+                # one's parents. A level's nodes past ``keep`` are the last
+                # level's, which nobody subtracts from (the update clamps
+                # there, onto slots never read again)
+                with jax.named_scope("tree/histogram"):
+                    kept = jax.lax.dynamic_update_slice_in_dim(
+                        kept.reshape(k_fits, keep, -1),
+                        hist.reshape(k_fits, chunk_nodes, -1)[:, :keep],
+                        c0, axis=1,
+                    ).reshape(kept.shape)
+                new_hists.append(kept)
             if adm is not None:
                 admitted = adm if admitted is None else admitted + adm
             if bg is None:
-                bg, bf, bb, bw = gg, gf, gbin, gw
+                bg, bf, bb, bw, br = gg, gf, gbin, gw, gr
             else:
                 with jax.named_scope("tree/split_search"):
                     take = (gg > bg) | ((gg == bg) & (gf < bf))
                     bg = jnp.where(take, gg, bg)
                     bf = jnp.where(take, gf, bf)
                     bb = jnp.where(take, gbin, bb)
+                    br = jnp.where(take, gr, br)
         with jax.named_scope("tree/split_search"):
             if info_gain_norm:
                 do_split = (bg > 0.0) & (2.0 * bg / bw >= mig)
@@ -850,12 +1010,6 @@ def _grow_tree_impl(
                 do_split = bg > jnp.maximum(mig, 0.0)
             counts = (jnp.int32(0), jnp.int32(0))
             if node_subset is not None:
-                # dense numbering: a lane's live slots are those below its
-                # live count
-                n_live_k = at[0].sum(axis=1, dtype=jnp.int32)
-                slot_live = (
-                    c0 + jnp.arange(chunk_nodes, dtype=jnp.int32)
-                ) < n_live_k[:, None]
                 pairs = slot_live.sum(dtype=jnp.int32) * f
                 counts = (
                     pairs if admitted is None else
@@ -865,8 +1019,10 @@ def _grow_tree_impl(
             return (
                 jnp.where(do_split, bf, -1),
                 jnp.where(do_split, bb, 0),
+                do_split & br,
                 counts,
-            )  # [K, chunk] each, then two scalars
+                tuple(new_hists),
+            )  # [K, chunk] x 3, two scalars, the kept histograms
 
     sentinel = jnp.int32(max_nodes)  # out-of-range → dropped by scatters
 
@@ -910,15 +1066,37 @@ def _grow_tree_impl(
     #
     # A level with few live slots does not pay for a whole chunk: its
     # histograms are built and searched at the smallest rung of `ladder`
-    # that holds them (live_level below). A build costs about in
-    # proportion to its width (the kernel's one-hot operand and its dots
-    # are [T, nvar·width]: 0.53 s at 256 slots, 0.27 at 128, 0.18 at 64,
-    # 0.13 at 32 on a v5e at 1M x 302 x 32 bins, hist_pallas.binloop_tiles),
-    # and in a depth-10 fit eight of the eleven builds have 128 live slots
-    # or fewer. The sharded path keeps the full width (_slot_layout).
+    # that holds them (live_level below). A build costs by its WIDTH, not
+    # by the rows a slot holds (every build passes over all rows against a
+    # [T, nvar·width] one-hot operand: 0.52 s at 256 slots, 0.26 at 128,
+    # 0.17 at 64, 0.13 at 32 on a v5e at 1M x 302 x 32 bins,
+    # hist_pallas.binloop_tiles). The sharded path keeps the full width
+    # (_slot_layout).
+    #
+    # So a level past the root builds HALF its nodes. A level's nodes come
+    # in sibling pairs whose parent's histogram the level above held, and
+    # hist(parent) = hist(left) + hist(right) cell for cell: one child of
+    # each pair is built, at slot = the pair's number, and the other is the
+    # parent less it, in float32 (XGBoost's ``hist`` updater and LightGBM
+    # do the same). A level of L nodes asks the ladder for L/2 slots, and
+    # 256 pair slots serve 512 nodes. The level above chooses the child:
+    # the one with the smaller hessian sum at the split taken (ties: the
+    # left). That is a rule of numerics, not of speed: the derived child
+    # inherits the absolute rounding error of two builds, which stays
+    # within a small factor of its own only if it is the heavier one.
+    # Where every sum is exact in float32 (integer weights, ±0.5 and 0.25)
+    # the trees are bit for bit those of direct builds. The fit keeps the
+    # level's histograms for this (`keep` nodes a lane, a feature group
+    # each, [K·keep, Fg·Bg·S]: a row is a node, so a pair's parent is one
+    # row gather) where hist_pallas.histogram_plan finds room
+    # for them; else (keep 0, and on the sharded path) every node is built.
+    # The root is pair 0's left child with no parent to subtract from.
     n_nodes = cap
     chunk_nodes = plan.chunk_cap
     num_chunks = (n_nodes + chunk_nodes - 1) // chunk_nodes
+    # a subtracting level's chunks hold `chunk_nodes` PAIRS
+    num_pair_chunks = (n_nodes + 2 * chunk_nodes - 1) // (2 * chunk_nodes)
+    pair_slots = num_pair_chunks * chunk_nodes
 
     def compact_local(hist_node):
         """Dense live-slot numbering via occupancy + cumsum rank. Slot =
@@ -953,7 +1131,7 @@ def _grow_tree_impl(
         # occupancy skip drops the dead bulk of deep levels; `node` keeps
         # the full routing chain (dead rows continue left) so leaf
         # assignment is unchanged.
-        node, active, alive = carry
+        node, active, alive, sib = carry
         with jax.named_scope("tree/partition"):
             hist_node = jnp.where(active, node, sentinel)
             (live, rank), local = compact_local(hist_node)
@@ -964,83 +1142,134 @@ def _grow_tree_impl(
 
         # live compact slots at this level, the widest lane's: slots are
         # numbered densely from 0, so every live one is below this count
-        n_live = live.sum(axis=1, dtype=jnp.int32).max()
+        n_live_k = live.sum(axis=1, dtype=jnp.int32)
+        n_live = n_live_k.max()
 
         at = (live, rank, level_idx)
         zero2 = (jnp.int32(0), jnp.int32(0))
+        hists0 = sib.hists if keep else ()
 
-        def chunk_loop():
+        def chunk_loop(sib_):
+            """The level in chunks of ``chunk_nodes`` slots a build: nodes,
+            or (``sib_``) sibling pairs, two nodes each."""
+            width = chunk_nodes if sib_ is None else 2 * chunk_nodes
+            chunks = num_chunks if sib_ is None else num_pair_chunks
+
             def chunk_body(ci, fb):
-                feats_a, bins_a, built, (adm, prs) = fb
-                c0 = ci * chunk_nodes
+                feats_a, bins_a, right_a, built, (adm, prs), hists = fb
+                c0 = ci * width
+                loc = build_slots(local, c0, width, sib_)
                 if axis_name is None:
-                    occupied = (
-                        (local >= c0) & (local < c0 + chunk_nodes)
-                    ).any()
-                    cf, cb, (ca, cp) = jax.lax.cond(
+                    occupied = (loc >= 0).any()
+                    cf, cb, cr, (ca, cp), hists = jax.lax.cond(
                         occupied,
-                        lambda: chunk_stats(local, c0, chunk_nodes, at),
+                        lambda: chunk_stats(loc, c0, width, at, sib_, hists),
                         lambda: (
-                            jnp.full(
-                                (k_fits, chunk_nodes), -1, dtype=jnp.int32
-                            ),
-                            jnp.zeros(
-                                (k_fits, chunk_nodes), dtype=jnp.int32
-                            ),
+                            jnp.full((k_fits, width), -1, dtype=jnp.int32),
+                            jnp.zeros((k_fits, width), dtype=jnp.int32),
+                            jnp.zeros((k_fits, width), dtype=bool),
                             zero2,
+                            hists,
                         ),
                     )
                     built = built + jnp.where(occupied, chunk_nodes, 0)
                 else:
                     # the sharded path always computes — its psums can't
                     # sit under a data-dependent cond
-                    cf, cb, (ca, cp) = chunk_stats(
-                        local, c0, chunk_nodes, at
+                    cf, cb, cr, (ca, cp), hists = chunk_stats(
+                        loc, c0, width, at, sib_, hists
                     )
                     built = built + chunk_nodes
                 return (
                     jax.lax.dynamic_update_slice(feats_a, cf, (0, c0)),
                     jax.lax.dynamic_update_slice(bins_a, cb, (0, c0)),
+                    jax.lax.dynamic_update_slice(right_a, cr, (0, c0)),
                     built,
                     (adm + ca, prs + cp),
+                    hists,
                 )
 
-            feats_a0 = jnp.full(
-                (k_fits, num_chunks * chunk_nodes), -1, dtype=jnp.int32
-            )
-            bins_a0 = jnp.zeros(
-                (k_fits, num_chunks * chunk_nodes), dtype=jnp.int32
-            )
-            feats_a, bins_a, built, counts = jax.lax.fori_loop(
-                0, num_chunks, chunk_body,
-                (feats_a0, bins_a0, jnp.int32(0), zero2),
-            )
-            return feats_a[:, :n_nodes], bins_a[:, :n_nodes], built, counts
-
-        def one_build(width):
-            """The whole level in ONE build at ``width`` slots (a rung that
-            holds every live slot), padded to the [K, n_nodes] the scan
-            carries."""
             def run():
-                cf, cb, counts = chunk_stats(local, 0, width, at)
-                pad = ((0, 0), (0, n_nodes - width))
+                slots = (k_fits, chunks * width)
+                feats_a, bins_a, right_a, built, counts, hists = (
+                    jax.lax.fori_loop(
+                        0, chunks, chunk_body,
+                        (
+                            jnp.full(slots, -1, dtype=jnp.int32),
+                            jnp.zeros(slots, dtype=jnp.int32),
+                            jnp.zeros(slots, dtype=bool),
+                            jnp.int32(0), zero2, hists0,
+                        ),
+                    )
+                )
                 return (
-                    jnp.pad(cf, pad, constant_values=-1),
-                    jnp.pad(cb, pad),
-                    jnp.int32(width),
-                    counts,
+                    feats_a[:, :n_nodes], bins_a[:, :n_nodes],
+                    right_a[:, :n_nodes], built, counts, hists,
                 )
 
             return run
 
-        def live_level():
-            if len(ladder) == 1:
-                return chunk_loop()
-            narrow = ladder[:-1]
-            rung = sum((n_live > w).astype(jnp.int32) for w in narrow)
+        def one_build(width, sib_):
+            """The whole level in ONE build at ``width`` slots (a rung that
+            holds every live node, or (``sib_``) every sibling pair),
+            brought to the [K, n_nodes] the scan carries."""
+            nodes = width if sib_ is None else 2 * width
+
+            def fit(a, fill):
+                return jnp.pad(
+                    a[:, :n_nodes],
+                    ((0, 0), (0, max(n_nodes - nodes, 0))),
+                    constant_values=fill,
+                )
+
+            def run():
+                loc = build_slots(local, 0, nodes, sib_)
+                cf, cb, cr, counts, hists = chunk_stats(
+                    loc, 0, nodes, at, sib_, hists0
+                )
+                return (
+                    fit(cf, -1), fit(cb, 0), fit(cr, False),
+                    jnp.int32(width), counts, hists,
+                )
+
+            return run
+
+        def live_level(sib_, narrow, count):
+            """The level at the smallest of the ``narrow`` rungs that holds
+            ``count`` slots, else in chunks."""
+            if not narrow:
+                return chunk_loop(sib_)()
+            rung = sum((count > w).astype(jnp.int32) for w in narrow)
             return jax.lax.switch(
-                rung, [one_build(w) for w in narrow] + [chunk_loop]
+                rung,
+                [one_build(w, sib_) for w in narrow] + [chunk_loop(sib_)],
             )
+
+        if keep:
+            # Pair j's children sit in the compact slots 2j and 2j + 1 iff
+            # every child of a split is live, i.e. holds a row. It does
+            # wherever ``min_child_weight`` > 0 (both children of a valid
+            # split hold hessian). Where it does not (a weightless child),
+            # the lane has fewer live nodes than twice its splits, and the
+            # level builds every node, in chunks: a path for soundness,
+            # not for speed.
+            subtract = (
+                (n_live_k == 2 * sib.n_pairs) | (level_idx == 0)
+            ).all()
+            n_built_k = jnp.where(subtract, sib.n_pairs, n_live_k)
+
+            def level():
+                return jax.lax.cond(
+                    subtract,
+                    lambda: live_level(sib, ladder[:-1], sib.n_pairs.max()),
+                    lambda: live_level(None, (), n_live),
+                )
+        else:
+            subtract = jnp.asarray(False)
+            n_built_k = n_live_k
+
+            def level():
+                return live_level(None, ladder[:-1], n_live)
 
         # ---- early level exit: no-split is hereditary, so once a level
         # produces zero splits every deeper level is all-leaves — skip the
@@ -1048,16 +1277,18 @@ def _grow_tree_impl(
         # (replicated-predicate collectives under shard_map are not worth
         # the coupling).
         if axis_name is not None:
-            feats_c, bins_c, built, counts = live_level()
+            feats_c, bins_c, right_c, built, counts, hists = level()
         else:
-            feats_c, bins_c, built, counts = jax.lax.cond(
+            feats_c, bins_c, right_c, built, counts, hists = jax.lax.cond(
                 alive,
-                live_level,
+                level,
                 lambda: (
                     jnp.full((k_fits, n_nodes), -1, dtype=jnp.int32),
                     jnp.zeros((k_fits, n_nodes), dtype=jnp.int32),
+                    jnp.zeros((k_fits, n_nodes), dtype=bool),
                     jnp.int32(0),
                     zero2,
+                    hists0,
                 ),
             )
         # builds made and chunks the occupancy branch left out: a rung is
@@ -1066,7 +1297,11 @@ def _grow_tree_impl(
         runs = jnp.where(
             chunked, built // chunk_nodes, (built > 0).astype(jnp.int32)
         )
-        skipped = jnp.where(chunked, num_chunks - runs, 0)
+        # (a chunk of pairs covers two chunks of nodes)
+        skipped = jnp.where(
+            chunked,
+            jnp.where(subtract, num_pair_chunks, num_chunks) - runs, 0,
+        )
         if max_depth_v is not None:
             # per-lane depth cap: a lane past its depth emits no splits
             # (identical trees to a program compiled at that lane's depth —
@@ -1098,22 +1333,75 @@ def _grow_tree_impl(
             # cheaper)
             slot = jnp.clip(local, 0, n_nodes - 1)
             row_feat = _small_table_lookup(feats_c, slot)  # [K, N]
-            row_thr = _small_table_lookup(bins_c, slot)
+            if keep:
+                # which child the next level builds rides in the table the
+                # threshold is looked up from (no gather of its own)
+                row_thr = _small_table_lookup(
+                    bins_c + (right_c.astype(jnp.int32) << _BUILT_BIT), slot
+                )
+                row_right = row_thr >> _BUILT_BIT
+                row_thr = row_thr & ((1 << _BUILT_BIT) - 1)
+            else:
+                row_thr = _small_table_lookup(bins_c, slot)
             code = _row_feature_select(binned, row_feat)
             go_right = active & (row_feat >= 0) & (code > row_thr)
             node = node * 2 + go_right.astype(jnp.int32)
             active = active & (row_feat >= 0)
-        return (node, active, alive), (
+            if keep:
+                # the next level's pairs: the nodes that split, numbered
+                # densely in slot order; pair j's parent is the j-th of
+                # them (only a level that is not the last has a next, and
+                # its nodes sit in the first `keep` slots)
+                splits = feats_c >= 0
+                split = splits[:, :keep]
+                split_i = split.astype(jnp.int32)
+                order = jnp.cumsum(split_i, axis=1) - split_i
+                is_parent = split[:, None, :] & (
+                    order[:, None, :]
+                    == jnp.arange(pair_slots, dtype=jnp.int32)[:, None]
+                )  # [K, pair_slots, keep]
+                sib = _Siblings(
+                    takes_part=active & (go_right == (row_right > 0)),
+                    n_pairs=splits.sum(axis=1, dtype=jnp.int32),
+                    parent_slot=jnp.where(
+                        is_parent, jnp.arange(keep, dtype=jnp.int32), 0
+                    ).sum(axis=2),
+                    built_right=(
+                        is_parent & right_c[:, None, :keep]
+                    ).any(axis=2),
+                    hists=hists,
+                )
+        return (node, active, alive, sib), (
             feats_d, bins_d,
-            HistSlots(n_live, built, runs, skipped, *counts, *zero2),
+            HistSlots(
+                n_built_k.max(), built, runs, skipped, *counts, *zero2,
+                n_built_k.sum(), (n_live_k - n_built_k).sum(),
+            ),
         )
 
-    (node, active, _), (feats_s, bins_s, slots_s) = jax.lax.scan(
+    sib0 = None
+    if keep:
+        # the root: pair 0's left child, every row taking part
+        sib0 = _Siblings(
+            takes_part=jnp.ones((k_fits, n), dtype=bool),
+            n_pairs=jnp.ones((k_fits,), dtype=jnp.int32),
+            parent_slot=jnp.zeros((k_fits, pair_slots), dtype=jnp.int32),
+            built_right=jnp.zeros((k_fits, pair_slots), dtype=bool),
+            hists=tuple(
+                jnp.zeros(
+                    (k_fits * keep, gb_.shape[1] * bb * (n_values + 1)),
+                    dtype=jnp.float32,
+                )
+                for gb_, _, bb, *_ in groups
+            ),
+        )
+    (node, active, _, _), (feats_s, bins_s, slots_s) = jax.lax.scan(
         level_body,
         (
             jnp.zeros((k_fits, n), dtype=jnp.int32),
             jnp.ones((k_fits, n), dtype=bool),
             jnp.asarray(True),
+            sib0,
         ),
         jnp.arange(max_depth, dtype=jnp.int32),
     )
