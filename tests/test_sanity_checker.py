@@ -295,3 +295,78 @@ def test_correlation_exclusion_hashed_text(rng):
     # default NoExclusion catches it
     est2 = SanityChecker(remove_bad_features=True).set_input(lbl, vec)
     assert est2.fit(ds).indices_to_keep == [1]
+
+
+# ------------------------------ spans -----------------------------------------
+def test_fit_is_broken_down_into_five_spans_under_its_train_fit(rng):
+    """``Workflow.train()`` opens ``train/fit`` [stage=SanityChecker]; the
+    checker's fit opens its five parts directly under it, on one thread, in
+    order, with their attributes, and leaves little of the fit to itself."""
+    from transmogrifai_tpu.features import from_dataset
+    from transmogrifai_tpu.ops import transmogrify
+    from transmogrifai_tpu.telemetry import spans as tspans
+    from transmogrifai_tpu.types.columns import column_from_values
+    from transmogrifai_tpu.workflow.workflow import Workflow
+
+    n = 300
+    ds = Dataset.of({
+        "label": column_from_values(T.RealNN, rng.integers(0, 2, n).tolist()),
+        "age": column_from_values(T.Real, rng.normal(40.0, 9.0, n).tolist()),
+        "city": column_from_values(
+            T.PickList, [["ankara", "bern", "cairo"][i % 3] for i in range(n)]
+        ),
+    })
+    label, predictors = from_dataset(ds, response="label")
+    checked = label.sanity_check(transmogrify(predictors), remove_bad_features=True)
+    tspans.reset_for_tests()
+    Workflow().set_result_features(checked).set_input_dataset(ds).train()
+    recs = tspans.snapshot_events()
+    (fit,) = [r for r in recs if r["name"] == "train/fit"
+              and r["args"]["stage"] == "SanityChecker"]
+    parts = sorted(
+        (r for r in recs if r["name"].startswith("sanity/")),
+        key=lambda r: r["ts"],
+    )
+    assert [r["name"] for r in parts] == [
+        "sanity/matrix", "sanity/stats", "sanity/categorical",
+        "sanity/rules", "sanity/report",
+    ]
+    assert all(r["parent"] == fit["id"] and r["trace"] == fit["trace"]
+               for r in parts)
+    args = {r["name"]: r.get("args", {}) for r in parts}
+    d = args["sanity/matrix"]["cols"]
+    assert args["sanity/matrix"] == {
+        "rows_in": n, "rows": n, "cols": d, "bytes": n * d * 8,
+    }
+    assert args["sanity/stats"] == {"kind": "pearson"}
+    # the city pick list is one group of indicator columns (three cities,
+    # other, null)
+    assert args["sanity/categorical"]["groups"] >= 1
+    assert args["sanity/categorical"]["cols"] >= 3
+    assert "sanity/rules" not in {k for k, v in args.items() if v}
+    covered = sum(r["dur"] for r in parts)
+    assert covered <= fit["dur"] and fit["dur"] - covered < 0.5 * fit["dur"]
+
+
+def test_sampled_fit_states_rows_in_and_rows(rng):
+    """The matrix span says how many rows came in and how many the
+    statistics read after the seeded sample."""
+    from transmogrifai_tpu.telemetry import spans as tspans
+
+    n = 2000
+    y = rng.integers(0, 2, n).astype(float)
+    x = np.stack([y * 0.3 + rng.normal(size=n), rng.normal(size=n)], axis=1)
+    ds = _vec_ds(x, [_col("f1"), _col("f2")], y)
+    lbl, vec = _checker_inputs()
+    est = SanityChecker(
+        sample_upper_limit=1500, correlation_type="spearman"
+    ).set_input(lbl, vec)
+    tspans.reset_for_tests()
+    est.fit(ds)
+    args = {r["name"]: r.get("args", {}) for r in tspans.snapshot_events()}
+    assert args["sanity/matrix"] == {
+        "rows_in": 2000, "rows": 1500, "cols": 2, "bytes": 1500 * 2 * 8,
+    }
+    assert args["sanity/stats"] == {"kind": "spearman"}
+    # a dense vector with no pick-list groups: nothing categorical
+    assert args["sanity/categorical"] == {"groups": 0, "cols": 0}

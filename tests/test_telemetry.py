@@ -134,6 +134,60 @@ def test_span_nesting_builds_serve_trace_tree():
     assert t["durMs"] == 35.0
 
 
+@pytest.mark.parametrize("root", ["selector/sweep", "train/run"])
+def test_spans_outside_a_serve_trace_keep_no_children(root):
+    """Only a root ``serve/*`` span's tree reaches the serving ring, so no
+    other span builds one; a ``serve/*`` span nested elsewhere neither."""
+    tspans.reset_for_tests()
+    with tspans.span(root) as outer:
+        with tspans.span("selector/evaluate") as mid:
+            with tspans.span("serve/stage/x"):
+                pass
+    assert outer.children is None and mid.children is None
+    assert tspans.recent_serve_traces() == []
+
+
+def test_a_serve_tree_keeps_grandchildren_and_not_handed_over_spans():
+    tspans.reset_for_tests()
+    with tspans.span("serve/request") as root:
+        with tspans.span("serve/stage/a"):
+            with tspans.span("serve/stage/a/inner"):
+                pass
+        # a span whose parent is handed over keeps out of the tree
+        with tspans.span("serve/stage/b", parent=root):
+            pass
+    (t,) = tspans.recent_serve_traces()
+    assert t["children"] == [{
+        "name": "serve/stage/a", "durMs": t["children"][0]["durMs"],
+        "children": [{"name": "serve/stage/a/inner",
+                      "durMs": t["children"][0]["children"][0]["durMs"]}],
+    }]
+
+
+def test_add_seconds_sums_into_the_innermost_span():
+    tspans.reset_for_tests()
+    with tspans.span("selector/evaluate", lanes=2) as outer:
+        tspans.add_seconds("metric_seconds", {"AuPR": 0.25, "AuROC": 0.5})
+        with tspans.span("tree/await_outputs") as inner:
+            tspans.add_seconds("metric_seconds", {"AuPR": 1.0})
+        tspans.add_seconds("metric_seconds", {"AuPR": 0.5})
+    assert outer.attrs["metric_seconds"] == {"AuPR": 0.75, "AuROC": 0.5}
+    assert inner.attrs["metric_seconds"] == {"AuPR": 1.0}
+    rec = tspans.snapshot_events()[-1]
+    assert rec["args"] == {
+        "lanes": 2, "metric_seconds": {"AuPR": 0.75, "AuROC": 0.5},
+    }
+
+
+def test_add_seconds_is_a_no_op_off_or_outside_a_span():
+    tspans.reset_for_tests()
+    tspans.add_seconds("metric_seconds", {"AuPR": 1.0})  # no span open
+    tspans.set_enabled(False)
+    with tspans.span("selector/evaluate") as sp:
+        tspans.add_seconds("metric_seconds", {"AuPR": 1.0})
+    assert sp.attrs == {} and tspans.snapshot_events() == []
+
+
 def test_span_records_have_monotonic_ts_and_duration():
     clock = FakeClock()
     tspans.set_clock(clock)
@@ -834,11 +888,17 @@ def test_fit_arrays_yields_the_sweep_span_tree(xgb_sweep):
         "rows": 600, "cols": 7, "families": 1, "points": 2, "lanes": 4,
     }
     evaluated = [r["args"] for r in recs if r["name"] == "selector/evaluate"]
+    timed = ("metric_seconds", "predict_seconds")
     # bytes: the [4, 600] float32 margins the fold lanes are scored from,
-    # and the refit lane's row of them
-    assert evaluated == [
-        {"lanes": 2, "rows": 600, "classes": 2, "bytes": 9600},
-        {"lanes": 1, "rows": 600, "classes": 2, "bytes": 2400},
+    # and the refit lane's row of them; reads: what the caller reads of the
+    # metrics, the selector's one in the fold lanes, all of them in the
+    # refit's train evaluation
+    assert [{k: v for k, v in a.items() if k not in timed}
+            for a in evaluated] == [
+        {"lanes": 2, "rows": 600, "classes": 2, "bytes": 9600,
+         "reads": "AuPR"},
+        {"lanes": 1, "rows": 600, "classes": 2, "bytes": 2400,
+         "reads": "all"},
     ]
     args = {r["name"]: r.get("args", {}) for r in recs}
     assert args["selector/row_select"] == {
@@ -858,8 +918,51 @@ def test_fit_arrays_yields_the_sweep_span_tree(xgb_sweep):
         "objective": "binary:logistic", "tree_weights": "0.02 0.02",
     }
     assert args["tree/feature_groups"] == {"narrow": 3, "wide": 4}
-    assert args["selector/refit"] == {"prefit": True}
+    refit = dict(args["selector/refit"])
+    assert refit.pop("predict_seconds") >= 0.0
+    assert refit == {"prefit": True}
     assert args["tree/bin_prepare"]["cache"] == "miss"
+
+
+def test_sweep_scoring_names_its_seconds(xgb_sweep):
+    """The fold lanes' turning of outputs into predictions rides their
+    ``selector/evaluate`` span, the winner's on ``selector/refit``; the
+    evaluator's groups of metrics ride each ``selector/evaluate``."""
+    recs = xgb_sweep["records"]
+    fold, train = [r["args"] for r in recs if r["name"] == "selector/evaluate"]
+    (refit,) = [r["args"] for r in recs if r["name"] == "selector/refit"]
+    groups = {"AuPR", "AuROC", "Counts", "ThresholdCurve"}
+    for args in (fold, train):
+        assert set(args["metric_seconds"]) == groups
+        assert all(s >= 0.0 for s in args["metric_seconds"].values())
+    assert fold["predict_seconds"] >= 0.0 and refit["predict_seconds"] >= 0.0
+    assert "predict_seconds" not in train
+    # the seconds lie inside the spans that hold them
+    (fold_rec,) = [r for r in recs if r.get("args") is fold]
+    assert (
+        fold["predict_seconds"] + sum(fold["metric_seconds"].values())
+        <= fold_rec["dur"]
+    )
+
+
+def test_per_fold_scoring_states_what_it_reads():
+    """A family without batched scoring takes the validator's per-fold
+    loop: its ``selector/evaluate`` spans carry the same attributes."""
+    x, y = _tree_table(seed=4)
+    selector = BinaryClassificationModelSelector(seed=5, models=LR_MODELS)
+    tspans.reset_for_tests()
+    selector.fit_arrays(x, y, np.ones(len(y), np.float32))
+    recs = tspans.snapshot_events()
+    evaluated = [r["args"] for r in recs if r["name"] == "selector/evaluate"]
+    folds = [a for a in evaluated if a["reads"] != "all"]
+    assert folds and all(a["reads"] == "AuPR" for a in folds)
+    assert [a["reads"] for a in evaluated].count("all") == 1
+    for a in folds:
+        assert a["predict_seconds"] >= 0.0
+        assert set(a["metric_seconds"]) == {
+            "AuPR", "AuROC", "Counts", "ThresholdCurve"}
+    (refit,) = [r["args"] for r in recs if r["name"] == "selector/refit"]
+    assert refit["predict_seconds"] >= 0.0
 
 
 def test_sweep_children_lie_inside_parents_and_root_self_time_is_small(

@@ -1103,21 +1103,32 @@ class _TreeEstimator(PredictorEstimator):
                 # classes
                 bytes=sum(int(o.nbytes) for o in outputs.values()),
             ) as sp:
+                # predictions and metrics are timed with clock reads into
+                # attributes, not child spans: the sweep's readers take the
+                # self time of selector/evaluate as the whole of the scoring
+                clock, predict_s = _tspans.clock, 0.0
                 prob = None
                 for fi, (_train_mask, val_mask) in enumerate(folds):
+                    t0 = clock()
                     val_idx = np.nonzero(val_mask)[0]
+                    y_val = y[val_idx]
+                    predict_s += clock() - t0
                     for gi, m in enumerate(models_by_fold[fi]):
+                        t0 = clock()
                         # a lane's outputs, the rows last: [N] or [V, N]
                         pred, prob, _ = m.predictions_from_sweep(
                             outputs[id(m._sweep_stack)][m._sweep_lane][
                                 ..., val_idx
                             ]
                         )
-                        metrics = evaluator.evaluate_arrays(
-                            y[val_idx], pred, prob
-                        )
+                        predict_s += clock() - t0
+                        metrics = evaluator.evaluate_arrays(y_val, pred, prob)
                         values[gi].append(evaluator.metric_of(metrics))
-                sp.attrs["classes"] = 0 if prob is None else prob.shape[1]
+                sp.attrs.update(
+                    classes=0 if prob is None else prob.shape[1],
+                    predict_seconds=predict_s,
+                    reads=evaluator.default_metric,
+                )
             return values
         except Exception:
             log.warning("batched sweep-eval failed; falling back", exc_info=True)

@@ -29,6 +29,7 @@ import numpy as np
 from ..dataset import Dataset
 from ..stages.base import Estimator
 from ..stages.metadata import VectorMetadata
+from ..telemetry import spans as _tspans
 from ..types import OPVector, RealNN
 from ..types.columns import Column, NumericColumn, VectorColumn
 from ..utils import stats as S
@@ -140,67 +141,149 @@ class SanityChecker(Estimator):
 
     # ------------------------------------------------------------------ fit
     def fit_model(self, dataset: Dataset) -> FeatureRemovalModel:
+        """Five spans under the workflow's ``train/fit`` of this stage, one
+        for each part of the work: ``sanity/matrix``, ``sanity/stats``,
+        ``sanity/categorical``, ``sanity/rules``, ``sanity/report``."""
         label_name, vector_name = self.input_names
         label_col = dataset[label_name]
         vec_col = dataset[vector_name]
         assert isinstance(label_col, NumericColumn) and isinstance(vec_col, VectorColumn)
 
-        x = np.asarray(vec_col.values, dtype=np.float64)
-        y = label_col.values.astype(np.float64)
-        n_total = x.shape[0]
-        frac = self._sample_fraction(n_total)
-        if frac < 1.0:
-            # stats on a seeded row sample (SanityChecker.scala:356-361,
-            # 562-564): the checker's cost is bounded by sample_upper_limit
-            # rows no matter the dataset size
-            rng = np.random.default_rng(self.sample_seed)
-            take = rng.choice(
-                n_total, size=max(1, round(frac * n_total)), replace=False
-            )
-            take.sort()
-            x, y = x[take], y[take]
-        n, d = x.shape
+        with _tspans.span("sanity/matrix") as sp:
+            x = np.asarray(vec_col.values, dtype=np.float64)
+            y = label_col.values.astype(np.float64)
+            n_total = x.shape[0]
+            frac = self._sample_fraction(n_total)
+            if frac < 1.0:
+                # stats on a seeded row sample (SanityChecker.scala:356-361,
+                # 562-564): the checker's cost is bounded by
+                # sample_upper_limit rows no matter the dataset size
+                rng = np.random.default_rng(self.sample_seed)
+                take = rng.choice(
+                    n_total, size=max(1, round(frac * n_total)), replace=False
+                )
+                take.sort()
+                x, y = x[take], y[take]
+            n, d = x.shape
+            sp.attrs.update(rows_in=n_total, rows=n, cols=d, bytes=x.nbytes)
         meta = vec_col.metadata or VectorMetadata(vector_name, ())
         names = (
             meta.column_names() if meta.size == d else [f"col_{j}" for j in range(d)]
         )
 
-        col_stats = S.column_stats(x)
-        if self.correlation_type == "spearman":
-            corr = S.spearman_correlation_matrix(x, y)
-        else:
-            corr = S.correlation_matrix(x, y)
-        corr_label = corr[:d, d].copy()
-        corr_features = corr[:d, :d].copy()
+        spearman = self.correlation_type == "spearman"
+        with _tspans.span(
+            "sanity/stats", kind="spearman" if spearman else "pearson"
+        ):
+            col_stats = S.column_stats(x)
+            if spearman:
+                corr = S.spearman_correlation_matrix(x, y)
+            else:
+                corr = S.correlation_matrix(x, y)
+            corr_label = corr[:d, d].copy()
+            corr_features = corr[:d, :d].copy()
 
-        # CorrelationExclusion.HashedText (SanityChecker.scala:428):
-        # text-shared-hash columns sit out the correlation checks entirely
-        if self.correlation_exclusion == "HashedText" and meta.size == d:
-            excluded = np.array(
-                [
-                    c.parent_type in _TEXT_HASH_PARENT_TYPES
-                    and c.grouping is None
-                    and c.indicator_value is None
-                    for c in meta.columns
-                ],
-                dtype=bool,
+            # CorrelationExclusion.HashedText (SanityChecker.scala:428):
+            # text-shared-hash columns sit out the correlation checks
+            # entirely
+            if self.correlation_exclusion == "HashedText" and meta.size == d:
+                excluded = np.array(
+                    [
+                        c.parent_type in _TEXT_HASH_PARENT_TYPES
+                        and c.grouping is None
+                        and c.indicator_value is None
+                        for c in meta.columns
+                    ],
+                    dtype=bool,
+                )
+                corr_label[excluded] = np.nan
+                corr_features[excluded, :] = 0.0
+                corr_features[:, excluded] = 0.0
+
+        # categorical groups: Cramér's V and association-rule confidences,
+        # with a label one-hot (binary or small multiclass). A CONTINUOUS
+        # label gets no Cramér's V / association-rule treatment at all
+        # (SanityChecker.scala categoricalLabel auto-detection: the label
+        # counts as categorical only when its distinct-value count is small
+        # relative to the row count; BadFeatureZooTest :264/:628 pin the
+        # skip).
+        group_v: dict[tuple, float] = {}
+        group_cols: dict[tuple, list[int]] = {}
+        group_rules: dict[tuple, tuple[np.ndarray, np.ndarray]] = {}
+        with _tspans.span("sanity/categorical") as sp:
+            classes = np.unique(y)
+            label_is_categorical = len(classes) <= min(
+                100, max(2, int(0.1 * len(y)))
             )
-            corr_label[excluded] = np.nan
-            corr_features[excluded, :] = 0.0
-            corr_features[:, excluded] = 0.0
+            label_onehot = (y[:, None] == classes[None, :]).astype(np.float64)
+            if meta.size == d and label_is_categorical:
+                for key, idxs in meta.index_of_group().items():
+                    cats = [
+                        i for i in idxs
+                        if meta.columns[i].indicator_value is not None
+                    ]
+                    if len(cats) < 1:
+                        continue
+                    contingency = S.contingency_table(x[:, cats], label_onehot)
+                    group_v[key] = S.cramers_v(contingency)
+                    group_cols[key] = cats
+                    group_rules[key] = S.association_rule_confidence(
+                        contingency
+                    )
+            sp.attrs.update(
+                groups=len(group_cols),
+                cols=sum(len(c) for c in group_cols.values()),
+            )
 
-        # label one-hot for categorical stats (binary or small multiclass).
-        # A CONTINUOUS label gets no Cramér's V / association-rule
-        # treatment at all (SanityChecker.scala categoricalLabel
-        # auto-detection: the label counts as categorical only when its
-        # distinct-value count is small relative to the row count;
-        # BadFeatureZooTest :264/:628 pin the skip).
-        classes = np.unique(y)
-        label_is_categorical = len(classes) <= min(
-            100, max(2, int(0.1 * len(y)))
-        )
-        label_onehot = (y[:, None] == classes[None, :]).astype(np.float64)
+        with _tspans.span("sanity/rules"):
+            drop_reasons = self._drop_reasons(
+                meta, d, col_stats, corr_label, corr_features,
+                group_v, group_cols, group_rules,
+            )
+            indices_to_keep = [j for j in range(d) if j not in drop_reasons]
 
+        with _tspans.span("sanity/report"):
+            reports = [
+                ColumnReport(
+                    name=names[j],
+                    parent=(
+                        meta.columns[j].parent_names[0]
+                        if meta.size == d and meta.columns[j].parent_names
+                        else None
+                    ),
+                    mean=float(col_stats.mean[j]),
+                    variance=float(col_stats.variance[j]),
+                    corr_label=float(corr_label[j]),
+                    cramers_v=(
+                        group_v.get(meta.columns[j].grouped_key())
+                        if meta.size == d
+                        else None
+                    ),
+                    dropped=j in drop_reasons,
+                    reasons=drop_reasons.get(j, []),
+                )
+                for j in range(d)
+            ]
+            self.metadata["sanityCheckerSummary"] = {
+                "numRows": n,
+                "numColumns": d,
+                "numDropped": len(drop_reasons),
+                "columns": [dataclasses.asdict(r) for r in reports],
+                "correlationType": self.correlation_type,
+            }
+            new_meta = meta.select(indices_to_keep) if meta.size == d else None
+            return FeatureRemovalModel(
+                indices_to_keep=indices_to_keep,
+                remove_bad_features=self.remove_bad_features,
+                new_metadata=new_meta,
+                operation_name="sanityCheck",
+            )
+
+    def _drop_reasons(
+        self, meta, d, col_stats, corr_label, corr_features,
+        group_v, group_cols, group_rules,
+    ) -> dict[int, list[str]]:
+        """{column: the reasons it is dropped}, by the rules in order."""
         drop_reasons: dict[int, list[str]] = {}
 
         def drop(j: int, reason: str) -> None:
@@ -224,30 +307,19 @@ class SanityChecker(Estimator):
             drop(int(j), f"featureCorr>{self.max_feature_corr}")
 
         # 4. categorical groups: Cramér's V + association rules
-        group_v: dict[tuple, float] = {}
-        group_cols: dict[tuple, list[int]] = {}
-        if meta.size == d and label_is_categorical:
-            for key, idxs in meta.index_of_group().items():
-                cats = [
-                    i for i in idxs if meta.columns[i].indicator_value is not None
-                ]
-                if len(cats) < 1:
-                    continue
-                contingency = S.contingency_table(x[:, cats], label_onehot)
-                v = S.cramers_v(contingency)
-                group_v[key] = v
-                group_cols[key] = cats
-                if v > self.max_cramers_v:
-                    for i in cats:
-                        drop(i, f"cramersV={v:.4f}>{self.max_cramers_v}")
-                conf, support = S.association_rule_confidence(contingency)
-                if self.max_rule_confidence < 1.0:
-                    for ci, i in enumerate(cats):
-                        if (
-                            conf[ci] > self.max_rule_confidence
-                            and support[ci] >= self.min_required_rule_support
-                        ):
-                            drop(i, f"ruleConfidence={conf[ci]:.4f}")
+        for key, v in group_v.items():
+            cats = group_cols[key]
+            if v > self.max_cramers_v:
+                for i in cats:
+                    drop(i, f"cramersV={v:.4f}>{self.max_cramers_v}")
+            conf, support = group_rules[key]
+            if self.max_rule_confidence < 1.0:
+                for ci, i in enumerate(cats):
+                    if (
+                        conf[ci] > self.max_rule_confidence
+                        and support[ci] >= self.min_required_rule_support
+                    ):
+                        drop(i, f"ruleConfidence={conf[ci]:.4f}")
 
         # 5. group-wise removal at PARENT-FEATURE granularity
         # (DerivedFeatureFilterUtils.reasonsToRemove parentExclusionReasons):
@@ -324,41 +396,4 @@ class SanityChecker(Estimator):
                     if i not in drop_reasons:
                         drop(i, "featureGroupRemoval")
 
-        indices_to_keep = [j for j in range(d) if j not in drop_reasons]
-
-        # ------------------------- summary ledger -------------------------
-        reports = [
-            ColumnReport(
-                name=names[j],
-                parent=(
-                    meta.columns[j].parent_names[0]
-                    if meta.size == d and meta.columns[j].parent_names
-                    else None
-                ),
-                mean=float(col_stats.mean[j]),
-                variance=float(col_stats.variance[j]),
-                corr_label=float(corr_label[j]),
-                cramers_v=(
-                    group_v.get(meta.columns[j].grouped_key())
-                    if meta.size == d
-                    else None
-                ),
-                dropped=j in drop_reasons,
-                reasons=drop_reasons.get(j, []),
-            )
-            for j in range(d)
-        ]
-        self.metadata["sanityCheckerSummary"] = {
-            "numRows": n,
-            "numColumns": d,
-            "numDropped": len(drop_reasons),
-            "columns": [dataclasses.asdict(r) for r in reports],
-            "correlationType": self.correlation_type,
-        }
-        new_meta = meta.select(indices_to_keep) if meta.size == d else None
-        return FeatureRemovalModel(
-            indices_to_keep=indices_to_keep,
-            remove_bad_features=self.remove_bad_features,
-            new_metadata=new_meta,
-            operation_name="sanityCheck",
-        )
+        return drop_reasons

@@ -454,14 +454,18 @@ class ModelSelector(PredictorEstimator):
                 else:
                     best_model = final_est.fit_arrays(xt, yt, final_mask)
 
+            t0 = _tspans.clock()
             if refit_raw is not None:
                 pred, prob, _ = best_model.predictions_from_sweep(refit_raw)
             else:
                 pred, prob, _ = best_model.predict_arrays(xt)
+            sp.attrs["predict_seconds"] = _tspans.clock() - t0
+            # the train evaluation goes whole into the summary
             with _tspans.span(
                 "selector/evaluate", lanes=1, rows=len(yt),
                 classes=0 if prob is None else int(np.shape(prob)[1]),
                 bytes=0 if refit_raw is None else int(refit_raw.nbytes),
+                reads="all",
             ):
                 train_metrics = self.evaluator.evaluate_arrays(yt, pred, prob)
                 extra_train = {

@@ -413,8 +413,10 @@ class Validator:
                     ]
             val_idx = np.nonzero(val_mask)[0]
             with _tspans.span(
-                "selector/evaluate", lanes=len(models), rows=len(val_idx)
-            ):
+                "selector/evaluate", lanes=len(models), rows=len(val_idx),
+                reads=evaluator.default_metric,
+            ) as sp:
+                predict_s = 0.0
                 for gi, model in enumerate(models):
                     # lane-granular isolation: one lane's scoring failure
                     # poisons only its own grid point (NaN metric —
@@ -422,7 +424,9 @@ class Validator:
                     # family. Fit failures above still propagate: the retry
                     # machinery scripts those at the candidate level.
                     try:
+                        t0 = _tspans.clock()
                         pred, prob, _ = model.predict_arrays(x[val_idx])
+                        predict_s += _tspans.clock() - t0
                         metrics = evaluator.evaluate_arrays(
                             y[val_idx], pred, prob
                         )
@@ -434,6 +438,7 @@ class Validator:
                         )
                         value = float("nan")
                     per_point_values[gi].append(value)
+                sp.attrs["predict_seconds"] = predict_s
         return [
             CandidateResult(
                 model_name=type(est).__name__,

@@ -18,7 +18,8 @@ and records it three ways:
   metrics registry (``tptpu_span_seconds{span="..."}``) — true
   p50/p95/p99 per stage family;
 * for root ``serve/*`` spans, a compact trace in the bounded **serving
-  ring buffer** (:func:`recent_serve_traces`).
+  ring buffer** (:func:`recent_serve_traces`): only a span under such a
+  root on the same thread builds the tree of children that trace keeps.
 
 The clock is injectable (:func:`set_clock`) so the telemetry suite runs on
 fake time — the same seam convention the resilience components use
@@ -30,7 +31,9 @@ The serving hot path records through :func:`record_serve_batch` (one call
 per scored batch with pre-aggregated per-family seconds) instead of one
 span per stage, so single-row scoring pays a handful of clock reads, not
 dozens of span objects; per-stage detail spans engage above
-``TPTPU_TRACE_STAGE_ROWS`` rows (default 16).
+``TPTPU_TRACE_STAGE_ROWS`` rows (default 16). Other host loops that time
+their parts with raw clock reads hand the seconds to the innermost open
+span as one attribute (:func:`add_seconds`).
 """
 from __future__ import annotations
 
@@ -48,6 +51,7 @@ __all__ = [
     "current",
     "record_span",
     "record_serve_batch",
+    "add_seconds",
     "clock",
     "set_clock",
     "get_clock",
@@ -180,8 +184,9 @@ def _record(
     ring: bool = False,
 ) -> None:
     """Append one record. ``ids`` is (id, parent id or None, trace id);
-    ``tree_parent`` is the enclosing span on this thread, whose serving-ring
-    trace tree gains this span as a child; ``ring`` sends a root
+    ``tree_parent`` is the enclosing span on this thread under a root
+    ``serve/*`` span, whose serving-ring trace tree gains this span as a
+    child (None elsewhere: no other tree is ever read); ``ring`` sends a root
     ``serve/*`` span's own tree to the serving ring."""
     sid, parent_id, trace_id = ids
     rec: dict[str, Any] = {
@@ -223,6 +228,21 @@ def current() -> "span | None":
     return stack[-1] if stack else None
 
 
+def add_seconds(attr: str, seconds: dict[str, float]) -> None:
+    """Add ``seconds`` ({part: seconds}, timed by the caller with raw
+    clock reads) into the ``attr`` dict attribute of the innermost span open
+    on this thread, as ``record_serve_batch`` takes a batch's stage seconds.
+    A no-op with telemetry off or no span open."""
+    if not _STATE["enabled"]:
+        return
+    sp = current()
+    if sp is None:
+        return
+    into = sp.attrs.setdefault(attr, {})
+    for part, secs in seconds.items():
+        into[part] = into.get(part, 0.0) + secs
+
+
 class span:
     """``with span("cv/fold", fold=2): ...`` — times the block and records
     it (see module docstring). ``parent=`` takes the handle of a span open
@@ -234,7 +254,7 @@ class span:
 
     __slots__ = (
         "name", "attrs", "children", "id", "parent", "trace",
-        "_cause", "_t0", "_ann",
+        "_cause", "_t0", "_ann", "_tree",
     )
 
     def __init__(self, name: str, parent: "span | None" = None, **attrs: Any):
@@ -254,6 +274,14 @@ class span:
         if stack is None:
             stack = _TLS.stack = []
         cause = self._cause
+        # the serving ring keeps the tree of a root serve/* span alone:
+        # only a span under one on this thread adds itself to a tree
+        if cause is None:
+            self._tree = (
+                stack[-1]._tree if stack else self.name.startswith("serve/")
+            )
+        else:
+            self._tree = False
         if cause is None and stack:
             cause = stack[-1]
         self.id = next(_IDS)
@@ -282,7 +310,7 @@ class span:
             stack.pop()
             # a parent handed over from another thread keeps its serving
             # tree to itself: its children list is not shared state
-            if stack and self._cause is None:
+            if stack and self._tree:
                 tree_parent = stack[-1]
         _record(
             self.name, self._t0, dur, self.attrs,
