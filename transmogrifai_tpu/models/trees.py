@@ -74,7 +74,11 @@ class HistSlots(NamedTuple):
     level): the lanes whose tree of that round was fitted to the labels
     themselves, and to a pseudo-residual of the margin so far — counted by
     ``_boost_chunk_body`` under a ``spark:*`` objective, 0 from every other
-    fit (a Newton round is neither; a forest has no rounds)."""
+    fit (a Newton round is neither; a forest has no rounds).
+    ``partition_run`` / ``partition_skipped``: of the level's two row-wide
+    passes (the compaction of the rows' nodes, the routing of the rows to
+    their children), those that ran and those a level where no lane can
+    split left out (always 2 / 0 on the sharded path)."""
 
     live: jax.Array
     built: jax.Array
@@ -86,6 +90,8 @@ class HistSlots(NamedTuple):
     rounds_residual: jax.Array
     nodes_built: jax.Array
     nodes_derived: jax.Array
+    partition_run: jax.Array
+    partition_skipped: jax.Array
 
 
 class _Siblings(NamedTuple):
@@ -267,6 +273,8 @@ class HistSlotStats(_tm.LedgerCore):
         "rounds_label": "boostRoundsLabel",
         "rounds_residual": "boostRoundsResidual",
         "nodes_built": "histNodesBuilt", "nodes_derived": "histNodesDerived",
+        "partition_run": "partitionPassesRun",
+        "partition_skipped": "partitionPassesSkipped",
     }
 
     #: per ``tree/fit_dispatch``: the fit's statistic channels and those
@@ -307,8 +315,8 @@ def await_outputs(value, hist_slots: HistSlots | None = None):
     on the host already passes through. ``hist_slots`` are the counts the
     same fit program returned: once its outputs have landed they are there
     too, and their sums go onto the span (``slots_live``, ``slots_built``,
-    ``chunks_run``, ``chunks_skipped``, ``nodes_built``, ``nodes_derived``;
-    from a fit that counts node
+    ``chunks_run``, ``chunks_skipped``, ``nodes_built``, ``nodes_derived``,
+    ``partition_run``, ``partition_skipped``; from a fit that counts node
     subsets ``subset_admitted``, ``subset_pairs``; from one that boosts in
     first order ``boost_rounds_label``, ``boost_rounds_residual``) and the
     ``tree`` ledger."""
@@ -332,6 +340,8 @@ def await_outputs(value, hist_slots: HistSlots | None = None):
                 chunks_skipped=sums["chunks_skipped"],
                 nodes_built=sums["nodes_built"],
                 nodes_derived=sums["nodes_derived"],
+                partition_run=sums["partition_run"],
+                partition_skipped=sums["partition_skipped"],
             )
             if sums["subset_pairs"]:
                 sp.attrs.update(
@@ -1131,14 +1141,34 @@ def _grow_tree_impl(
         # occupancy skip drops the dead bulk of deep levels; `node` keeps
         # the full routing chain (dead rows continue left) so leaf
         # assignment is unchanged.
-        node, active, alive, sib = carry
-        with jax.named_scope("tree/partition"):
+        node, active, alive_in, sib = carry
+
+        def compact():
             hist_node = jnp.where(active, node, sentinel)
             (live, rank), local = compact_local(hist_node)
             # dead rows out of every histogram / occupancy check,
             # regardless of which slot the sentinel landed on after
             # compaction
-            local = jnp.where(active, local, sentinel)
+            return live, rank, jnp.where(active, local, sentinel)
+
+        # ---- dead levels: once a level splits nothing in any lane, every
+        # row is inactive, so compaction would find no live node and
+        # routing would only send each row left. Both row-wide passes are
+        # skipped for what they would give; the sharded path always
+        # computes (compaction's psum can't sit under a data-dependent cond)
+        with jax.named_scope("tree/partition"):
+            if axis_name is not None:
+                live, rank, local = compact()
+            else:
+                live, rank, local = jax.lax.cond(
+                    alive_in,
+                    compact,
+                    lambda: (
+                        jnp.zeros((k_fits, max_nodes), dtype=bool),
+                        jnp.zeros((k_fits, max_nodes), dtype=jnp.int32),
+                        jnp.full((k_fits, n), sentinel, dtype=jnp.int32),
+                    ),
+                )
 
         # live compact slots at this level, the widest lane's: slots are
         # numbered densely from 0, so every live one is below this count
@@ -1280,7 +1310,7 @@ def _grow_tree_impl(
             feats_c, bins_c, right_c, built, counts, hists = level()
         else:
             feats_c, bins_c, right_c, built, counts, hists = jax.lax.cond(
-                alive,
+                alive_in,
                 level,
                 lambda: (
                     jnp.full((k_fits, n_nodes), -1, dtype=jnp.int32),
@@ -1329,24 +1359,40 @@ def _grow_tree_impl(
             )
             bins_d = jnp.where(live, _small_table_lookup(bins_c, rank_c), 0)
 
-            # ---- route rows to children (gather via compact slots —
-            # cheaper)
-            slot = jnp.clip(local, 0, n_nodes - 1)
-            row_feat = _small_table_lookup(feats_c, slot)  # [K, N]
-            if keep:
-                # which child the next level builds rides in the table the
-                # threshold is looked up from (no gather of its own)
-                row_thr = _small_table_lookup(
-                    bins_c + (right_c.astype(jnp.int32) << _BUILT_BIT), slot
-                )
-                row_right = row_thr >> _BUILT_BIT
-                row_thr = row_thr & ((1 << _BUILT_BIT) - 1)
+            def route():
+                """Each row's child (gather via compact slots — cheaper),
+                whether it stays active, and (``keep``) whether it is in
+                the child the next level builds."""
+                slot = jnp.clip(local, 0, n_nodes - 1)
+                row_feat = _small_table_lookup(feats_c, slot)  # [K, N]
+                if keep:
+                    # which child the next level builds rides in the table
+                    # the threshold is looked up from (no gather of its own)
+                    row_thr = _small_table_lookup(
+                        bins_c + (right_c.astype(jnp.int32) << _BUILT_BIT),
+                        slot,
+                    )
+                    row_right = row_thr >> _BUILT_BIT
+                    row_thr = row_thr & ((1 << _BUILT_BIT) - 1)
+                else:
+                    row_thr = _small_table_lookup(bins_c, slot)
+                code = _row_feature_select(binned, row_feat)
+                go_right = active & (row_feat >= 0) & (code > row_thr)
+                stays = active & (row_feat >= 0)
+                out = (node * 2 + go_right.astype(jnp.int32), stays)
+                if keep:
+                    out += (stays & (go_right == (row_right > 0)),)
+                return out
+
+            if axis_name is not None:
+                node, active, *takes_part = route()
             else:
-                row_thr = _small_table_lookup(bins_c, slot)
-            code = _row_feature_select(binned, row_feat)
-            go_right = active & (row_feat >= 0) & (code > row_thr)
-            node = node * 2 + go_right.astype(jnp.int32)
-            active = active & (row_feat >= 0)
+                # no split anywhere: every row goes left and leaves the tree
+                dead = jnp.zeros((k_fits, n), dtype=bool)
+                node, active, *takes_part = jax.lax.cond(
+                    alive, route,
+                    lambda: (node * 2, dead) + (dead,) * bool(keep),
+                )
             if keep:
                 # the next level's pairs: the nodes that split, numbered
                 # densely in slot order; pair j's parent is the j-th of
@@ -1361,7 +1407,7 @@ def _grow_tree_impl(
                     == jnp.arange(pair_slots, dtype=jnp.int32)[:, None]
                 )  # [K, pair_slots, keep]
                 sib = _Siblings(
-                    takes_part=active & (go_right == (row_right > 0)),
+                    takes_part=takes_part[0],
                     n_pairs=splits.sum(axis=1, dtype=jnp.int32),
                     parent_slot=jnp.where(
                         is_parent, jnp.arange(keep, dtype=jnp.int32), 0
@@ -1371,11 +1417,16 @@ def _grow_tree_impl(
                     ).any(axis=2),
                     hists=hists,
                 )
+        passes = (
+            jnp.int32(2) if axis_name is not None
+            else alive_in.astype(jnp.int32) + alive.astype(jnp.int32)
+        )
         return (node, active, alive, sib), (
             feats_d, bins_d,
             HistSlots(
                 n_built_k.max(), built, runs, skipped, *counts, *zero2,
                 n_built_k.sum(), (n_live_k - n_built_k).sum(),
+                passes, 2 - passes,
             ),
         )
 
