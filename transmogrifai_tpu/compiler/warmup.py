@@ -51,7 +51,9 @@ _TREE_PROGRAMS = frozenset(
 #: through ``aot_call``. Families absent here (GLM/IRLS, NaiveBayes, SVC,
 #: MLP) compile through the plain jit cache and bank nothing.
 _FAMILY_PROGRAMS: dict[str, frozenset] = {
-    "LogisticRegression": frozenset({"logistic_binary_batched"}),
+    "LogisticRegression": frozenset(
+        {"logistic_binary_batched", "logistic_multinomial_batched"}
+    ),
     "LinearRegression": frozenset({"linear_batched"}),
     "XGBoostClassifier": _TREE_PROGRAMS,
     "XGBoostRegressor": _TREE_PROGRAMS,

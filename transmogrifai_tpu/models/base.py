@@ -90,6 +90,46 @@ class PredictorEstimator(Estimator):
         return c
 
 
+def score_sweep_lanes(models_by_fold, y, folds, evaluator, lane_outputs,
+                      *, lanes: int, nbytes: int) -> list[list[float]]:
+    """The host half of a family's ``sweep_eval_batched``: every fold lane
+    scored from outputs its fit program computed on the device, under one
+    ``selector/evaluate`` span. ``lane_outputs(fold, model, val_idx)`` is a
+    lane's raw outputs on the fold's validation rows, the rows last (what
+    the model's ``predictions_from_sweep`` takes); ``lanes`` and ``nbytes``
+    (the outputs pulled to the host for them) go on the span. Returns
+    ``[n_points][n_folds]`` metric values."""
+    from ..telemetry import spans as _tspans
+
+    values: list[list[float]] = [[] for _ in range(len(models_by_fold[0]))]
+    with _tspans.span(
+        "selector/evaluate", lanes=lanes, rows=len(y), bytes=nbytes,
+    ) as sp:
+        # predictions and metrics are timed with clock reads into
+        # attributes, not child spans: the sweep's readers take the self
+        # time of selector/evaluate as the whole of the scoring
+        clock, predict_s = _tspans.clock, 0.0
+        prob = None
+        for fi, (_train_mask, val_mask) in enumerate(folds):
+            t0 = clock()
+            val_idx = np.nonzero(val_mask)[0]
+            y_val = y[val_idx]
+            predict_s += clock() - t0
+            for gi, m in enumerate(models_by_fold[fi]):
+                t0 = clock()
+                pred, prob, _ = m.predictions_from_sweep(
+                    lane_outputs(fi, m, val_idx))
+                predict_s += clock() - t0
+                metrics = evaluator.evaluate_arrays(y_val, pred, prob)
+                values[gi].append(evaluator.metric_of(metrics))
+        sp.attrs.update(
+            classes=0 if prob is None else prob.shape[1],
+            predict_seconds=predict_s,
+            reads=evaluator.default_metric,
+        )
+    return values
+
+
 def group_grid_by_statics(points, known_keys, statics_of):
     """Group grid-point indices by their STATIC (shape-affecting) params so
     dynamic params batch as lanes of one program; points carrying unknown

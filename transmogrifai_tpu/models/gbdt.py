@@ -49,7 +49,7 @@ log = logging.getLogger(__name__)
 
 from ..telemetry import metrics as _tm
 from ..telemetry import spans as _tspans
-from .base import PredictorEstimator, PredictorModel
+from .base import PredictorEstimator, PredictorModel, score_sweep_lanes
 from . import trees as TR
 
 import threading as _threading
@@ -1093,43 +1093,16 @@ class _TreeEstimator(PredictorEstimator):
                     {},
                 )
                 outputs[sid] = TR.await_outputs(out)  # [K, (V,) N]
-            values: list[list[float]] = [
-                [] for _ in range(len(models_by_fold[0]))
-            ]
-            with _tspans.span(
-                "selector/evaluate", lanes=len(flat), rows=len(y),
-                # what the lanes are scored from: the outputs pulled to
-                # the host, one row of them a class where the model has
-                # classes
-                bytes=sum(int(o.nbytes) for o in outputs.values()),
-            ) as sp:
-                # predictions and metrics are timed with clock reads into
-                # attributes, not child spans: the sweep's readers take the
-                # self time of selector/evaluate as the whole of the scoring
-                clock, predict_s = _tspans.clock, 0.0
-                prob = None
-                for fi, (_train_mask, val_mask) in enumerate(folds):
-                    t0 = clock()
-                    val_idx = np.nonzero(val_mask)[0]
-                    y_val = y[val_idx]
-                    predict_s += clock() - t0
-                    for gi, m in enumerate(models_by_fold[fi]):
-                        t0 = clock()
-                        # a lane's outputs, the rows last: [N] or [V, N]
-                        pred, prob, _ = m.predictions_from_sweep(
-                            outputs[id(m._sweep_stack)][m._sweep_lane][
-                                ..., val_idx
-                            ]
-                        )
-                        predict_s += clock() - t0
-                        metrics = evaluator.evaluate_arrays(y_val, pred, prob)
-                        values[gi].append(evaluator.metric_of(metrics))
-                sp.attrs.update(
-                    classes=0 if prob is None else prob.shape[1],
-                    predict_seconds=predict_s,
-                    reads=evaluator.default_metric,
-                )
-            return values
+            # what the lanes are scored from: the outputs pulled to the
+            # host, one row of them a class where the model has classes; a
+            # lane's outputs, the rows last: [N] or [V, N]
+            return score_sweep_lanes(
+                models_by_fold, y, folds, evaluator,
+                lambda _fi, m, val_idx: outputs[id(m._sweep_stack)][
+                    m._sweep_lane][..., val_idx],
+                lanes=len(flat),
+                nbytes=sum(int(o.nbytes) for o in outputs.values()),
+            )
         except Exception:
             log.warning("batched sweep-eval failed; falling back", exc_info=True)
             return None

@@ -4,20 +4,138 @@ Reference: core/.../stages/impl/classification/OpLogisticRegression.scala —
 wraps Spark LR (L-BFGS/OWL-QN over native BLAS). Here training is the pure
 XLA solver in models/solvers.py; gradients over a sharded batch reduce with
 ``psum`` when the data axis is sharded over a mesh.
+
+Over more than two classes the learner is Spark's multinomial family
+(``LOGISTIC_MULTINOMIAL``): ONE softmax model a fit over all K classes, no
+pivot class, by the binary fit's L-BFGS/OWL-QN for ``max_iter`` iterations
+(1:1), a sweep's lanes batched on the shared plane
+(``solvers.fit_logistic_multinomial_batched``). Its lanes are scored from
+the class logits the fit program computed on the device: only the fold
+lanes' validation rows and the refit winner's lane come to the host.
 """
 from __future__ import annotations
+
+import logging
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
-from .base import PredictorEstimator, PredictorModel
+from ..telemetry import metrics as _tm
+from ..telemetry import runlog as _runlog
+from ..telemetry import spans as _tspans
+from . import solvers
+from .base import PredictorEstimator, PredictorModel, score_sweep_lanes
 from .solvers import (
     fit_logistic_binary,
     fit_logistic_binary_batched,
     fit_logistic_multinomial,
+    fit_logistic_multinomial_batched,
 )
+
+log = logging.getLogger(__name__)
+
+#: How this estimator fits a label of more than two classes, stated as
+#: ``gbdt.FOREST_MULTICLASS`` states the forest's: one softmax fit a lane
+#: over the K classes (Spark's multinomial family), by L-BFGS/OWL-QN for
+#: ``max_iter`` iterations.
+LOGISTIC_MULTINOMIAL = (
+    "one softmax fit over K classes: L-BFGS/OWL-QN, maxIter 1:1"
+)
+
+
+def solver_precision() -> str:
+    """The matmul precision every GLM product runs at
+    (``solvers.GLM_PRECISION``), in lower case: ``highest``."""
+    return solvers.GLM_PRECISION.name.lower()
+
+
+class GLMStats(_tm.LedgerCore):
+    """The ``glm`` ledger: what the multinomial fit programs report once
+    their outputs land (``glm/await_outputs``), cumulative: iterations in
+    which a lane moved, iterations scanned (lanes × ``max_iter``: the scan
+    runs every iteration, a frozen lane too) and lanes whose gradient test
+    held at the end."""
+
+    KEYS = ("itersMoved", "itersScanned", "lanesConverged")
+
+    def __init__(self) -> None:
+        super().__init__(self.KEYS)
+
+    def record(self, moved: int, scanned: int, converged: int) -> None:
+        with self._lock:
+            self._counts["itersMoved"] += moved
+            self._counts["itersScanned"] += scanned
+            self._counts["lanesConverged"] += converged
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return dict(self._counts)
+
+
+_GLM_STATS = GLMStats()
+_tm.REGISTRY.register_source("glm", _GLM_STATS.snapshot)
+
+
+@jax.jit
+def _lane_rows(logits, lanes, rows):
+    """[L, C, V]: lanes ``lanes`` of ``logits`` [K, C, N] at rows ``rows``."""
+    return jnp.take(jnp.take(logits, lanes, axis=0), rows, axis=2)
+
+
+def _await(value, counts=None):
+    """``value`` (device arrays) on the host under a ``glm/await_outputs``
+    span: how long the host waited for the fit program, and one download on
+    the run ledger's census. ``counts(out)`` gives the attributes of the
+    program's own counts once ``out`` has landed."""
+    with _tspans.span("glm/await_outputs") as sp:
+        t0 = _tspans.clock()
+        out = jax.tree.map(np.asarray, value)
+        nbytes = sum(int(a.nbytes) for a in jax.tree.leaves(out))
+        sp.attrs["bytes"] = nbytes
+        _runlog.record_download(nbytes, _tspans.clock() - t0)
+        if counts is not None and _tspans.enabled():
+            sp.attrs.update(counts(out))
+    return out
+
+
+class _MultinomialSweep:
+    """One multinomial fit program's outputs for a sweep: ``k`` real lanes
+    of ``solvers.MultinomialFit`` (the padded ones after them), over
+    ``rows`` real rows. The class logits stay on the device; what is
+    scored comes to the host by lane and row."""
+
+    def __init__(self, fit, k: int, rows: int, num_iters: int):
+        self.fit, self.k, self.rows, self.num_iters = fit, k, rows, num_iters
+
+    def await_params(self):
+        """(weights [k, D, C], intercept [k, C]) on the host; the read also
+        lands the program's counts of iterations, on the span and the
+        ``glm`` ledger."""
+        f, k = self.fit, self.k
+
+        def counts(out):
+            moved, conv = out[2][:k], out[3][:k]
+            got = dict(iters_moved=int(moved.sum()),
+                       iters_scanned=k * self.num_iters,
+                       lanes_converged=int(conv.sum()))
+            _GLM_STATS.record(got["iters_moved"], got["iters_scanned"],
+                              got["lanes_converged"])
+            return got
+
+        w, b, _, _ = _await(
+            (f.weights, f.intercept, f.iters_moved, f.converged), counts)
+        return w[:k], b[:k]
+
+    def lane_rows(self, lanes, rows) -> np.ndarray:
+        """[L, C, V] host logits of ``lanes`` at ``rows``."""
+        from ..utils.aot import aot_call
+
+        return _await(aot_call(
+            "glm_lane_rows", _lane_rows,
+            (self.fit.logits, jnp.asarray(np.asarray(lanes, dtype=np.int32)),
+             jnp.asarray(np.asarray(rows, dtype=np.int32))), {}))
 
 
 class LogisticRegressionModel(PredictorModel):
@@ -63,6 +181,26 @@ class LogisticRegressionModel(PredictorModel):
             raw = logits
         pred = prob.argmax(axis=1).astype(np.float64)
         return pred, prob, raw
+
+    # ---- sweep protocol (validators._sweep_family, the selector's refit):
+    # a multinomial sweep's lanes keep ``_sweep_stack`` (the program's
+    # ``_MultinomialSweep``) and ``_sweep_lane``
+    def predictions_from_sweep(self, logits):
+        """(pred, prob, raw) from one lane's class logits as the fit
+        program computed them, the rows last: [C, N]."""
+        return self.predictions_from_core(np.asarray(logits).T)
+
+    def sweep_lane_outputs(self) -> np.ndarray:
+        """This lane's [C, N] logits on every training row, fetched
+        alone (the refit winner's train evaluation)."""
+        stack = self._sweep_stack
+        return stack.lane_rows([self._sweep_lane], np.arange(stack.rows))[0]
+
+    def detach_from_sweep(self) -> None:
+        """Drop the reference to the sweep's device logits."""
+        for attr in ("_sweep_stack", "_sweep_lane"):
+            if hasattr(self, attr):
+                delattr(self, attr)
 
     def fused_predict_spec(self):
         """Device core for the fused scoring graph: ``plane @ w + b`` in
@@ -138,9 +276,7 @@ class LogisticRegression(PredictorEstimator):
         present = y[row_mask > 0]
         num_classes = max(int(present.max()) + 1 if len(present) else 2, 2)
         x, y, row_mask = self._mesh_rows(x, y, row_mask)
-        # binary runs quasi-Newton (maxIter is the Spark-semantic knob,
-        # 1:1); multinomial still runs FISTA, which needs a larger budget
-        iters = self.max_iter * 4
+        # both run quasi-Newton; maxIter is the Spark-semantic knob, 1:1
         if num_classes == 2:
             params = fit_logistic_binary(
                 x,
@@ -160,7 +296,7 @@ class LogisticRegression(PredictorEstimator):
                 float(self.reg_param),
                 float(self.elastic_net_param),
                 num_classes=num_classes,
-                num_iters=iters,
+                num_iters=self.max_iter,
                 fit_intercept=self.fit_intercept,
                 standardization=self.standardization,
             )
@@ -220,73 +356,132 @@ class LogisticRegression(PredictorEstimator):
         program each; points with unknown params fit sequentially."""
         return self.fit_arrays_batched_masks(x, y, [row_mask], grid_points)[0]
 
-    def _batched_fit(self, xp, yp, rm, regs, ens, num_classes, statics,
-                     mesh=None):
+    def _batched_fit(self, xp, yp, rm, regs, ens, statics, mesh=None):
+        """A binary static group's program (sharded under ``mesh``)."""
         fit_intercept, max_iter, standardization = statics
-        if num_classes == 2:
-            from ..compiler import bucketing, dispatch
-            from ..utils.aot import aot_call
+        from ..compiler import bucketing, dispatch
+        from ..utils.aot import aot_call
 
-            statics_kw = dict(
-                num_iters=max_iter,
-                fit_intercept=fit_intercept,
-                standardization=standardization,
-            )
-            if mesh is not None:
-                # the sharded sweep: lanes over MODEL_AXIS, rows over
-                # DATA_AXIS, on the explicit SweepLayout PartitionSpecs,
-                # with fold-level buffer donation (parallel/fit.py)
-                from ..parallel.fit import sweep_parallel_fit
+        statics_kw = dict(
+            num_iters=max_iter,
+            fit_intercept=fit_intercept,
+            standardization=standardization,
+        )
+        if mesh is not None:
+            # the sharded sweep: lanes over MODEL_AXIS, rows over
+            # DATA_AXIS, on the explicit SweepLayout PartitionSpecs,
+            # with fold-level buffer donation (parallel/fit.py)
+            from ..parallel.fit import sweep_parallel_fit
 
-                return sweep_parallel_fit(
-                    fit_logistic_binary_batched,
-                    "sweep_logistic_binary_sharded", mesh,
-                    xp, yp, rm, regs, ens, **statics_kw,
-                )
-            # cross-candidate dedup: every lane of this sweep shares ONE
-            # program, and the lane count pads onto a shape bucket so a
-            # near-miss sweep (one more grid point, one more fold) reuses
-            # the same banked executable instead of compiling its own
-            k, (rm, regs, ens) = bucketing.bucket_sweep_lanes(rm, regs, ens)
-            # shared-x GEMM sweep (see fit_logistic_binary_batched); the x
-            # upload reuses the transfer the DAG fit prefetched, when one
-            # is in flight (compiler.dispatch)
-            fit_fn = dispatch.donating(
-                "logistic_binary_batched", fit_logistic_binary_batched,
-                donate_argnums=(3, 4),
-                static_argnames=(
-                    "num_iters", "fit_intercept", "standardization"
-                ),
+            return sweep_parallel_fit(
+                fit_logistic_binary_batched,
+                "sweep_logistic_binary_sharded", mesh,
+                xp, yp, rm, regs, ens, **statics_kw,
             )
-            out = aot_call(
-                "logistic_binary_batched", fit_fn,
+        # cross-candidate dedup: every lane of this sweep shares ONE
+        # program, and the lane count pads onto a shape bucket so a
+        # near-miss sweep (one more grid point, one more fold) reuses
+        # the same banked executable instead of compiling its own
+        k, (rm, regs, ens) = bucketing.bucket_sweep_lanes(rm, regs, ens)
+        # shared-x GEMM sweep (see fit_logistic_binary_batched); the x
+        # upload reuses the transfer the DAG fit prefetched, when one
+        # is in flight (compiler.dispatch)
+        fit_fn = dispatch.donating(
+            "logistic_binary_batched", fit_logistic_binary_batched,
+            donate_argnums=(3, 4),
+            static_argnames=(
+                "num_iters", "fit_intercept", "standardization"
+            ),
+        )
+        out = aot_call(
+            "logistic_binary_batched", fit_fn,
+            (
+                dispatch.device_f32(xp), jnp.asarray(yp),
+                jnp.asarray(rm), jnp.asarray(regs), jnp.asarray(ens),
+            ),
+            statics_kw,
+        )
+        if rm.shape[0] > k:
+            from .solvers import GLMParams
+
+            out = GLMParams(
+                weights=out.weights[:k], intercept=out.intercept[:k]
+            )
+        return out
+
+    @staticmethod
+    def _multinomial_fit(xp, yp, rm, regs, ens, num_classes, statics, rows):
+        """Every lane of one static group in ONE program on the shared
+        plane (``solvers.fit_logistic_multinomial_batched``), dispatched
+        asynchronously: lanes padded onto a shape bucket, the plane
+        through ``dispatch.device_f32``, the program through the
+        executable bank. ``rows``: the real rows (``xp`` may be padded
+        onto a mesh's row multiple)."""
+        from ..compiler import bucketing, dispatch
+        from ..utils.aot import aot_call
+
+        fit_intercept, max_iter, standardization = statics
+        k, (rm, regs, ens) = bucketing.bucket_sweep_lanes(rm, regs, ens)
+        with _tspans.span(
+            "glm/fit_dispatch", lanes=k, classes=num_classes,
+            rows=int(xp.shape[0]), cols=int(xp.shape[1]), num_iters=max_iter,
+            precision=solver_precision(), history=solvers._LBFGS_M,
+            ls_steps=len(solvers._LS_STEPS),
+        ):
+            fit = aot_call(
+                "logistic_multinomial_batched",
+                fit_logistic_multinomial_batched,
                 (
                     dispatch.device_f32(xp), jnp.asarray(yp),
                     jnp.asarray(rm), jnp.asarray(regs), jnp.asarray(ens),
                 ),
-                statics_kw,
+                dict(num_classes=num_classes, num_iters=max_iter,
+                     fit_intercept=fit_intercept,
+                     standardization=standardization),
             )
-            if rm.shape[0] > k:
-                from .solvers import GLMParams
+        return _MultinomialSweep(fit, k, rows, max_iter)
 
-                out = GLMParams(
-                    weights=out.weights[:k], intercept=out.intercept[:k]
-                )
-            return out
-        return jax.vmap(
-            lambda r, e, m: fit_logistic_multinomial(
-                xp, yp, m, r, e, num_classes=num_classes,
-                num_iters=max_iter * 4, fit_intercept=fit_intercept,
-                standardization=standardization,
+    def sweep_eval_batched(self, models_by_fold, x, y, folds, evaluator):
+        """Validator hook: every fold lane scored from the class logits
+        its fit program computed on the device, only the validation rows of
+        a fold's lanes downloaded (one gather a fold). None where a lane
+        has no such logits (binary lanes, points fitted one by one): the
+        validator then scores by ``predict_arrays``."""
+        flat = [m for fold_models in models_by_fold for m in fold_models]
+        if not flat or any(
+            getattr(m, "_sweep_stack", None) is None for m in flat
+        ):
+            return None
+        try:
+            fetched: dict = {}
+            for fi, (_train_mask, val_mask) in enumerate(folds):
+                val_idx = np.nonzero(val_mask)[0]
+                by_stack: dict = {}
+                for m in models_by_fold[fi]:
+                    by_stack.setdefault(
+                        id(m._sweep_stack), (m._sweep_stack, []))[1].append(m)
+                for stack, ms in by_stack.values():
+                    block = stack.lane_rows(
+                        [m._sweep_lane for m in ms], val_idx)
+                    for m, lane in zip(ms, block):
+                        fetched[fi, id(m)] = lane
+            return score_sweep_lanes(
+                models_by_fold, y, folds, evaluator,
+                lambda fi, m, _val_idx: fetched[fi, id(m)],
+                lanes=len(flat),
+                nbytes=sum(int(a.nbytes) for a in fetched.values()),
             )
-        )(regs, ens, rm)
+        except Exception:
+            log.warning("batched sweep-eval failed; falling back",
+                        exc_info=True)
+            return None
 
     def sweep_dispatch_masks(self, x, y, masks, grid_points):
         """Dispatch the folds × grid sweep, return a collector closure.
 
         Each same-(fit_intercept, max_iter, standardization) group batches
-        (fold-mask, reg, elastic-net) triples onto the fit axis (binary:
-        shared-x GEMM FISTA); points with unknown params fall back to
+        (fold-mask, reg, elastic-net) triples onto the fit axis (shared-x
+        GEMM L-BFGS/OWL-QN); points with unknown params fall back to
         sequential fits inside the collector. Binary groups under an
         active execution mesh route through the pjit'd SweepLayout path —
         explicit per-axis PartitionSpecs, donated fold buffers. Dispatch
@@ -319,9 +514,15 @@ class LogisticRegression(PredictorEstimator):
                 rm = np.repeat(
                     masksp, len(pts), axis=0
                 )  # [K, N], mask-major to match regs/ens tiling
-                stacked = self._batched_fit(
-                    xp, yp, rm, regs, ens, num_classes, statics, mesh=mesh
-                )
+                if num_classes == 2:
+                    stacked = self._batched_fit(
+                        xp, yp, rm, regs, ens, statics, mesh=mesh
+                    )
+                else:
+                    stacked = self._multinomial_fit(
+                        xp, yp, rm, regs, ens, num_classes, statics,
+                        rows=len(y),
+                    )
                 stacked_groups.append((idxs, len(pts), stacked))
 
         def collect() -> list[list]:
@@ -329,14 +530,22 @@ class LogisticRegression(PredictorEstimator):
                 [None] * len(grid_points) for _ in masks
             ]
             for idxs, n_pts, stacked in stacked_groups:
-                w = np.asarray(stacked.weights)
-                b = np.asarray(stacked.intercept)
+                sweep = isinstance(stacked, _MultinomialSweep)
+                if sweep:
+                    w, b = stacked.await_params()
+                else:
+                    w = np.asarray(stacked.weights)
+                    b = np.asarray(stacked.intercept)
                 for mi in range(n_masks):
                     for j, i in enumerate(idxs):
-                        models[mi][i] = LogisticRegressionModel(
-                            w[mi * n_pts + j], b[mi * n_pts + j],
-                            num_classes,
+                        lane = mi * n_pts + j
+                        model = LogisticRegressionModel(
+                            w[lane], b[lane], num_classes,
                         )
+                        if sweep:
+                            model._sweep_stack = stacked
+                            model._sweep_lane = lane
+                        models[mi][i] = model
             for i in sequential:
                 est = self.with_params(**grid_points[i])
                 for mi, m in enumerate(masks):

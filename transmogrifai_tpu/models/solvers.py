@@ -15,6 +15,11 @@ Losses follow Spark semantics: mean log-loss / squared error over unmasked
 rows + lambda * (alpha*||w||_1 + (1-alpha)/2*||w||_2^2), intercept
 unregularized, features standardized internally (standardization=true
 default) with coefficients mapped back to the original scale.
+
+Every product of a GLM fit runs at ONE stated matmul precision,
+``GLM_PRECISION`` (through ``_mm``): on a TPU the default precision of a
+float32 product is one bfloat16 pass, so a configuration that states
+float32 holds the solvers to this constant. It is not an option.
 """
 from __future__ import annotations
 
@@ -23,6 +28,15 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+#: the matmul precision of every GLM product (six bfloat16 passes on a TPU:
+#: float32 products; the default is one pass)
+GLM_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _mm(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` at ``GLM_PRECISION``: the one matmul of this module."""
+    return jnp.matmul(a, b, precision=GLM_PRECISION)
 
 
 class GLMParams(NamedTuple):
@@ -67,6 +81,88 @@ def _masked_minmax(x: jax.Array, rm: jax.Array):
         )
 
     return jax.lax.map(one, rm)
+
+
+class _LaneMoments(NamedTuple):
+    """Per-lane column moments of a shared plane under K row masks (the
+    implicit standardization of the batched solvers)."""
+
+    n: jax.Array         # [K] rows a lane holds (at least 1)
+    gshift: jax.Array    # [D] global column means (0 without standardization)
+    xc: jax.Array        # [N, D] the plane the products read
+    s2: jax.Array        # [K, D] second moments of x - gshift
+    mean_raw: jax.Array  # [K, D] lane means of x - gshift
+    var: jax.Array       # [K, D]
+    const: jax.Array     # [K, D] columns constant on a lane's rows
+    safe: jax.Array      # [K, D] the std a lane divides by
+    mean_c: jax.Array    # [K, D] the centring the products apply
+
+
+def _lane_moments(x, rm, standardization: bool, fit_intercept: bool):
+    """One pass of moments for K lanes on the shared ``x`` [N, D] under
+    masks ``rm`` [K, N]: the standardization every batched solver applies
+    implicitly, ``xsᵀr = (xcᵀ(r·m) − mean_c·Σ(r·m)) / safe``."""
+    n = jnp.maximum(rm.sum(axis=1), 1.0)                 # [K]
+    # shifted-data moments: center on the GLOBAL column means first so the
+    # one-pass per-lane variance s2c/n - mean_c² operates on small values —
+    # the raw one-pass form catastrophically cancels in f32 for large-mean
+    # columns (mean² ~4e6 has float spacing ~0.5). Without standardization
+    # the model must NOT center (iterates match the sequential raw-x path),
+    # so gshift/mean_c stay zero and s2 is the raw second moment.
+    if standardization:
+        gshift = x.mean(axis=0)                          # [D]
+    else:
+        gshift = jnp.zeros(x.shape[1], dtype=x.dtype)
+    xc = x - gshift[None, :]
+    s1 = _mm(rm, xc)                                     # [K, D]
+    s2 = _mm(rm, xc * xc)                                # [K, D]
+    mean_raw = s1 / n[:, None]
+    var = jnp.maximum(s2 / n[:, None] - mean_raw**2, 0.0)
+    std = jnp.sqrt(var)
+    # Fold-constant detection must be EXACT and reduction-order-invariant:
+    # a variance threshold computed from one-pass moments sits in f32
+    # cancellation noise, so a mesh-sharded run and a single-device run
+    # can flip a borderline column in opposite directions — one path pins
+    # the weight at 0, the other divides by the phantom std and amplifies
+    # it to O(10) (observed on Titanic fold masks). Masked min/max are
+    # exact under ANY association, so both paths agree bit-for-bit
+    # (_masked_minmax scans lanes instead of broadcasting [K, N, D]).
+    xmin, xmax = _masked_minmax(x, rm)                      # [K, D] each
+    const = xmax <= xmin
+    # near-constant (but not exactly constant) columns still carry one-pass
+    # cancellation noise in std; clamp to the noise floor instead of gating
+    # — a continuous guard cannot flip discretely between shardings
+    noise_floor = 2e-3 * jnp.sqrt(s2 / n[:, None]) + 1e-12
+    if standardization:
+        safe = jnp.where(const, 1.0, jnp.maximum(std, noise_floor))
+        if fit_intercept:
+            mean_c = mean_raw
+        else:
+            # no intercept → scale only, never center (Spark parity; a
+            # centered fit would differ from predict by mean·w). Gradients
+            # must then see RAW x, so undo the moment shift.
+            mean_c = jnp.zeros_like(mean_raw)
+            xc = x
+    else:
+        mean_c = jnp.zeros_like(mean_raw)
+        safe = jnp.ones_like(std)
+        xc = x
+    return _LaneMoments(n, gshift, xc, s2, mean_raw, var, const, safe, mean_c)
+
+
+def _col_second_moments(m: _LaneMoments, standardization: bool,
+                        fit_intercept: bool) -> jax.Array:
+    """tr(XsᵀXs)/n per lane [K]: centered standardized columns have unit
+    variance (0 for constant columns) → count of non-constant columns.
+    Scaled-but-NOT-centered columns (fit_intercept=False) have second
+    moment (var + mean²)/std² ≥ 1; without standardization it is the raw
+    masked second moment per column."""
+    if standardization and fit_intercept:
+        return (~m.const).sum(axis=1).astype(m.xc.dtype)
+    if standardization:
+        raw_second = m.var + (m.gshift[None, :] + m.mean_raw) ** 2
+        return jnp.where(m.const, 0.0, raw_second / m.safe**2).sum(axis=1)
+    return (m.s2 / m.n[:, None]).sum(axis=1)
 
 
 def _standardize(x: jax.Array, row_mask: jax.Array):
@@ -137,8 +233,8 @@ def fit_linear_batched(
     n = jnp.maximum(rm.sum(axis=1), 1.0)                    # [K]
     gshift = x.mean(axis=0)
     xc = x - gshift[None, :]
-    s1 = rm @ xc                                            # [K, D]
-    s2 = rm @ (xc * xc)
+    s1 = _mm(rm, xc)                                        # [K, D]
+    s2 = _mm(rm, xc * xc)
     mean_shift = s1 / n[:, None]
     var = jnp.maximum(s2 / n[:, None] - mean_shift**2, 0.0)
     std = jnp.sqrt(var)
@@ -160,7 +256,7 @@ def fit_linear_batched(
         xc = x
         ym = jnp.zeros_like(n)
     else:
-        ym = (rm @ y) / n                                   # [K]
+        ym = _mm(rm, y) / n                                 # [K]
     yc = jnp.where(rm > 0, y[None, :] - ym[:, None], 0.0)   # [K, N]
     l1 = (reg_params * elastic_nets)[:, None]
     l2 = (reg_params * (1.0 - elastic_nets))[:, None]
@@ -168,9 +264,9 @@ def fit_linear_batched(
     def grad(w_std):
         # w_std [K, D] in standardized space; const columns pinned at 0
         v = jnp.where(const, 0.0, w_std / safe)             # [K, D]
-        logits = xc @ v.T - (mean_shift * v).sum(axis=1)[None, :]  # [N, K]
+        logits = _mm(xc, v.T) - (mean_shift * v).sum(axis=1)[None, :]  # [N, K]
         r = (logits.T - yc) * rm                            # [K, N]
-        g_raw = r @ xc - mean_shift * r.sum(axis=1)[:, None]
+        g_raw = _mm(r, xc) - mean_shift * r.sum(axis=1)[:, None]
         g = jnp.where(const, 0.0, g_raw / safe) / n[:, None]
         return g + l2 * w_std
 
@@ -213,6 +309,13 @@ def fit_linear_batched(
 _LBFGS_M = 8           # history pairs (MLlib/breeze default m=10; 8 aligns)
 _LS_STEPS = (1.0, 0.5, 0.25, 0.1, 0.03, 0.01, 0.003)  # Armijo candidates
 _LS_C1 = 1e-4
+_GTOL = 1e-7
+
+
+class OwlqnResult(NamedTuple):
+    params: jax.Array      # [K, P]
+    iters_moved: jax.Array  # [K] int32: iterations in which the lane moved
+    converged: jax.Array   # [K] bool: the gradient test held at the end
 
 
 def _lbfgs_owlqn(
@@ -222,10 +325,12 @@ def _lbfgs_owlqn(
     l1_mat,            # [K, P] per-component l1 strength (0 on intercepts)
     gamma0,            # [K] initial inverse-Hessian scale (≈ 1/Lipschitz)
     num_iters: int,
-    gtol: float = 1e-7,
-):
-    """Returns argmin params [K, P]. All control flow is branchless so the
-    whole optimizer is one scanned XLA program, vmap- and GSPMD-friendly."""
+    gtol: float = _GTOL,
+) -> OwlqnResult:
+    """argmin params [K, P], with each lane's count of iterations that
+    moved it and whether its gradient test held. All control flow is
+    branchless so the whole optimizer is one scanned XLA program, vmap-
+    and GSPMD-friendly."""
     k_fits, p_dim = p0.shape
     m = _LBFGS_M
     ts = jnp.asarray(_LS_STEPS, dtype=p0.dtype)
@@ -253,69 +358,79 @@ def _lbfgs_owlqn(
         return -r
 
     def body(carry, _):
-        w, f_cur, g, S, Y, rho, gamma = carry
-        pg = pseudo_grad(w, g)
-        d = two_loop(pg, S, Y, rho, gamma)
-        # OWL-QN: constrain d to a descent direction of the pseudo-gradient
-        # on l1-active components (l1=0 lanes pass through untouched)
-        d = jnp.where((l1_mat > 0) & (d * pg >= 0), 0.0, d)
-        xi = jnp.where(w != 0, jnp.sign(w), jnp.sign(-pg))
-        cand = w[None] + ts[:, None, None] * d[None]          # [T, K, P]
-        cand = jnp.where((l1_mat > 0) & (cand * xi < 0), 0.0, cand)
-        f_cand = candidates_value(cand)                       # [T, K]
-        pgd = ((cand - w[None]) * pg[None]).sum(-1)           # [T, K]
-        accept = f_cand <= f_cur[None] + _LS_C1 * pgd
-        first_ok = jnp.argmax(accept, axis=0)                 # largest t ok
-        fallback = jnp.argmin(f_cand, axis=0)
-        idx = jnp.where(accept.any(axis=0), first_ok, fallback)
-        sel = jax.nn.one_hot(idx, len(_LS_STEPS), dtype=w.dtype, axis=0)
-        w_sel = (cand * sel[:, :, None]).sum(0)
-        f_sel = (f_cand * sel).sum(0)
+        w, f_cur, g, S, Y, rho, gamma, moved, _conv = carry
+        with jax.named_scope("glm/direction"):
+            pg = pseudo_grad(w, g)
+            d = two_loop(pg, S, Y, rho, gamma)
+            # OWL-QN: constrain d to a descent direction of the
+            # pseudo-gradient on l1-active components (l1=0 lanes pass
+            # through untouched)
+            d = jnp.where((l1_mat > 0) & (d * pg >= 0), 0.0, d)
+            xi = jnp.where(w != 0, jnp.sign(w), jnp.sign(-pg))
+            cand = w[None] + ts[:, None, None] * d[None]      # [T, K, P]
+            cand = jnp.where((l1_mat > 0) & (cand * xi < 0), 0.0, cand)
+        with jax.named_scope("glm/line_search"):
+            f_cand = candidates_value(cand)                   # [T, K]
+            pgd = ((cand - w[None]) * pg[None]).sum(-1)       # [T, K]
+            accept = f_cand <= f_cur[None] + _LS_C1 * pgd
+            first_ok = jnp.argmax(accept, axis=0)             # largest t ok
+            fallback = jnp.argmin(f_cand, axis=0)
+            idx = jnp.where(accept.any(axis=0), first_ok, fallback)
+            sel = jax.nn.one_hot(idx, len(_LS_STEPS), dtype=w.dtype, axis=0)
+            w_sel = (cand * sel[:, :, None]).sum(0)
+            f_sel = (f_cand * sel).sum(0)
         conv = jnp.abs(pg).max(-1) <= gtol * jnp.maximum(1.0, jnp.abs(f_cur))
         move = (f_sel < f_cur) & ~conv
         w_next = jnp.where(move[:, None], w_sel, w)
         f_next_sel, g_next = value_grad(w_next)
         f_next = jnp.where(move, f_next_sel, f_cur)
-        s = w_next - w
-        yv = g_next - g
-        sy = (s * yv).sum(-1)
-        # relative curvature gate (breeze-style): tiny-positive f32 sy
-        # garbage would otherwise produce huge rho and garbage directions
-        s_nrm = jnp.sqrt((s * s).sum(-1))
-        y_nrm = jnp.sqrt((yv * yv).sum(-1))
-        valid = move & (sy > 1e-8 * s_nrm * y_nrm + 1e-20)
-        # line-search failure away from convergence means the quasi-Newton
-        # direction went bad (stale/ill-conditioned history): RESET to
-        # steepest descent with the 1/Lipschitz scale. Without this the
-        # carry never changes and the lane deadlocks at a non-converged
-        # point (every later iteration rebuilds the same rejected step).
-        fail = ~move & ~conv
-        s = jnp.where(valid[:, None], s, 0.0)
-        yv = jnp.where(valid[:, None], yv, 0.0)
-        rho_new = jnp.where(valid, 1.0 / jnp.where(valid, sy, 1.0), 0.0)
-        vslot = valid[None, :, None]
-        S_next = jnp.where(vslot, jnp.concatenate([S[1:], s[None]]), S)
-        Y_next = jnp.where(vslot, jnp.concatenate([Y[1:], yv[None]]), Y)
-        rho_next = jnp.where(
-            valid[None, :], jnp.concatenate([rho[1:], rho_new[None]]), rho
-        )
-        S_next = jnp.where(fail[None, :, None], 0.0, S_next)
-        Y_next = jnp.where(fail[None, :, None], 0.0, Y_next)
-        rho_next = jnp.where(fail[None, :], 0.0, rho_next)
-        gamma_next = jnp.where(
-            valid, sy / jnp.maximum((yv * yv).sum(-1), 1e-20), gamma
-        )
-        gamma_next = jnp.where(fail, gamma00, gamma_next)
-        return (w_next, f_next, g_next, S_next, Y_next, rho_next, gamma_next), None
+        with jax.named_scope("glm/direction"):
+            s = w_next - w
+            yv = g_next - g
+            sy = (s * yv).sum(-1)
+            # relative curvature gate (breeze-style): tiny-positive f32 sy
+            # garbage would otherwise produce huge rho and garbage
+            # directions
+            s_nrm = jnp.sqrt((s * s).sum(-1))
+            y_nrm = jnp.sqrt((yv * yv).sum(-1))
+            valid = move & (sy > 1e-8 * s_nrm * y_nrm + 1e-20)
+            # line-search failure away from convergence means the
+            # quasi-Newton direction went bad (stale/ill-conditioned
+            # history): RESET to steepest descent with the 1/Lipschitz
+            # scale. Without this the carry never changes and the lane
+            # deadlocks at a non-converged point (every later iteration
+            # rebuilds the same rejected step).
+            fail = ~move & ~conv
+            s = jnp.where(valid[:, None], s, 0.0)
+            yv = jnp.where(valid[:, None], yv, 0.0)
+            rho_new = jnp.where(valid, 1.0 / jnp.where(valid, sy, 1.0), 0.0)
+            vslot = valid[None, :, None]
+            S_next = jnp.where(vslot, jnp.concatenate([S[1:], s[None]]), S)
+            Y_next = jnp.where(vslot, jnp.concatenate([Y[1:], yv[None]]), Y)
+            rho_next = jnp.where(
+                valid[None, :], jnp.concatenate([rho[1:], rho_new[None]]),
+                rho,
+            )
+            S_next = jnp.where(fail[None, :, None], 0.0, S_next)
+            Y_next = jnp.where(fail[None, :, None], 0.0, Y_next)
+            rho_next = jnp.where(fail[None, :], 0.0, rho_next)
+            gamma_next = jnp.where(
+                valid, sy / jnp.maximum((yv * yv).sum(-1), 1e-20), gamma
+            )
+            gamma_next = jnp.where(fail, gamma00, gamma_next)
+        return (w_next, f_next, g_next, S_next, Y_next, rho_next, gamma_next,
+                moved + move.astype(jnp.int32), conv), None
 
     f0, g0 = value_grad(p0)
     gamma00 = gamma0.astype(p0.dtype)
     S0 = jnp.zeros((m, k_fits, p_dim), dtype=p0.dtype)
     Y0 = jnp.zeros((m, k_fits, p_dim), dtype=p0.dtype)
     rho0 = jnp.zeros((m, k_fits), dtype=p0.dtype)
-    carry0 = (p0, f0, g0, S0, Y0, rho0, gamma0.astype(p0.dtype))
-    (w, *_), _ = jax.lax.scan(body, carry0, None, length=num_iters)
-    return w
+    carry0 = (p0, f0, g0, S0, Y0, rho0, gamma0.astype(p0.dtype),
+              jnp.zeros(k_fits, jnp.int32), jnp.zeros(k_fits, bool))
+    (w, *_, moved, conv), _ = jax.lax.scan(body, carry0, None,
+                                          length=num_iters)
+    return OwlqnResult(w, moved, conv)
 
 
 @partial(
@@ -373,7 +488,7 @@ def fit_logistic_binary_batched(
     sweep) and turns every iteration into a memory-bound pass over them.
     Here lanes batch as GEMM columns on the shared x (per iteration: one
     [T·K]-lane line-search GEMM + one gradient GEMM pair), with per-lane
-    standardization applied IMPLICITLY:
+    standardization applied IMPLICITLY (``_lane_moments``):
         xsᵀr = (xᵀ(r·m) − mean·Σ(r·m)) / std
     Round 2 ran FISTA here, which provably did not converge on Titanic's
     κ≈2e4 one-hot matrix within maxIter·4 iterations (fold metrics drifted
@@ -383,51 +498,9 @@ def fit_logistic_binary_batched(
     """
     k_fits, _ = row_masks.shape
     rm = row_masks.astype(x.dtype)
-    n = jnp.maximum(rm.sum(axis=1), 1.0)                 # [K]
-    # shifted-data moments: center on the GLOBAL column means first so the
-    # one-pass per-lane variance s2c/n - mean_c² operates on small values —
-    # the raw one-pass form catastrophically cancels in f32 for large-mean
-    # columns (mean² ~4e6 has float spacing ~0.5). Without standardization
-    # the model must NOT center (iterates match the sequential raw-x path),
-    # so gshift/mean_c stay zero and s2 is the raw second moment.
-    if standardization:
-        gshift = x.mean(axis=0)                          # [D]
-    else:
-        gshift = jnp.zeros(x.shape[1], dtype=x.dtype)
-    xc = x - gshift[None, :]
-    s1 = rm @ xc                                         # [K, D]
-    s2 = rm @ (xc * xc)                                  # [K, D]
-    mean_raw = s1 / n[:, None]
-    var = jnp.maximum(s2 / n[:, None] - mean_raw**2, 0.0)
-    std = jnp.sqrt(var)
-    # Fold-constant detection must be EXACT and reduction-order-invariant:
-    # a variance threshold computed from one-pass moments sits in f32
-    # cancellation noise, so a mesh-sharded run and a single-device run
-    # can flip a borderline column in opposite directions — one path pins
-    # the weight at 0, the other divides by the phantom std and amplifies
-    # it to O(10) (observed on Titanic fold masks). Masked min/max are
-    # exact under ANY association, so both paths agree bit-for-bit
-    # (_masked_minmax scans lanes instead of broadcasting [K, N, D]).
-    xmin, xmax = _masked_minmax(x, rm)                      # [K, D] each
-    const = xmax <= xmin
-    # near-constant (but not exactly constant) columns still carry one-pass
-    # cancellation noise in std; clamp to the noise floor instead of gating
-    # — a continuous guard cannot flip discretely between shardings
-    noise_floor = 2e-3 * jnp.sqrt(s2 / n[:, None]) + 1e-12
-    if standardization:
-        safe = jnp.where(const, 1.0, jnp.maximum(std, noise_floor))
-        if fit_intercept:
-            mean_c = mean_raw
-        else:
-            # no intercept → scale only, never center (Spark parity; a
-            # centered fit would differ from predict by mean·w). Gradients
-            # must then see RAW x, so undo the moment shift.
-            mean_c = jnp.zeros_like(mean_raw)
-            xc = x
-    else:
-        mean_c = jnp.zeros_like(mean_raw)
-        safe = jnp.ones_like(std)
-        xc = x
+    with jax.named_scope("glm/moments"):
+        mo = _lane_moments(x, rm, standardization, fit_intercept)
+    n, xc, safe, mean_c = mo.n, mo.xc, mo.safe, mo.mean_c
     l1 = (reg_params * elastic_nets)[:, None]            # [K, 1]
     l2 = (reg_params * (1.0 - elastic_nets))[:, None]
     d_cols = x.shape[1]
@@ -442,7 +515,7 @@ def fit_logistic_binary_batched(
     def _logits_of(ws, b):
         # ws [..., K, D] (already scaled by 1/safe) -> logits [..., K, N]
         lead = ws.shape[:-1]
-        lin = (xc @ ws.reshape(-1, d_cols).T).T.reshape(*lead, -1)
+        lin = _mm(xc, ws.reshape(-1, d_cols).T).T.reshape(*lead, -1)
         out = lin - (mean_c * ws).sum(-1)[..., None]
         if fit_intercept:
             out = out + b[..., None]
@@ -453,34 +526,24 @@ def fit_logistic_binary_batched(
         return _loss_terms(_logits_of(w_std / safe, b), w_std)
 
     def value_grad(params):                              # [K, P]
-        w_std, b = params[:, :-1], params[:, -1]
-        ws = w_std / safe
-        logits = _logits_of(ws, b)
-        f_total = _loss_terms(logits, w_std)
-        p = jax.nn.sigmoid(logits)
-        r = (p - y[None, :]) * rm                        # [K, N]
-        xr = r @ xc                                      # [K, D]
-        rsum = r.sum(axis=1)[:, None]
-        gw = (xr - mean_c * rsum) / safe / n[:, None] + l2 * w_std
-        if standardization:
-            # constant columns are pure cancellation noise: pin their
-            # weights at 0 (matches _standardize zeroing those columns)
-            gw = jnp.where(const, 0.0, gw)
-        gb = jnp.where(fit_intercept, rsum[:, 0] / n, 0.0)
-        return f_total, jnp.concatenate([gw, gb[:, None]], axis=1)
+        with jax.named_scope("glm/value_grad"):
+            w_std, b = params[:, :-1], params[:, -1]
+            ws = w_std / safe
+            logits = _logits_of(ws, b)
+            f_total = _loss_terms(logits, w_std)
+            p = jax.nn.sigmoid(logits)
+            r = (p - y[None, :]) * rm                    # [K, N]
+            xr = _mm(r, xc)                              # [K, D]
+            rsum = r.sum(axis=1)[:, None]
+            gw = (xr - mean_c * rsum) / safe / n[:, None] + l2 * w_std
+            if standardization:
+                # constant columns are pure cancellation noise: pin their
+                # weights at 0 (matches _standardize zeroing those columns)
+                gw = jnp.where(mo.const, 0.0, gw)
+            gb = jnp.where(fit_intercept, rsum[:, 0] / n, 0.0)
+            return f_total, jnp.concatenate([gw, gb[:, None]], axis=1)
 
-    # tr(XsᵀXs)/n per lane: centered standardized columns have unit
-    # variance (0 for constant columns) → count of non-constant columns.
-    # Scaled-but-NOT-centered columns (fit_intercept=False) have second
-    # moment (var + mean²)/std² ≥ 1; without standardization it is the raw
-    # masked second moment per column.
-    if standardization and fit_intercept:
-        col_sum = (~const).sum(axis=1).astype(x.dtype)
-    elif standardization:
-        raw_second = var + (gshift[None, :] + mean_raw) ** 2
-        col_sum = jnp.where(const, 0.0, raw_second / safe**2).sum(axis=1)
-    else:
-        col_sum = (s2 / n[:, None]).sum(axis=1)
+    col_sum = _col_second_moments(mo, standardization, fit_intercept)
     lip = 0.25 * col_sum + l2[:, 0]
     gamma0 = 1.0 / jnp.maximum(lip, 1e-6)                # [K]
 
@@ -492,15 +555,159 @@ def fit_logistic_binary_batched(
     params0 = jnp.zeros((k_fits, d_cols + 1), dtype=x.dtype)
     params = _lbfgs_owlqn(
         value_grad, candidates_value, params0, l1_mat, gamma0, num_iters
-    )
+    ).params
     w_std, b_std = params[:, :-1], params[:, -1]
     w = w_std / safe
-    mean_total = gshift[None, :] + mean_c
+    mean_total = mo.gshift[None, :] + mean_c
     b = b_std - (w_std * mean_total / safe).sum(axis=1)
     return GLMParams(
         weights=w,
         intercept=jnp.where(fit_intercept, b, jnp.zeros_like(b)),
     )
+
+
+class MultinomialFit(NamedTuple):
+    """What one batched multinomial fit program returns, lane by lane."""
+
+    weights: jax.Array      # [K, D, C] original-scale coefficients
+    intercept: jax.Array    # [K, C], centred to mean 0
+    logits: jax.Array       # [K, C, N] every row's class logits at the fit
+    iters_moved: jax.Array  # [K] int32 iterations in which the lane moved
+    converged: jax.Array    # [K] bool: the gradient test held at the end
+
+
+def _multinomial_penalty_weights(reg_params, elastic_nets, safe, classes):
+    """Spark's elastic net in the ``[K, D·C + C]`` parameter layout: per
+    parameter (l1 [K, P], l2 [K, P]), λα and λ(1 − α) on every coefficient
+    of the STANDARDIZED plane (Spark's with standardization), 0 on the
+    intercepts, which are never penalised."""
+    k, d = safe.shape
+    coef = jnp.ones((k, d * classes), dtype=safe.dtype)
+    free = jnp.zeros((k, classes), dtype=safe.dtype)
+    l1 = (reg_params * elastic_nets)[:, None] * coef
+    l2 = (reg_params * (1.0 - elastic_nets))[:, None] * coef
+    return (jnp.concatenate([l1, free], axis=1),
+            jnp.concatenate([l2, free], axis=1))
+
+
+@partial(
+    jax.jit,
+    static_argnames=(
+        "num_classes", "num_iters", "fit_intercept", "standardization"
+    ),
+)
+def fit_logistic_multinomial_batched(
+    x: jax.Array,            # [N, D] SHARED feature matrix
+    y: jax.Array,            # [N] class ids 0 … C - 1
+    row_masks: jax.Array,    # [K, N] per-fit masks (folds × grid)
+    reg_params: jax.Array,   # [K]
+    elastic_nets: jax.Array,  # [K]
+    num_classes: int,
+    num_iters: int = 100,
+    fit_intercept: bool = True,
+    standardization: bool = True,
+) -> MultinomialFit:
+    """K softmax regressions over C classes sharing ONE feature matrix:
+    Spark 2.4's multinomial family (no pivot class), by the binary fit's
+    L-BFGS/OWL-QN, ``num_iters`` iterations (Spark's ``maxIter``, 1:1).
+
+    Lanes batch as GEMM columns on the shared x, standardized implicitly
+    by the binary fit's moments (``_lane_moments``): parameters are
+    ``[K, D·C + C]`` (the standardized coefficients, then the intercepts),
+    so a line search evaluates every step candidate of every lane and
+    class in ONE ``[N, D] × [D, T·K·C]`` product.
+
+    Per lane with row mask m over n rows and one-hot labels Y:
+    ``F = (1/n) Σ m_i (logsumexp(z_i) − z_i·Y_i) + ½λ(1−α)‖β‖² + λα‖β‖₁``
+    with ``z = xs β + b`` on the lane's standardized plane xs; columns
+    constant on the lane's rows are pinned at 0. Start: β = 0 and b the
+    centred log(1 + class count) of the lane's rows (Spark's start). At
+    the end the coefficients map back to the original scale, the
+    intercepts are centred to mean 0, and at λ = 0 the coefficients too
+    (Spark's identifiability rule); ``logits`` are every row's class
+    logits at the fitted point, up to a constant a row (what a softmax
+    and an arg max read)."""
+    k_fits = row_masks.shape[0]
+    c, d = num_classes, x.shape[1]
+    rm = row_masks.astype(x.dtype)
+    with jax.named_scope("glm/moments"):
+        mo = _lane_moments(x, rm, standardization, fit_intercept)
+        y1h = jax.nn.one_hot(y.astype(jnp.int32), c, dtype=x.dtype)  # [N, C]
+        counts = _mm(rm, y1h)                                 # [K, C]
+    n, xc, safe, mean_c = mo.n, mo.xc, mo.safe, mo.mean_c
+    l1_mat, l2_mat = _multinomial_penalty_weights(
+        reg_params, elastic_nets, safe, c)
+    pinned = jnp.concatenate(
+        [jnp.repeat(mo.const if standardization else jnp.zeros_like(
+            mo.const), c, axis=1), jnp.zeros((k_fits, c), bool)], axis=1)
+    rm_t = rm.T                                               # [N, K]
+
+    def logits_of(params):
+        # params [..., K, P] -> class logits [N, ..., K, C]
+        lead = params.shape[:-1]
+        w_std = params[..., : d * c].reshape(*lead, d, c)
+        ws = w_std / safe[..., None]                          # [..., K, D, C]
+        flat = jnp.moveaxis(ws, -2, 0).reshape(d, -1)         # [D, ...·K·C]
+        z = _mm(xc, flat).reshape(-1, *lead, c)
+        z = z - (mean_c[..., None] * ws).sum(-2)[None]
+        if fit_intercept:
+            z = z + params[..., d * c:][None]
+        return z
+
+    def objective(params, z):
+        # z [N, ..., K, C] -> F [..., K]
+        lse = jax.nn.logsumexp(z, axis=-1)
+        zy = (z * y1h.reshape(-1, *([1] * (z.ndim - 2)), c)).sum(-1)
+        loss = ((lse - zy) * rm_t.reshape(
+            -1, *([1] * (z.ndim - 3)), k_fits)).sum(0) / n
+        return (loss + 0.5 * (l2_mat * params * params).sum(-1)
+                + (l1_mat * jnp.abs(params)).sum(-1))
+
+    def candidates_value(cand):                               # [T, K, P]
+        return objective(cand, logits_of(cand))
+
+    def value_grad(params):                                   # [K, P]
+        with jax.named_scope("glm/value_grad"):
+            z = logits_of(params)                             # [N, K, C]
+            f_total = objective(params, z)
+            r = (jax.nn.softmax(z, axis=-1) - y1h[:, None, :]) * rm_t[
+                :, :, None]                                   # [N, K, C]
+            xr = _mm(xc.T, r.reshape(-1, k_fits * c)).reshape(d, k_fits, c)
+            rsum = r.sum(axis=0)                              # [K, C]
+            gw = (jnp.moveaxis(xr, 0, 1) - mean_c[..., None] * rsum[:, None])
+            gw = gw / safe[..., None] / n[:, None, None]
+            gb = rsum / n[:, None] if fit_intercept else jnp.zeros_like(rsum)
+            g = jnp.concatenate([gw.reshape(k_fits, d * c), gb], axis=1)
+            g = g + l2_mat * params
+            return f_total, jnp.where(pinned, 0.0, g)
+
+    col_sum = _col_second_moments(mo, standardization, fit_intercept)
+    gamma0 = 1.0 / jnp.maximum(
+        0.5 * col_sum + reg_params * (1.0 - elastic_nets), 1e-6)
+    if fit_intercept:
+        prior = jnp.log1p(counts)
+        b0 = prior - prior.mean(axis=1, keepdims=True)
+    else:
+        b0 = jnp.zeros((k_fits, c), dtype=x.dtype)
+    params0 = jnp.concatenate(
+        [jnp.zeros((k_fits, d * c), dtype=x.dtype), b0], axis=1)
+    fit = _lbfgs_owlqn(
+        value_grad, candidates_value, params0, l1_mat, gamma0, num_iters)
+    params = fit.params
+    with jax.named_scope("glm/scores"):
+        z = logits_of(params)                                 # [N, K, C]
+        logits = jnp.moveaxis(z, 0, -1)                       # [K, C, N]
+    w_std = params[:, : d * c].reshape(k_fits, d, c)
+    w = w_std / safe[..., None]
+    mean_total = (mo.gshift[None, :] + mean_c)[..., None]
+    if fit_intercept:
+        b = params[:, d * c:] - (w_std * mean_total / safe[..., None]).sum(1)
+        b = b - b.mean(axis=1, keepdims=True)
+    else:
+        b = jnp.zeros((k_fits, c), dtype=x.dtype)
+    w = jnp.where((reg_params == 0.0)[:, None, None],
+                  w - w.mean(axis=(1, 2), keepdims=True), w)
+    return MultinomialFit(w, b, logits, fit.iters_moved, fit.converged)
 
 
 @partial(
@@ -516,52 +723,21 @@ def fit_logistic_multinomial(
     reg_param: jax.Array,
     elastic_net: jax.Array,
     num_classes: int,
-    num_iters: int = 200,
+    num_iters: int = 100,
     fit_intercept: bool = True,
     standardization: bool = True,
 ) -> GLMParams:
-    """Softmax regression (Spark multinomial logistic parity)."""
-    row_mask = row_mask.astype(x.dtype)
-    n = jnp.maximum(row_mask.sum(), 1.0)
-    if standardization:
-        xs, mean, std, const = _standardize(x, row_mask)
-        if not fit_intercept:
-            mean = jnp.zeros(x.shape[1], dtype=x.dtype)
-            xs = _scale_only(x, row_mask, std, const)
-    else:
-        xs = jnp.where(row_mask[:, None] > 0, x, 0.0)
-        mean = jnp.zeros(x.shape[1], dtype=x.dtype)
-        std = jnp.ones(x.shape[1], dtype=x.dtype)
-    y1h = jax.nn.one_hot(y.astype(jnp.int32), num_classes, dtype=x.dtype)
-    l1 = reg_param * elastic_net
-    l2 = reg_param * (1.0 - elastic_net)
-    d = x.shape[1]
-
-    def unpack(params):
-        return params[: d * num_classes].reshape(d, num_classes), params[d * num_classes:]
-
-    def grad(params):
-        w, b = unpack(params)
-        logits = xs @ w + jnp.where(fit_intercept, b, 0.0)
-        p = jax.nn.softmax(logits, axis=-1)
-        r = (p - y1h) * row_mask[:, None]
-        gw = xs.T @ r / n + l2 * w
-        gb = jnp.where(fit_intercept, r.sum(0) / n, jnp.zeros_like(b))
-        return jnp.concatenate([gw.reshape(-1), gb])
-
-    def prox(params, step):
-        w, b = unpack(params)
-        return jnp.concatenate([_soft_threshold(w, step * l1).reshape(-1), b])
-
-    col = (xs * xs).sum(0) / n
-    lip = 0.5 * col.sum() + l2
-    step = 1.0 / jnp.maximum(lip, 1e-6)
-    params0 = jnp.zeros(d * num_classes + num_classes, dtype=x.dtype)
-    params = _fista(grad, prox, params0, step, num_iters)
-    w_std, b_std = unpack(params)
-    w = w_std / std[:, None]
-    b = b_std - (w_std * (mean / std)[:, None]).sum(0)
-    return GLMParams(weights=w, intercept=b if fit_intercept else jnp.zeros_like(b))
+    """Softmax regression (Spark's multinomial family): the K=1 lane of
+    ``fit_logistic_multinomial_batched``, so a single fit and a sweep's
+    lane run IDENTICAL math."""
+    out = fit_logistic_multinomial_batched(
+        x, y, row_mask[None, :],
+        jnp.asarray(reg_param, dtype=x.dtype)[None],
+        jnp.asarray(elastic_net, dtype=x.dtype)[None],
+        num_classes=num_classes, num_iters=num_iters,
+        fit_intercept=fit_intercept, standardization=standardization,
+    )
+    return GLMParams(weights=out.weights[0], intercept=out.intercept[0])
 
 
 @partial(jax.jit, static_argnames=("num_iters", "fit_intercept", "standardization"))
@@ -595,7 +771,7 @@ def fit_linear_svc(
 
     def grad(params):
         w, b = params[:-1], params[-1]
-        margin = s * (xs @ w + jnp.where(fit_intercept, b, 0.0))
+        margin = s * (_mm(xs, w) + jnp.where(fit_intercept, b, 0.0))
         # dL/dmargin for Huberized hinge: -1 below the band, linear inside
         slope = -jnp.clip((1.0 - margin) / delta, 0.0, 1.0)
         r = slope * s * row_mask
@@ -711,15 +887,15 @@ def fit_glm_irls(
         )
 
     def body(beta, _):
-        eta = xa @ beta
+        eta = _mm(xa, beta)
         mu = linkinv(eta)
         dmu = dmu_deta(eta, mu)
         dmu = jnp.where(jnp.abs(dmu) > eps, dmu, eps)
         var = jnp.maximum(variance(mu), eps)
         z = eta + (y - mu) / dmu
         w = row_mask * dmu * dmu / var
-        xtwx = (xa * w[:, None]).T @ xa / n
-        xtwz = (xa * w[:, None]).T @ z / n
+        xtwx = _mm((xa * w[:, None]).T, xa) / n
+        xtwz = _mm((xa * w[:, None]).T, z) / n
         reg = reg_param * jnp.eye(da, dtype=x.dtype)
         if fit_intercept:  # intercept unregularized
             reg = reg.at[da - 1, da - 1].set(0.0)
@@ -728,8 +904,8 @@ def fit_glm_irls(
 
     eta0 = init_eta()
     w0 = row_mask
-    xtwx0 = (xa * w0[:, None]).T @ xa / n
-    xtwz0 = (xa * w0[:, None]).T @ eta0 / n
+    xtwx0 = _mm((xa * w0[:, None]).T, xa) / n
+    xtwz0 = _mm((xa * w0[:, None]).T, eta0) / n
     beta0 = jnp.linalg.solve(
         xtwx0 + (reg_param + eps) * jnp.eye(da, dtype=x.dtype), xtwz0
     )
@@ -768,8 +944,8 @@ def fit_linear(
     l2 = reg_param * (1.0 - elastic_net)
 
     def grad(w):
-        r = (xs @ w - yc) * row_mask
-        return xs.T @ r / n + l2 * w
+        r = (_mm(xs, w) - yc) * row_mask
+        return _mm(xs.T, r) / n + l2 * w
 
     def prox(w, step):
         return _soft_threshold(w, step * l1)
@@ -839,6 +1015,24 @@ def program_trace_specs():
             ),
             static_argnames=(
                 "num_iters", "fit_intercept", "standardization"
+            ),
+        ),
+        dict(
+            name="logistic_multinomial_batched",
+            fn=fit_logistic_multinomial_batched,
+            build=lambda k: (
+                _glm_args(k),
+                dict(num_classes=3, num_iters=2, fit_intercept=True,
+                     standardization=True),
+            ),
+            buckets=(8, 64, 96),
+            bucket_axis="lanes",
+            base_fn=getattr(
+                fit_logistic_multinomial_batched, "__wrapped__", None
+            ),
+            static_argnames=(
+                "num_classes", "num_iters", "fit_intercept",
+                "standardization",
             ),
         ),
     ]

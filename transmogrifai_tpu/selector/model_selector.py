@@ -431,7 +431,13 @@ class ModelSelector(PredictorEstimator):
                     # fit program itself — grab them BEFORE detach frees the
                     # stack, so train evaluation needs no re-predict
                     stack = getattr(best_model, "_sweep_stack", None)
-                    if (
+                    if stack is not None and hasattr(
+                        best_model, "sweep_lane_outputs"
+                    ):
+                        # a family whose lanes' outputs stay on the device
+                        # hands over the refit lane's alone
+                        refit_raw = best_model.sweep_lane_outputs()
+                    elif (
                         stack is not None
                         and stack.get("outputs") is not None
                         and hasattr(best_model, "predictions_from_sweep")
