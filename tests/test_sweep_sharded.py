@@ -13,6 +13,7 @@ import jax
 import transmogrifai_tpu.types as T
 from transmogrifai_tpu.compiler import bucketing
 from transmogrifai_tpu.dataset import Dataset
+from transmogrifai_tpu.evaluators.base import Evaluator
 from transmogrifai_tpu.features import from_dataset
 from transmogrifai_tpu.models.logistic import LogisticRegression
 from transmogrifai_tpu.models.solvers import (
@@ -189,7 +190,7 @@ class _OkModel:
         return np.full(len(x), self.v), None, None
 
 
-class _Eval:
+class _Eval(Evaluator):
     is_larger_better = False
 
     def evaluate_arrays(self, y, pred, prob):

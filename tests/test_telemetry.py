@@ -927,13 +927,16 @@ def test_fit_arrays_yields_the_sweep_span_tree(xgb_sweep):
 def test_sweep_scoring_names_its_seconds(xgb_sweep):
     """The fold lanes' turning of outputs into predictions rides their
     ``selector/evaluate`` span, the winner's on ``selector/refit``; the
-    evaluator's groups of metrics ride each ``selector/evaluate``."""
+    evaluator's groups of metrics ride each ``selector/evaluate``: the fold
+    lanes' the selector's metric alone, the train evaluation's all four."""
     recs = xgb_sweep["records"]
     fold, train = [r["args"] for r in recs if r["name"] == "selector/evaluate"]
     (refit,) = [r["args"] for r in recs if r["name"] == "selector/refit"]
-    groups = {"AuPR", "AuROC", "Counts", "ThresholdCurve"}
+    assert set(fold["metric_seconds"]) == {fold["reads"]} == {"AuPR"}
+    assert train["reads"] == "all"
+    assert set(train["metric_seconds"]) == {
+        "AuPR", "AuROC", "Counts", "ThresholdCurve"}
     for args in (fold, train):
-        assert set(args["metric_seconds"]) == groups
         assert all(s >= 0.0 for s in args["metric_seconds"].values())
     assert fold["predict_seconds"] >= 0.0 and refit["predict_seconds"] >= 0.0
     assert "predict_seconds" not in train
@@ -947,7 +950,8 @@ def test_sweep_scoring_names_its_seconds(xgb_sweep):
 
 def test_per_fold_scoring_states_what_it_reads():
     """A family without batched scoring takes the validator's per-fold
-    loop: its ``selector/evaluate`` spans carry the same attributes."""
+    loop: its ``selector/evaluate`` spans carry the same attributes, and
+    its fold lanes are scored by the selector's metric alone."""
     x, y = _tree_table(seed=4)
     selector = BinaryClassificationModelSelector(seed=5, models=LR_MODELS)
     tspans.reset_for_tests()
@@ -959,8 +963,10 @@ def test_per_fold_scoring_states_what_it_reads():
     assert [a["reads"] for a in evaluated].count("all") == 1
     for a in folds:
         assert a["predict_seconds"] >= 0.0
-        assert set(a["metric_seconds"]) == {
-            "AuPR", "AuROC", "Counts", "ThresholdCurve"}
+        assert set(a["metric_seconds"]) == {"AuPR"}
+    (train,) = [a for a in evaluated if a["reads"] == "all"]
+    assert set(train["metric_seconds"]) == {
+        "AuPR", "AuROC", "Counts", "ThresholdCurve"}
     (refit,) = [r["args"] for r in recs if r["name"] == "selector/refit"]
     assert refit["predict_seconds"] >= 0.0
 

@@ -82,9 +82,7 @@ def main() -> None:
                     vi = np.nonzero(vm)[0]
                     for m in models[fi]:
                         pred, prob, _ = m.predict_arrays(x[vi])
-                        evaluator.metric_of(
-                            evaluator.evaluate_arrays(y[vi], pred, prob)
-                        )
+                        evaluator.evaluate_metric(y[vi], pred, prob)
                 t2 = time.perf_counter()
             print(
                 f"{name} rep{rep}: fit {t1-t0:6.2f}s  eval {t2-t1:6.2f}s "

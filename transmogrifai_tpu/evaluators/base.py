@@ -41,3 +41,15 @@ class Evaluator:
 
     def metric_of(self, metrics: EvalMetrics) -> float:
         return float(metrics[self.default_metric])
+
+    def evaluate_metric(
+        self,
+        y: np.ndarray,
+        pred: np.ndarray,
+        prob: np.ndarray | None,
+    ) -> float:
+        """The selection metric alone, what a fold lane keeps: exactly
+        ``metric_of(evaluate_arrays(y, pred, prob))``. An evaluator whose
+        ``default_metric`` has a timed group of its own computes that group
+        alone; any other metric takes the whole record."""
+        return self.metric_of(self.evaluate_arrays(y, pred, prob))

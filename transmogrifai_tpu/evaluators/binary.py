@@ -52,6 +52,12 @@ def aupr(y: np.ndarray, score: np.ndarray) -> float:
     return float(np.trapezoid(precision, recall))
 
 
+def _score(pred: np.ndarray, prob: np.ndarray | None) -> np.ndarray:
+    """What the areas rank rows by: the positive class's probability where
+    there is one, else the prediction."""
+    return prob[:, 1] if prob is not None and prob.ndim == 2 else pred
+
+
 class BinaryClassificationEvaluator(Evaluator):
     default_metric = "AuPR"
     is_larger_better = True
@@ -65,7 +71,7 @@ class BinaryClassificationEvaluator(Evaluator):
         # exists for; the seconds go to the caller's span (``metric_seconds``)
         clock = _tspans.clock
         t0 = clock()
-        score = prob[:, 1] if prob is not None and prob.ndim == 2 else pred
+        score = _score(pred, prob)
         tp = float(((pred == 1) & (y == 1)).sum())
         tn = float(((pred == 0) & (y == 0)).sum())
         fp = float(((pred == 1) & (y == 0)).sum())
@@ -116,3 +122,15 @@ class BinaryClassificationEvaluator(Evaluator):
             "recallByThreshold": curve_r,
             "f1ByThreshold": curve_f,
         }
+
+    def evaluate_metric(self, y, pred, prob):
+        # the areas a selection metric can name, each a timed group of its
+        # own; looked up at the call, as evaluate_arrays does
+        area = {"AuPR": aupr, "AuROC": auroc}.get(self.default_metric)
+        if area is None:
+            return super().evaluate_metric(y, pred, prob)
+        t0 = _tspans.clock()
+        value = area(y, _score(pred, prob))
+        _tspans.add_seconds(
+            "metric_seconds", {self.default_metric: _tspans.clock() - t0})
+        return value

@@ -81,6 +81,32 @@ def calculate_threshold_metrics(
     }
 
 
+def _weighted_scores(y: np.ndarray, pred: np.ndarray) -> dict[str, float]:
+    """Precision, recall and F1 of every class of ``y`` and ``pred``, each
+    weighted by the class's share of the rows."""
+    classes = np.unique(np.concatenate([y, pred]))
+    n = max(len(y), 1)
+    weights, precisions, recalls, f1s = [], [], [], []
+    for c in classes:
+        tp = float(((pred == c) & (y == c)).sum())
+        fp = float(((pred == c) & (y != c)).sum())
+        fn = float(((pred != c) & (y == c)).sum())
+        support = float((y == c).sum())
+        p = tp / (tp + fp) if tp + fp > 0 else 0.0
+        r = tp / (tp + fn) if tp + fn > 0 else 0.0
+        f = 2 * p * r / (p + r) if p + r > 0 else 0.0
+        weights.append(support / n)
+        precisions.append(p)
+        recalls.append(r)
+        f1s.append(f)
+    w = np.asarray(weights)
+    return {
+        "Precision": float(np.dot(w, precisions)),
+        "Recall": float(np.dot(w, recalls)),
+        "F1": float(np.dot(w, f1s)),
+    }
+
+
 class MultiClassificationEvaluator(Evaluator):
     default_metric = "F1"
     is_larger_better = True
@@ -101,26 +127,8 @@ class MultiClassificationEvaluator(Evaluator):
         # exists for; the seconds go to the caller's span (``metric_seconds``)
         clock = _tspans.clock
         t0 = clock()
-        classes = np.unique(np.concatenate([y, pred]))
-        n = max(len(y), 1)
-        weights, precisions, recalls, f1s = [], [], [], []
-        for c in classes:
-            tp = float(((pred == c) & (y == c)).sum())
-            fp = float(((pred == c) & (y != c)).sum())
-            fn = float(((pred != c) & (y == c)).sum())
-            support = float((y == c).sum())
-            p = tp / (tp + fp) if tp + fp > 0 else 0.0
-            r = tp / (tp + fn) if tp + fn > 0 else 0.0
-            f = 2 * p * r / (p + r) if p + r > 0 else 0.0
-            weights.append(support / n)
-            precisions.append(p)
-            recalls.append(r)
-            f1s.append(f)
-        w = np.asarray(weights)
         metrics = {
-            "Precision": float(np.dot(w, precisions)),
-            "Recall": float(np.dot(w, recalls)),
-            "F1": float(np.dot(w, f1s)),
+            **_weighted_scores(y, pred),
             "Error": float((pred != y).mean()),
         }
         t1 = clock()
@@ -141,3 +149,11 @@ class MultiClassificationEvaluator(Evaluator):
             seconds.update(TopKAccuracy=t2 - t1, ThresholdMetrics=clock() - t2)
         _tspans.add_seconds("metric_seconds", seconds)
         return metrics
+
+    def evaluate_metric(self, y, pred, prob):
+        if self.default_metric != "F1":
+            return super().evaluate_metric(y, pred, prob)
+        t0 = _tspans.clock()
+        value = _weighted_scores(y, pred)["F1"]
+        _tspans.add_seconds("metric_seconds", {"F1": _tspans.clock() - t0})
+        return value

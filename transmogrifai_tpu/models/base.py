@@ -120,8 +120,8 @@ def score_sweep_lanes(models_by_fold, y, folds, evaluator, lane_outputs,
                 pred, prob, _ = m.predictions_from_sweep(
                     lane_outputs(fi, m, val_idx))
                 predict_s += clock() - t0
-                metrics = evaluator.evaluate_arrays(y_val, pred, prob)
-                values[gi].append(evaluator.metric_of(metrics))
+                values[gi].append(
+                    evaluator.evaluate_metric(y_val, pred, prob))
         sp.attrs.update(
             classes=0 if prob is None else prob.shape[1],
             predict_seconds=predict_s,

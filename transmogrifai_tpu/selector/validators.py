@@ -427,10 +427,9 @@ class Validator:
                         t0 = _tspans.clock()
                         pred, prob, _ = model.predict_arrays(x[val_idx])
                         predict_s += _tspans.clock() - t0
-                        metrics = evaluator.evaluate_arrays(
+                        value = evaluator.evaluate_metric(
                             y[val_idx], pred, prob
                         )
-                        value = evaluator.metric_of(metrics)
                     except Exception as e:  # lane-level isolation
                         log.warning(
                             "Lane %d (%s) of %s failed scoring in fold %d: "

@@ -357,8 +357,7 @@ def _eval_lanes(
             continue
         try:
             pred, prob, _ = model.predict_arrays(xv)
-            metrics = evaluator.evaluate_arrays(yv, pred, prob)
-            value = evaluator.metric_of(metrics)
+            value = evaluator.evaluate_metric(yv, pred, prob)
         except Exception as e:  # lane-level isolation
             log.warning(
                 "Lane %d (%s) of %s failed scoring: %s",
