@@ -29,6 +29,11 @@ MULTICLASS_GROUPS = {
     "TopKAccuracy": ("TopKAccuracy",),
     "ThresholdMetrics": ("ThresholdMetrics",),
 }
+REGRESSION_GROUPS = {
+    "RMSE": ("RMSE",),
+    "Errors": ("MSE", "R2", "MAE"),
+    "SignedPercentageErrorHistogram": ("SignedPercentageErrorHistogram",),
+}
 
 
 @pytest.fixture(autouse=True)
@@ -68,6 +73,7 @@ CASES = {
     "binary_pred_only": (lambda: _binary(False), BINARY_GROUPS),
     "multiclass_prob": (lambda: _multiclass(True), MULTICLASS_GROUPS),
     "multiclass_pred_only": (lambda: _multiclass(False), MULTICLASS_GROUPS),
+    "regression": (_regression, REGRESSION_GROUPS),
 }
 
 
@@ -134,12 +140,11 @@ METRIC_CASES = [
     (case, metric, metric == "F1")
     for case in ("multiclass_prob", "multiclass_pred_only")
     for metric in ("F1", "Error")
-] + [("regression", "RMSE", False)]
+] + [("regression", "RMSE", True), ("regression", "MSE", False)]
 
 
 def _metric_case(case, metric):
-    make = _regression if case == "regression" else CASES[case][0]
-    ev, y, pred, prob = make()
+    ev, y, pred, prob = CASES[case][0]()
     ev.default_metric = metric
     return ev, y, pred, prob
 

@@ -370,3 +370,33 @@ def test_sampled_fit_states_rows_in_and_rows(rng):
     assert args["sanity/stats"] == {"kind": "spearman"}
     # a dense vector with no pick-list groups: nothing categorical
     assert args["sanity/categorical"] == {"groups": 0, "cols": 0}
+
+
+def test_continuous_label_builds_no_label_one_hot(rng):
+    """A continuous label has about as many distinct values as rows: the
+    checker skips the categorical stage for it without building a
+    [rows, distinct values] label one-hot (the 1,000,000-row regression
+    table's would be 8 TB). Peak host allocation stays far under the
+    20,000 × 20,000 float64 such a one-hot would take."""
+    import tracemalloc
+
+    n = 20_000
+    y = rng.gamma(4.0, 10.0, n)
+    cat_a = (rng.random(n) < 0.5).astype(float)
+    x = np.stack([cat_a, 1.0 - cat_a, y * 0.01 + rng.normal(size=n)], axis=1)
+    metas = [
+        _col("cat", grouping="cat", indicator_value="A", parent_type="PickList"),
+        _col("cat", grouping="cat", indicator_value="B", parent_type="PickList"),
+        _col("good"),
+    ]
+    ds = _vec_ds(x, metas, y)
+    lbl, vec = _checker_inputs()
+    est = SanityChecker(remove_bad_features=True).set_input(lbl, vec)
+    tracemalloc.start()
+    try:
+        model = est.fit(ds)
+        _now, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert peak < 200 * 2**20, peak
+    assert 2 in model.indices_to_keep

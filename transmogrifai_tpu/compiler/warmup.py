@@ -48,13 +48,14 @@ _TREE_PROGRAMS = frozenset(
 )
 
 #: estimator class name -> banked program names its fit/predict path routes
-#: through ``aot_call``. Families absent here (GLM/IRLS, NaiveBayes, SVC,
-#: MLP) compile through the plain jit cache and bank nothing.
+#: through ``aot_call``. Families absent here (NaiveBayes, SVC, MLP)
+#: compile through the plain jit cache and bank nothing.
 _FAMILY_PROGRAMS: dict[str, frozenset] = {
     "LogisticRegression": frozenset(
         {"logistic_binary_batched", "logistic_multinomial_batched"}
     ),
     "LinearRegression": frozenset({"linear_batched"}),
+    "GeneralizedLinearRegression": frozenset({"glr_irls_batched"}),
     "XGBoostClassifier": _TREE_PROGRAMS,
     "XGBoostRegressor": _TREE_PROGRAMS,
     "GBTClassifier": _TREE_PROGRAMS,

@@ -11,6 +11,7 @@ become vmap axes in the model selector rather than driver threads.
 """
 from __future__ import annotations
 
+import logging
 from typing import Any
 
 import numpy as np
@@ -19,6 +20,8 @@ from ..dataset import Dataset
 from ..stages.base import Estimator, Model
 from ..types import OPVector, Prediction, RealNN
 from ..types.columns import Column, NumericColumn, PredictionColumn, VectorColumn
+
+log = logging.getLogger(__name__)
 
 
 class PredictorModel(Model):
@@ -128,6 +131,42 @@ def score_sweep_lanes(models_by_fold, y, folds, evaluator, lane_outputs,
             reads=evaluator.default_metric,
         )
     return values
+
+
+def score_sweep_stacks(models_by_fold, y, folds, evaluator):
+    """A family's ``sweep_eval_batched`` where its lanes' outputs stay on
+    the device in the fit program's stack (``_sweep_stack``, whose
+    ``lane_rows(lanes, rows)`` gathers lanes at rows): every fold lane
+    scored from them, only the validation rows of a fold's lanes
+    downloaded, one gather a fold and stack. None where a lane has no
+    stack (points fitted one by one): the validator then scores by
+    ``predict_arrays``."""
+    flat = [m for fold_models in models_by_fold for m in fold_models]
+    if not flat or any(
+        getattr(m, "_sweep_stack", None) is None for m in flat
+    ):
+        return None
+    try:
+        fetched: dict = {}
+        for fi, (_train_mask, val_mask) in enumerate(folds):
+            val_idx = np.nonzero(val_mask)[0]
+            by_stack: dict = {}
+            for m in models_by_fold[fi]:
+                by_stack.setdefault(
+                    id(m._sweep_stack), (m._sweep_stack, []))[1].append(m)
+            for stack, ms in by_stack.values():
+                block = stack.lane_rows([m._sweep_lane for m in ms], val_idx)
+                for m, lane in zip(ms, block):
+                    fetched[fi, id(m)] = lane
+        return score_sweep_lanes(
+            models_by_fold, y, folds, evaluator,
+            lambda fi, m, _val_idx: fetched[fi, id(m)],
+            lanes=len(flat),
+            nbytes=sum(int(a.nbytes) for a in fetched.values()),
+        )
+    except Exception:
+        log.warning("batched sweep-eval failed; falling back", exc_info=True)
+        return None
 
 
 def group_grid_by_statics(points, known_keys, statics_of):

@@ -15,8 +15,6 @@ lanes' validation rows and the refit winner's lane come to the host.
 """
 from __future__ import annotations
 
-import logging
-
 import numpy as np
 
 import jax
@@ -26,15 +24,13 @@ from ..telemetry import metrics as _tm
 from ..telemetry import runlog as _runlog
 from ..telemetry import spans as _tspans
 from . import solvers
-from .base import PredictorEstimator, PredictorModel, score_sweep_lanes
+from .base import PredictorEstimator, PredictorModel, score_sweep_stacks
 from .solvers import (
     fit_logistic_binary,
     fit_logistic_binary_batched,
     fit_logistic_multinomial,
     fit_logistic_multinomial_batched,
 )
-
-log = logging.getLogger(__name__)
 
 #: How this estimator fits a label of more than two classes, stated as
 #: ``gbdt.FOREST_MULTICLASS`` states the forest's: one softmax fit a lane
@@ -52,13 +48,18 @@ def solver_precision() -> str:
 
 
 class GLMStats(_tm.LedgerCore):
-    """The ``glm`` ledger: what the multinomial fit programs report once
-    their outputs land (``glm/await_outputs``), cumulative: iterations in
-    which a lane moved, iterations scanned (lanes × ``max_iter``: the scan
-    runs every iteration, a frozen lane too) and lanes whose gradient test
-    held at the end."""
+    """The ``glm`` ledger: what the GLM fit programs report once their
+    outputs land (``glm/await_outputs``), cumulative. From the multinomial
+    fits: iterations in which a lane moved, iterations scanned (lanes ×
+    ``max_iter``: the scan runs every iteration, a frozen lane too) and
+    lanes whose gradient test held at the end. From the IRLS fits
+    (``models/glm.py``): the iterations each lane took to its stop, the
+    lane-iterations the programs ran, the lanes that stopped before
+    ``max_iter`` and the lane rows whose mean was projected."""
 
-    KEYS = ("itersMoved", "itersScanned", "lanesConverged")
+    KEYS = ("itersMoved", "itersScanned", "lanesConverged",
+            "irlsItersNeeded", "irlsItersRun", "irlsLanesStopped",
+            "irlsRowsProjected")
 
     def __init__(self) -> None:
         super().__init__(self.KEYS)
@@ -68,6 +69,14 @@ class GLMStats(_tm.LedgerCore):
             self._counts["itersMoved"] += moved
             self._counts["itersScanned"] += scanned
             self._counts["lanesConverged"] += converged
+
+    def record_irls(self, needed: int, run: int, stopped: int,
+                    projected: int) -> None:
+        with self._lock:
+            self._counts["irlsItersNeeded"] += needed
+            self._counts["irlsItersRun"] += run
+            self._counts["irlsLanesStopped"] += stopped
+            self._counts["irlsRowsProjected"] += projected
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -447,34 +456,7 @@ class LogisticRegression(PredictorEstimator):
         a fold's lanes downloaded (one gather a fold). None where a lane
         has no such logits (binary lanes, points fitted one by one): the
         validator then scores by ``predict_arrays``."""
-        flat = [m for fold_models in models_by_fold for m in fold_models]
-        if not flat or any(
-            getattr(m, "_sweep_stack", None) is None for m in flat
-        ):
-            return None
-        try:
-            fetched: dict = {}
-            for fi, (_train_mask, val_mask) in enumerate(folds):
-                val_idx = np.nonzero(val_mask)[0]
-                by_stack: dict = {}
-                for m in models_by_fold[fi]:
-                    by_stack.setdefault(
-                        id(m._sweep_stack), (m._sweep_stack, []))[1].append(m)
-                for stack, ms in by_stack.values():
-                    block = stack.lane_rows(
-                        [m._sweep_lane for m in ms], val_idx)
-                    for m, lane in zip(ms, block):
-                        fetched[fi, id(m)] = lane
-            return score_sweep_lanes(
-                models_by_fold, y, folds, evaluator,
-                lambda fi, m, _val_idx: fetched[fi, id(m)],
-                lanes=len(flat),
-                nbytes=sum(int(a.nbytes) for a in fetched.values()),
-            )
-        except Exception:
-            log.warning("batched sweep-eval failed; falling back",
-                        exc_info=True)
-            return None
+        return score_sweep_stacks(models_by_fold, y, folds, evaluator)
 
     def sweep_dispatch_masks(self, x, y, masks, grid_points):
         """Dispatch the folds × grid sweep, return a collector closure.

@@ -39,6 +39,15 @@ def _mm(a: jax.Array, b: jax.Array) -> jax.Array:
     return jnp.matmul(a, b, precision=GLM_PRECISION)
 
 
+def _bmm(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` for ``[S, m, k] × [S, k, n]`` over a leading lane axis, at
+    ``GLM_PRECISION``: ``lax.dot_general`` keeps a lane axis of 1 (the
+    batched matmul of ``jnp.matmul`` squeezes it), so a program's structure
+    does not change with its lane count."""
+    return jax.lax.dot_general(
+        a, b, (((2,), (1,)), ((0,), (0,))), precision=GLM_PRECISION)
+
+
 class GLMParams(NamedTuple):
     weights: jax.Array    # [D] or [D, C]
     intercept: jax.Array  # scalar or [C]
@@ -793,8 +802,31 @@ def fit_linear_svc(
     return GLMParams(weights=w, intercept=jnp.where(fit_intercept, b, 0.0))
 
 
-# GLM family/link codes (static ints so the IRLS graph stays compiled once
-# per (family, link) pair — Spark GeneralizedLinearRegression.scala parity)
+# --------------------------------------------------------------------------
+# Generalized linear regression: Spark's GeneralizedLinearRegression as one
+# K-lane program on the shared plane. A lane is one (family, link, λ, tol)
+# on one row mask; the family and the link are lane DATA, so one program
+# fits a grid that mixes them. Per lane (Spark 2.4's learner):
+#
+#   * gaussian/identity: ONE WeightedLeastSquares solve of the label at
+#     unit weights with standardized features and label (Spark's closed
+#     form: it does not iterate);
+#   * every other pair: the start of FamilyAndLink.initialize (μ⁰ = the
+#     family's initialize(y), η⁰ = g(μ⁰), one such solve of η⁰), then
+#     IterativelyReweightedLeastSquares: η = xβ + b, μ = project(g⁻¹(η)),
+#     z = η + (y − μ)·g′(μ), w = 1 / (g′(μ)²·V(μ)), a solve of z at weights
+#     w with neither standardized, until every coefficient and the
+#     intercept move by less than ``tol`` (or ``max_iter`` solves).
+#
+# WeightedLeastSquares here: the columns centred by their weighted means
+# and scaled by their weighted stds, the penalty on the diagonal of that
+# system as Spark puts it there (λ / σ_label; ÷ σ_j² where the features are
+# not standardized, × σ_label where the label is not), the intercept never
+# penalised, a batched Cholesky. The weighted Grams stream the plane once
+# an iteration in row blocks, only for the lanes that have not stopped.
+# --------------------------------------------------------------------------
+
+# GLM family/link codes: lane data of the IRLS program
 GLM_FAMILIES = {"gaussian": 0, "binomial": 1, "poisson": 2, "gamma": 3}
 GLM_LINKS = {"identity": 0, "log": 1, "logit": 2, "inverse": 3, "sqrt": 4}
 GLM_DEFAULT_LINK = {
@@ -802,117 +834,410 @@ GLM_DEFAULT_LINK = {
     "gamma": "inverse",
 }
 
+#: Spark's ``project`` floor for float32 (its float64 takes 1e-16): 2^-24,
+#: so that a binomial lane's 1 − ε stays below 1
+GLR_EPS = 2.0 ** -24
+#: Tweedie's δ: a poisson lane starts at μ⁰ = δ where its label is 0
+GLR_DELTA = 0.1
+#: the float32 floor of the stop rule: a component has also stopped once
+#: it moves by at most this many ulps (2^-23 of its own size) — Spark's
+#: absolute ``tol`` cannot be met in float32 by a value over ≈ 8
+GLR_ULP_FLOOR = 16.0
+#: rows a block of the program's passes over the plane
+GLR_ROW_BLOCK = 4096
+#: a column's std is at least this share of its root mean square about
+#: the plane's column means (the noise floor of ``_lane_moments``)
+_GLR_NOISE_FLOOR = 2e-3
+#: the ridge of a lane whose standardized system is singular (λ = 0 on
+#: collinear columns: Spark's Cholesky fails there and it falls back to a
+#: quasi-Newton solver)
+_GLR_SINGULAR_RIDGE = 1e-6
 
-@partial(jax.jit, static_argnames=("family", "link", "num_iters", "fit_intercept"))
-def fit_glm_irls(
-    x: jax.Array,
-    y: jax.Array,
-    row_mask: jax.Array,
-    reg_param: jax.Array,  # L2 only, like Spark GLM
-    family: int = 0,
-    link: int = 0,
-    num_iters: int = 25,
-    fit_intercept: bool = True,
-) -> GLMParams:
-    """Iteratively reweighted least squares for generalized linear models
-    (OpGeneralizedLinearRegression parity — Spark GLR's IRLS, maxIter=25).
-    One `lax.scan` of normal-equation solves; D is small in tabular AutoML so
-    the [D+1, D+1] solve per iteration is cheap on the MXU."""
-    row_mask = row_mask.astype(x.dtype)
-    n = jnp.maximum(row_mask.sum(), 1.0)
-    d = x.shape[1]
-    ones = jnp.ones((x.shape[0], 1), dtype=x.dtype)
-    xa = jnp.concatenate([x, ones], axis=1) if fit_intercept else x
-    da = xa.shape[1]
-    eps = jnp.asarray(1e-7, dtype=x.dtype)
 
-    def linkinv(eta):
-        return jax.lax.switch(
-            link,
-            [
-                lambda e: e,                       # identity
-                lambda e: jnp.exp(e),              # log
-                lambda e: jax.nn.sigmoid(e),       # logit
-                lambda e: 1.0 / jnp.where(jnp.abs(e) > eps, e, eps),  # inverse
-                lambda e: e * e,                   # sqrt
-            ],
-            eta,
-        )
+class GLRFit(NamedTuple):
+    """What one IRLS program returns, lane by lane."""
 
-    def dmu_deta(eta, mu):
-        return jax.lax.switch(
-            link,
-            [
-                lambda: jnp.ones_like(eta),
-                lambda: mu,
-                lambda: mu * (1.0 - mu),
-                lambda: -mu * mu,
-                lambda: 2.0 * jnp.sqrt(jnp.maximum(mu, eps)),
-            ],
-        )
+    weights: jax.Array           # [K, D] original-scale coefficients
+    intercept: jax.Array         # [K]
+    mu: jax.Array                # [K, N] every row's fitted mean
+    iters: jax.Array             # [K] int32 IRLS solves after the start
+    stop_tol: jax.Array          # [K] bool: Spark's rule stopped the lane
+    stop_floor: jax.Array        # [K] bool: the float32 floor stopped it
+    rows_projected: jax.Array    # [K] int32 lane rows whose μ was projected
+    rows_nonpositive: jax.Array  # [K] int32 lane rows fitted at μ ≤ 0
+    iterations: jax.Array        # [] int32 iterations the program ran
 
-    def variance(mu):
-        return jax.lax.switch(
-            family,
-            [
-                lambda m: jnp.ones_like(m),        # gaussian
-                lambda m: m * (1.0 - m),           # binomial
-                lambda m: m,                       # poisson
-                lambda m: m * m,                   # gamma
-            ],
-            mu,
-        )
 
-    def init_eta():
-        # family-aware starting point on the linear scale
-        mu0 = jax.lax.switch(
-            family,
-            [
-                lambda: y,
-                lambda: (y + 0.5) / 2.0,
-                lambda: jnp.maximum(y, 0.0) + 0.1,
-                lambda: jnp.maximum(y, eps),
-            ],
-        )
-        return jax.lax.switch(
-            link,
-            [
-                lambda m: m,
-                lambda m: jnp.log(jnp.maximum(m, eps)),
-                lambda m: jnp.log(jnp.maximum(m, eps) / jnp.maximum(1.0 - m, eps)),
-                lambda m: 1.0 / jnp.maximum(m, eps),
-                lambda m: jnp.sqrt(jnp.maximum(m, 0.0)),
-            ],
-            mu0,
-        )
+def _glr_unlink(eta, link):
+    """g⁻¹(η) by the lane's link code."""
+    return jnp.select(
+        [link == 0, link == 1, link == 2, link == 3],
+        [eta, jnp.exp(eta), jax.nn.sigmoid(eta), 1.0 / eta], eta * eta)
 
-    def body(beta, _):
-        eta = _mm(xa, beta)
-        mu = linkinv(eta)
-        dmu = dmu_deta(eta, mu)
-        dmu = jnp.where(jnp.abs(dmu) > eps, dmu, eps)
-        var = jnp.maximum(variance(mu), eps)
-        z = eta + (y - mu) / dmu
-        w = row_mask * dmu * dmu / var
-        xtwx = _mm((xa * w[:, None]).T, xa) / n
-        xtwz = _mm((xa * w[:, None]).T, z) / n
-        reg = reg_param * jnp.eye(da, dtype=x.dtype)
-        if fit_intercept:  # intercept unregularized
-            reg = reg.at[da - 1, da - 1].set(0.0)
-        beta_next = jnp.linalg.solve(xtwx + reg + eps * jnp.eye(da, dtype=x.dtype), xtwz)
-        return beta_next, None
 
-    eta0 = init_eta()
-    w0 = row_mask
-    xtwx0 = _mm((xa * w0[:, None]).T, xa) / n
-    xtwz0 = _mm((xa * w0[:, None]).T, eta0) / n
-    beta0 = jnp.linalg.solve(
-        xtwx0 + (reg_param + eps) * jnp.eye(da, dtype=x.dtype), xtwz0
-    )
-    beta, _ = jax.lax.scan(body, beta0, None, length=num_iters)
+def _glr_link(mu, link):
+    """g(μ) by the lane's link code."""
+    return jnp.select(
+        [link == 0, link == 1, link == 2, link == 3],
+        [mu, jnp.log(mu), jnp.log(mu) - jnp.log1p(-mu), 1.0 / mu],
+        jnp.sqrt(mu))
+
+
+def _glr_deriv(mu, link):
+    """g′(μ) by the lane's link code."""
+    return jnp.select(
+        [link == 0, link == 1, link == 2, link == 3],
+        [jnp.ones_like(mu), 1.0 / mu, 1.0 / (mu * (1.0 - mu)),
+         -1.0 / (mu * mu)],
+        0.5 / jnp.sqrt(mu))
+
+
+def _glr_variance(mu, family):
+    """V(μ) by the lane's family code."""
+    return jnp.select(
+        [family == 0, family == 1, family == 2],
+        [jnp.ones_like(mu), mu * (1.0 - mu), mu], mu * mu)
+
+
+def _glr_project(mu, family):
+    """Spark's ``project``: infinities to the largest float; a binomial μ
+    into [ε, 1 − ε], a poisson or gamma μ to at least ε."""
+    big = jnp.finfo(mu.dtype).max
+    return jnp.select(
+        [family == 0, family == 1],
+        [jnp.clip(mu, -big, big), jnp.clip(mu, GLR_EPS, 1.0 - GLR_EPS)],
+        jnp.clip(mu, GLR_EPS, big))
+
+
+def _glr_initialize(y, family):
+    """μ⁰ by the lane's family (Spark's ``Family.initialize``)."""
+    return jnp.select(
+        [family == 1, family == 2],
+        [(y + 0.5) / 2.0, jnp.where(y == 0.0, GLR_DELTA, y)], y)
+
+
+def _glr_slot_sizes(k: int) -> tuple[int, ...]:
+    """The lane counts an iteration's Gram pass is compiled for: eighths
+    of ``k`` (rounded up), so a pass over the lanes that have not stopped
+    costs at most an eighth of ``k`` more than they need."""
+    return tuple(sorted({max(1, -(-k * j // 8)) for j in range(1, 9)}))
+
+
+def _cholesky_bordered(a, c):
+    """(low, y): the lower Cholesky factors ``low`` of the SPD matrices
+    ``a`` [S, D, D] and y = low⁻¹·c for ``c`` [S, D], from ONE loop over
+    the D columns of the system bordered by its right-hand side (the
+    border's row of the factor is y, so no forward substitution runs), a
+    column a step, every product through ``_bmm``. NaN where a matrix is
+    not positive definite. (``jnp.linalg.cholesky`` and its triangular
+    solves lower to LAPACK custom calls on XLA:CPU, and a banked
+    executable of this program that holds them faults when a later
+    process runs it, at widths of 256 and more; these loops lower alike on
+    every backend and for every lane count.)"""
+    s, d = c.shape
+    border = jnp.concatenate([c, jnp.zeros((s, 1), a.dtype)], axis=1)
+    m = jnp.concatenate(
+        [jnp.concatenate([a, c[:, :, None]], axis=2), border[:, None, :]],
+        axis=1)                                                 # [S, D+1, D+1]
+    rows = jnp.arange(d + 1)
+
+    def column(j, low):
+        # low holds columns < j; row j of it is zero from column j on
+        col = m[:, :, j] - _bmm(low, low[:, j, :, None])[..., 0]  # [S, D+1]
+        pivot = jnp.sqrt(col[:, j])
+        below = jnp.broadcast_to(rows > j, col.shape)
+        col = jnp.where(below, col / pivot[:, None], 0.0)
+        return low.at[:, :, j].set(col.at[:, j].set(pivot))
+
+    low = jax.lax.fori_loop(0, d, column, jnp.zeros_like(m))
+    return low[:, :d, :d], low[:, d, :d]
+
+
+def _back_solve(low, y):
+    """x with lowᵀ·x = y for factors ``low`` [S, D, D], ``y`` [S, D]: back
+    substitution, a component a step."""
+    d = y.shape[-1]
+
+    def backward(i, x):
+        j = d - 1 - i
+        xj = (y[:, j] - _bmm(low[:, None, :, j], x[..., None])[:, 0, 0]) / (
+            low[:, j, j])
+        return x.at[:, j].set(xj)
+
+    return jax.lax.fori_loop(0, d, backward, jnp.zeros_like(y))
+
+
+def _glr_wls(sw, s1, gram, sz, szz, r, zshift, const, lam, gshift,
+             standardize: bool, fit_intercept: bool):
+    """Spark's WeightedLeastSquares for lanes ``[S]`` from their weighted
+    sums over the shifted plane xc = x − gshift and the shifted label
+    z′ = z − ``zshift`` [S]: Σv ``sw`` [S], Σv·xc ``s1`` [S, D], Σv·xc·xcᵀ
+    ``gram`` [S, D, D], Σv·z′ ``sz``, Σv·z′² ``szz``, Σv·z′·xc ``r``
+    [S, D] (a label far from 0 beside its spread would cancel in float32
+    sums, as a column far from 0 does); ``const`` [S, D] the columns
+    constant on a lane's rows. ``standardize``: Spark's standardizeFeatures
+    and standardizeLabel (the start and the closed form: true; an IRLS
+    step: false). Returns the original-scale (β [S, D], b [S])."""
+    n = jnp.maximum(sw, jnp.finfo(sw.dtype).tiny)
+    m = s1 / n[:, None]                                  # weighted means
+    zdev = sz / n
+    zbar = zdev + zshift
+    second = gram / n[:, None, None]
+    cov = second - m[:, :, None] * m[:, None, :]
+    cz = r / n[:, None] - m * zdev[:, None]
+    sigma = jnp.sqrt(jnp.maximum(jnp.diagonal(cov, axis1=1, axis2=2), 0.0))
+    rms = jnp.sqrt(jnp.maximum(jnp.diagonal(second, axis1=1, axis2=2), 0.0))
+    safe = jnp.where(const, 1.0,
+                     jnp.maximum(sigma, _GLR_NOISE_FLOOR * rms + 1e-12))
+    zstd = jnp.sqrt(jnp.maximum(szz / n - zdev * zdev, 0.0))
+    # Spark's bStd: the label's std, or |mean| where the label is constant
+    zscale = jnp.where(zstd > 0.0, zstd, jnp.abs(zbar))
+    lam_eff = lam / jnp.maximum(zscale, jnp.finfo(sw.dtype).tiny)
+    if standardize:
+        pen = lam_eff[:, None] * jnp.ones_like(safe)
+    else:
+        pen = lam_eff[:, None] / (safe * safe) * zscale[:, None]
+    if not fit_intercept:
+        # no intercept: raw second moments about 0, still scaled by the
+        # centred stds (Spark's WLS without an intercept column)
+        g = gshift[None, :]
+        raw = m + g
+        cov = (second + m[:, :, None] * g[:, None, :]
+               + g[:, :, None] * raw[:, None, :])
+        cz = r / n[:, None] + zshift[:, None] * m + g * zbar[:, None]
+    off = const[:, :, None] | const[:, None, :]
+    a = jnp.where(off, 0.0, cov / (safe[:, :, None] * safe[:, None, :]))
+    diag = jnp.arange(a.shape[-1])
+    a = a.at[:, diag, diag].add(jnp.where(const, 1.0, pen))
+    c = jnp.where(const, 0.0, cz / safe)
+    chol, y = _cholesky_bordered(a, c)
+    singular = ~(jnp.isfinite(chol).all(axis=(1, 2))
+                 & jnp.isfinite(y).all(axis=1))
+
+    def ridged():
+        chol_r, y_r = _cholesky_bordered(
+            a.at[:, diag, diag].add(_GLR_SINGULAR_RIDGE), c)
+        return (jnp.where(singular[:, None, None], chol_r, chol),
+                jnp.where(singular[:, None], y_r, y))
+
+    chol, y = jax.lax.cond(singular.any(), ridged, lambda: (chol, y))
+    gamma = _back_solve(chol, y)
+    beta = jnp.where(const, 0.0, gamma / safe)
     if fit_intercept:
-        return GLMParams(weights=beta[:-1], intercept=beta[-1])
-    return GLMParams(weights=beta, intercept=jnp.zeros((), dtype=x.dtype))
+        # a constant label: no coefficients, the intercept its mean
+        beta = jnp.where((zstd > 0.0)[:, None], beta, 0.0)
+        b = zbar - _mm(beta, gshift) - (beta * m).sum(axis=1)
+    else:
+        b = jnp.zeros_like(zbar)
+    return beta, b
+
+
+@partial(jax.jit, static_argnames=("max_iter", "fit_intercept"))
+def fit_glr_irls_batched(
+    x: jax.Array,           # [N, D] SHARED feature matrix
+    y: jax.Array,           # [N]
+    masks: jax.Array,       # [M, N] the sweep's distinct row masks
+    lane_mask: jax.Array,   # [K] int32: the row mask of each lane
+    family: jax.Array,      # [K] int32 (GLM_FAMILIES)
+    link: jax.Array,        # [K] int32 (GLM_LINKS)
+    reg_params: jax.Array,  # [K] λ
+    tols: jax.Array,        # [K] Spark's tol
+    max_iter: int = 25,
+    fit_intercept: bool = True,
+) -> GLRFit:
+    """K generalized linear regressions (Spark's IRLS) sharing ONE plane,
+    in one program: the learner the section above states, every product
+    at ``GLM_PRECISION``.
+
+    The plane is read in blocks of ``GLR_ROW_BLOCK`` rows, shifted by its
+    column means as ``_lane_moments`` shifts it; no ``[K, N, D]`` buffer
+    and no per-lane ``[N, D]`` copy is made. The unweighted Gram of each
+    row mask is summed once, with every lane's start right-hand side; it
+    gives every lane's start and every gaussian/identity lane's closed
+    form. Each iteration then sums, for the lanes that have not stopped
+    (gathered into the smallest of ``_glr_slot_sizes(K)`` slots that holds
+    them), the weighted Grams and right-hand sides of their reweighted
+    rows, and solves. A stopped lane stays as it is; the loop ends when
+    every lane has stopped or after ``max_iter`` iterations. A lane stops
+    when every coefficient and the intercept move by less than its ``tol``
+    (Spark's rule), or by at most ``GLR_ULP_FLOOR`` ulps of their own size.
+    ``mu`` is every row's fitted mean, project(g⁻¹(xβ + b))."""
+    n, d = x.shape
+    k = lane_mask.shape[0]
+    n_masks = masks.shape[0]
+    masks = masks.astype(x.dtype)
+    y = y.astype(x.dtype)
+    block = min(GLR_ROW_BLOCK, n)
+    n_blocks = -(-n // block)
+    big = jnp.asarray(jnp.finfo(x.dtype).max, x.dtype)
+    gshift = x.mean(axis=0)                                    # [D]
+
+    def rows_of(i):
+        """Block ``i``: (x, y, masks) at rows [start, start + block), the
+        rows an earlier block already read masked off."""
+        start = jnp.minimum(i * block, n - block)
+        keep = (start + jnp.arange(block)) >= i * block
+        xb = jax.lax.dynamic_slice(x, (start, 0), (block, d))
+        yb = jax.lax.dynamic_slice(y, (start,), (block,))
+        mb = jax.lax.dynamic_slice(masks, (0, start), (n_masks, block))
+        return xb, yb, mb * keep.astype(x.dtype)
+
+    def gram_of(xc, v):
+        """Σ_rows v·xc·xcᵀ for each row of ``v`` [S, B]: [S, D, D]."""
+        return _mm(jnp.swapaxes(xc[None] * v[:, :, None], 1, 2), xc)
+
+    fam_k, link_k = family[:, None], link[:, None]
+
+    def start_label(yb):
+        """Each lane's start label g(μ⁰) at a block's rows [K, B]."""
+        return _glr_link(_glr_initialize(yb[None], fam_k), link_k)
+
+    # each lane's start label is summed about its mean over the first block
+    _x0, y0, m0 = rows_of(0)
+    ml0 = m0[lane_mask]
+    zshift0 = (jnp.where(ml0 > 0, start_label(y0), 0.0) * ml0).sum(
+        axis=1) / jnp.maximum(ml0.sum(axis=1), 1.0)
+
+    def start_block(i, acc):
+        g0, s10, sw0, lo, hi, r0, sz0, szz0 = acc
+        xb, yb, mb = rows_of(i)
+        xc = xb - gshift
+        with jax.named_scope("glm/gram"):
+            g0 = g0 + gram_of(xc, mb)
+            s10 = s10 + _mm(mb, xc)
+            sw0 = sw0 + mb.sum(axis=1)
+        inside = mb[:, :, None] > 0
+        lo = jnp.minimum(lo, jnp.where(inside, xb[None], big).min(axis=1))
+        hi = jnp.maximum(hi, jnp.where(inside, xb[None], -big).max(axis=1))
+        with jax.named_scope("glm/reweight"):
+            ml = mb[lane_mask]                                  # [K, B]
+            z0 = jnp.where(ml > 0, start_label(yb) - zshift0[:, None], 0.0)
+            vz = ml * z0
+        with jax.named_scope("glm/gram"):
+            r0 = r0 + _mm(vz, xc)
+            sz0 = sz0 + vz.sum(axis=1)
+            szz0 = szz0 + (vz * z0).sum(axis=1)
+        return g0, s10, sw0, lo, hi, r0, sz0, szz0
+
+    zeros_k = jnp.zeros(k, x.dtype)
+    g0, s10, sw0, lo, hi, r0, sz0, szz0 = jax.lax.fori_loop(
+        0, n_blocks, start_block,
+        (jnp.zeros((n_masks, d, d), x.dtype),
+         jnp.zeros((n_masks, d), x.dtype), jnp.zeros(n_masks, x.dtype),
+         jnp.full((n_masks, d), big), jnp.full((n_masks, d), -big),
+         jnp.zeros((k, d), x.dtype), zeros_k, zeros_k))
+    const = (hi <= lo)[lane_mask]                               # [K, D]
+    with jax.named_scope("glm/solve"):
+        beta, b = _glr_wls(
+            sw0[lane_mask], s10[lane_mask], g0[lane_mask], sz0, szz0, r0,
+            zshift0, const, reg_params, gshift, True, fit_intercept)
+    # gaussian/identity is Spark's closed form: the start is its answer
+    active = ~((family == 0) & (link == 0)) & (max_iter > 0)
+    sizes = _glr_slot_sizes(k)
+    floor = GLR_ULP_FLOOR * 2.0 ** -23
+
+    def step(s, state):
+        """One IRLS iteration over the (at most ``s``) lanes that have not
+        stopped, gathered into ``s`` slots."""
+        t, beta, b, active, iters, stol, sfloor, proj = state
+        order = jnp.argsort(~active, stable=True)               # active first
+        idx = order[:s]
+        live = active[idx]
+        bs, bb = beta[idx], b[idx]
+        fam_s, link_s = family[idx][:, None], link[idx][:, None]
+        lm = lane_mask[idx]
+        off = _mm(bs, gshift) + bb
+
+        def body(i, acc):
+            gram, s1, sw, sz, szz, r, pj = acc
+            xb, yb, mb = rows_of(i)
+            xc = xb - gshift
+            ml = mb[lm] * live[:, None].astype(x.dtype)         # [s, B]
+            inside = ml > 0
+            with jax.named_scope("glm/reweight"):
+                # z is summed about the lane's η at the shifted plane's
+                # origin, ``off``
+                lin = _mm(xc, bs.T).T
+                raw = _glr_unlink(lin + off[:, None], link_s)
+                mu = _glr_project(raw, fam_s)
+                gp = _glr_deriv(mu, link_s)
+                z = jnp.where(inside, lin + (yb[None] - mu) * gp, 0.0)
+                w = 1.0 / (gp * gp * _glr_variance(mu, fam_s))
+                v = jnp.where(inside, ml * w, 0.0)
+                vz = v * z
+                pj = pj + (inside & (mu != raw)).sum(axis=1)
+            with jax.named_scope("glm/gram"):
+                gram = gram + gram_of(xc, v)
+                s1 = s1 + _mm(v, xc)
+                r = r + _mm(vz, xc)
+                sw = sw + v.sum(axis=1)
+                sz = sz + vz.sum(axis=1)
+                szz = szz + (vz * z).sum(axis=1)
+            return gram, s1, sw, sz, szz, r, pj
+
+        zs = jnp.zeros(s, x.dtype)
+        gram, s1, sw, sz, szz, r, pj = jax.lax.fori_loop(
+            0, n_blocks, body,
+            (jnp.zeros((s, d, d), x.dtype), jnp.zeros((s, d), x.dtype), zs,
+             zs, zs, jnp.zeros((s, d), x.dtype), jnp.zeros(s, jnp.int32)))
+        with jax.named_scope("glm/solve"):
+            nbeta, nbias = _glr_wls(
+                sw, s1, gram, sz, szz, r, off, const[idx], reg_params[idx],
+                gshift, False, fit_intercept)
+            dbeta, dbias = jnp.abs(nbeta - bs), jnp.abs(nbias - bb)
+            tol = tols[idx]
+            by_tol = jnp.maximum(dbeta.max(axis=1), dbias) < tol
+            by_floor = (
+                (dbeta <= jnp.maximum(tol[:, None], floor * jnp.maximum(
+                    jnp.abs(nbeta), jnp.abs(bs)))).all(axis=1)
+                & (dbias <= jnp.maximum(tol, floor * jnp.maximum(
+                    jnp.abs(nbias), jnp.abs(bb)))))
+            stop = live & (by_tol | by_floor)
+        beta = beta.at[idx].set(jnp.where(live[:, None], nbeta, bs))
+        b = b.at[idx].set(jnp.where(live, nbias, bb))
+        active = active.at[idx].set(live & ~stop)
+        iters = iters.at[idx].add(live.astype(jnp.int32))
+        stol = stol.at[idx].set(stol[idx] | (stop & by_tol))
+        sfloor = sfloor.at[idx].set(sfloor[idx] | (stop & ~by_tol))
+        proj = proj.at[idx].add(jnp.where(live, pj, 0))
+        return t + 1, beta, b, active, iters, stol, sfloor, proj
+
+    def more(state):
+        return (state[0] < max_iter) & state[3].any()
+
+    def iterate(state):
+        branch = jnp.searchsorted(
+            jnp.asarray(sizes, jnp.int32), state[3].sum(dtype=jnp.int32))
+        return jax.lax.switch(
+            branch, [partial(step, s) for s in sizes], state)
+
+    no = jnp.zeros(k, bool)
+    t, beta, b, active, iters, stol, sfloor, proj = jax.lax.while_loop(
+        more, iterate,
+        (jnp.int32(0), beta, b, active, jnp.zeros(k, jnp.int32), no, no,
+         jnp.zeros(k, jnp.int32)))
+    with jax.named_scope("glm/scores"):
+        eta = _mm(x - gshift, beta.T).T + (_mm(beta, gshift) + b)[:, None]
+        raw = _glr_unlink(eta, link[:, None])
+        mu = _glr_project(raw, family[:, None])
+        rows = masks[lane_mask] > 0                              # [K, N]
+        proj = proj + (rows & (mu != raw)).sum(axis=1)
+        nonpositive = (rows & (mu <= 0.0)).sum(axis=1)
+    return GLRFit(beta, b, mu, iters, stol, sfloor, proj, nonpositive, t)
+
+
+def fit_glr_irls(x, y, row_mask, reg_param, family: int = 0, link: int = 0,
+                 max_iter: int = 25, tol: float = 1e-6,
+                 fit_intercept: bool = True) -> GLRFit:
+    """One generalized linear regression: the K=1 lane of
+    ``fit_glr_irls_batched``, so a single fit and a sweep's lane run
+    IDENTICAL math."""
+    one = jnp.ones(1, jnp.int32)
+    return fit_glr_irls_batched(
+        x, y, jnp.asarray(row_mask)[None], jnp.zeros(1, jnp.int32),
+        one * int(family), one * int(link),
+        jnp.full(1, reg_param, jnp.float32), jnp.full(1, tol, jnp.float32),
+        max_iter=max_iter, fit_intercept=fit_intercept)
 
 
 @partial(jax.jit, static_argnames=("num_iters", "fit_intercept"))
@@ -983,6 +1308,19 @@ def program_trace_specs():
             jax.ShapeDtypeStruct((k,), f32),      # elastic_nets
         )
 
+    def _glr_args(k: int):
+        f32, i32 = "float32", "int32"
+        return (
+            jax.ShapeDtypeStruct((16, 3), f32),   # x
+            jax.ShapeDtypeStruct((16,), f32),     # y
+            jax.ShapeDtypeStruct((2, 16), f32),   # masks
+            jax.ShapeDtypeStruct((k,), i32),      # lane_mask
+            jax.ShapeDtypeStruct((k,), i32),      # family
+            jax.ShapeDtypeStruct((k,), i32),      # link
+            jax.ShapeDtypeStruct((k,), f32),      # reg_params
+            jax.ShapeDtypeStruct((k,), f32),      # tols
+        )
+
     # donation contract of the lane sweep (mirrored by the sharded twins
     # in parallel/sweep.py): the per-lane hyperparam vectors [K] alias
     # into the output intercept [K] — TPJ003 lowers this donating twin
@@ -1034,5 +1372,15 @@ def program_trace_specs():
                 "num_classes", "num_iters", "fit_intercept",
                 "standardization",
             ),
+        ),
+        dict(
+            name="glr_irls_batched",
+            fn=fit_glr_irls_batched,
+            build=lambda k: (
+                _glr_args(k), dict(max_iter=2, fit_intercept=True)),
+            buckets=(8, 64, 96),
+            bucket_axis="lanes",
+            base_fn=getattr(fit_glr_irls_batched, "__wrapped__", None),
+            static_argnames=("max_iter", "fit_intercept"),
         ),
     ]

@@ -215,8 +215,11 @@ class SanityChecker(Estimator):
             label_is_categorical = len(classes) <= min(
                 100, max(2, int(0.1 * len(y)))
             )
-            label_onehot = (y[:, None] == classes[None, :]).astype(np.float64)
             if meta.size == d and label_is_categorical:
+                # [rows, classes]: only for a label of few classes (a
+                # continuous label has about as many classes as rows)
+                label_onehot = (y[:, None] == classes[None, :]).astype(
+                    np.float64)
                 for key, idxs in meta.index_of_group().items():
                     cats = [
                         i for i in idxs

@@ -64,6 +64,9 @@ XGB_ETA = [0.02]
 XGB_MIN_CHILD_WEIGHT = [1.0, 10.0]
 XGB_MAX_DEPTH_BINARY = [10]
 XGB_GAMMA_BINARY = [0.8]
+GLR_FAMILY = ["gaussian", "poisson"]
+GLR_LINK = ["identity", "log"]
+GLR_TOL = [1e-6]
 
 
 # Full candidate enums (BinaryClassificationModelsToTry / MultiClassification /
@@ -148,10 +151,7 @@ def _default_grid_for(cls: type) -> dict[str, Sequence[Any]]:
         NaiveBayes: {"smoothing": [1.0]},
         LinearSVC: {"reg_param": REGULARIZATION, "max_iter": MAX_ITER_LIN},
         MLPClassifier: {},
-        GeneralizedLinearRegression: {
-            "family": ["gaussian", "poisson", "gamma"],
-            "reg_param": REGULARIZATION,
-        },
+        GeneralizedLinearRegression: _glr_grid(),
     }
     return grids.get(cls, {})
 
@@ -162,6 +162,20 @@ def _lr_grid() -> dict[str, Sequence[Any]]:
         "elastic_net_param": ELASTIC_NET,
         "max_iter": MAX_ITER_LIN,
         "reg_param": REGULARIZATION,
+    }
+
+
+def _glr_grid() -> dict[str, Sequence[Any]]:
+    """The regression selector's GLR grid (DefaultSelectorParams: family
+    {gaussian, poisson} × link {identity, log} × regParam, maxIter 50, tol
+    1e-6, an intercept; quoted from memory of the published source)."""
+    return {
+        "fit_intercept": FIT_INTERCEPT,
+        "family": GLR_FAMILY,
+        "link": GLR_LINK,
+        "max_iter": MAX_ITER_LIN,
+        "reg_param": REGULARIZATION,
+        "tol": GLR_TOL,
     }
 
 
