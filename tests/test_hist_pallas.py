@@ -93,3 +93,58 @@ class TestHistogramKernel:
             max_depth=2, num_bins=16, hist_impl="scatter",
         )
         assert int(tree.split_feat[0][0]) == 2  # found the true feature
+
+
+def _lanes_data(lanes, slots, channels, lowp, n=300, f=5, b=4, seed=0):
+    """Codes, nodes and statistics of ``lanes`` fits: a quarter of the rows
+    dead (node -1), and the last lane of several weightless (every row's
+    statistics 0). ``lowp``: bfloat16-exact values, as a forest's."""
+    rng = np.random.default_rng(seed + 97 * lanes + slots + channels)
+    binned = rng.integers(0, b, (n, f))
+    node = rng.integers(0, slots, (lanes, n))
+    node[rng.random((lanes, n)) < 0.25] = -1
+    shape = (lanes, n) if channels == 2 else (lanes, channels - 1, n)
+    grad = rng.normal(size=shape)
+    hess = rng.uniform(0.1, 1.0, (lanes, n))
+    if lowp:
+        grad, hess = np.sign(grad), np.ones_like(hess)
+    if lanes > 1:
+        grad[-1], hess[-1] = 0.0, 0.0
+    return (
+        jnp.asarray(binned, jnp.int32), jnp.asarray(node, jnp.int32),
+        jnp.asarray(grad, jnp.float32), jnp.asarray(hess, jnp.float32), b,
+    )
+
+
+@pytest.mark.parametrize("lowp", [False, True], ids=["hilo", "lowp"])
+@pytest.mark.parametrize("channels", [2, 7])
+@pytest.mark.parametrize("slots", [8, 32, 64])
+@pytest.mark.parametrize("lanes", [1, 3, 4, 5])
+def test_binloop_lanes_sharing_a_pass_build_the_one_lane_histograms(
+    lanes, slots, channels, lowp
+):
+    """The lanes one grid step builds together (``binloop_lane_group``; a
+    last partial group padded with dead lanes) give every lane the
+    histograms of the kernel that builds one lane a pass, bit for bit, and
+    the scatter reference's within the kernel tests' tolerance."""
+    from transmogrifai_tpu.models.hist_pallas import binloop_lane_group
+
+    binned, node, grad, hess, b = _lanes_data(lanes, slots, channels, lowp)
+    group = binloop_lane_group(lanes, channels, lowp, slots)
+    assert 1 <= group <= lanes
+
+    def build(lane_group):
+        return np.asarray(build_histogram_pallas_binloop(
+            binned, node, grad, hess, slots, b, lowp=lowp, row_tile=128,
+            interpret=True, lane_group=lane_group,
+        ))
+
+    folded, one = build(None), build(1)
+    assert folded.shape == (lanes, slots, binned.shape[1], b, channels)
+    np.testing.assert_array_equal(folded, one)
+    ref = np.asarray(
+        build_histogram_scatter_batched(binned, node, grad, hess, slots, b)
+    )
+    np.testing.assert_allclose(folded, ref, atol=2e-4)
+    if lanes > 1:
+        assert not folded[-1].any()  # the weightless lane

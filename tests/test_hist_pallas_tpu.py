@@ -389,3 +389,36 @@ def test_binloop_builds_every_statistic_channel_on_device(
         np.testing.assert_array_equal(got, ref)  # integer sums stay exact
     else:
         np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-3)
+
+
+@pytest.mark.parametrize("lanes", [4, 5])
+@pytest.mark.parametrize("slots", [8, 32, 64])
+@pytest.mark.parametrize("channels,lowp", [(2, False), (2, True), (7, True)])
+def test_binloop_lanes_sharing_a_pass_match_one_lane_a_pass_on_device(
+    channels, lowp, slots, lanes
+):
+    """The lanes one grid step builds together (``binloop_lane_group``) get
+    the histograms of the kernel that builds one lane a pass, bit for bit:
+    the same row tiles, added in the same order, on real Mosaic."""
+    from transmogrifai_tpu.models.hist_pallas import (
+        binloop_lane_group,
+        build_histogram_pallas_binloop,
+    )
+
+    n, cols, bins = 5000, 302, 32
+    rng = np.random.default_rng(lanes * 100 + slots + channels)
+    binned = rng.integers(0, bins, size=(n, cols)).astype(np.int32)
+    node = rng.integers(-1, slots, size=(lanes, n)).astype(np.int32)
+    shape = (lanes, n) if channels == 2 else (lanes, channels - 1, n)
+    grad = rng.normal(size=shape).astype(np.float32)
+    hess = rng.uniform(0.1, 1.0, size=(lanes, n)).astype(np.float32)
+    if lowp:
+        grad, hess = np.sign(grad), np.ones_like(hess)
+    assert binloop_lane_group(lanes, channels, lowp, slots) > 1
+    args = (jnp.asarray(binned), jnp.asarray(node), jnp.asarray(grad),
+            jnp.asarray(hess), slots, bins)
+    folded = np.asarray(build_histogram_pallas_binloop(*args, lowp=lowp))
+    one = np.asarray(
+        build_histogram_pallas_binloop(*args, lowp=lowp, lane_group=1)
+    )
+    np.testing.assert_array_equal(folded, one)

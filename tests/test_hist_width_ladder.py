@@ -427,17 +427,18 @@ def test_old_and_new_tiles_build_the_same_histograms(slots, values):
 @pytest.mark.parametrize(
     "kw,text",
     [
-        # the boosted cell: four rungs, the widest group is the 302 wide
+        # the boosted cell: four rungs, the widest group is the 302 wide;
+        # the 4 lanes share a pass up to 64 slots (the one-lane tiles)
         (dict(max_depth=10, lowp=False),
-         "32:2048/104 64:2048/104 128:2048/104 256:1024/104"),
+         "32x4:2048/104 64x4:2048/104 128x1:2048/104 256x1:1024/104"),
         # the forest cell's three depth programs (lowp)
         (dict(max_depth=12, lowp=True),
-         "32:2048/104 64:2048/104 128:2048/104 256:2048/104"),
-        (dict(max_depth=6, lowp=True), "32:2048/104 64:2048/104"),
-        (dict(max_depth=3, lowp=True), "8:2048/104"),
+         "32x4:2048/104 64x4:2048/104 128x4:2048/104 256x1:2048/104"),
+        (dict(max_depth=6, lowp=True), "32x4:2048/104 64x4:2048/104"),
+        (dict(max_depth=3, lowp=True), "8x4:2048/104"),
         # four shards: the local rows' plan, the full width only
-        (dict(max_depth=10, lowp=False, shards=4), "256:1024/104"),
-        (dict(max_depth=10, lowp=False, shards=1), "256:1024/104"),
+        (dict(max_depth=10, lowp=False, shards=4), "256x1:1024/104"),
+        (dict(max_depth=10, lowp=False, shards=1), "256x1:1024/104"),
         # builders without tiles
         (dict(max_depth=10, lowp=False, n=4096), "none"),
         (dict(max_depth=10, lowp=False, impl="scatter"), "none"),

@@ -156,6 +156,120 @@ def test_tiles_and_built_channels_by_channel_count(case):
         assert HP.binloop_tiles(302, slots, 32, lowp=lowp) == tiles
 
 
+# (channels, lowp, slots, lanes) -> g, the lanes one grid step of the
+# bin-loop kernel builds: as many as its [T, g·variants·slots] operand
+# holds in 1,024 lanes, spread evenly over the passes; none where one
+# lane's operand is 512 lanes or more (measured slower folded)
+LANE_GROUPS = {
+    # two channels, four (hi, lo) variants: the boosted and GBT cells
+    (2, False, 8, 4): 4,
+    (2, False, 32, 4): 4,
+    (2, False, 64, 4): 4,
+    (2, False, 128, 4): 1,
+    (2, False, 256, 4): 1,
+    # two channels at lowp, two variants: the forest cell
+    (2, True, 8, 4): 4,
+    (2, True, 32, 4): 4,
+    (2, True, 128, 4): 4,
+    (2, True, 256, 4): 1,
+    # seven channels at lowp: the seven-class forest cell
+    (7, True, 8, 4): 4,
+    (7, True, 32, 4): 4,
+    (7, True, 64, 4): 2,
+    (7, True, 128, 4): 1,
+    # seven channels, (hi, lo): 14 variants
+    (7, False, 8, 4): 4,
+    (7, False, 32, 4): 2,
+    (7, False, 64, 4): 1,
+    # lane counts that do not divide: 5 in two passes of 3 + 2, 3 in two
+    # of 2 + 1; one lane folds nothing; many lanes fold where they fit
+    (2, False, 64, 5): 3,
+    (7, True, 64, 3): 2,
+    (2, False, 32, 1): 1,
+    (2, False, 32, 12): 6,
+    (2, False, 8, 24): 24,
+}
+
+
+@pytest.mark.parametrize(
+    "case", sorted(LANE_GROUPS), ids=lambda c: "{}ch{}-{}slots-{}lanes".format(
+        c[0], "-lowp" if c[1] else "", c[2], c[3]
+    ),
+)
+def test_lane_group_by_channels_precision_slots_and_lanes(case):
+    channels, lowp, slots, lanes = case
+    g = HP.binloop_lane_group(lanes, channels, lowp, slots)
+    assert g == LANE_GROUPS[case]
+    # the folded operand fits the lanes measured
+    one = HP.stack_variants(channels, lowp) * max(slots, 8)
+    assert g * one <= HP._BINLOOP_STACK_LANES or g == 1
+    assert g == 1 or one < HP._BINLOOP_FOLD_UNDER
+    passes = -(-lanes // g)
+    if one < HP._BINLOOP_FOLD_UNDER:  # no fewer passes exist
+        assert passes == -(-lanes // (HP._BINLOOP_STACK_LANES // one))
+    assert HP.code_passes("binloop", lanes, channels, lowp, slots) == passes
+    assert HP.code_passes("scatter", lanes, channels, lowp, slots) == lanes
+
+
+# (channels, lowp) -> the fits' widths: every rung a plan of the cells'
+# shape can build at (the ladder under the chunk cap the plan gives)
+FOLD_RUNGS = {
+    (2, False): (8, 32, 64, 128, 256),
+    (2, True): (8, 32, 64, 128, 256),
+    (7, True): (8, 32, 64, 128),
+    (7, False): (8, 32, 64),
+}
+
+
+@pytest.mark.parametrize("group", CELL_GROUPS)
+@pytest.mark.parametrize("case", sorted(FOLD_RUNGS))
+def test_folded_tiles_stay_in_the_vmem_budget_at_every_planned_rung(
+    case, group
+):
+    """Four lanes at each rung of the cells' shape: the tiles the folded
+    operand is given keep the VMEM model under the budget, and they are
+    the one-lane build's (the measured table): each lane adds the same row
+    tiles in the same order, so its histograms are the one-lane build's
+    bit for bit."""
+    channels, lowp = case
+    f, bins = group
+    plan = HP.histogram_plan(
+        "pallas", CELL_ROWS, 4, CELL_GROUPS, 1 << 12, channels, lowp
+    )
+    for slots in FOLD_RUNGS[case]:
+        assert slots <= plan.chunk_cap
+        g = HP.binloop_lane_group(4, channels, lowp, slots)
+        row_tile, feat_tile = HP.binloop_tiles(
+            f, slots, bins, lowp=lowp, stat_channels=channels, lanes=g
+        )
+        assert HP.binloop_vmem_bytes(
+            row_tile, feat_tile, slots, bins, lowp, channels, g
+        ) <= HP._BINLOOP_VMEM_BUDGET
+        assert (row_tile, feat_tile) == HP.binloop_tiles(
+            f, slots, bins, lowp=lowp, stat_channels=channels
+        )
+
+
+# (channels, lowp, slots, lanes) -> the channels the folded operand has
+# lanes for: 7 at 32 slots four lanes a pass fill 896 lanes, at 8 slots
+# 224 lanes pad to 256
+FOLDED_CHANNELS_BUILT = {
+    (7, True, 32, 4): 7,
+    (7, True, 8, 4): 8,
+    (7, True, 64, 2): 7,
+    (2, True, 32, 4): 2,
+    (2, False, 32, 4): 2,
+    (2, False, 8, 4): 2,
+}
+
+
+@pytest.mark.parametrize("case", sorted(FOLDED_CHANNELS_BUILT))
+def test_built_channels_of_a_folded_operand(case):
+    channels, lowp, slots, lanes = case
+    assert HP.stat_channels_built(
+        channels, lowp, slots, lanes) == FOLDED_CHANNELS_BUILT[case]
+
+
 @pytest.mark.parametrize("case", sorted(PLAN_TABLE))
 def test_plan_table(case):
     args, (builders, chunk_cap) = PLAN_TABLE[case]

@@ -912,9 +912,10 @@ def test_fit_arrays_yields_the_sweep_span_tree(xgb_sweep):
     assert args["tree/fit_dispatch"] == {
         "lanes": 4, "rounds": 2, "depth": 3, "bins": 32,
         "hist_impl": "scatter", "hist_tiles": "none",
-        # (g, h); at 8 slots the kernel's operand (4 variants x 8 lanes)
-        # pads to one 128-lane tile: room for 8 statistics
-        "stat_channels": 2, "stat_channels_built": 8,
+        # (g, h); at 8 slots the kernel builds the 4 lanes in one pass:
+        # its operand (4 variants x 4 lanes x 8 slots) is one whole
+        # 128-lane tile, room for the 2 statistics and no more
+        "stat_channels": 2, "stat_channels_built": 2,
         "objective": "binary:logistic", "tree_weights": "0.02 0.02",
     }
     assert args["tree/feature_groups"] == {"narrow": 3, "wide": 4}

@@ -15,7 +15,8 @@ mode; Mosaic refused it at every shape).
 Compiled at main-path shapes: the bin-loop histogram (32 bins) and the
 lane-packed histogram (256 bins) in both precisions, the bin-loop kernel
 at the benchmark cells' shape and full widths (1,002,701 x 302, the tiles
-``binloop_tiles`` picks under the VMEM limit it states), the serve-side
+``binloop_tiles`` picks under the VMEM limit it states) and at the narrow
+widths where four lanes share a grid step, the serve-side
 traversal kernel at more than one tree tile (one-byte and split codes), one
 whole ``boost_chunk`` program (65,536 x 64, depth 10) with the Pallas
 histogram inside, and the binning's column statistics (the chunked sort).
@@ -72,15 +73,21 @@ def main(argv) -> int:
             .lower(*a, num_nodes=m, num_bins=256, lowp=lp),
         ))
     # the benchmark cells' wide group at the widths whose tiles ask for
-    # more scoped VMEM than Mosaic's default (binloop_tiles, PR 30)
+    # more scoped VMEM than Mosaic's default (``binloop_tiles``), and at
+    # the narrow rungs where the 4 lanes share a grid step
+    # (binloop_lane_group: g lanes in one [T, g·variants·slots] operand)
     cn, cf, ck = 1_002_701, 302, 4
     cell_args = (sds((cn, cf), i32), sds((ck, cn), i32), sds((ck, cn), f32),
                  sds((ck, cn), f32))
-    for slots, lowp in ((256, False), (256, True), (128, False)):
-        rt, ft = HP.binloop_tiles(cf, slots, 32, lowp=lowp)
+    for slots, lowp in (
+        (256, False), (256, True), (128, False), (64, False), (32, False),
+        (32, True), (8, True),
+    ):
+        g = HP.binloop_lane_group(ck, 2, lowp, slots)
+        rt, ft = HP.binloop_tiles(cf, slots, 32, lowp=lowp, lanes=g)
         jobs.append((
-            f"hist binloop cell shape {slots} slots lowp={lowp} "
-            f"(tiles {rt}/{ft})",
+            f"hist binloop cell shape {slots} slots x {g} lanes a pass "
+            f"lowp={lowp} (tiles {rt}/{ft})",
             lambda s_=slots, lp=lowp: HP.build_histogram_pallas_binloop
             .lower(*cell_args, num_nodes=s_, num_bins=32, lowp=lp),
         ))
@@ -88,11 +95,14 @@ def main(argv) -> int:
     # one bfloat16 variant each) at the widths its plan allows
     class_args = (sds((cn, cf), i32), sds((ck, cn), i32),
                   sds((ck, 6, cn), f32), sds((ck, cn), f32))
-    for slots in (32, 128):
-        rt, ft = HP.binloop_tiles(cf, slots, 32, lowp=True, stat_channels=7)
+    for slots in (8, 32, 64, 128):
+        g = HP.binloop_lane_group(ck, 7, True, slots)
+        rt, ft = HP.binloop_tiles(
+            cf, slots, 32, lowp=True, stat_channels=7, lanes=g
+        )
         jobs.append((
-            f"hist binloop cell shape {slots} slots, 7 channels lowp "
-            f"(tiles {rt}/{ft})",
+            f"hist binloop cell shape {slots} slots x {g} lanes a pass, "
+            f"7 channels lowp (tiles {rt}/{ft})",
             lambda s_=slots: HP.build_histogram_pallas_binloop
             .lower(*class_args, num_nodes=s_, num_bins=32, lowp=True),
         ))

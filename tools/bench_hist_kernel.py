@@ -4,13 +4,15 @@ width, at one table shape (default: the flagship cell's wide group,
 
     python tools/bench_hist_kernel.py [--rows N] [--cols F] [--bins B]
         [--lanes K] [--widths 32,64,128,256] [--lowp]
-        [--row-tile T] [--feat-tile FT] [--packed]
+        [--row-tile T] [--feat-tile FT] [--lane-group G] [--packed]
         [--grid [--row-tiles 128,...] [--feat-tiles 8,...]]
         [--vmem-limit-mb MB]
 
 Prints, per width, the tiles ``hist_pallas.binloop_tiles`` picks and the
 best and median of ``--repeat`` timed calls: the width -> time curve of
-PERF.md. ``--lowp`` times the two-variant kernel (the forest's: values
+PERF.md. ``--lane-group`` sets how many of the ``--lanes`` one grid step
+builds together (default: ``binloop_lane_group``'s; 1 times the kernel
+as it was before lanes shared a pass). ``--lowp`` times the two-variant kernel (the forest's: values
 already bf16-exact). ``--packed`` times the lane-packed kernel beside it
 (the default above 64 bins) and compares the two histograms. ``--grid``
 forces every (row_tile, feat_tile) pair of the two lists in turn and
@@ -49,6 +51,7 @@ def main(argv) -> int:
         help="statistic channels (2: grad and hess; K: a K-class forest's)",
     )
     ap.add_argument("--row-tile", type=int, default=None)
+    ap.add_argument("--lane-group", type=int, default=None)
     ap.add_argument("--feat-tile", type=int, default=None)
     ap.add_argument("--repeat", type=int, default=3)
     ap.add_argument("--packed", action="store_true")
@@ -112,7 +115,10 @@ def main(argv) -> int:
 
     def grid(node, m):
         """One table: rows row_tile, columns feat_tile, best s a call."""
-        picked = HP.binloop_tiles(f, m, b, lowp=args.lowp, stat_channels=ch)
+        lanes = args.lane_group or HP.binloop_lane_group(k, ch, args.lowp, m)
+        picked = HP.binloop_tiles(
+            f, m, b, lowp=args.lowp, stat_channels=ch, lanes=lanes
+        )
         print(f"width {m}: best s a call; row_tile down, feat_tile across; "
               f"* = binloop_tiles {picked}", flush=True)
         print("       " + "".join(f"{ft:>9d}" for ft in args.feat_tiles))
@@ -123,6 +129,7 @@ def main(argv) -> int:
                     _, best, _ = timed(
                         HP.build_histogram_pallas_binloop, node, m,
                         lowp=args.lowp, row_tile=rt, feat_tile=ft,
+                        lane_group=lanes,
                     )
                     cell = f"{best:.4f}"
                 except Exception as e:  # Mosaic refused the pair
@@ -138,14 +145,18 @@ def main(argv) -> int:
         if args.grid:
             grid(node, m)
             continue
-        rt, ft = HP.binloop_tiles(f, m, b, lowp=args.lowp, stat_channels=ch)
+        lanes = args.lane_group or HP.binloop_lane_group(k, ch, args.lowp, m)
+        rt, ft = HP.binloop_tiles(
+            f, m, b, lowp=args.lowp, stat_channels=ch, lanes=lanes
+        )
         rt, ft = args.row_tile or rt, args.feat_tile or ft
         total, best, med = timed(
             HP.build_histogram_pallas_binloop, node, m, lowp=args.lowp,
-            row_tile=rt, feat_tile=ft,
+            row_tile=rt, feat_tile=ft, lane_group=lanes,
         )
-        print(f"binloop width {m:4d} row_tile {rt:5d} feat_tile {ft:4d}: "
-              f"best {best:8.4f} s median {med:8.4f} s", flush=True)
+        print(f"binloop width {m:4d} lane_group {lanes} row_tile {rt:5d} "
+              f"feat_tile {ft:4d}: best {best:8.4f} s median {med:8.4f} s",
+              flush=True)
         if args.packed:
             ptotal, best, med = timed(
                 HP.build_histogram_pallas_batched, node, m, lowp=args.lowp

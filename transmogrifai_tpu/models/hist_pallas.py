@@ -277,15 +277,22 @@ def _stat_rows(grad, hess) -> list:
     return [grad[:, v] for v in range(grad.shape[1])] + [hess]
 
 
-def _hist_binloop_kernel(binned_ref, node_ref, *refs, m_pad, num_bins, lowp):
+def _hist_binloop_kernel(binned_ref, node_ref, *refs, m_pad, num_bins, lowp,
+                         lanes=1):
     """Bin-loop histogram step: one whole-block compare per bin instead of
     the per-group select-chain assembly. The comb construction drops from
     ~5 VPU ops per one-hot element to 2 (compare + convert) — the
     select-chain was measured at 333 ms of the 408 ms level cost at
     1M×500×32. Layout: binned block [feat_tile, T]
-    (features on sublanes), stack [T, nvar·M]; per bin b the dot
-    [feat_tile, T] @ [T, nvar·M] emits that bin's [feat_tile, nvar·M]
+    (features on sublanes), stack [T, nvar·G]; per bin b the dot
+    [feat_tile, T] @ [T, nvar·G] emits that bin's [feat_tile, nvar·G]
     plane, written at a static outermost index.
+
+    ``lanes`` (g) fits share the step: one read of the codes and one set of
+    bin compares serves all of them. A variant's G = g·M columns are its
+    lanes' M slots each, lane-major, so a statistic's hi + lo sum stays two
+    single slices. At one lane the step traces as the one-lane kernel
+    always has (its select chain's order is part of its compiled form).
 
     ``refs``: the S statistic rows of the fit (its value channels, then
     the hessian / weight: ``(g, h)`` of a boosted or a two-class fit, the
@@ -299,32 +306,67 @@ def _hist_binloop_kernel(binned_ref, node_ref, *refs, m_pad, num_bins, lowp):
     nstat = len(refs) // 2
     val_refs, out_refs = refs[:nstat], refs[nstat:]
 
-    nodes = node_ref[0, 0, :]
-    vals = [r[0, 0, :] for r in val_refs]
-    t = nodes.shape[0]
+    nodes = [node_ref[0, q, :] for q in range(lanes)]
+    vals = [[r[0, q, :] for q in range(lanes)] for r in val_refs]
+    t = nodes[0].shape[0]
+    width = lanes * m_pad  # one variant's columns
 
     nvar = stack_variants(nstat, lowp)
-    iota_s = lax.broadcasted_iota(jnp.int32, (t, nvar * m_pad), 1)
-    m_lane = iota_s % m_pad
-    variant = iota_s // m_pad
-    oh = nodes[:, None] == m_lane
-    if lowp:
-        pieces = vals
+    if lanes == 1:
+        iota_s = lax.broadcasted_iota(jnp.int32, (t, nvar * m_pad), 1)
+        m_lane = iota_s % m_pad
+        variant = iota_s // m_pad
+        oh = nodes[0][:, None] == m_lane
+        if lowp:
+            pieces = [v for (v,) in vals]
+        else:
+            pieces = []
+            for (v,) in vals:
+                hi = v.astype(jnp.bfloat16).astype(jnp.float32)
+                pieces += [hi, v - hi]
+
+        def select(i):
+            """Variant i's values on its lanes, the later variants' on
+            theirs."""
+            if i == nvar - 1:
+                return pieces[i][:, None]
+            return jnp.where(variant == i, pieces[i][:, None], select(i + 1))
+
+        stack = jnp.where(oh, select(0), 0.0).astype(jnp.bfloat16)
     else:
-        pieces = []
-        for v in vals:
-            hi = v.astype(jnp.bfloat16).astype(jnp.float32)
-            pieces += [hi, v - hi]
+        # one [T, G] block a variant, each selected over the lanes alone,
+        # side by side: one chain over every (variant, lane) piece of the
+        # whole width took a third of a folded call
+        iota_w = lax.broadcasted_iota(jnp.int32, (t, width), 1)
+        m_lane = iota_w % m_pad
+        lane = iota_w // m_pad
+        on_lane = [lane == q for q in range(lanes - 1)]
 
-    def select(i):
-        """Variant i's values on its lanes, the later variants' on theirs
-        (traced in the order the two-statistic kernel always had: its
-        compiled form is part of what the cells measure)."""
-        if i == nvar - 1:
-            return pieces[i][:, None]
-        return jnp.where(variant == i, pieces[i][:, None], select(i + 1))
+        def spread(rows):
+            """Lane q's row on lane q's M columns: [T, G]."""
+            out = rows[-1][:, None]
+            for q in range(lanes - 2, -1, -1):
+                out = jnp.where(on_lane[q], rows[q][:, None], out)
+            return out
 
-    stack = jnp.where(oh, select(0), 0.0).astype(jnp.bfloat16)
+        oh = spread(nodes) == m_lane
+        if lowp:
+            variants = vals
+        else:
+            variants = []
+            for per_lane in vals:
+                his = [
+                    v.astype(jnp.bfloat16).astype(jnp.float32)
+                    for v in per_lane
+                ]
+                variants += [his, [v - hi for v, hi in zip(per_lane, his)]]
+        stack = jnp.concatenate(
+            [
+                jnp.where(oh, spread(rows), 0.0).astype(jnp.bfloat16)
+                for rows in variants
+            ],
+            axis=1,
+        )
     codes = binned_ref[...]  # [feat_tile, T] int32
     contract = (((1,), (0,)), ((), ()))  # contract the row-tile axis
 
@@ -334,13 +376,13 @@ def _hist_binloop_kernel(binned_ref, node_ref, *refs, m_pad, num_bins, lowp):
             comb, stack, contract,
             preferred_element_type=jnp.float32,
             precision=lax.Precision.DEFAULT,
-        )  # [feat_tile, nvar·M]
+        )  # [feat_tile, nvar·G]
         if lowp:
-            sums = [out[:, s * m_pad:(s + 1) * m_pad] for s in range(nstat)]
+            sums = [out[:, s * width:(s + 1) * width] for s in range(nstat)]
         else:
             sums = [
-                out[:, 2 * s * m_pad:(2 * s + 1) * m_pad]
-                + out[:, (2 * s + 1) * m_pad:(2 * s + 2) * m_pad]
+                out[:, 2 * s * width:(2 * s + 1) * width]
+                + out[:, (2 * s + 1) * width:(2 * s + 2) * width]
                 for s in range(nstat)
             ]
 
@@ -371,6 +413,8 @@ _BINLOOP_STACK_ELEMS = 1 << 20
 # (256 slots x 4 variants), so a fit of more variants takes fewer slots a
 # chunk (``histogram_plan``): 7 statistics at ``lowp`` 128, two 256.
 _BINLOOP_STACK_LANES = 1024
+# One lane's operand at least this wide builds alone (``binloop_lane_group``)
+_BINLOOP_FOLD_UNDER = 512
 
 
 def stack_variants(stat_channels: int, lowp: bool) -> int:
@@ -380,19 +424,70 @@ def stack_variants(stat_channels: int, lowp: bool) -> int:
     return stat_channels * (1 if lowp else 2)
 
 
-def stat_channels_built(stat_channels: int, lowp: bool, slots: int) -> int:
-    """Statistic channels the ``[T, nvar·M]`` operand of a ``slots``-slot
-    build has lanes for: the operand pads to whole 128-lane tiles, so 7
-    statistics at 32 slots (224 lanes) are built as 8, two at ``lowp`` (64
-    lanes) as 4."""
+def binloop_lane_group(
+    k_fits: int, stat_channels: int, lowp: bool, slots: int,
+) -> int:
+    """Lanes of a fit one grid step of the bin-loop kernel builds together
+    (g): as many as the ``[T, g·nvar·M]`` stacked operand holds within
+    ``_BINLOOP_STACK_LANES``, spread evenly over the passes that takes (5
+    lanes that fit 4 at a time go as 3 + 2, not 4 + 1); none where one
+    lane's operand is ``_BINLOOP_FOLD_UNDER`` lanes or more. From shapes
+    alone: two channels fold 4 lanes up to 64 slots, none at 128; two at
+    ``lowp`` 4 up to 128, none at 256; seven at ``lowp`` 4 up to 32, 2 at
+    64, none at 128.
+
+    Measured on a v5e at 1,002,701 x 302 x 32 bins, 4 lanes
+    (``tools/bench_hist_kernel.py``; seconds a call, one lane a
+    pass -> folded):
+
+        two channels     8: 0.1339 -> 0.0531    32: 0.1339 -> 0.0860
+                        64: 0.1754 -> 0.1563   128: 0.2677 -> 0.2826 (g 2)
+        two, lowp        8: 0.1127 -> 0.0426    32: 0.1125 -> 0.0522
+                        64: 0.1127 -> 0.0813   128: 0.1522 -> 0.1492
+                       256: 0.2705 -> 0.2822 (g 2)
+        seven, lowp      8: 0.1531 -> 0.0791    32: 0.2063 -> 0.1539
+                        64: 0.3654 -> 0.2699 (g 2)
+
+    A 512-lane pass is mostly the MXU's: the codes' read and compares it
+    shares are a fifth of it, and two such lanes in one 1,024-lane operand
+    cost more than two passes, hence ``_BINLOOP_FOLD_UNDER``."""
+    m_pad = _round_up(max(slots, 8), 8)
+    one = stack_variants(stat_channels, lowp) * m_pad
+    if one >= _BINLOOP_FOLD_UNDER:
+        return 1
+    passes = -(-k_fits // max(1, _BINLOOP_STACK_LANES // one))
+    return -(-k_fits // passes)
+
+
+def code_passes(
+    builder: str, k_fits: int, stat_channels: int, lowp: bool, slots: int,
+) -> int:
+    """Passes over the codes one build of ``k_fits`` lanes makes with
+    ``builder`` (a key of ``BUILDERS``): the bin-loop kernel's lane groups,
+    one a lane for every other builder."""
+    if builder != "binloop":
+        return k_fits
+    return -(-k_fits // binloop_lane_group(k_fits, stat_channels, lowp, slots))
+
+
+def stat_channels_built(
+    stat_channels: int, lowp: bool, slots: int, lanes: int = 1,
+) -> int:
+    """Statistic channels the ``[T, nvar·g·M]`` operand of a ``slots``-slot
+    build of ``lanes`` (g) lanes a pass has lanes for: the operand pads to
+    whole 128-lane tiles, so 7 statistics at 32 slots (224 lanes) are
+    built as 8 one lane a pass and as 7 four at a time (896 lanes), two at
+    ``lowp`` (64 lanes) as 4 and as 2."""
     m_pad = _round_up(max(slots, 8), 8)
     per = stack_variants(stat_channels, lowp) // stat_channels
-    return _round_up(per * stat_channels * m_pad, 128) // (per * m_pad)
+    return _round_up(per * stat_channels * lanes * m_pad, 128) // (
+        per * lanes * m_pad
+    )
 
 
 def binloop_vmem_bytes(
     row_tile: int, feat_tile: int, num_nodes: int, num_bins: int,
-    lowp: bool = False, stat_channels: int = 2,
+    lowp: bool = False, stat_channels: int = 2, lanes: int = 1,
 ) -> int:
     """Scoped VMEM one grid step of the bin-loop kernel takes, fitted from
     above to what Mosaic asked for at 240 (slots, bins, variants, tiles)
@@ -407,21 +502,24 @@ def binloop_vmem_bytes(
     and 1 KB of row vectors. ``stat_channels`` (S: two up to PR 33) scales
     what there is one of a statistic: S accumulators, S + 1 rows, the
     stack's variants (derived from the two-channel fit, not re-fitted:
-    ``binloop_tiles``)."""
+    ``binloop_tiles``). ``lanes`` (g) fits a step widen the accumulators
+    and the stack to g·M slots and take g row vectors of each kind
+    (derived the same way, not re-fitted)."""
     m_pad = _round_up(max(num_nodes, 8), 8)
-    stack_lanes = _round_up(stack_variants(stat_channels, lowp) * m_pad, 128)
+    width = lanes * m_pad
+    stack_lanes = _round_up(stack_variants(stat_channels, lowp) * width, 128)
     blocks = (
-        stat_channels * num_bins * feat_tile * _round_up(m_pad, 128) * 4
+        stat_channels * num_bins * feat_tile * _round_up(width, 128) * 4
         + feat_tile * row_tile * 4
-        + (stat_channels + 1) * 8 * row_tile * 4
+        + (stat_channels + 1) * _round_up(lanes, 8) * row_tile * 4
     )
-    temps = row_tile * (stack_lanes * 7 // 2 + 8 * feat_tile + 1024)
+    temps = row_tile * (stack_lanes * 7 // 2 + 8 * feat_tile + 1024 * lanes)
     return 2 * blocks + temps
 
 
 def binloop_tiles(
     f: int, num_nodes: int, num_bins: int, lowp: bool = False,
-    stat_channels: int = 2,
+    stat_channels: int = 2, lanes: int = 1,
 ) -> tuple[int, int]:
     """(row_tile, feat_tile) of the bin-loop kernel at ``num_nodes`` node
     slots, from shapes alone, chosen together. The kernel's dot is
@@ -476,7 +574,13 @@ def binloop_tiles(
     grid of pairs was timed at S = 7, and the v5e compiler took every
     pair of the table (32 MB at most of the 48 MB budget:
     ``tools/aot_v5e.py``). ``histogram_plan`` holds such a fit to
-    128-slot chunks: no operand wider than the 1,024 lanes measured."""
+    128-slot chunks: no operand wider than the 1,024 lanes measured.
+
+    ``lanes`` (g, ``binloop_lane_group``): the fits a step builds together.
+    Their accumulators and operand are g times as wide, which the VMEM
+    budget reckons with; the row tile stays the one-lane build's, so that
+    each lane adds its row tiles in the same order and its histograms are
+    the one-lane build's bit for bit."""
     m_pad = _round_up(max(num_nodes, 8), 8)
     nvar = stack_variants(stat_channels, lowp)
     # a power of two, so that its halvings stay multiples of 128 lanes
@@ -491,7 +595,7 @@ def binloop_tiles(
             if (
                 binloop_vmem_bytes(
                     row_tile, feat_tile, num_nodes, num_bins, lowp,
-                    stat_channels,
+                    stat_channels, lanes,
                 ) <= _BINLOOP_VMEM_BUDGET
             ):
                 return row_tile, feat_tile
@@ -503,6 +607,7 @@ def binloop_tiles(
     jax.jit,
     static_argnames=(
         "num_nodes", "num_bins", "row_tile", "lowp", "interpret", "feat_tile",
+        "lane_group",
     ),
 )
 def build_histogram_pallas_binloop(
@@ -516,12 +621,15 @@ def build_histogram_pallas_binloop(
     lowp: bool = False,
     interpret: bool = False,
     feat_tile: int | None = None,
+    lane_group: int | None = None,
 ) -> jax.Array:
     """hist [K, num_nodes, F, num_bins, S] via the bin-loop kernel (see
     _hist_binloop_kernel): S = 2 (grad, hess) from a ``[K, N]`` grad, V + 1
     (the value channels, then hess) from ``[K, V, N]``. Same contract as
-    build_histogram_pallas_batched; ``row_tile`` / ``feat_tile`` override
-    ``binloop_tiles`` (timing probes: tools/bench_hist_kernel.py)."""
+    build_histogram_pallas_batched. The grid runs over groups of g lanes
+    (``binloop_lane_group``), a last partial group padded with dead lanes.
+    ``row_tile`` / ``feat_tile`` / ``lane_group`` override
+    ``binloop_tiles`` and g (timing probes: tools/bench_hist_kernel.py)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -529,33 +637,43 @@ def build_histogram_pallas_binloop(
     f = binned.shape[1]
     stats = _stat_rows(grad, hess)
     m_pad = _round_up(max(num_nodes, 8), 8)
+    lanes = lane_group or binloop_lane_group(
+        k_fits, len(stats), lowp, num_nodes
+    )
+    passes = -(-k_fits // lanes)
     tiles = binloop_tiles(
-        f, num_nodes, num_bins, lowp=lowp, stat_channels=len(stats)
+        f, num_nodes, num_bins, lowp=lowp, stat_channels=len(stats),
+        lanes=lanes,
     )
     row_tile, feat_tile = row_tile or tiles[0], feat_tile or tiles[1]
     n_pad = _round_up(max(n, row_tile), row_tile)
     f_pad = _round_up(f, feat_tile)
 
+    def by_pass(rows, fill, dtype):
+        """[K, N] -> [passes, g, n_pad]: lane k is row k % g of pass k // g"""
+        return jnp.full((passes * lanes, n_pad), fill, dtype=dtype).at[
+            :k_fits, :n
+        ].set(rows).reshape(passes, lanes, n_pad)
+
     binned_t = jnp.full((f_pad, n_pad), -1, dtype=jnp.int32)
     binned_t = binned_t.at[:f, :n].set(binned.T)
-    node_p = jnp.full((k_fits, 1, n_pad), -1, dtype=jnp.int32).at[:, 0, :n].set(node)
-    stats_p = [
-        jnp.zeros((k_fits, 1, n_pad), dtype=jnp.float32).at[:, 0, :n].set(v)
-        for v in stats
-    ]
+    node_p = by_pass(node, -1, jnp.int32)
+    stats_p = [by_pass(v, 0.0, jnp.float32) for v in stats]
 
-    grid = (k_fits, f_pad // feat_tile, n_pad // row_tile)
+    grid = (passes, f_pad // feat_tile, n_pad // row_tile)
     row_spec = pl.BlockSpec(
-        (1, 1, row_tile), lambda k, i, j: (k, 0, j), memory_space=pltpu.VMEM,
+        (1, lanes, row_tile), lambda k, i, j: (k, 0, j),
+        memory_space=pltpu.VMEM,
     )
 
     outs = pl.pallas_call(
         functools.partial(
             _hist_binloop_kernel, m_pad=m_pad, num_bins=num_bins, lowp=lowp,
+            lanes=lanes,
         ),
         out_shape=tuple(
             jax.ShapeDtypeStruct(
-                (k_fits, num_bins, f_pad, m_pad), jnp.float32
+                (passes, num_bins, f_pad, lanes * m_pad), jnp.float32
             )
             for _ in stats
         ),
@@ -569,7 +687,7 @@ def build_histogram_pallas_binloop(
         ] + [row_spec] * len(stats),
         out_specs=tuple(
             pl.BlockSpec(
-                (1, num_bins, feat_tile, m_pad),
+                (1, num_bins, feat_tile, lanes * m_pad),
                 lambda k, i, j: (k, 0, i, 0),
                 memory_space=pltpu.VMEM,
             )
@@ -581,8 +699,13 @@ def build_histogram_pallas_binloop(
         interpret=interpret,
     )(binned_t, node_p, *stats_p)
 
-    # S x [K, B, F, M] -> [K, M, F, B, S]
+    # S x [passes, B, F, g·M] -> [K, M, F, B, S]
     out = jnp.stack(outs, axis=-1)
+    if lanes > 1:
+        out = out.reshape(passes, num_bins, f_pad, lanes, m_pad, len(stats))
+        out = jnp.moveaxis(out, 3, 1).reshape(
+            passes * lanes, num_bins, f_pad, m_pad, len(stats)
+        )[:k_fits]
     out = jnp.transpose(out, (0, 3, 2, 1, 4))
     return out[:, :num_nodes, :f, :, :]
 

@@ -78,7 +78,12 @@ class HistSlots(NamedTuple):
     ``partition_run`` / ``partition_skipped``: of the level's two row-wide
     passes (the compaction of the rows' nodes, the routing of the rows to
     their children), those that ran and those a level where no lane can
-    split left out (always 2 / 0 on the sharded path)."""
+    split left out (always 2 / 0 on the sharded path).
+    ``code_passes`` / ``lane_builds``: over the level's builds (a feature
+    group's each), the passes over the codes made and the lanes built: the
+    bin-loop kernel builds ``hist_pallas.binloop_lane_group`` lanes a pass
+    (``code_passes`` = ⌈K / g⌉ a build), every other builder one
+    (``code_passes`` = ``lane_builds`` = K a build)."""
 
     live: jax.Array
     built: jax.Array
@@ -92,6 +97,8 @@ class HistSlots(NamedTuple):
     nodes_derived: jax.Array
     partition_run: jax.Array
     partition_skipped: jax.Array
+    code_passes: jax.Array
+    lane_builds: jax.Array
 
 
 class _Siblings(NamedTuple):
@@ -140,6 +147,9 @@ GINI = 4.0
 # bin-loop kernel's [T, nvar·M] operand is exactly one 128-lane tile
 # (nvar 4); below it the lanes only pad (on a v5e at 1M x 302 x 32 bins a
 # build takes 0.134 s at 32 slots and at 8, 0.176 s at 64: PR 26).
+# That is a lane a pass: where a fit's lanes share one
+# (hist_pallas.binloop_lane_group) 8 slots cost less than 32, 0.053
+# against 0.086 s at 4 lanes, and this floor was not measured again.
 _HIST_WIDTH_FLOOR = 32
 _HIST_WIDTH_RUNGS = 4
 
@@ -215,15 +225,16 @@ def hist_tiles(
     stat_channels: int = 2,
 ) -> str:
     """The ``hist_tiles`` attribute of ``tree/fit_dispatch``: for each rung
-    of the fit's width ladder, ``slots:row_tile/feat_tile`` of the bin-loop
-    kernel over the widest feature group (the one with the most histogram
-    cells), so a trace reader can tell which table of
-    ``hist_pallas.binloop_tiles`` a run used. ``groups``: the fit's
+    of the fit's width ladder, ``slotsxg:row_tile/feat_tile`` of the
+    bin-loop kernel over the widest feature group (the one with the most
+    histogram cells), g the lanes a pass (``hist_pallas.binloop_lane_group``),
+    so a trace reader can tell which table of ``hist_pallas.binloop_tiles``
+    a run used. ``groups``: the fit's
     ``(columns, bins)``; ``n``: the rows of the whole fit; ``shards``: the
     data-axis size of the mesh a sharded fit runs on (None: one device);
     ``stat_channels``: the fit's statistic channels (K for a K-class
     forest). ``none`` where that group takes another builder."""
-    from .hist_pallas import binloop_tiles
+    from .hist_pallas import binloop_lane_group, binloop_tiles
 
     plan, ladder = _dispatch_layout(
         n, k_fits, groups, max_depth, lowp, impl, shards, stat_channels
@@ -233,12 +244,14 @@ def hist_tiles(
     )
     if builder != "binloop":
         return "none"
-    return " ".join(
-        "{}:{}/{}".format(
-            w, *binloop_tiles(f, w, b, lowp=lowp, stat_channels=stat_channels)
-        )
-        for w in ladder
-    )
+
+    def rung(w):
+        g = binloop_lane_group(k_fits, stat_channels, lowp, w)
+        return "{}x{}:{}/{}".format(w, g, *binloop_tiles(
+            f, w, b, lowp=lowp, stat_channels=stat_channels, lanes=g
+        ))
+
+    return " ".join(rung(w) for w in ladder)
 
 
 def stat_channels_built(
@@ -247,16 +260,18 @@ def stat_channels_built(
 ) -> int:
     """The ``stat_channels_built`` attribute of ``tree/fit_dispatch``: the
     statistic channels the bin-loop kernel's stacked operand has lanes for
-    at the narrowest rung of the fit's width ladder
-    (``hist_pallas.stat_channels_built``), from the shapes ``hist_tiles``
-    takes. Reckoned whichever builder the fit takes: it is what the
-    channel count costs on the TPU's kernel."""
+    at the narrowest rung of the fit's width ladder, its lanes a pass
+    folded in (``hist_pallas.stat_channels_built``), from the shapes
+    ``hist_tiles`` takes. Reckoned whichever builder the fit takes: it is
+    what the channel count costs on the TPU's kernel."""
+    from .hist_pallas import binloop_lane_group
     from .hist_pallas import stat_channels_built as built
 
     _, ladder = _dispatch_layout(
         n, k_fits, groups, max_depth, lowp, impl, shards, stat_channels
     )
-    return built(stat_channels, lowp, ladder[0])
+    g = binloop_lane_group(k_fits, stat_channels, lowp, ladder[0])
+    return built(stat_channels, lowp, ladder[0], g)
 
 
 class HistSlotStats(_tm.LedgerCore):
@@ -275,6 +290,7 @@ class HistSlotStats(_tm.LedgerCore):
         "nodes_built": "histNodesBuilt", "nodes_derived": "histNodesDerived",
         "partition_run": "partitionPassesRun",
         "partition_skipped": "partitionPassesSkipped",
+        "code_passes": "histCodePasses", "lane_builds": "histLaneBuilds",
     }
 
     #: per ``tree/fit_dispatch``: the fit's statistic channels and those
@@ -316,7 +332,8 @@ def await_outputs(value, hist_slots: HistSlots | None = None):
     same fit program returned: once its outputs have landed they are there
     too, and their sums go onto the span (``slots_live``, ``slots_built``,
     ``chunks_run``, ``chunks_skipped``, ``nodes_built``, ``nodes_derived``,
-    ``partition_run``, ``partition_skipped``; from a fit that counts node
+    ``partition_run``, ``partition_skipped``, ``code_passes``,
+    ``lane_builds``; from a fit that counts node
     subsets ``subset_admitted``, ``subset_pairs``; from one that boosts in
     first order ``boost_rounds_label``, ``boost_rounds_residual``) and the
     ``tree`` ledger."""
@@ -342,6 +359,8 @@ def await_outputs(value, hist_slots: HistSlots | None = None):
                 nodes_derived=sums["nodes_derived"],
                 partition_run=sums["partition_run"],
                 partition_skipped=sums["partition_skipped"],
+                code_passes=sums["code_passes"],
+                lane_builds=sums["lane_builds"],
             )
             if sums["subset_pairs"]:
                 sp.attrs.update(
@@ -714,7 +733,7 @@ def _grow_tree_impl(
     on the lane, the chunk, the compact slot, the width rung or the mesh.
     ``node_subset == F`` draws nothing; None also counts nothing
     (``HistSlots.subset_*`` stay 0)."""
-    from .hist_pallas import BUILDERS, default_impl
+    from .hist_pallas import BUILDERS, code_passes, default_impl
 
     k_fits, n = hess.shape
     f = binned.shape[1]
@@ -1026,13 +1045,22 @@ def _grow_tree_impl(
                     jnp.where(slot_live, admitted, 0).sum(dtype=jnp.int32),
                     pairs,
                 )
+            # the groups' builds: passes over the codes, lanes (static)
+            build_w = chunk_nodes if sib is None else chunk_nodes // 2
+            counts += (
+                jnp.int32(sum(
+                    code_passes(name, k_fits, n_values + 1, lowp, build_w)
+                    for name in plan.builders
+                )),
+                jnp.int32(k_fits * len(groups)),
+            )
             return (
                 jnp.where(do_split, bf, -1),
                 jnp.where(do_split, bb, 0),
                 do_split & br,
                 counts,
                 tuple(new_hists),
-            )  # [K, chunk] x 3, two scalars, the kept histograms
+            )  # [K, chunk] x 3, four scalars, the kept histograms
 
     sentinel = jnp.int32(max_nodes)  # out-of-range → dropped by scatters
 
@@ -1079,9 +1107,10 @@ def _grow_tree_impl(
     # that holds them (live_level below). A build costs by its WIDTH, not
     # by the rows a slot holds (every build passes over all rows against a
     # [T, nvar·width] one-hot operand: 0.52 s at 256 slots, 0.26 at 128,
-    # 0.17 at 64, 0.13 at 32 on a v5e at 1M x 302 x 32 bins,
-    # hist_pallas.binloop_tiles). The sharded path keeps the full width
-    # (_slot_layout).
+    # 0.17 at 64, 0.13 at 32 on a v5e at 1M x 302 x 32 bins, four lanes one
+    # a pass, hist_pallas.binloop_tiles; 0.16 at 64 and 0.086 at 32 where
+    # the lanes share a pass, hist_pallas.binloop_lane_group). The sharded
+    # path keeps the full width (_slot_layout).
     #
     # So a level past the root builds HALF its nodes. A level's nodes come
     # in sibling pairs whose parent's histogram the level above held, and
@@ -1177,6 +1206,7 @@ def _grow_tree_impl(
 
         at = (live, rank, level_idx)
         zero2 = (jnp.int32(0), jnp.int32(0))
+        zero4 = zero2 * 2
         hists0 = sib.hists if keep else ()
 
         def chunk_loop(sib_):
@@ -1186,19 +1216,19 @@ def _grow_tree_impl(
             chunks = num_chunks if sib_ is None else num_pair_chunks
 
             def chunk_body(ci, fb):
-                feats_a, bins_a, right_a, built, (adm, prs), hists = fb
+                feats_a, bins_a, right_a, built, counts, hists = fb
                 c0 = ci * width
                 loc = build_slots(local, c0, width, sib_)
                 if axis_name is None:
                     occupied = (loc >= 0).any()
-                    cf, cb, cr, (ca, cp), hists = jax.lax.cond(
+                    cf, cb, cr, cc, hists = jax.lax.cond(
                         occupied,
                         lambda: chunk_stats(loc, c0, width, at, sib_, hists),
                         lambda: (
                             jnp.full((k_fits, width), -1, dtype=jnp.int32),
                             jnp.zeros((k_fits, width), dtype=jnp.int32),
                             jnp.zeros((k_fits, width), dtype=bool),
-                            zero2,
+                            zero4,
                             hists,
                         ),
                     )
@@ -1206,7 +1236,7 @@ def _grow_tree_impl(
                 else:
                     # the sharded path always computes — its psums can't
                     # sit under a data-dependent cond
-                    cf, cb, cr, (ca, cp), hists = chunk_stats(
+                    cf, cb, cr, cc, hists = chunk_stats(
                         loc, c0, width, at, sib_, hists
                     )
                     built = built + chunk_nodes
@@ -1215,7 +1245,7 @@ def _grow_tree_impl(
                     jax.lax.dynamic_update_slice(bins_a, cb, (0, c0)),
                     jax.lax.dynamic_update_slice(right_a, cr, (0, c0)),
                     built,
-                    (adm + ca, prs + cp),
+                    tuple(a + c for a, c in zip(counts, cc)),
                     hists,
                 )
 
@@ -1228,7 +1258,7 @@ def _grow_tree_impl(
                             jnp.full(slots, -1, dtype=jnp.int32),
                             jnp.zeros(slots, dtype=jnp.int32),
                             jnp.zeros(slots, dtype=bool),
-                            jnp.int32(0), zero2, hists0,
+                            jnp.int32(0), zero4, hists0,
                         ),
                     )
                 )
@@ -1317,7 +1347,7 @@ def _grow_tree_impl(
                     jnp.zeros((k_fits, n_nodes), dtype=jnp.int32),
                     jnp.zeros((k_fits, n_nodes), dtype=bool),
                     jnp.int32(0),
-                    zero2,
+                    zero4,
                     hists0,
                 ),
             )
@@ -1424,9 +1454,9 @@ def _grow_tree_impl(
         return (node, active, alive, sib), (
             feats_d, bins_d,
             HistSlots(
-                n_built_k.max(), built, runs, skipped, *counts, *zero2,
+                n_built_k.max(), built, runs, skipped, *counts[:2], *zero2,
                 n_built_k.sum(), (n_live_k - n_built_k).sum(),
-                passes, 2 - passes,
+                passes, 2 - passes, *counts[2:],
             ),
         )
 
