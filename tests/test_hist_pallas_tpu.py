@@ -422,3 +422,29 @@ def test_binloop_lanes_sharing_a_pass_match_one_lane_a_pass_on_device(
         build_histogram_pallas_binloop(*args, lowp=lowp, lane_group=1)
     )
     np.testing.assert_array_equal(folded, one)
+
+
+@pytest.mark.parametrize("lanes", [1, 4])
+@pytest.mark.parametrize("slots", [8, 32, 64, 128, 256])
+def test_an_exact_hessian_builds_the_four_variant_histograms_on_device(
+    slots, lanes
+):
+    """A first-order boosted fit's statistics (real g, h = 1 under a 0/1
+    row mask) with the hessian stated exact: three variants, the lanes
+    folded as ``binloop_lane_group`` says for three, give the histograms
+    of the four-variant build bit for bit, on real Mosaic."""
+    from transmogrifai_tpu.models.hist_pallas import (
+        build_histogram_pallas_binloop,
+    )
+
+    n, cols, bins = 5000, 302, 32
+    rng = np.random.default_rng(lanes * 1000 + slots)
+    binned = rng.integers(0, bins, size=(n, cols)).astype(np.int32)
+    node = rng.integers(-1, slots, size=(lanes, n)).astype(np.int32)
+    grad = rng.normal(size=(lanes, n)).astype(np.float32)
+    hess = (rng.random((lanes, n)) < 0.8).astype(np.float32)
+    args = (jnp.asarray(binned), jnp.asarray(node), jnp.asarray(grad),
+            jnp.asarray(hess), slots, bins)
+    three = np.asarray(build_histogram_pallas_binloop(*args, exact_hess=True))
+    four = np.asarray(build_histogram_pallas_binloop(*args))
+    np.testing.assert_array_equal(three, four)

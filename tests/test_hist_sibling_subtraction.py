@@ -107,7 +107,8 @@ def builds_seen(monkeypatch):
     seen = []
     scatter = HP.BUILDERS["scatter"]
 
-    def build(operand, loc, g, h, num_nodes, num_bins, lowp=False):
+    def build(operand, loc, g, h, num_nodes, num_bins, lowp=False,
+              exact_hess=False):
         seen.append(np.asarray(loc))
         return scatter.build(operand, loc, g, h, num_nodes, num_bins)
 

@@ -16,7 +16,8 @@ Compiled at main-path shapes: the bin-loop histogram (32 bins) and the
 lane-packed histogram (256 bins) in both precisions, the bin-loop kernel
 at the benchmark cells' shape and full widths (1,002,701 x 302, the tiles
 ``binloop_tiles`` picks under the VMEM limit it states) and at the narrow
-widths where four lanes share a grid step, the serve-side
+widths where four lanes share a grid step, and with a first-order boosted
+fit's hessian stated exact (three variants), the serve-side
 traversal kernel at more than one tree tile (one-byte and split codes), one
 whole ``boost_chunk`` program (65,536 x 64, depth 10) with the Pallas
 histogram inside, and the binning's column statistics (the chunked sort).
@@ -91,6 +92,17 @@ def main(argv) -> int:
             lambda s_=slots, lp=lowp: HP.build_histogram_pallas_binloop
             .lower(*cell_args, num_nodes=s_, num_bins=32, lowp=lp),
         ))
+    # a first-order boosted fit's statistics with the hessian stated exact
+    # (g hi, g lo, h): the folded 128-slot rung and the widest operands
+    for slots in (32, 128, 256):
+        g = HP.binloop_lane_group(ck, 2, False, slots, exact_hess=True)
+        rt, ft = HP.binloop_tiles(cf, slots, 32, lanes=g)
+        jobs.append((
+            f"hist binloop cell shape {slots} slots x {g} lanes a pass "
+            f"exact_hess (tiles {rt}/{ft})",
+            lambda s_=slots: HP.build_histogram_pallas_binloop
+            .lower(*cell_args, num_nodes=s_, num_bins=32, exact_hess=True),
+        ))
     # a seven-class forest's statistic axis (w and six class indicators,
     # one bfloat16 variant each) at the widths its plan allows
     class_args = (sds((cn, cf), i32), sds((ck, cn), i32),
@@ -133,14 +145,15 @@ def main(argv) -> int:
     ))
     jobs.append((
         "boost_chunk 65536x64 K=4 depth 12, 2 rounds, spark:logloss "
-        "(first-order targets, per-round weights, 8 chunks a level)",
+        "(first-order targets, per-round weights, 8 chunks a level, the "
+        "hessian stated exact)",
         lambda: TR._boost_rounds_batched.lower(
             sds((n, f), i32), sds((n,), f32), sds((4, n), f32),
             sds((4, n), f32), sds((4,), f32), sds((), f32), sds((), f32),
             sds((4,), f32), sds((4,), f32), None,
             num_rounds=2, max_depth=12, num_bins=32,
             objective="spark:logloss", hist_impl="pallas",
-            info_gain_norm=2.0,
+            info_gain_norm=2.0, binary_weights=True,
         ),
     ))
     jobs.append((

@@ -3,7 +3,7 @@ width, at one table shape (default: the flagship cell's wide group,
 1,002,701 x 302 x 32 bins, 4 lanes).
 
     python tools/bench_hist_kernel.py [--rows N] [--cols F] [--bins B]
-        [--lanes K] [--widths 32,64,128,256] [--lowp]
+        [--lanes K] [--widths 32,64,128,256] [--lowp | --exact-hess]
         [--row-tile T] [--feat-tile FT] [--lane-group G] [--packed]
         [--grid [--row-tiles 128,...] [--feat-tiles 8,...]]
         [--vmem-limit-mb MB]
@@ -13,7 +13,10 @@ best and median of ``--repeat`` timed calls: the width -> time curve of
 PERF.md. ``--lane-group`` sets how many of the ``--lanes`` one grid step
 builds together (default: ``binloop_lane_group``'s; 1 times the kernel
 as it was before lanes shared a pass). ``--lowp`` times the two-variant kernel (the forest's: values
-already bf16-exact). ``--packed`` times the lane-packed kernel beside it
+already bf16-exact). ``--exact-hess`` times the three-variant kernel (a
+first-order boosted fit's: real g, h = 1 carried as one variant) at the
+tiles of the four-variant one, then builds the four-variant histograms
+and says whether they are the same bit for bit. ``--packed`` times the lane-packed kernel beside it
 (the default above 64 bins) and compares the two histograms. ``--grid``
 forces every (row_tile, feat_tile) pair of the two lists in turn and
 prints one table a width, best seconds a call, ``-`` where Mosaic refused
@@ -46,6 +49,7 @@ def main(argv) -> int:
     ap.add_argument("--lanes", type=int, default=4)
     ap.add_argument("--widths", type=_ints, default=[32, 64, 128, 256])
     ap.add_argument("--lowp", action="store_true")
+    ap.add_argument("--exact-hess", action="store_true")
     ap.add_argument(
         "--channels", type=int, default=2,
         help="statistic channels (2: grad and hess; K: a K-class forest's)",
@@ -99,8 +103,15 @@ def main(argv) -> int:
     dev = jax.devices()[0]
     limit = HP._BINLOOP_VMEM_LIMIT
     print(f"device {dev.platform} {dev.device_kind}; {n} x {f} x {b} bins, "
-          f"{k} lanes, lowp {args.lowp}, {ch} channels, vmem limit "
+          f"{k} lanes, lowp {args.lowp}, exact_hess {args.exact_hess}, "
+          f"{ch} channels, vmem limit "
           f"{'default' if limit is None else limit >> 20} MB", flush=True)
+    stated = dict(lowp=args.lowp, exact_hess=args.exact_hess)
+
+    def lane_group(m):
+        return args.lane_group or HP.binloop_lane_group(
+            k, ch, args.lowp, m, args.exact_hess
+        )
 
     def timed(fn, node, m, **kw):
         out = fn(binned, node, g, h, m, b, interpret=interpret, **kw)
@@ -115,7 +126,7 @@ def main(argv) -> int:
 
     def grid(node, m):
         """One table: rows row_tile, columns feat_tile, best s a call."""
-        lanes = args.lane_group or HP.binloop_lane_group(k, ch, args.lowp, m)
+        lanes = lane_group(m)
         picked = HP.binloop_tiles(
             f, m, b, lowp=args.lowp, stat_channels=ch, lanes=lanes
         )
@@ -128,8 +139,8 @@ def main(argv) -> int:
                 try:
                     _, best, _ = timed(
                         HP.build_histogram_pallas_binloop, node, m,
-                        lowp=args.lowp, row_tile=rt, feat_tile=ft,
-                        lane_group=lanes,
+                        row_tile=rt, feat_tile=ft, lane_group=lanes,
+                        **stated,
                     )
                     cell = f"{best:.4f}"
                 except Exception as e:  # Mosaic refused the pair
@@ -145,18 +156,30 @@ def main(argv) -> int:
         if args.grid:
             grid(node, m)
             continue
-        lanes = args.lane_group or HP.binloop_lane_group(k, ch, args.lowp, m)
+        lanes = lane_group(m)
         rt, ft = HP.binloop_tiles(
             f, m, b, lowp=args.lowp, stat_channels=ch, lanes=lanes
         )
         rt, ft = args.row_tile or rt, args.feat_tile or ft
         total, best, med = timed(
-            HP.build_histogram_pallas_binloop, node, m, lowp=args.lowp,
-            row_tile=rt, feat_tile=ft, lane_group=lanes,
+            HP.build_histogram_pallas_binloop, node, m,
+            row_tile=rt, feat_tile=ft, lane_group=lanes, **stated,
         )
         print(f"binloop width {m:4d} lane_group {lanes} row_tile {rt:5d} "
               f"feat_tile {ft:4d}: best {best:8.4f} s median {med:8.4f} s",
               flush=True)
+        if args.exact_hess:
+            kw = dict(row_tile=rt, feat_tile=ft, interpret=interpret)
+            three = HP.build_histogram_pallas_binloop(
+                binned, node, g, h, m, b, lane_group=lanes, exact_hess=True,
+                **kw,
+            )
+            four = HP.build_histogram_pallas_binloop(
+                binned, node, g, h, m, b, **kw
+            )
+            same = bool(jnp.array_equal(three, four))
+            print(f"exact_hess width {m:4d}: the four-variant build's "
+                  f"histograms bit for bit: {same}", flush=True)
         if args.packed:
             ptotal, best, med = timed(
                 HP.build_histogram_pallas_batched, node, m, lowp=args.lowp

@@ -173,7 +173,8 @@ def _groups_from_flags(binary: np.ndarray):
 
 
 def _hist_shape_attrs(
-    binned, fgroups, lanes, depth, bins, lowp, stat_channels
+    binned, fgroups, lanes, depth, bins, lowp, stat_channels,
+    exact_hess=False,
 ) -> dict:
     """What a ``tree/fit_dispatch`` span says of the histogram kernel, from
     the same shapes the fit program is traced with: ``hist_tiles`` (the
@@ -191,7 +192,7 @@ def _hist_shape_attrs(
     mesh = execution_mesh()
     shape = dict(
         shards=None if mesh is None else mesh.shape[DATA_AXIS],
-        stat_channels=stat_channels,
+        stat_channels=stat_channels, exact_hess=exact_hess,
     )
     return dict(
         hist_tiles=TR.hist_tiles(n, lanes, groups, depth, lowp, **shape),
@@ -1109,7 +1110,7 @@ class _TreeEstimator(PredictorEstimator):
 
     def _batched_group_fit(
         self, x, masks, group_points, run_batched, make_model, normalize=None,
-        dispatch_attrs=None, lowp=False, stat_channels=2,
+        dispatch_attrs=None, lowp=False, stat_channels=2, exact_hess=False,
     ):
         """Shared plumbing for the masks × points batched fit: bin once,
         merge (+ normalize) params, stack the float knobs mask-major
@@ -1123,9 +1124,9 @@ class _TreeEstimator(PredictorEstimator):
         ``make_model(thresholds, sliced_trees, merged_params, mask_index)``;
         ``dispatch_attrs(binned, m0)`` gives the family's own attributes of
         the ``tree/fit_dispatch`` span; ``lowp`` says the trainer hands the
-        histogram kernel bf16-exact values and ``stat_channels`` how many
-        statistics a node holds (the span's ``hist_tiles``,
-        ``stat_channels``, ``stat_channels_built``).
+        histogram kernel bf16-exact values (``exact_hess``: the hessian
+        alone) and ``stat_channels`` how many statistics a node holds (the
+        span's ``hist_tiles``, ``stat_channels``, ``stat_channels_built``).
         The training outputs (every lane's raw model output on the full
         training matrix, computed by the fit program itself) ride the stack
         so sweep_eval_batched needs no re-traversal program.
@@ -1158,7 +1159,7 @@ class _TreeEstimator(PredictorEstimator):
         shape_attrs = _hist_shape_attrs(
             binned, fgroups, n_masks * n_pts,
             max(int(m["max_depth"]) for m in merged),
-            int(m0["max_bins"]), lowp, stat_channels,
+            int(m0["max_bins"]), lowp, stat_channels, exact_hess,
         )
         if _tspans.enabled():
             TR.hist_slot_stats().record_channels(
@@ -1299,7 +1300,10 @@ class XGBoostClassifier(_TreeEstimator):
         present = y[row_mask > 0]
         num_classes = max(int(present.max()) + 1 if len(present) else 2, 2)
         m = self._normalize_boost(self.get_params())
-        kwargs = dict(self._boost_kwargs(m), feature_groups=fgroups)
+        kwargs = dict(
+            self._boost_kwargs(m), feature_groups=fgroups,
+            binary_weights=TR.binary_row_weights(row_mask),
+        )
         rm = jnp.asarray(row_mask, dtype=jnp.float32)
         if num_classes == 2:
             trees, _ = TR.fit_boosted(binned, jnp.asarray(y, dtype=jnp.float32), rm, **kwargs)
@@ -1317,12 +1321,14 @@ class XGBoostClassifier(_TreeEstimator):
         if num_classes != 2:
             return None  # one-vs-rest loops stay sequential
         yj = np.asarray(y, dtype=np.float32)
+        binary = TR.binary_row_weights(masks)
 
         def run_batched(binned, m0, row_mask_k, knob, fgroups):
             # the final margin IS each lane's raw output on every row
             return TR.fit_boosted_batched(
                 binned, yj, row_mask_k, **self._boost_kwargs(m0, knob),
                 feature_groups=fgroups, return_slots=True,
+                binary_weights=binary,
             )
 
         return self._batched_group_fit(
@@ -1330,6 +1336,7 @@ class XGBoostClassifier(_TreeEstimator):
             lambda th, tr, m, mi: self._boosted_model(th, tr, m),
             normalize=self._normalize_boost,
             dispatch_attrs=self._dispatch_attrs,
+            exact_hess=TR.exact_hessian(self._OBJECTIVE, binary),
         )
 
 
@@ -1377,12 +1384,14 @@ class XGBoostRegressor(_TreeEstimator):
         yj = np.asarray(y, dtype=np.float32)
         base_scores = self._base_scores(y, masks)
         n_pts = len(group_points)
+        binary = TR.binary_row_weights(masks)
 
         def run_batched(binned, m0, row_mask_k, knob, fgroups):
             return TR.fit_boosted_batched(
                 binned, yj, row_mask_k, **self._boost_kwargs(m0, knob),
                 base_score=np.repeat(base_scores, n_pts).astype(np.float32),
                 feature_groups=fgroups, return_slots=True,
+                binary_weights=binary,
             )
 
         return self._batched_group_fit(
@@ -1392,6 +1401,7 @@ class XGBoostRegressor(_TreeEstimator):
             ),
             normalize=self._normalize_boost,
             dispatch_attrs=self._dispatch_attrs,
+            exact_hess=TR.exact_hessian(self._OBJECTIVE, binary),
         )
 
     def fit_arrays(self, x, y, row_mask):
@@ -1405,6 +1415,7 @@ class XGBoostRegressor(_TreeEstimator):
             jnp.asarray(y, dtype=jnp.float32),
             jnp.asarray(mask),
             **kwargs, base_score=base, feature_groups=fgroups,
+            binary_weights=TR.binary_row_weights(mask),
         )
         return self._boosted_model(thresholds, trees, m, base)
 

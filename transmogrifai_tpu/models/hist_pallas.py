@@ -278,7 +278,7 @@ def _stat_rows(grad, hess) -> list:
 
 
 def _hist_binloop_kernel(binned_ref, node_ref, *refs, m_pad, num_bins, lowp,
-                         lanes=1):
+                         lanes=1, exact_hess=False):
     """Bin-loop histogram step: one whole-block compare per bin instead of
     the per-group select-chain assembly. The comb construction drops from
     ~5 VPU ops per one-hot element to 2 (compare + convert) — the
@@ -297,31 +297,37 @@ def _hist_binloop_kernel(binned_ref, node_ref, *refs, m_pad, num_bins, lowp,
     ``refs``: the S statistic rows of the fit (its value channels, then
     the hessian / weight: ``(g, h)`` of a boosted or a two-class fit, the
     K - 1 class indicators and w of a K-class forest), then their S
-    output accumulators. The stack holds one variant a statistic under
-    ``lowp`` and two (hi, lo) without, statistic-major."""
+    output accumulators. The stack holds, statistic-major, one variant of
+    each statistic that is bfloat16-exact (every one under ``lowp``, the
+    hessian alone under ``exact_hess``) and a (hi, lo) pair of every
+    other. Where all or none are exact the pieces are made, selected and
+    summed in the one order the four- and two-variant kernels' compiled
+    schedules were measured in: a select chain traced in another order
+    compiles to another schedule."""
     import jax.lax as lax
     from jax.experimental import pallas as pl
 
     j = pl.program_id(2)
     nstat = len(refs) // 2
     val_refs, out_refs = refs[:nstat], refs[nstat:]
+    exact = _exact_stats(nstat, lowp, exact_hess)
 
     nodes = [node_ref[0, q, :] for q in range(lanes)]
     vals = [[r[0, q, :] for q in range(lanes)] for r in val_refs]
     t = nodes[0].shape[0]
     width = lanes * m_pad  # one variant's columns
 
-    nvar = stack_variants(nstat, lowp)
+    nvar = stack_variants(nstat, lowp, exact_hess)
     if lanes == 1:
         iota_s = lax.broadcasted_iota(jnp.int32, (t, nvar * m_pad), 1)
         m_lane = iota_s % m_pad
         variant = iota_s // m_pad
         oh = nodes[0][:, None] == m_lane
-        if lowp:
-            pieces = [v for (v,) in vals]
-        else:
-            pieces = []
-            for (v,) in vals:
+        pieces = []
+        for (v,), one in zip(vals, exact):
+            if one:
+                pieces.append(v)
+            else:
                 hi = v.astype(jnp.bfloat16).astype(jnp.float32)
                 pieces += [hi, v - hi]
 
@@ -350,11 +356,11 @@ def _hist_binloop_kernel(binned_ref, node_ref, *refs, m_pad, num_bins, lowp,
             return out
 
         oh = spread(nodes) == m_lane
-        if lowp:
-            variants = vals
-        else:
-            variants = []
-            for per_lane in vals:
+        variants = []
+        for per_lane, one in zip(vals, exact):
+            if one:
+                variants.append(per_lane)
+            else:
                 his = [
                     v.astype(jnp.bfloat16).astype(jnp.float32)
                     for v in per_lane
@@ -377,14 +383,13 @@ def _hist_binloop_kernel(binned_ref, node_ref, *refs, m_pad, num_bins, lowp,
             preferred_element_type=jnp.float32,
             precision=lax.Precision.DEFAULT,
         )  # [feat_tile, nvar·G]
-        if lowp:
-            sums = [out[:, s * width:(s + 1) * width] for s in range(nstat)]
-        else:
-            sums = [
-                out[:, 2 * s * width:(2 * s + 1) * width]
-                + out[:, (2 * s + 1) * width:(2 * s + 2) * width]
-                for s in range(nstat)
-            ]
+        sums, at = [], 0  # at: the statistic's first variant
+        for one in exact:
+            part = out[:, at * width:(at + 1) * width]
+            if not one:
+                part = part + out[:, (at + 1) * width:(at + 2) * width]
+            sums.append(part)
+            at += 1 if one else 2
 
         @pl.when(j == 0)
         def _(b=b, sums=sums):
@@ -417,15 +422,43 @@ _BINLOOP_STACK_LANES = 1024
 _BINLOOP_FOLD_UNDER = 512
 
 
-def stack_variants(stat_channels: int, lowp: bool) -> int:
+def _exact_stats(
+    stat_channels: int, lowp: bool, exact_hess: bool
+) -> tuple[bool, ...]:
+    """Whether each statistic (the value channels, then the hessian) is
+    bfloat16-exact: all of them under ``lowp``, the hessian alone under
+    ``exact_hess``."""
+    return (lowp,) * (stat_channels - 1) + (lowp or exact_hess,)
+
+
+def stack_variants(
+    stat_channels: int, lowp: bool, exact_hess: bool = False
+) -> int:
     """Value variants of the bin-loop kernel's stacked operand: one a
-    statistic where the values are bfloat16-exact (``lowp``), else a
-    (hi, lo) pair."""
-    return stat_channels * (1 if lowp else 2)
+    statistic whose values are bfloat16-exact (every statistic under
+    ``lowp``; the hessian alone under ``exact_hess``, as a first-order
+    boosted fit's h = 1 times 0/1 row weights), a (hi, lo) pair for any
+    other: the lo of an exact value is zero, and multiplying it is work."""
+    return sum(1 if one else 2 for one in _exact_stats(
+        stat_channels, lowp, exact_hess
+    ))
+
+
+def carried_variants(
+    builder: str, stat_channels: int, lowp: bool, exact_hess: bool = False,
+) -> int:
+    """Value variants one lane's build with ``builder`` (a key of
+    ``BUILDERS``) carries its statistics in: the bin-loop kernel's stacked
+    operand (``stack_variants``); every other builder is counted at the
+    full (hi, lo) pair a statistic, 2·S (none of them runs in a cell)."""
+    if builder != "binloop":
+        return 2 * stat_channels
+    return stack_variants(stat_channels, lowp, exact_hess)
 
 
 def binloop_lane_group(
     k_fits: int, stat_channels: int, lowp: bool, slots: int,
+    exact_hess: bool = False,
 ) -> int:
     """Lanes of a fit one grid step of the bin-loop kernel builds together
     (g): as many as the ``[T, g·nvar·M]`` stacked operand holds within
@@ -434,7 +467,8 @@ def binloop_lane_group(
     lane's operand is ``_BINLOOP_FOLD_UNDER`` lanes or more. From shapes
     alone: two channels fold 4 lanes up to 64 slots, none at 128; two at
     ``lowp`` 4 up to 128, none at 256; seven at ``lowp`` 4 up to 32, 2 at
-    64, none at 128.
+    64, none at 128; two with an exact hessian (three variants) 4 up to
+    64, 2 at 128, none at 256.
 
     Measured on a v5e at 1,002,701 x 302 x 32 bins, 4 lanes
     (``tools/bench_hist_kernel.py``; seconds a call, one lane a
@@ -452,7 +486,7 @@ def binloop_lane_group(
     shares are a fifth of it, and two such lanes in one 1,024-lane operand
     cost more than two passes, hence ``_BINLOOP_FOLD_UNDER``."""
     m_pad = _round_up(max(slots, 8), 8)
-    one = stack_variants(stat_channels, lowp) * m_pad
+    one = stack_variants(stat_channels, lowp, exact_hess) * m_pad
     if one >= _BINLOOP_FOLD_UNDER:
         return 1
     passes = -(-k_fits // max(1, _BINLOOP_STACK_LANES // one))
@@ -461,28 +495,31 @@ def binloop_lane_group(
 
 def code_passes(
     builder: str, k_fits: int, stat_channels: int, lowp: bool, slots: int,
+    exact_hess: bool = False,
 ) -> int:
     """Passes over the codes one build of ``k_fits`` lanes makes with
     ``builder`` (a key of ``BUILDERS``): the bin-loop kernel's lane groups,
     one a lane for every other builder."""
     if builder != "binloop":
         return k_fits
-    return -(-k_fits // binloop_lane_group(k_fits, stat_channels, lowp, slots))
+    return -(-k_fits // binloop_lane_group(
+        k_fits, stat_channels, lowp, slots, exact_hess
+    ))
 
 
 def stat_channels_built(
     stat_channels: int, lowp: bool, slots: int, lanes: int = 1,
+    exact_hess: bool = False,
 ) -> int:
     """Statistic channels the ``[T, nvar·g·M]`` operand of a ``slots``-slot
     build of ``lanes`` (g) lanes a pass has lanes for: the operand pads to
     whole 128-lane tiles, so 7 statistics at 32 slots (224 lanes) are
     built as 8 one lane a pass and as 7 four at a time (896 lanes), two at
-    ``lowp`` (64 lanes) as 4 and as 2."""
+    ``lowp`` (64 lanes) as 4 and as 2. Where the statistics take unequal
+    variants (an exact hessian) a channel counts their mean."""
     m_pad = _round_up(max(slots, 8), 8)
-    per = stack_variants(stat_channels, lowp) // stat_channels
-    return _round_up(per * stat_channels * lanes * m_pad, 128) // (
-        per * lanes * m_pad
-    )
+    used = stack_variants(stat_channels, lowp, exact_hess) * lanes * m_pad
+    return _round_up(used, 128) * stat_channels // used
 
 
 def binloop_vmem_bytes(
@@ -580,7 +617,9 @@ def binloop_tiles(
     Their accumulators and operand are g times as wide, which the VMEM
     budget reckons with; the row tile stays the one-lane build's, so that
     each lane adds its row tiles in the same order and its histograms are
-    the one-lane build's bit for bit."""
+    the one-lane build's bit for bit. For the same reason a fit that
+    states its hessian exact (three variants, not four) takes the tiles of
+    the (hi, lo) fit: it has no argument here."""
     m_pad = _round_up(max(num_nodes, 8), 8)
     nvar = stack_variants(stat_channels, lowp)
     # a power of two, so that its halvings stay multiples of 128 lanes
@@ -607,7 +646,7 @@ def binloop_tiles(
     jax.jit,
     static_argnames=(
         "num_nodes", "num_bins", "row_tile", "lowp", "interpret", "feat_tile",
-        "lane_group",
+        "lane_group", "exact_hess",
     ),
 )
 def build_histogram_pallas_binloop(
@@ -622,12 +661,15 @@ def build_histogram_pallas_binloop(
     interpret: bool = False,
     feat_tile: int | None = None,
     lane_group: int | None = None,
+    exact_hess: bool = False,
 ) -> jax.Array:
     """hist [K, num_nodes, F, num_bins, S] via the bin-loop kernel (see
     _hist_binloop_kernel): S = 2 (grad, hess) from a ``[K, N]`` grad, V + 1
     (the value channels, then hess) from ``[K, V, N]``. Same contract as
     build_histogram_pallas_batched. The grid runs over groups of g lanes
     (``binloop_lane_group``), a last partial group padded with dead lanes.
+    ``exact_hess``: the caller states ``hess`` bfloat16-exact (``lowp``
+    states every statistic so), and the operand carries it as one variant.
     ``row_tile`` / ``feat_tile`` / ``lane_group`` override
     ``binloop_tiles`` and g (timing probes: tools/bench_hist_kernel.py)."""
     from jax.experimental import pallas as pl
@@ -638,7 +680,7 @@ def build_histogram_pallas_binloop(
     stats = _stat_rows(grad, hess)
     m_pad = _round_up(max(num_nodes, 8), 8)
     lanes = lane_group or binloop_lane_group(
-        k_fits, len(stats), lowp, num_nodes
+        k_fits, len(stats), lowp, num_nodes, exact_hess
     )
     passes = -(-k_fits // lanes)
     tiles = binloop_tiles(
@@ -669,7 +711,7 @@ def build_histogram_pallas_binloop(
     outs = pl.pallas_call(
         functools.partial(
             _hist_binloop_kernel, m_pad=m_pad, num_bins=num_bins, lowp=lowp,
-            lanes=lanes,
+            lanes=lanes, exact_hess=exact_hess,
         ),
         out_shape=tuple(
             jax.ShapeDtypeStruct(
@@ -830,8 +872,9 @@ class Builder(NamedTuple):
     """One way to build a feature group's [K, M, F, B, S] histograms.
     ``prepare(binned [N, F], num_bins, lowp)`` makes the operand that is the
     same at every level (once a fit, outside the level scan);
-    ``build(operand, node, grad, hess, num_nodes, num_bins, lowp=...)``
-    builds one level's or one chunk's histograms from it."""
+    ``build(operand, node, grad, hess, num_nodes, num_bins, lowp=...,
+    exact_hess=...)`` builds one level's or one chunk's histograms from
+    it. Only the bin-loop kernel acts on ``exact_hess``."""
 
     prepare: Callable
     build: Callable
@@ -841,17 +884,25 @@ def _codes(binned, num_bins, lowp):
     return binned
 
 
+def _lowp_only(build: Callable) -> Callable:
+    """A builder that takes ``lowp`` (every statistic exact) and no
+    statement on the hessian alone: it carries an exact hessian as it
+    carries any other statistic."""
+    return lambda *operands, lowp=False, exact_hess=False: build(
+        *operands, lowp=lowp
+    )
+
+
 BUILDERS: dict[str, Builder] = {
     # XLA scatter-add: the CPU builder and the reference (f32 whatever lowp)
     "scatter": Builder(
         _codes,
-        lambda *operands, lowp=False: build_histogram_scatter_batched(
-            *operands
-        ),
+        lambda *operands, lowp=False, exact_hess=False:
+            build_histogram_scatter_batched(*operands),
     ),
-    "gemm": Builder(one_hot_codes, build_histogram_gemm),
+    "gemm": Builder(one_hot_codes, _lowp_only(build_histogram_gemm)),
     "binloop": Builder(_codes, build_histogram_pallas_binloop),
-    "lanepacked": Builder(_codes, build_histogram_pallas_batched),
+    "lanepacked": Builder(_codes, _lowp_only(build_histogram_pallas_batched)),
 }
 
 # ``"pallas"`` means "whatever is fastest on the TPU": up to this many local
@@ -907,7 +958,7 @@ def _pow2_floor(x: int) -> int:
 def histogram_plan(
     impl: str, n: int, k_fits: int, groups: Sequence[tuple[int, int]],
     max_slots: int, stat_channels: int = 2, lowp: bool = False,
-    max_parents: int = 0,
+    max_parents: int = 0, exact_hess: bool = False,
 ) -> HistogramPlan:
     """How a fit builds its histograms, from what its trace can see:
     ``impl`` ('pallas' = choose for the TPU; 'gemm' / 'scatter' force one
@@ -915,7 +966,8 @@ def histogram_plan(
     feature groups' ``(columns, bins)``, ``max_slots``, the most compact
     node slots a level can have live, and the fit's statistic channels
     (two: grad and hess, or w*y and w; K for a K-class forest) with
-    whether their values are bfloat16-exact (``lowp``). This is the one
+    whether their values are bfloat16-exact (``lowp``: all of them;
+    ``exact_hess``: the hessian alone). This is the one
     place that decides; it runs while a program is traced, never per
     call.
 
@@ -923,7 +975,8 @@ def histogram_plan(
     whose two accumulators are its layout: a fit of more channels is
     planned onto the bin-loop kernel whatever its bins. The kernels' chunk
     also holds the stacked operand to ``_BINLOOP_STACK_LANES`` (two
-    channels: 256 slots as before; 7 at ``lowp``: 128).
+    channels: 256 slots as before, three variants too; 7 at ``lowp``:
+    128).
 
     ``max_parents``: the most nodes a level that is not the last can hold
     (0 from a fit with no such level, or one that may not subtract: the
@@ -948,7 +1001,8 @@ def histogram_plan(
         b_pad = _round_up(max(b for _, b in groups), 128)
         ceil = min(
             _KERNEL_CHUNK_CEIL, _KERNEL_BLOCK_ELEMS // (8 * b_pad),
-            _BINLOOP_STACK_LANES // stack_variants(stat_channels, lowp),
+            _BINLOOP_STACK_LANES
+            // stack_variants(stat_channels, lowp, exact_hess),
         )
     else:
         return HistogramPlan(("scatter",) * len(groups), cap, parent_slots)

@@ -83,7 +83,12 @@ class HistSlots(NamedTuple):
     group's each), the passes over the codes made and the lanes built: the
     bin-loop kernel builds ``hist_pallas.binloop_lane_group`` lanes a pass
     (``code_passes`` = ⌈K / g⌉ a build), every other builder one
-    (``code_passes`` = ``lane_builds`` = K a build)."""
+    (``code_passes`` = ``lane_builds`` = K a build).
+    ``stack_variants`` / ``stack_variants_full``: over the level's builds
+    (a feature group's each), the value variants a lane's statistics were
+    carried in (``hist_pallas.carried_variants``: one an exact statistic,
+    a (hi, lo) pair any other) and 2 × the statistic channels, the (hi,
+    lo) pair of every one."""
 
     live: jax.Array
     built: jax.Array
@@ -99,6 +104,8 @@ class HistSlots(NamedTuple):
     partition_skipped: jax.Array
     code_passes: jax.Array
     lane_builds: jax.Array
+    stack_variants: jax.Array
+    stack_variants_full: jax.Array
 
 
 class _Siblings(NamedTuple):
@@ -172,16 +179,17 @@ def _width_ladder(chunk_nodes: int) -> tuple[int, ...]:
 def _slot_layout(
     impl: str, n: int, k_fits: int, groups, max_depth: int,
     axis_size: int = 1, sharded: bool = False, stat_channels: int = 2,
-    lowp: bool = False,
+    lowp: bool = False, exact_hess: bool = False,
 ):
     """(cap, plan, ladder) of one tree fit, from what its trace can see:
     ``cap`` compact node slots a level can have live (``2^max_depth``, or
     the power of two that holds the GLOBAL rows where there are fewer),
     ``hist_pallas.histogram_plan``'s builders and chunk width for the
     LOCAL rows ``n`` and the fit's ``stat_channels`` (bfloat16-exact where
-    ``lowp``), and the widths a level may be built at; ``plan.parent_slots``
-    are the nodes a lane whose histograms a level keeps for the next one's
-    sibling subtraction. The sharded path keeps the full width and builds
+    ``lowp``, the hessian alone where ``exact_hess``), and the widths a
+    level may be built at; ``plan.parent_slots`` are the nodes a lane
+    whose histograms a level keeps for the next one's sibling
+    subtraction. The sharded path keeps the full width and builds
     every node: its psums may not sit under a data-dependent branch."""
     from .hist_pallas import histogram_plan
 
@@ -199,14 +207,14 @@ def _slot_layout(
     max_parents = 0 if sharded or max_depth < 2 else min(cap, max_nodes // 4)
     plan = histogram_plan(
         impl, n, k_fits, groups, cap, stat_channels=stat_channels, lowp=lowp,
-        max_parents=max_parents,
+        max_parents=max_parents, exact_hess=exact_hess,
     )
     ladder = (plan.chunk_cap,) if sharded else _width_ladder(plan.chunk_cap)
     return cap, plan, ladder
 
 
 def _dispatch_layout(n, k_fits, groups, max_depth, lowp, impl, shards,
-                     stat_channels):
+                     stat_channels, exact_hess=False):
     """(plan, ladder) of a fit as its program will be traced, from what a
     ``tree/fit_dispatch`` span knows: the rows of the whole fit and the
     data-axis size of the mesh it runs on (``shards``; None: one device)."""
@@ -214,7 +222,7 @@ def _dispatch_layout(n, k_fits, groups, max_depth, lowp, impl, shards,
     _, plan, ladder = _slot_layout(
         impl or _resolved_impl(), -(-n // axis_size), k_fits, groups,
         max_depth, axis_size, sharded=shards is not None,
-        stat_channels=stat_channels, lowp=lowp,
+        stat_channels=stat_channels, lowp=lowp, exact_hess=exact_hess,
     )
     return plan, ladder
 
@@ -222,7 +230,7 @@ def _dispatch_layout(n, k_fits, groups, max_depth, lowp, impl, shards,
 def hist_tiles(
     n: int, k_fits: int, groups, max_depth: int, lowp: bool,
     impl: str | None = None, shards: int | None = None,
-    stat_channels: int = 2,
+    stat_channels: int = 2, exact_hess: bool = False,
 ) -> str:
     """The ``hist_tiles`` attribute of ``tree/fit_dispatch``: for each rung
     of the fit's width ladder, ``slotsxg:row_tile/feat_tile`` of the
@@ -233,11 +241,13 @@ def hist_tiles(
     ``(columns, bins)``; ``n``: the rows of the whole fit; ``shards``: the
     data-axis size of the mesh a sharded fit runs on (None: one device);
     ``stat_channels``: the fit's statistic channels (K for a K-class
-    forest). ``none`` where that group takes another builder."""
+    forest); ``exact_hess``: the fit states its hessian bfloat16-exact.
+    ``none`` where that group takes another builder."""
     from .hist_pallas import binloop_lane_group, binloop_tiles
 
     plan, ladder = _dispatch_layout(
-        n, k_fits, groups, max_depth, lowp, impl, shards, stat_channels
+        n, k_fits, groups, max_depth, lowp, impl, shards, stat_channels,
+        exact_hess,
     )
     (f, b), builder = max(
         zip(groups, plan.builders), key=lambda gb: gb[0][0] * gb[0][1]
@@ -246,7 +256,7 @@ def hist_tiles(
         return "none"
 
     def rung(w):
-        g = binloop_lane_group(k_fits, stat_channels, lowp, w)
+        g = binloop_lane_group(k_fits, stat_channels, lowp, w, exact_hess)
         return "{}x{}:{}/{}".format(w, g, *binloop_tiles(
             f, w, b, lowp=lowp, stat_channels=stat_channels, lanes=g
         ))
@@ -257,6 +267,7 @@ def hist_tiles(
 def stat_channels_built(
     n: int, k_fits: int, groups, max_depth: int, lowp: bool,
     stat_channels: int, impl: str | None = None, shards: int | None = None,
+    exact_hess: bool = False,
 ) -> int:
     """The ``stat_channels_built`` attribute of ``tree/fit_dispatch``: the
     statistic channels the bin-loop kernel's stacked operand has lanes for
@@ -268,10 +279,11 @@ def stat_channels_built(
     from .hist_pallas import stat_channels_built as built
 
     _, ladder = _dispatch_layout(
-        n, k_fits, groups, max_depth, lowp, impl, shards, stat_channels
+        n, k_fits, groups, max_depth, lowp, impl, shards, stat_channels,
+        exact_hess,
     )
-    g = binloop_lane_group(k_fits, stat_channels, lowp, ladder[0])
-    return built(stat_channels, lowp, ladder[0], g)
+    g = binloop_lane_group(k_fits, stat_channels, lowp, ladder[0], exact_hess)
+    return built(stat_channels, lowp, ladder[0], g, exact_hess)
 
 
 class HistSlotStats(_tm.LedgerCore):
@@ -291,6 +303,8 @@ class HistSlotStats(_tm.LedgerCore):
         "partition_run": "partitionPassesRun",
         "partition_skipped": "partitionPassesSkipped",
         "code_passes": "histCodePasses", "lane_builds": "histLaneBuilds",
+        "stack_variants": "histStackVariants",
+        "stack_variants_full": "histStackVariantsFull",
     }
 
     #: per ``tree/fit_dispatch``: the fit's statistic channels and those
@@ -333,7 +347,8 @@ def await_outputs(value, hist_slots: HistSlots | None = None):
     too, and their sums go onto the span (``slots_live``, ``slots_built``,
     ``chunks_run``, ``chunks_skipped``, ``nodes_built``, ``nodes_derived``,
     ``partition_run``, ``partition_skipped``, ``code_passes``,
-    ``lane_builds``; from a fit that counts node
+    ``lane_builds``, ``stack_variants``, ``stack_variants_full``; from a
+    fit that counts node
     subsets ``subset_admitted``, ``subset_pairs``; from one that boosts in
     first order ``boost_rounds_label``, ``boost_rounds_residual``) and the
     ``tree`` ledger."""
@@ -361,6 +376,8 @@ def await_outputs(value, hist_slots: HistSlots | None = None):
                 partition_skipped=sums["partition_skipped"],
                 code_passes=sums["code_passes"],
                 lane_builds=sums["lane_builds"],
+                stack_variants=sums["stack_variants"],
+                stack_variants_full=sums["stack_variants_full"],
             )
             if sums["subset_pairs"]:
                 sp.attrs.update(
@@ -679,6 +696,7 @@ def _grow_tree_impl(
     info_gain_norm: float = 0.0,
     node_subset: int | None = None,
     node_key: jax.Array | None = None,
+    exact_hess: bool = False,
 ) -> tuple[Tree, jax.Array, HistSlots]:
     """Tree-growth body shared by the single-device jit wrapper and the
     shard_map'd path: (tree, each row's final leaf slot, the histogram
@@ -732,8 +750,15 @@ def _grow_tree_impl(
     and 2j+1): the draw depends on the tree's key and the node alone, not
     on the lane, the chunk, the compact slot, the width rung or the mesh.
     ``node_subset == F`` draws nothing; None also counts nothing
-    (``HistSlots.subset_*`` stay 0)."""
-    from .hist_pallas import BUILDERS, code_passes, default_impl
+    (``HistSlots.subset_*`` stay 0).
+
+    ``lowp`` (static): the caller states every statistic bfloat16-exact;
+    ``exact_hess`` (static): the hessian alone, ``hess * row_mask`` (h = 1
+    and 0/1 row weights: ``_boost_chunk_body``). The histogram kernel then
+    carries such a statistic as one variant, not a (hi, lo) pair."""
+    from .hist_pallas import (
+        BUILDERS, carried_variants, code_passes, default_impl,
+    )
 
     k_fits, n = hess.shape
     f = binned.shape[1]
@@ -798,7 +823,7 @@ def _grow_tree_impl(
     cap, plan, ladder = _slot_layout(
         impl, n, k_fits, [(gb_.shape[1], bb) for gb_, _, bb, _ in groups],
         max_depth, axis_size, sharded=axis_name is not None,
-        stat_channels=n_values + 1, lowp=lowp,
+        stat_channels=n_values + 1, lowp=lowp, exact_hess=exact_hess,
     )
     # nodes a lane whose histograms a level keeps for the next (0: none)
     keep = plan.parent_slots
@@ -850,7 +875,10 @@ def _grow_tree_impl(
         with jax.named_scope("tree/histogram"):
             # [K, M, Fg, Bg, V + 1] (value channels, hess) sums of the group
             if pairs is None:
-                hist = build(operand, loc, g, h, chunk_nodes, gb, lowp=lowp)
+                hist = build(
+                    operand, loc, g, h, chunk_nodes, gb, lowp=lowp,
+                    exact_hess=exact_hess,
+                )
                 if axis_name is not None:
                     # the Rabit-allreduce moment: per-shard partial
                     # histograms reduce over ICI; everything after sees the
@@ -859,7 +887,8 @@ def _grow_tree_impl(
             else:
                 built_right, parent, slot_live = pairs
                 one = build(
-                    operand, loc, g, h, chunk_nodes // 2, gb, lowp=lowp
+                    operand, loc, g, h, chunk_nodes // 2, gb, lowp=lowp,
+                    exact_hess=exact_hess,
                 )
                 rows = sibling_rows(
                     node_rows(one), parent, built_right, slot_live
@@ -1045,14 +1074,22 @@ def _grow_tree_impl(
                     jnp.where(slot_live, admitted, 0).sum(dtype=jnp.int32),
                     pairs,
                 )
-            # the groups' builds: passes over the codes, lanes (static)
+            # the groups' builds: passes over the codes, lanes, the
+            # variants an operand carried and all there could be (static)
             build_w = chunk_nodes if sib is None else chunk_nodes // 2
             counts += (
                 jnp.int32(sum(
-                    code_passes(name, k_fits, n_values + 1, lowp, build_w)
+                    code_passes(
+                        name, k_fits, n_values + 1, lowp, build_w, exact_hess
+                    )
                     for name in plan.builders
                 )),
                 jnp.int32(k_fits * len(groups)),
+                jnp.int32(sum(
+                    carried_variants(name, n_values + 1, lowp, exact_hess)
+                    for name in plan.builders
+                )),
+                jnp.int32(2 * (n_values + 1) * len(groups)),
             )
             return (
                 jnp.where(do_split, bf, -1),
@@ -1060,7 +1097,7 @@ def _grow_tree_impl(
                 do_split & br,
                 counts,
                 tuple(new_hists),
-            )  # [K, chunk] x 3, four scalars, the kept histograms
+            )  # [K, chunk] x 3, six scalars, the kept histograms
 
     sentinel = jnp.int32(max_nodes)  # out-of-range → dropped by scatters
 
@@ -1206,7 +1243,7 @@ def _grow_tree_impl(
 
         at = (live, rank, level_idx)
         zero2 = (jnp.int32(0), jnp.int32(0))
-        zero4 = zero2 * 2
+        zero6 = zero2 * 3
         hists0 = sib.hists if keep else ()
 
         def chunk_loop(sib_):
@@ -1228,7 +1265,7 @@ def _grow_tree_impl(
                             jnp.full((k_fits, width), -1, dtype=jnp.int32),
                             jnp.zeros((k_fits, width), dtype=jnp.int32),
                             jnp.zeros((k_fits, width), dtype=bool),
-                            zero4,
+                            zero6,
                             hists,
                         ),
                     )
@@ -1258,7 +1295,7 @@ def _grow_tree_impl(
                             jnp.full(slots, -1, dtype=jnp.int32),
                             jnp.zeros(slots, dtype=jnp.int32),
                             jnp.zeros(slots, dtype=bool),
-                            jnp.int32(0), zero4, hists0,
+                            jnp.int32(0), zero6, hists0,
                         ),
                     )
                 )
@@ -1347,7 +1384,7 @@ def _grow_tree_impl(
                     jnp.zeros((k_fits, n_nodes), dtype=jnp.int32),
                     jnp.zeros((k_fits, n_nodes), dtype=bool),
                     jnp.int32(0),
-                    zero4,
+                    zero6,
                     hists0,
                 ),
             )
@@ -2160,6 +2197,26 @@ def _fit_result(trees, outputs, slots, return_outputs, return_slots):
 #: ``spark:squarederror`` under (y - F)^2, negative gradient 2 (y - F).
 SPARK_OBJECTIVES = ("spark:logloss", "spark:squarederror")
 OBJECTIVES = ("binary:logistic", "reg:squarederror") + SPARK_OBJECTIVES
+#: the objectives whose hessian is 1 on every row (``_boost_chunk_body``'s
+#: ``grads``): all but ``binary:logistic``, whose p(1 - p) is a real number
+UNIT_HESSIAN_OBJECTIVES = ("reg:squarederror",) + SPARK_OBJECTIVES
+
+
+def binary_row_weights(row_mask) -> bool:
+    """Whether every weight of a host ``row_mask`` is 0 or 1: the
+    trainer's statement ``binary_weights`` of ``fit_boosted_batched``,
+    made from the mask's values (the selector's fold and refit masks are
+    row subsets: ``selector/validators.py``, ``prep/splitters.py``)."""
+    m = np.asarray(row_mask)
+    return bool(((m == 0) | (m == 1)).all())
+
+
+def exact_hessian(objective: str, binary_weights: bool) -> bool:
+    """Whether a boosted fit's masked hessian ``h * row_mask`` is
+    bfloat16-exact: h is 1 (``UNIT_HESSIAN_OBJECTIVES``) and every row
+    weight 0 or 1. The histogram kernel then carries it as one variant
+    (``hist_pallas.stack_variants``)."""
+    return binary_weights and objective in UNIT_HESSIAN_OBJECTIVES
 
 
 def boost_tree_weights(objective: str, num_rounds: int, eta) -> np.ndarray:
@@ -2186,13 +2243,15 @@ def fit_boosted(
     objective: str = "binary:logistic",
     feature_groups=None,
     info_gain_norm: float = 0.0,
+    binary_weights: bool = False,
 ) -> tuple[Tree, jax.Array]:
     """Gradient boosting — the K=1 case of fit_boosted_batched, under one
     of ``OBJECTIVES`` (XGBoost's Newton trees at shrinkage eta, or Spark
     GBT's first-order ones). Returns stacked trees [R, ...] and the
     training margin [N].
     ``info_gain_norm``: 0 for XGBoost's absolute stop rule, 2 for Spark
-    GBT's per-row variance decrease (``_grow_tree_impl``)."""
+    GBT's per-row variance decrease (``_grow_tree_impl``);
+    ``binary_weights``: as ``fit_boosted_batched``'s."""
     trees, margin = fit_boosted_batched(
         binned, y, jnp.asarray(row_mask)[None, :],
         num_rounds=num_rounds, max_depth=max_depth, num_bins=num_bins,
@@ -2200,6 +2259,7 @@ def fit_boosted(
         min_child_weight=min_child_weight, min_info_gain=min_info_gain,
         base_score=base_score, objective=objective,
         feature_groups=feature_groups, info_gain_norm=info_gain_norm,
+        binary_weights=binary_weights,
     )
     return jax.tree.map(lambda a: a[0], trees), margin[0]
 
@@ -2232,12 +2292,15 @@ def _boost_chunk_body(
     min_child_weight, min_info_gain, feature_groups=None, *,
     num_rounds, max_depth, num_bins, objective,
     axis_name=None, axis_size=1, hist_impl=None, info_gain_norm=0.0,
+    binary_weights=False,
 ) -> tuple[Tree, jax.Array, HistSlots]:
     """The boosting rounds of all K fits (lax.scan inside one program) —
     shared by the single-device jit and the shard_map'd path
     (axis_name set: per-level histograms psum over the mesh axis; margins,
     gradients and predictions stay row-local). Returns (trees [K, R, ...],
-    margins [K, N], HistSlots [R, depth])."""
+    margins [K, N], HistSlots [R, depth]). ``binary_weights`` (static):
+    the trainer's statement that every weight of ``row_mask`` is 0 or 1
+    (``fit_boosted_batched``)."""
     k_fits, n = row_mask.shape
     f = binned.shape[1]
     feat_mask = jnp.ones((k_fits, f), dtype=jnp.float32)
@@ -2245,6 +2308,9 @@ def _boost_chunk_body(
     if objective not in OBJECTIVES:
         raise ValueError(f"objective {objective!r}: one of {OBJECTIVES}")
     first_order = objective in SPARK_OBJECTIVES
+    # h = 1 times 0/1 row weights is bfloat16-exact: the kernel carries it
+    # as one variant, where a (hi, lo) pair's lo would be all zeros
+    exact_hess = exact_hessian(objective, binary_weights)
 
     def grads(margin, r):  # [K, N_local]; r: the round, from 0
         if objective == "binary:logistic":
@@ -2266,7 +2332,8 @@ def _boost_chunk_body(
             g, h = grads(margin, r)
         # no lowp under any objective: from the second round on g is a real
         # number (a pseudo-residual, a Newton gradient), which the kernel's
-        # split-operand path carries float32-exact and one bfloat16 would not
+        # split-operand path carries float32-exact and one bfloat16 would
+        # not; only h may be stated exact, and only where it is 1
         tree, leaf_slot, slots = _grow_tree_impl(
             binned, g, h, row_mask, feat_mask,
             max_depth=max_depth, num_bins=num_bins,
@@ -2274,6 +2341,7 @@ def _boost_chunk_body(
             min_child_weight=min_child_weight, min_info_gain=min_info_gain,
             axis_name=axis_name, axis_size=axis_size, hist_impl=hist_impl,
             feature_groups=feature_groups, info_gain_norm=info_gain_norm,
+            exact_hess=exact_hess,
         )
         # margin update straight from the grower's final routing — one
         # small-table lookup instead of a full predict_tree re-traversal
@@ -2303,6 +2371,7 @@ _boost_rounds_batched = partial(
     static_argnames=(
         "num_rounds", "max_depth", "num_bins", "objective",
         "axis_name", "axis_size", "hist_impl", "info_gain_norm",
+        "binary_weights",
     ),
 )(_boost_chunk_body)
 
@@ -2334,6 +2403,7 @@ def fit_boosted_batched(
     feature_groups=None,
     return_slots: bool = False,
     info_gain_norm: float = 0.0,
+    binary_weights: bool = False,
 ) -> tuple[Tree, jax.Array]:
     """K boosting runs batched over the fit axis: every round grows all K
     trees in one histogram build, and all the rounds are one scan in one
@@ -2342,10 +2412,22 @@ def fit_boosted_batched(
     ``HistSlots`` [R, depth] (for ``await_outputs``; None from a sharded
     fit, which has recorded them).
 
+    ``binary_weights``: the caller states that every weight of
+    ``row_mask`` is 0 or 1 (``binary_row_weights`` of its host masks;
+    never by default), whatever the objective. Under an objective whose
+    hessian is 1 the fit then hands the histogram kernel an exact hessian
+    (``exact_hessian``, the one place that reads the objective for it); a
+    fractional weight keeps the (hi, lo) pair. A ``row_mask`` on the host
+    is checked against the statement; one already on the device is not
+    (that would wait for it), so its caller answers for it.
+
     With ``mesh`` set, rows shard over the mesh's data axis: gradients and
     margins live sharded, per-level histograms psum over ICI, and trees come
     back replicated — the Rabit-tracker topology with XLA collectives."""
     k_fits, n = row_mask.shape
+    if (binary_weights and isinstance(row_mask, np.ndarray)
+            and not binary_row_weights(row_mask)):
+        raise ValueError("binary_weights stated of row weights other than 0/1")
     # numpy, not eager jnp: dtype-converting/broadcasting eager ops each
     # compile a device program per process; f32 numpy transfers compile
     # nothing
@@ -2370,6 +2452,7 @@ def fit_boosted_batched(
             base_score=base_score, num_rounds=num_rounds,
             max_depth=max_depth, num_bins=num_bins, objective=objective,
             feature_groups=feature_groups, info_gain_norm=info_gain_norm,
+            binary_weights=binary_weights,
         )
         return _fit_result(trees, margin, None, True, return_slots)
     # f32 numpy broadcast (no eager compile), then ONE device transfer
@@ -2387,6 +2470,7 @@ def fit_boosted_batched(
         static_argnames=(
             "num_rounds", "max_depth", "num_bins", "objective",
             "axis_name", "axis_size", "hist_impl", "info_gain_norm",
+            "binary_weights",
         ),
     )
     trees, margin, slots = aot_call(
@@ -2395,7 +2479,8 @@ def fit_boosted_batched(
          feature_groups),
         dict(num_rounds=num_rounds, max_depth=max_depth, num_bins=num_bins,
              objective=objective, hist_impl=_resolved_impl(),
-             info_gain_norm=float(info_gain_norm)),
+             info_gain_norm=float(info_gain_norm),
+             binary_weights=bool(binary_weights)),
     )
     return _fit_result(trees, margin, slots, True, return_slots)
 
@@ -2574,7 +2659,7 @@ def _fit_forest_batched_sharded(
 @lru_cache(maxsize=None)
 def _sharded_boost_kernel(mesh, num_rounds, max_depth, num_bins, objective,
                           hist_impl=None, has_groups=False,
-                          info_gain_norm=0.0):
+                          info_gain_norm=0.0, binary_weights=False):
     """jit(shard_map(boosting rounds)): margins stay row-sharded across
     the scan; each round's histogram build psums over the data axis."""
     from jax import shard_map
@@ -2592,6 +2677,7 @@ def _sharded_boost_kernel(mesh, num_rounds, max_depth, num_bins, objective,
             num_rounds=num_rounds, max_depth=max_depth, num_bins=num_bins,
             objective=objective, axis_name=DATA_AXIS, axis_size=size,
             hist_impl=hist_impl, info_gain_norm=info_gain_norm,
+            binary_weights=binary_weights,
         )
 
     rep = P()
@@ -2618,7 +2704,7 @@ def _sharded_boost_kernel(mesh, num_rounds, max_depth, num_bins, objective,
 def _fit_boosted_batched_sharded(
     mesh, binned, y, row_mask, eta_v, lam, gam, mcw, mig,
     base_score, num_rounds, max_depth, num_bins, objective,
-    feature_groups=None, info_gain_norm=0.0,
+    feature_groups=None, info_gain_norm=0.0, binary_weights=False,
 ) -> tuple[Tree, jax.Array]:
     from ..parallel.mesh import DATA_AXIS
 
@@ -2640,6 +2726,7 @@ def _fit_boosted_batched_sharded(
         mesh, num_rounds, max_depth, num_bins, objective, _resolved_impl(),
         has_groups=feature_groups is not None,
         info_gain_norm=float(info_gain_norm),
+        binary_weights=bool(binary_weights),
     )
     grp_args = tuple(feature_groups) if feature_groups is not None else ()
     trees, margin, slots = kern(
@@ -2722,6 +2809,7 @@ def program_trace_specs():
             static_argnames=(
                 "num_rounds", "max_depth", "num_bins", "objective",
                 "axis_name", "axis_size", "hist_impl", "info_gain_norm",
+                "binary_weights",
             ),
         ),
         dict(
