@@ -1,10 +1,11 @@
 """Levels where no lane can split skip ``tree/partition``'s two row-wide
-passes (models/trees.py, ``level_body``): the compaction of the rows' nodes
-once the level above split nothing, the routing of the rows once this level
-splits nothing. What the skipped pass would give is returned in its place,
-so the trees, every row's leaf slot and the outputs are those of a fit that
-runs every pass; ``HistSlots.partition_run`` / ``partition_skipped`` count
-the passes that ran and those left out, per tree and level."""
+passes (models/trees.py, ``_compact`` and ``_route``): the compaction of
+the rows' nodes once the level above split nothing, the routing of the
+rows once this level splits nothing. What the skipped pass would give is
+returned in its place, so the trees, every row's leaf slot and the outputs
+are those of a fit that runs every pass; ``HistSlots.partition_run`` /
+``partition_skipped`` count the passes that ran and those left out, per
+tree and level."""
 import functools
 import sys
 

@@ -303,3 +303,127 @@ def test_fori_chunk_path_matches_unrolled(rng):
     leaf_ref = np.asarray(t1.leaf_value)
     np.testing.assert_allclose(leaf[0], leaf_ref, atol=1e-4, err_msg="leaf")
     np.testing.assert_allclose(leaf[-1], leaf_ref, atol=1e-4, err_msg="leaf")
+
+
+# ------------------------------ grower functions ----------------------------
+def _record(n=16, f=3, k=1, classes=0, **kw):
+    """A ``trees._Grow`` of a small fit (its arrays are never grown on)."""
+    grad = np.zeros((k, max(classes - 1, 1), n) if classes > 2 else (k, n),
+                    np.float32)
+    ones = np.ones((k, n), np.float32)
+    return TR._grow_record(
+        jnp.zeros((n, f), jnp.int32), jnp.asarray(grad), jnp.asarray(ones),
+        jnp.asarray(ones), jnp.ones((k, f)), max_depth=kw.pop("depth", 3),
+        num_bins=kw.pop("num_bins", 8), hist_impl="scatter", **kw,
+    )
+
+
+@pytest.mark.parametrize("c0, width, pairs, want", [
+    # every node built: the rows of nodes [c0, c0 + width), from 0
+    (0, 4, False, [0, 1, 2, 3, -1, -1, -1, -1]),
+    (4, 4, False, [-1, -1, -1, -1, 0, 1, 2, 3]),
+    # sibling pairs: nodes 2j, 2j + 1 are pair j, numbered from c0 / 2,
+    # for the rows of the child the level above chose (odd rows here)
+    (0, 8, True, [-1, 0, -1, 1, -1, 2, -1, 3]),
+    (4, 4, True, [-1, -1, -1, -1, -1, 0, -1, 1]),
+])
+def test_build_slots_numbers_nodes_or_sibling_pairs(c0, width, pairs, want):
+    local = jnp.arange(8, dtype=jnp.int32)[None, :]  # row r in node r
+    sib = None
+    if pairs:
+        sib = TR._Siblings(
+            takes_part=(jnp.arange(8) % 2 == 1)[None, :], n_pairs=None,
+            parent_slot=None, built_right=None, hists=(),
+        )
+    got = TR._build_slots(local, c0, width, sib)
+    np.testing.assert_array_equal(np.asarray(got)[0], want)
+
+
+@pytest.mark.parametrize("feats, right, n_pairs, parent_slot, built_right", [
+    # slots 0, 2 and 3 split: pairs 0, 1, 2 hang from them
+    ([3, -1, 0, 2, -1, -1, -1, -1], [1, 0, 0, 1, 1, 0, 0, 0], 3,
+     [0, 2, 3, 0], [True, False, True, False]),
+    # no split: no pair
+    ([-1] * 8, [1] * 8, 0, [0, 0, 0, 0], [False] * 4),
+    # every kept slot splits
+    ([1, 1, 1, 1, -1, -1, -1, -1], [0, 1, 0, 1, 0, 0, 0, 0], 4,
+     [0, 1, 2, 3], [False, True, False, True]),
+])
+def test_next_siblings_from_a_split_table(feats, right, n_pairs, parent_slot,
+                                          built_right):
+    rec = _record()._replace(keep=4, pair_slots=4)
+    takes = jnp.ones((1, 16), bool)
+    sib = TR._next_siblings(
+        rec, jnp.asarray([feats], jnp.int32), jnp.asarray([right], bool),
+        takes, ("kept",),
+    )
+    assert int(sib.n_pairs[0]) == n_pairs
+    np.testing.assert_array_equal(np.asarray(sib.parent_slot)[0], parent_slot)
+    np.testing.assert_array_equal(np.asarray(sib.built_right)[0], built_right)
+    assert sib.takes_part is takes and sib.hists == ("kept",)
+
+
+@pytest.mark.parametrize("narrow, wide", [([1], [0]), ([0], [1])])
+def test_chunk_stats_breaks_a_cross_group_tie_by_feature_id(narrow, wide):
+    """Columns 0 and 1 part the rows alike, one in each feature group: the
+    gains tie, and the lower original id wins whichever group is merged
+    first (the narrow one)."""
+    n = 16
+    half = np.arange(n) >= n // 2
+    codes = np.zeros((n, 2), np.int32)
+    codes[:, wide[0]] = np.where(half, 5, 2)
+    codes[:, narrow[0]] = half  # the narrow group reads code > 0
+    y = np.where(half, 1.0, -1.0).astype(np.float32)
+    ones = jnp.ones((1, n))
+    rec = TR._grow_record(
+        jnp.asarray(codes), jnp.asarray(-y[None, :]), ones, ones,
+        jnp.ones((1, 2)), max_depth=1, num_bins=8, hist_impl="scatter",
+        min_child_weight=1.0, reg_lambda=0.0,
+        feature_groups=(jnp.asarray(narrow), jnp.asarray(wide)),
+    )
+    assert [grp[3].tolist() for grp in rec.groups] == [narrow, wide]
+    live = jnp.zeros((1, rec.max_nodes), bool).at[0, 0].set(True)
+    local = jnp.zeros((1, n), jnp.int32)
+    lv = TR._Level(live, jnp.zeros((1, rec.max_nodes), jnp.int32),
+                   jnp.int32(0), local, (), (jnp.int32(0), jnp.int32(0)))
+    loc = TR._build_slots(local, 0, rec.chunk_nodes, None)
+    feats, bins, _, counts, _ = TR._chunk_stats(
+        rec, lv, loc, 0, rec.chunk_nodes, None, ()
+    )
+    assert int(feats[0, 0]) == 0
+    # the wide column's split bin sits between its two codes
+    assert int(bins[0, 0]) == (0 if narrow == [0] else 2)
+    assert int(counts.lane_builds) == 2
+
+
+@pytest.mark.parametrize("left, right", [
+    ((4, 1, 0), (0, 2, 5)),   # class counts (c0, c1, c2) of each child
+    ((3, 3, 3), (1, 0, 0)),
+    ((0, 5, 1), (2, 0, 6)),
+])
+def test_best_split_gini_adds_class_0_term(left, right):
+    """Under ``GINI`` the gain is half the weighted Gini decrease over all
+    three classes, class 0's (W less the others) included."""
+    rec = _record(classes=3, info_gain_norm=TR.GINI, reg_lambda=0.0,
+                  min_child_weight=0.0)
+    assert rec.n_values == 2
+    # bins 0 (left) and 1 (right): the negated class-1, class-2 indicator
+    # sums, then the weight
+    hist = jnp.asarray([[[[
+        [-left[1], -left[2], sum(left)], [-right[1], -right[2], sum(right)],
+    ]]]], jnp.float32)
+    gain = TR._best_split(
+        rec, hist, jnp.ones((1, 1)), None, 2, None, 1
+    )[0]
+    parent = np.add(left, right)
+
+    def sq(counts):
+        return sum(c * c for c in counts) / sum(counts)
+
+    decrease = sq(left) + sq(right) - sq(parent)
+    np.testing.assert_allclose(float(gain[0, 0]), 0.5 * decrease, rtol=1e-6)
+    without_class_0 = (
+        sq(left[1:]) + sq(right[1:]) - sq(parent[1:])
+        if sum(left[1:]) and sum(right[1:]) else None
+    )
+    assert without_class_0 is None or not np.isclose(decrease, without_class_0)

@@ -985,16 +985,9 @@ class _TreeEstimator(PredictorEstimator):
         static shapes into one compiled program per group — the TPU
         replacement for the reference's driver thread pool
         (OpValidator.scala:363-367). A 3-fold × 18-point RF grid becomes 3
-        programs (one per max_depth) instead of 54 dispatches.
-
-        Set TPTPU_BATCHED_FITS=0 to force sequential fits."""
-        import os
-
+        programs (one per max_depth) instead of 54 dispatches."""
         masks = [np.asarray(m, dtype=np.float32) for m in masks]
-        if (
-            os.environ.get("TPTPU_BATCHED_FITS") == "0"
-            or not self._STATIC_GRID_KEYS
-        ):
+        if not self._STATIC_GRID_KEYS:
             return [
                 [self.with_params(**p).fit_arrays(x, y, m) for p in points]
                 for m in masks
@@ -1620,7 +1613,7 @@ class RandomForestClassifier(_TreeEstimator):
     # max_depth STAYS static by default: collapsing the depth groups into
     # one max-depth program via max_depth_v makes every lane pay deep-level
     # eval in one fat program (not re-measured on a local chip; see
-    # ROADMAP D3). run_batched still
+    # ROADMAP M1b). run_batched still
     # wires per-lane caps for custom groupings that mix depths.
     _STATIC_GRID_KEYS = (
         "num_trees", "max_depth", "max_bins", "seed",

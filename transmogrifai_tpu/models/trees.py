@@ -337,6 +337,14 @@ def hist_slot_stats() -> HistSlotStats:
     return _HIST_SLOT_STATS
 
 
+#: a ``HistSlots`` field's ``tree/await_outputs`` name, where not its own
+_SPAN_ATTRS = {
+    "live": "slots_live", "built": "slots_built",
+    "rounds_label": "boost_rounds_label",
+    "rounds_residual": "boost_rounds_residual",
+}
+
+
 def await_outputs(value, hist_slots: HistSlots | None = None):
     """``value`` (an array or a pytree of arrays) on the host. A read of a
     device result blocks until the program that makes it has run, so it is
@@ -344,13 +352,9 @@ def await_outputs(value, hist_slots: HistSlots | None = None):
     device — and one download on the run ledger's transfer census. What is
     on the host already passes through. ``hist_slots`` are the counts the
     same fit program returned: once its outputs have landed they are there
-    too, and their sums go onto the span (``slots_live``, ``slots_built``,
-    ``chunks_run``, ``chunks_skipped``, ``nodes_built``, ``nodes_derived``,
-    ``partition_run``, ``partition_skipped``, ``code_passes``,
-    ``lane_builds``, ``stack_variants``, ``stack_variants_full``; from a
-    fit that counts node
-    subsets ``subset_admitted``, ``subset_pairs``; from one that boosts in
-    first order ``boost_rounds_label``, ``boost_rounds_residual``) and the
+    too, and their sums go onto the span (each under its field's name or
+    ``_SPAN_ATTRS``'; ``subset_*`` from a fit that counts node subsets,
+    ``boost_rounds_*`` from one that boosts in first order) and the
     ``tree`` ledger."""
     leaves = jax.tree.leaves(value)
     if all(isinstance(a, np.ndarray) for a in leaves):
@@ -366,29 +370,15 @@ def await_outputs(value, hist_slots: HistSlots | None = None):
                 field: int(np.asarray(a, dtype=np.int64).sum())
                 for field, a in hist_slots._asdict().items()
             }
-            sp.attrs.update(
-                slots_live=sums["live"], slots_built=sums["built"],
-                chunks_run=sums["chunks_run"],
-                chunks_skipped=sums["chunks_skipped"],
-                nodes_built=sums["nodes_built"],
-                nodes_derived=sums["nodes_derived"],
-                partition_run=sums["partition_run"],
-                partition_skipped=sums["partition_skipped"],
-                code_passes=sums["code_passes"],
-                lane_builds=sums["lane_builds"],
-                stack_variants=sums["stack_variants"],
-                stack_variants_full=sums["stack_variants_full"],
-            )
+            shown = [
+                f for f in HistSlots._fields
+                if not f.startswith(("subset_", "rounds_"))
+            ]
             if sums["subset_pairs"]:
-                sp.attrs.update(
-                    subset_admitted=sums["subset_admitted"],
-                    subset_pairs=sums["subset_pairs"],
-                )
+                shown += ["subset_admitted", "subset_pairs"]
             if sums["rounds_label"] or sums["rounds_residual"]:
-                sp.attrs.update(
-                    boost_rounds_label=sums["rounds_label"],
-                    boost_rounds_residual=sums["rounds_residual"],
-                )
+                shown += ["rounds_label", "rounds_residual"]
+            sp.attrs.update({_SPAN_ATTRS.get(f, f): sums[f] for f in shown})
             _HIST_SLOT_STATS.record(sums)
     return out
 
@@ -660,11 +650,12 @@ def grow_tree_batched(
     info_gain_norm: float = 0.0,
 ) -> Tree:
     """Grow K trees at once — one per batched fit (hyperparameter grid point
-    × CV fold). The fit axis is a kernel GRID dimension of the histogram
-    build (hist_pallas.build_histogram_pallas_batched), NOT a vmap over the
-    custom call (which crashes this TPU runtime), so the entire candidate
-    sweep's tree growth runs as one compiled program. Returned Tree arrays
-    carry a leading K axis."""
+    × CV fold). The fit axis is an axis of the histogram build itself
+    (every builder takes [K, N] statistics; the bin-loop kernel folds
+    ``hist_pallas.binloop_lane_group`` lanes into one pass of its grid),
+    NOT a vmap over the custom call (which crashes this TPU runtime), so
+    the entire candidate sweep's tree growth runs as one compiled program.
+    Returned Tree arrays carry a leading K axis."""
     return _grow_tree_impl(
         binned, grad, hess, row_mask, feat_mask,
         max_depth=max_depth, num_bins=num_bins,
@@ -756,373 +747,20 @@ def _grow_tree_impl(
     ``exact_hess`` (static): the hessian alone, ``hess * row_mask`` (h = 1
     and 0/1 row weights: ``_boost_chunk_body``). The histogram kernel then
     carries such a statistic as one variant, not a (hi, lo) pair."""
-    from .hist_pallas import (
-        BUILDERS, carried_variants, code_passes, default_impl,
+    rec = _grow_record(
+        binned, grad, hess, row_mask, feat_mask, max_depth, num_bins,
+        reg_lambda, gamma, min_child_weight, min_info_gain, hist_impl, lowp,
+        axis_name, axis_size, feature_groups, max_depth_v, info_gain_norm,
+        node_subset, node_key, exact_hess,
     )
-
-    k_fits, n = hess.shape
-    f = binned.shape[1]
-    b = num_bins
-    max_nodes = 1 << max_depth
-    # [K, N], or [K, V, N]: the builders take either (hist_pallas)
-    g = grad * (row_mask if grad.ndim == 2 else row_mask[:, None, :])
-    h = hess * row_mask
-    g_rows = [g] if g.ndim == 2 else [g[:, v] for v in range(g.shape[1])]
-    n_values = len(g_rows)
-    gini = info_gain_norm == GINI
-    impl = hist_impl or default_impl()
-
-    if feature_groups is not None:
-        narrow_idx, wide_idx = feature_groups
-        # (binned columns, per-fit feature mask, bin count, orig ids).
-        # Narrow features hold exactly two values {0, t} in code space
-        # (duplicate quantile edges put the '1' value at code t = #zeros);
-        # recoding (code > 0) compresses them to b=2 while the stored split
-        # bin 0 routes identically in ORIGINAL code space (code > 0 ⇔
-        # value is the upper one) — predict needs no remapping. Index
-        # arrays may be traced (per-tree colsample subsets); shapes are
-        # static, values aren't. Empty groups simply drop out.
-        groups = []
-        with jax.named_scope("tree/group_columns"):
-            if narrow_idx.shape[0]:
-                groups.append(
-                    (
-                        (binned[:, narrow_idx] > 0).astype(jnp.int32),
-                        feat_mask[:, narrow_idx], 2, narrow_idx,
-                    )
-                )
-            if wide_idx.shape[0]:
-                groups.append(
-                    (binned[:, wide_idx], feat_mask[:, wide_idx], b,
-                     wide_idx)
-                )
-        if not groups:
-            groups = [(binned, feat_mask, b, None)]
-    else:
-        groups = [(binned, feat_mask, b, None)]
-
-    def vec(v):
-        arr = jnp.asarray(v, dtype=jnp.float32).reshape(-1)
-        return arr  # shape (1,) broadcasts over K; shape (K,) is per-fit
-
-    lam = vec(reg_lambda)[:, None, None, None]
-    gam = vec(gamma)[:, None, None, None]
-    mcw = vec(min_child_weight)[:, None, None, None]
-    mig = vec(min_info_gain)[:, None]
-
-    # ---- node compaction: at any level at most min(2^depth, N) node slots
-    # are LIVE (every live slot holds ≥1 row), so histograms are built over
-    # a compact slot space of ``cap`` ids instead of the full 2^d range —
-    # depth-12 growth on 1k rows costs the same as depth-10 (the dominant
-    # win for the deep ends of the reference's maxDepth {3,6,12} grids).
-    # When sharded, the live bound is the GLOBAL row count.
-    # which builder each group takes and how many node slots one build may
-    # hold: decided in ONE place, hist_pallas.histogram_plan. A builder's
-    # loop-invariant operand (the GEMM's one-hot codes) is made here, once
-    # per group, outside the level scan and the tree scan above it.
-    cap, plan, ladder = _slot_layout(
-        impl, n, k_fits, [(gb_.shape[1], bb) for gb_, _, bb, _ in groups],
-        max_depth, axis_size, sharded=axis_name is not None,
-        stat_channels=n_values + 1, lowp=lowp, exact_hess=exact_hess,
-    )
-    # nodes a lane whose histograms a level keeps for the next (0: none)
-    keep = plan.parent_slots
-    assert b <= 1 << _BUILT_BIT, "a bin code shares its table entry's low bits"
-    with jax.named_scope("tree/group_columns"):
-        groups = [
-            (gb_, gm, bb, gi, BUILDERS[name].build,
-             BUILDERS[name].prepare(gb_, bb, lowp))
-            for (gb_, gm, bb, gi), name in zip(groups, plan.builders)
-        ]
-    draw_subsets = node_subset is not None and node_subset < f
-
-    # A level's histograms as rows, one a (lane, node): [K·M, S·B·F] from
-    # the builders' [K, M, F, B, S], and back. The orders are the TPU
-    # kernel's, whose output lies [S][K][B][F][M] in memory under that
-    # logical shape: each way is ONE transpose of the node axis against the
-    # rest, between shapes whose last two axes are both wide (a trailing
-    # axis of S = 2 or B = 2 pads 64-fold to the chip's (8, 128) tiles).
-    def node_rows(hist):
-        return jnp.transpose(hist, (0, 1, 4, 3, 2)).reshape(
-            hist.shape[0] * hist.shape[1], -1
-        )
-
-    def node_hists(rows, nodes, fg, bg):
-        by_cell = jnp.transpose(rows.reshape(k_fits, nodes, -1), (0, 2, 1))
-        return jnp.transpose(
-            by_cell.reshape(k_fits, n_values + 1, bg, fg, nodes),
-            (0, 4, 3, 2, 1),
-        )
-
-    def group_stats(gbinned, gmask, gb, gidx, build, operand, loc,
-                    chunk_nodes, sel, pairs):
-        """(gain, orig feat, bin, right child lighter) of the best split per
-        compact slot for ONE feature group; ``sel`` [K, M, node_subset] are
-        the slots' admissible columns (None: all). Also the (slot, feature)
-        pairs of this group that ``sel`` admits, [K, M] (None: all), and the
-        slots' histograms [K·M, Fg·Bg·S] for the next level to subtract
-        from. ``pairs`` (None: every slot is built): ``loc`` numbers sibling
-        PAIRS, M/2 of them, and holds the rows of one child of each; the
-        other child is its parent less the one built."""
-        nmask = None
-        if sel is not None:
-            with jax.named_scope("tree/node_subset"):
-                fid = (
-                    jnp.arange(gbinned.shape[1], dtype=jnp.int32)
-                    if gidx is None else gidx.astype(jnp.int32)
-                )
-                nmask = (sel[..., None] == fid).any(axis=2)  # [K, M, Fg]
-        with jax.named_scope("tree/histogram"):
-            # [K, M, Fg, Bg, V + 1] (value channels, hess) sums of the group
-            if pairs is None:
-                hist = build(
-                    operand, loc, g, h, chunk_nodes, gb, lowp=lowp,
-                    exact_hess=exact_hess,
-                )
-                if axis_name is not None:
-                    # the Rabit-allreduce moment: per-shard partial
-                    # histograms reduce over ICI; everything after sees the
-                    # global histogram
-                    hist = jax.lax.psum(hist, axis_name)
-            else:
-                built_right, parent, slot_live = pairs
-                one = build(
-                    operand, loc, g, h, chunk_nodes // 2, gb, lowp=lowp,
-                    exact_hess=exact_hess,
-                )
-                rows = sibling_rows(
-                    node_rows(one), parent, built_right, slot_live
-                )
-                hist = node_hists(rows, chunk_nodes, gbinned.shape[1], gb)
-        with jax.named_scope("tree/split_search"):
-            best = best_split(hist, gmask, nmask, gb, gidx, chunk_nodes)
-        if not keep:
-            rows = None
-        elif pairs is None:
-            rows = node_rows(hist)
-        return best, None if nmask is None else nmask.sum(axis=2), rows
-
-    def best_split(hist, gmask, nmask, gb, gidx, chunk_nodes):
-        hh = hist[..., n_values]  # [K, M, Fg, Bg]
-        hl = jnp.cumsum(hh, axis=3)[..., :-1]
-        ht = hh.sum(axis=3, keepdims=True)
-        hr = ht - hl
-
-        def decrease(hg):
-            """GL²/(HL+λ) + GR²/(HR+λ) − G²/(H+λ) of one value channel."""
-            gl = jnp.cumsum(hg, axis=3)[..., :-1]
-            gt = hg.sum(axis=3, keepdims=True)
-            gr = gt - gl
-            parent = (gt**2) / (ht + lam)
-            return gl**2 / (hl + lam) + gr**2 / (hr + lam) - parent
-
-        total = decrease(hist[..., 0])
-        for v in range(1, n_values):
-            total = total + decrease(hist[..., v])
-        if gini:
-            # class 0: the decrease of the other classes' summed indicator
-            total = total + decrease(hist[..., :n_values].sum(axis=-1))
-        gain = 0.5 * total - gam
-        valid = (
-            (hl >= mcw)
-            & (hr >= mcw)
-            & (gmask[:, None, :, None] > 0)
-        )
-        if nmask is not None:
-            valid = valid & nmask[..., None]
-        gain = jnp.where(valid, gain, -jnp.inf)
-
-        flat_gain = gain.reshape(gain.shape[0], chunk_nodes, -1)
-        best = jnp.argmax(flat_gain, axis=2)
-        best_gain = jnp.take_along_axis(flat_gain, best[..., None], axis=2)[..., 0]
-        best_feat = (best // (gb - 1)).astype(jnp.int32)
-        best_bin = (best % (gb - 1)).astype(jnp.int32)
-        if gidx is not None:
-            best_feat = gidx[best_feat].astype(jnp.int32)
-        # every row of a node lies in one bin of each feature: any
-        # feature's total is the node's weight
-        # the hessian sums of the two children at the split taken: the
-        # LIGHTER child is the one the next level builds (ties: the left)
-        def at_best(a):
-            return jnp.take_along_axis(
-                a.reshape(a.shape[0], chunk_nodes, -1), best[..., None],
-                axis=2,
-            )[..., 0]
-
-        right_lighter = at_best(hr) < at_best(hl)
-        return best_gain, best_feat, best_bin, ht[:, :, 0, 0], right_lighter
-
-    def node_subsets(at, c0, chunk_nodes):
-        """[K, M, node_subset] int32: the admissible columns of the nodes
-        in compact slots [c0, c0 + M), drawn from each node's heap index
-        (see the docstring); ``at`` is the level's (live, rank, level)."""
-        live, rank, level = at
-        with jax.named_scope("tree/node_subset"):
-            ids = jnp.arange(max_nodes, dtype=jnp.int32)
-            slots = c0 + jnp.arange(chunk_nodes, dtype=jnp.int32)
-            # the node whose dense rank is the slot (0 where none is live:
-            # its draw is never read)
-            node_of = jnp.where(
-                live[:, :, None] & (rank[:, :, None] == slots),
-                ids[None, :, None], 0,
-            ).sum(axis=1)
-            heap = jnp.left_shift(jnp.int32(1), level) + node_of  # [K, M]
-
-            def draw(j):
-                return jax.random.choice(
-                    jax.random.fold_in(node_key, j), f, (node_subset,),
-                    replace=False,
-                ).astype(jnp.int32)
-
-            return jax.vmap(jax.vmap(draw))(heap)
-
-    def build_slots(local, c0, chunk_nodes, sib):
-        """[K, N] int32: the slot of the build over the compact node slots
-        [c0, c0 + chunk_nodes) each row goes to, -1 for a row that takes no
-        part. Every node is built (``sib`` None): the row's node, from 0.
-        A subtracting level: the row's sibling PAIR (nodes 2j and 2j + 1
-        are pair j), from 0 at c0 / 2, for the rows of the child the level
-        above chose to build."""
-        with jax.named_scope("tree/partition"):
-            if sib is None:
-                part = (local >= c0) & (local < c0 + chunk_nodes)
-                return jnp.where(part, local - c0, -1)
-            pair, p0 = local >> 1, c0 // 2
-            part = sib.takes_part & (pair >= p0) & (
-                pair < p0 + chunk_nodes // 2
-            )
-            return jnp.where(part, pair - p0, -1)
-
-    def chunk_stats(loc, c0, chunk_nodes, at, sib, hists):
-        """Best (feat, bin) per compact slot in [c0, c0 + chunk_nodes),
-        merged across feature groups (tie-break: lowest original feature
-        id — matches the single-group argmax order), whether the right
-        child of that split is the lighter, the (live node, feature)
-        pairs admitted and possible there, and ``hists`` (per group
-        [K·keep, Fg·Bg·S]: the level's histograms so far) with these
-        slots' written in. ``loc``: ``build_slots``' of the same range."""
-        halves = [None] * len(groups)
-        # dense numbering: a lane's live slots are those below its live count
-        slot_live = (
-            c0 + jnp.arange(chunk_nodes, dtype=jnp.int32)
-        ) < at[0].sum(axis=1, dtype=jnp.int32)[:, None]
-        if sib is not None:
-            def of_chunk(a):
-                return jax.lax.dynamic_slice_in_dim(
-                    a, c0 // 2, chunk_nodes // 2, axis=1
-                )
-
-            # rows of the kept histograms ([K·keep, Fg·Bg·S], lane-major)
-            parent_row = (
-                jnp.arange(k_fits, dtype=jnp.int32)[:, None] * keep
-                + of_chunk(sib.parent_slot)
-            ).reshape(-1)
-            with jax.named_scope("tree/histogram"):
-                halves = [
-                    (
-                        of_chunk(sib.built_right),
-                        jnp.take(kept, parent_row, axis=0, mode="clip"),
-                        slot_live,
-                    )
-                    for kept in sib.hists
-                ]
-        sel = node_subsets(at, c0, chunk_nodes) if draw_subsets else None
-        bg, bf, bb, bw, br = None, None, None, None, None
-        admitted = None
-        new_hists = []
-        for grp, half, kept in zip(
-            groups, halves, hists or [None] * len(groups)
-        ):
-            gbinned, gmask, grp_b, gidx, build, operand = grp
-            (gg, gf, gbin, gw, gr), adm, hist = group_stats(
-                gbinned, gmask, grp_b, gidx, build, operand, loc,
-                chunk_nodes, sel, half,
-            )
-            if kept is not None:
-                # compact slots [c0, c0 + chunk) of this level: the next
-                # one's parents. A level's nodes past ``keep`` are the last
-                # level's, which nobody subtracts from (the update clamps
-                # there, onto slots never read again)
-                with jax.named_scope("tree/histogram"):
-                    kept = jax.lax.dynamic_update_slice_in_dim(
-                        kept.reshape(k_fits, keep, -1),
-                        hist.reshape(k_fits, chunk_nodes, -1)[:, :keep],
-                        c0, axis=1,
-                    ).reshape(kept.shape)
-                new_hists.append(kept)
-            if adm is not None:
-                admitted = adm if admitted is None else admitted + adm
-            if bg is None:
-                bg, bf, bb, bw, br = gg, gf, gbin, gw, gr
-            else:
-                with jax.named_scope("tree/split_search"):
-                    take = (gg > bg) | ((gg == bg) & (gf < bf))
-                    bg = jnp.where(take, gg, bg)
-                    bf = jnp.where(take, gf, bf)
-                    bb = jnp.where(take, gbin, bb)
-                    br = jnp.where(take, gr, br)
-        with jax.named_scope("tree/split_search"):
-            if info_gain_norm:
-                do_split = (bg > 0.0) & (2.0 * bg / bw >= mig)
-            else:
-                do_split = bg > jnp.maximum(mig, 0.0)
-            counts = (jnp.int32(0), jnp.int32(0))
-            if node_subset is not None:
-                pairs = slot_live.sum(dtype=jnp.int32) * f
-                counts = (
-                    pairs if admitted is None else
-                    jnp.where(slot_live, admitted, 0).sum(dtype=jnp.int32),
-                    pairs,
-                )
-            # the groups' builds: passes over the codes, lanes, the
-            # variants an operand carried and all there could be (static)
-            build_w = chunk_nodes if sib is None else chunk_nodes // 2
-            counts += (
-                jnp.int32(sum(
-                    code_passes(
-                        name, k_fits, n_values + 1, lowp, build_w, exact_hess
-                    )
-                    for name in plan.builders
-                )),
-                jnp.int32(k_fits * len(groups)),
-                jnp.int32(sum(
-                    carried_variants(name, n_values + 1, lowp, exact_hess)
-                    for name in plan.builders
-                )),
-                jnp.int32(2 * (n_values + 1) * len(groups)),
-            )
-            return (
-                jnp.where(do_split, bf, -1),
-                jnp.where(do_split, bb, 0),
-                do_split & br,
-                counts,
-                tuple(new_hists),
-            )  # [K, chunk] x 3, six scalars, the kept histograms
-
-    sentinel = jnp.int32(max_nodes)  # out-of-range → dropped by scatters
-
-    def leaf_values(sums_g, sum_h):
-        """-G/(H+λ) of each value channel from the leaves' [K, leaves]
-        sums: [K, leaves], or [K, leaves, V] at V > 1. Under ``GINI`` with
-        V > 1 the leaf is the whole class distribution, [K, leaves, V + 1]:
-        class 0's count is W less the others' (exact: they are integers),
-        divided like theirs, so that equal counts give equal shares and an
-        arg max can break ties by the lowest class. (A two-class leaf stays
-        its class-1 share: 1 - p is exact there.)"""
-        if axis_name is not None:
-            sums_g = [jax.lax.psum(sg, axis_name) for sg in sums_g]
-            sum_h = jax.lax.psum(sum_h, axis_name)
-        if gini and n_values > 1:
-            sums_g = [-(sum_h + sum(sums_g))] + sums_g
-        leaves = [-sg / (sum_h + vec(reg_lambda)[:, None]) for sg in sums_g]
-        return leaves[0] if len(leaves) == 1 else jnp.stack(leaves, axis=-1)
-
+    k_fits, n = rec.k_fits, rec.n
     if max_depth == 0:
         # root-only tree (legal Spark maxDepth=0): no splits, leaf = all rows
         with jax.named_scope("tree/leaf"):
-            leaf_value0 = leaf_values(
-                [gv.sum(axis=1, keepdims=True) for gv in g_rows],
-                h.sum(axis=1, keepdims=True),
+            leaf_value0 = _leaf_values(
+                rec,
+                [gv.sum(axis=1, keepdims=True) for gv in rec.g_rows],
+                rec.h.sum(axis=1, keepdims=True),
             )
         return Tree(
             split_feat=jnp.full((k_fits, 0, 1), -1, dtype=jnp.int32),
@@ -1132,389 +770,12 @@ def _grow_tree_impl(
             *(jnp.zeros((0,), dtype=jnp.int32) for _ in HistSlots._fields)
         )
 
-    # ---- lax.scan over levels with ONE shared body: an unrolled level
-    # loop multiplies the compiled body (and its compile time and
-    # executable size) by max_depth. Every level therefore uses the SAME
-    # static slot layout: `cap` compact slots in `num_chunks` fixed chunks,
-    # with node compaction numbering live slots densely from 0 so the
-    # per-chunk occupancy cond skips the provably-empty tail.
-    #
-    # A level with few live slots does not pay for a whole chunk: its
-    # histograms are built and searched at the smallest rung of `ladder`
-    # that holds them (live_level below). A build costs by its WIDTH, not
-    # by the rows a slot holds (every build passes over all rows against a
-    # [T, nvar·width] one-hot operand: 0.52 s at 256 slots, 0.26 at 128,
-    # 0.17 at 64, 0.13 at 32 on a v5e at 1M x 302 x 32 bins, four lanes one
-    # a pass, hist_pallas.binloop_tiles; 0.16 at 64 and 0.086 at 32 where
-    # the lanes share a pass, hist_pallas.binloop_lane_group). The sharded
-    # path keeps the full width (_slot_layout).
-    #
-    # So a level past the root builds HALF its nodes. A level's nodes come
-    # in sibling pairs whose parent's histogram the level above held, and
-    # hist(parent) = hist(left) + hist(right) cell for cell: one child of
-    # each pair is built, at slot = the pair's number, and the other is the
-    # parent less it, in float32 (XGBoost's ``hist`` updater and LightGBM
-    # do the same). A level of L nodes asks the ladder for L/2 slots, and
-    # 256 pair slots serve 512 nodes. The level above chooses the child:
-    # the one with the smaller hessian sum at the split taken (ties: the
-    # left). That is a rule of numerics, not of speed: the derived child
-    # inherits the absolute rounding error of two builds, which stays
-    # within a small factor of its own only if it is the heavier one.
-    # Where every sum is exact in float32 (integer weights, ±0.5 and 0.25)
-    # the trees are bit for bit those of direct builds. The fit keeps the
-    # level's histograms for this (`keep` nodes a lane, a feature group
-    # each, [K·keep, Fg·Bg·S]: a row is a node, so a pair's parent is one
-    # row gather) where hist_pallas.histogram_plan finds room
-    # for them; else (keep 0, and on the sharded path) every node is built.
-    # The root is pair 0's left child with no parent to subtract from.
-    n_nodes = cap
-    chunk_nodes = plan.chunk_cap
-    num_chunks = (n_nodes + chunk_nodes - 1) // chunk_nodes
-    # a subtracting level's chunks hold `chunk_nodes` PAIRS
-    num_pair_chunks = (n_nodes + 2 * chunk_nodes - 1) // (2 * chunk_nodes)
-    pair_slots = num_pair_chunks * chunk_nodes
-
-    def compact_local(hist_node):
-        """Dense live-slot numbering via occupancy + cumsum rank. Slot =
-        number of live node ids BELOW this row's id — identical numbering
-        to sorted-unique compaction, but built from one scatter-add and a
-        cumsum instead of sort + searchsorted (each searchsorted lowers to
-        a ~log2(N)-step binary-search while loop of gather fusions, and
-        three of them per level measured ~75% of deep forest exec). When
-        sharded, every shard agrees on the numbering because the occupancy
-        psums first. Returns ((live, rank), slot): live/rank are
-        [K, max_nodes] masks/prefix-ranks used to densify per-slot results
-        back into global node-id space gather-side."""
-        occ = _occupancy(hist_node, max_nodes)
-        if axis_name is not None:
-            occ = jax.lax.psum(occ, axis_name)
-        live = occ > 0
-        live_i = live.astype(jnp.int32)
-        rank = jnp.cumsum(live_i, axis=1) - live_i  # exclusive prefix
-        slot = _small_table_lookup(
-            rank, jnp.minimum(hist_node, max_nodes - 1)
-        )
-        slot = jnp.where(hist_node >= max_nodes, sentinel, slot).astype(
-            jnp.int32
-        )
-        return (live, rank), slot
-
-    def level_body(carry, level_idx):
-        # rows whose node failed to split are DEAD for histogram purposes:
-        # a non-split node's child holds the same rows, hence the same
-        # histogram and the same failed gain test (the hereditary no-split
-        # argument). Excluding them shrinks the live-slot frontier so the
-        # occupancy skip drops the dead bulk of deep levels; `node` keeps
-        # the full routing chain (dead rows continue left) so leaf
-        # assignment is unchanged.
-        node, active, alive_in, sib = carry
-
-        def compact():
-            hist_node = jnp.where(active, node, sentinel)
-            (live, rank), local = compact_local(hist_node)
-            # dead rows out of every histogram / occupancy check,
-            # regardless of which slot the sentinel landed on after
-            # compaction
-            return live, rank, jnp.where(active, local, sentinel)
-
-        # ---- dead levels: once a level splits nothing in any lane, every
-        # row is inactive, so compaction would find no live node and
-        # routing would only send each row left. Both row-wide passes are
-        # skipped for what they would give; the sharded path always
-        # computes (compaction's psum can't sit under a data-dependent cond)
-        with jax.named_scope("tree/partition"):
-            if axis_name is not None:
-                live, rank, local = compact()
-            else:
-                live, rank, local = jax.lax.cond(
-                    alive_in,
-                    compact,
-                    lambda: (
-                        jnp.zeros((k_fits, max_nodes), dtype=bool),
-                        jnp.zeros((k_fits, max_nodes), dtype=jnp.int32),
-                        jnp.full((k_fits, n), sentinel, dtype=jnp.int32),
-                    ),
-                )
-
-        # live compact slots at this level, the widest lane's: slots are
-        # numbered densely from 0, so every live one is below this count
-        n_live_k = live.sum(axis=1, dtype=jnp.int32)
-        n_live = n_live_k.max()
-
-        at = (live, rank, level_idx)
-        zero2 = (jnp.int32(0), jnp.int32(0))
-        zero6 = zero2 * 3
-        hists0 = sib.hists if keep else ()
-
-        def chunk_loop(sib_):
-            """The level in chunks of ``chunk_nodes`` slots a build: nodes,
-            or (``sib_``) sibling pairs, two nodes each."""
-            width = chunk_nodes if sib_ is None else 2 * chunk_nodes
-            chunks = num_chunks if sib_ is None else num_pair_chunks
-
-            def chunk_body(ci, fb):
-                feats_a, bins_a, right_a, built, counts, hists = fb
-                c0 = ci * width
-                loc = build_slots(local, c0, width, sib_)
-                if axis_name is None:
-                    occupied = (loc >= 0).any()
-                    cf, cb, cr, cc, hists = jax.lax.cond(
-                        occupied,
-                        lambda: chunk_stats(loc, c0, width, at, sib_, hists),
-                        lambda: (
-                            jnp.full((k_fits, width), -1, dtype=jnp.int32),
-                            jnp.zeros((k_fits, width), dtype=jnp.int32),
-                            jnp.zeros((k_fits, width), dtype=bool),
-                            zero6,
-                            hists,
-                        ),
-                    )
-                    built = built + jnp.where(occupied, chunk_nodes, 0)
-                else:
-                    # the sharded path always computes — its psums can't
-                    # sit under a data-dependent cond
-                    cf, cb, cr, cc, hists = chunk_stats(
-                        loc, c0, width, at, sib_, hists
-                    )
-                    built = built + chunk_nodes
-                return (
-                    jax.lax.dynamic_update_slice(feats_a, cf, (0, c0)),
-                    jax.lax.dynamic_update_slice(bins_a, cb, (0, c0)),
-                    jax.lax.dynamic_update_slice(right_a, cr, (0, c0)),
-                    built,
-                    tuple(a + c for a, c in zip(counts, cc)),
-                    hists,
-                )
-
-            def run():
-                slots = (k_fits, chunks * width)
-                feats_a, bins_a, right_a, built, counts, hists = (
-                    jax.lax.fori_loop(
-                        0, chunks, chunk_body,
-                        (
-                            jnp.full(slots, -1, dtype=jnp.int32),
-                            jnp.zeros(slots, dtype=jnp.int32),
-                            jnp.zeros(slots, dtype=bool),
-                            jnp.int32(0), zero6, hists0,
-                        ),
-                    )
-                )
-                return (
-                    feats_a[:, :n_nodes], bins_a[:, :n_nodes],
-                    right_a[:, :n_nodes], built, counts, hists,
-                )
-
-            return run
-
-        def one_build(width, sib_):
-            """The whole level in ONE build at ``width`` slots (a rung that
-            holds every live node, or (``sib_``) every sibling pair),
-            brought to the [K, n_nodes] the scan carries."""
-            nodes = width if sib_ is None else 2 * width
-
-            def fit(a, fill):
-                return jnp.pad(
-                    a[:, :n_nodes],
-                    ((0, 0), (0, max(n_nodes - nodes, 0))),
-                    constant_values=fill,
-                )
-
-            def run():
-                loc = build_slots(local, 0, nodes, sib_)
-                cf, cb, cr, counts, hists = chunk_stats(
-                    loc, 0, nodes, at, sib_, hists0
-                )
-                return (
-                    fit(cf, -1), fit(cb, 0), fit(cr, False),
-                    jnp.int32(width), counts, hists,
-                )
-
-            return run
-
-        def live_level(sib_, narrow, count):
-            """The level at the smallest of the ``narrow`` rungs that holds
-            ``count`` slots, else in chunks."""
-            if not narrow:
-                return chunk_loop(sib_)()
-            rung = sum((count > w).astype(jnp.int32) for w in narrow)
-            return jax.lax.switch(
-                rung,
-                [one_build(w, sib_) for w in narrow] + [chunk_loop(sib_)],
-            )
-
-        if keep:
-            # Pair j's children sit in the compact slots 2j and 2j + 1 iff
-            # every child of a split is live, i.e. holds a row. It does
-            # wherever ``min_child_weight`` > 0 (both children of a valid
-            # split hold hessian). Where it does not (a weightless child),
-            # the lane has fewer live nodes than twice its splits, and the
-            # level builds every node, in chunks: a path for soundness,
-            # not for speed.
-            subtract = (
-                (n_live_k == 2 * sib.n_pairs) | (level_idx == 0)
-            ).all()
-            n_built_k = jnp.where(subtract, sib.n_pairs, n_live_k)
-
-            def level():
-                return jax.lax.cond(
-                    subtract,
-                    lambda: live_level(sib, ladder[:-1], sib.n_pairs.max()),
-                    lambda: live_level(None, (), n_live),
-                )
-        else:
-            subtract = jnp.asarray(False)
-            n_built_k = n_live_k
-
-            def level():
-                return live_level(None, ladder[:-1], n_live)
-
-        # ---- early level exit: no-split is hereditary, so once a level
-        # produces zero splits every deeper level is all-leaves — skip the
-        # histogram work under a cond. The sharded path always computes
-        # (replicated-predicate collectives under shard_map are not worth
-        # the coupling).
-        if axis_name is not None:
-            feats_c, bins_c, right_c, built, counts, hists = level()
-        else:
-            feats_c, bins_c, right_c, built, counts, hists = jax.lax.cond(
-                alive_in,
-                level,
-                lambda: (
-                    jnp.full((k_fits, n_nodes), -1, dtype=jnp.int32),
-                    jnp.zeros((k_fits, n_nodes), dtype=jnp.int32),
-                    jnp.zeros((k_fits, n_nodes), dtype=bool),
-                    jnp.int32(0),
-                    zero6,
-                    hists0,
-                ),
-            )
-        # builds made and chunks the occupancy branch left out: a rung is
-        # one build under a chunk's width, the chunk loop whole chunks
-        chunked = built >= chunk_nodes
-        runs = jnp.where(
-            chunked, built // chunk_nodes, (built > 0).astype(jnp.int32)
-        )
-        # (a chunk of pairs covers two chunks of nodes)
-        skipped = jnp.where(
-            chunked,
-            jnp.where(subtract, num_pair_chunks, num_chunks) - runs, 0,
-        )
-        if max_depth_v is not None:
-            # per-lane depth cap: a lane past its depth emits no splits
-            # (identical trees to a program compiled at that lane's depth —
-            # dead levels route left and add nothing)
-            lane_live = (level_idx < max_depth_v)[:, None]
-            feats_c = jnp.where(lane_live, feats_c, -1)
-            bins_c = jnp.where(lane_live, bins_c, 0)
-        alive = (feats_c >= 0).any()
-
-        # write per-slot decisions into the GLOBAL node-slot tree arrays —
-        # gather-side via the compaction rank (live id → its dense slot):
-        # scatters serialize per index on TPU and searchsorted lowers to
-        # binary-search while loops; both measured to dominate deep levels.
-        # (A one-shot post-scan densify over all levels measured ~35%
-        # SLOWER than these per-level gathers — the [depth, K, max_nodes]
-        # batched gather schedules worse than the level-sized ones.)
-        with jax.named_scope("tree/partition"):
-            rank_c = jnp.minimum(rank, n_nodes - 1)
-            # one-hot select, NOT take_along_axis: the [K, max_nodes]
-            # gather from [K, cap] lowered to a serializing custom-fusion
-            # gather measured at ~1 ms per level — 1.2 s of the 1.7 s
-            # depth-12 RF program (Titanic's 891 rows, round 5)
-            feats_d = jnp.where(
-                live, _small_table_lookup(feats_c, rank_c), -1
-            )
-            bins_d = jnp.where(live, _small_table_lookup(bins_c, rank_c), 0)
-
-            def route():
-                """Each row's child (gather via compact slots — cheaper),
-                whether it stays active, and (``keep``) whether it is in
-                the child the next level builds."""
-                slot = jnp.clip(local, 0, n_nodes - 1)
-                row_feat = _small_table_lookup(feats_c, slot)  # [K, N]
-                if keep:
-                    # which child the next level builds rides in the table
-                    # the threshold is looked up from (no gather of its own)
-                    row_thr = _small_table_lookup(
-                        bins_c + (right_c.astype(jnp.int32) << _BUILT_BIT),
-                        slot,
-                    )
-                    row_right = row_thr >> _BUILT_BIT
-                    row_thr = row_thr & ((1 << _BUILT_BIT) - 1)
-                else:
-                    row_thr = _small_table_lookup(bins_c, slot)
-                code = _row_feature_select(binned, row_feat)
-                go_right = active & (row_feat >= 0) & (code > row_thr)
-                stays = active & (row_feat >= 0)
-                out = (node * 2 + go_right.astype(jnp.int32), stays)
-                if keep:
-                    out += (stays & (go_right == (row_right > 0)),)
-                return out
-
-            if axis_name is not None:
-                node, active, *takes_part = route()
-            else:
-                # no split anywhere: every row goes left and leaves the tree
-                dead = jnp.zeros((k_fits, n), dtype=bool)
-                node, active, *takes_part = jax.lax.cond(
-                    alive, route,
-                    lambda: (node * 2, dead) + (dead,) * bool(keep),
-                )
-            if keep:
-                # the next level's pairs: the nodes that split, numbered
-                # densely in slot order; pair j's parent is the j-th of
-                # them (only a level that is not the last has a next, and
-                # its nodes sit in the first `keep` slots)
-                splits = feats_c >= 0
-                split = splits[:, :keep]
-                split_i = split.astype(jnp.int32)
-                order = jnp.cumsum(split_i, axis=1) - split_i
-                is_parent = split[:, None, :] & (
-                    order[:, None, :]
-                    == jnp.arange(pair_slots, dtype=jnp.int32)[:, None]
-                )  # [K, pair_slots, keep]
-                sib = _Siblings(
-                    takes_part=takes_part[0],
-                    n_pairs=splits.sum(axis=1, dtype=jnp.int32),
-                    parent_slot=jnp.where(
-                        is_parent, jnp.arange(keep, dtype=jnp.int32), 0
-                    ).sum(axis=2),
-                    built_right=(
-                        is_parent & right_c[:, None, :keep]
-                    ).any(axis=2),
-                    hists=hists,
-                )
-        passes = (
-            jnp.int32(2) if axis_name is not None
-            else alive_in.astype(jnp.int32) + alive.astype(jnp.int32)
-        )
-        return (node, active, alive, sib), (
-            feats_d, bins_d,
-            HistSlots(
-                n_built_k.max(), built, runs, skipped, *counts[:2], *zero2,
-                n_built_k.sum(), (n_live_k - n_built_k).sum(),
-                passes, 2 - passes, *counts[2:],
-            ),
-        )
-
-    sib0 = None
-    if keep:
-        # the root: pair 0's left child, every row taking part
-        sib0 = _Siblings(
-            takes_part=jnp.ones((k_fits, n), dtype=bool),
-            n_pairs=jnp.ones((k_fits,), dtype=jnp.int32),
-            parent_slot=jnp.zeros((k_fits, pair_slots), dtype=jnp.int32),
-            built_right=jnp.zeros((k_fits, pair_slots), dtype=bool),
-            hists=tuple(
-                jnp.zeros(
-                    (k_fits * keep, gb_.shape[1] * bb * (n_values + 1)),
-                    dtype=jnp.float32,
-                )
-                for gb_, _, bb, *_ in groups
-            ),
-        )
-    (node, active, _, _), (feats_s, bins_s, slots_s) = jax.lax.scan(
-        level_body,
+    # lax.scan over levels with ONE shared body (``_level_body``): an
+    # unrolled level loop multiplies the compiled body (and its compile
+    # time and executable size) by max_depth
+    sib0 = _root_siblings(rec) if rec.keep else None
+    (node, _, _, _), (feats_s, bins_s, slots_s) = jax.lax.scan(
+        partial(_level_body, rec),
         (
             jnp.zeros((k_fits, n), dtype=jnp.int32),
             jnp.ones((k_fits, n), dtype=bool),
@@ -1527,15 +788,814 @@ def _grow_tree_impl(
     bins = jnp.swapaxes(bins_s, 0, 1)
 
     with jax.named_scope("tree/leaf"):
-        leaf_value = leaf_values(
-            [_segment_sum_small(gv, node, max_nodes) for gv in g_rows],
-            _segment_sum_small(h, node, max_nodes),
+        leaf_value = _leaf_values(
+            rec,
+            [_segment_sum_small(gv, node, rec.max_nodes) for gv in rec.g_rows],
+            _segment_sum_small(rec.h, node, rec.max_nodes),
         )
     tree = Tree(split_feat=feats, split_bin=bins, leaf_value=leaf_value)
     # `node` is each row's final leaf slot — boosting's margin update reuses
     # it (leaf_value lookup) instead of re-traversing the tree (measured
     # ~100 ms/round of serialized gathers at 1M rows)
     return tree, node, slots_s
+
+
+class _Grow(NamedTuple):
+    """One tree fit as ``_grow_tree_impl`` traces it (``_grow_record``):
+    the statics, then the per-fit arrays. ``groups``: per feature group
+    (codes, feature mask, bins, original ids or None, builder, its prepared
+    operand); ``keep``: the nodes a lane whose histograms a level keeps for
+    the next (0: none); ``n_nodes``: the compact slots a level can have
+    live, in ``num_chunks`` chunks of ``chunk_nodes`` (``num_pair_chunks``
+    of as many pairs: ``pair_slots``)."""
+
+    k_fits: int
+    n: int
+    f: int
+    max_nodes: int
+    n_values: int
+    info_gain_norm: float
+    node_subset: int | None
+    lowp: bool
+    exact_hess: bool
+    axis_name: str | None
+    groups: tuple
+    plan: object
+    ladder: tuple
+    keep: int
+    n_nodes: int
+    chunk_nodes: int
+    num_chunks: int
+    num_pair_chunks: int
+    pair_slots: int
+    sentinel: jax.Array  # a node id past every slot: scatters drop it
+    binned: jax.Array
+    g: jax.Array
+    h: jax.Array
+    g_rows: list
+    reg_lambda: jax.Array | float  # as given: the leaves shape their own
+    lam: jax.Array
+    gam: jax.Array
+    mcw: jax.Array
+    mig: jax.Array
+    node_key: jax.Array | None
+    max_depth_v: jax.Array | None
+
+
+def _per_fit(v):
+    """A knob as float32 [1] (broadcasts over K) or [K] (one a fit)."""
+    return jnp.asarray(v, dtype=jnp.float32).reshape(-1)
+
+
+def _grow_record(
+    binned, grad, hess, row_mask, feat_mask, max_depth, num_bins,
+    reg_lambda=1.0, gamma=0.0, min_child_weight=1.0, min_info_gain=0.0,
+    hist_impl=None, lowp=False, axis_name=None, axis_size=1,
+    feature_groups=None, max_depth_v=None, info_gain_norm=0.0,
+    node_subset=None, node_key=None, exact_hess=False,
+) -> _Grow:
+    """The ``_Grow`` of one fit, from ``_grow_tree_impl``'s arguments."""
+    from .hist_pallas import BUILDERS, default_impl
+
+    k_fits, n = hess.shape
+    f = binned.shape[1]
+    # [K, N], or [K, V, N]: the builders take either (hist_pallas)
+    g = grad * (row_mask if grad.ndim == 2 else row_mask[:, None, :])
+    h = hess * row_mask
+    g_rows = [g] if g.ndim == 2 else [g[:, v] for v in range(g.shape[1])]
+    # (codes, per-fit feature mask, bins, original ids) of each feature
+    # group. Narrow features hold two values {0, t} in code space (duplicate
+    # quantile edges put the '1' value at code t = #zeros); recoding
+    # (code > 0) makes them b=2 while the stored split bin 0 routes the same
+    # in ORIGINAL code space — predict needs no remapping. Index arrays may
+    # be traced (per-tree colsample subsets). Empty groups drop out.
+    groups = []
+    if feature_groups is not None:
+        narrow_idx, wide_idx = feature_groups
+        with jax.named_scope("tree/group_columns"):
+            if narrow_idx.shape[0]:
+                groups.append((
+                    (binned[:, narrow_idx] > 0).astype(jnp.int32),
+                    feat_mask[:, narrow_idx], 2, narrow_idx,
+                ))
+            if wide_idx.shape[0]:
+                groups.append((
+                    binned[:, wide_idx], feat_mask[:, wide_idx], num_bins,
+                    wide_idx,
+                ))
+    groups = groups or [(binned, feat_mask, num_bins, None)]
+    lam = _per_fit(reg_lambda)[:, None, None, None]
+    gam = _per_fit(gamma)[:, None, None, None]
+    mcw = _per_fit(min_child_weight)[:, None, None, None]
+    mig = _per_fit(min_info_gain)[:, None]
+
+    # node compaction: at any level at most min(2^depth, N) node slots are
+    # LIVE, so histograms are built over ``cap`` compact ids, not 2^d —
+    # depth-12 growth on 1k rows costs what depth-10 does (GLOBAL rows when
+    # sharded). Builders and build width: hist_pallas.histogram_plan; a
+    # builder's loop-invariant operand (the GEMM's one-hot codes) is made
+    # here, once per group, outside the level scan and the tree scan.
+    cap, plan, ladder = _slot_layout(
+        hist_impl or default_impl(), n, k_fits,
+        [(gb_.shape[1], bb) for gb_, _, bb, _ in groups], max_depth,
+        axis_size, sharded=axis_name is not None,
+        stat_channels=len(g_rows) + 1, lowp=lowp, exact_hess=exact_hess,
+    )
+    assert num_bins <= 1 << _BUILT_BIT, (
+        "a bin code shares its table entry's low bits"
+    )
+    with jax.named_scope("tree/group_columns"):
+        groups = tuple(
+            (gb_, gm, bb, gi, BUILDERS[name].build,
+             BUILDERS[name].prepare(gb_, bb, lowp))
+            for (gb_, gm, bb, gi), name in zip(groups, plan.builders)
+        )
+    chunk_nodes = plan.chunk_cap
+    # a subtracting level's chunks hold `chunk_nodes` PAIRS
+    num_pair_chunks = (cap + 2 * chunk_nodes - 1) // (2 * chunk_nodes)
+    return _Grow(
+        k_fits=k_fits, n=n, f=f, max_nodes=1 << max_depth,
+        n_values=len(g_rows), info_gain_norm=info_gain_norm,
+        node_subset=node_subset,
+        lowp=lowp, exact_hess=exact_hess, axis_name=axis_name,
+        groups=groups, plan=plan, ladder=ladder, keep=plan.parent_slots,
+        n_nodes=cap, chunk_nodes=chunk_nodes,
+        num_chunks=(cap + chunk_nodes - 1) // chunk_nodes,
+        num_pair_chunks=num_pair_chunks,
+        pair_slots=num_pair_chunks * chunk_nodes,
+        sentinel=jnp.int32(1 << max_depth), binned=binned, g=g, h=h,
+        g_rows=g_rows, reg_lambda=reg_lambda, lam=lam, gam=gam, mcw=mcw,
+        mig=mig, node_key=node_key, max_depth_v=max_depth_v,
+    )
+
+
+class _Level(NamedTuple):
+    """One level as its builds see it: ``live`` / ``rank`` [K, max_nodes]
+    (``_compact_local``), ``level`` its index, ``local`` [K, N] each row's
+    compact slot (the sentinel: none), ``hists`` the level above's kept
+    histograms, ``zeros`` the two int32 zeros every zero count of the
+    level reads (its program holds two such constants)."""
+
+    live: jax.Array
+    rank: jax.Array
+    level: jax.Array
+    local: jax.Array
+    hists: tuple
+    zeros: tuple
+
+
+class _BuildCounts(NamedTuple):
+    """What a level's builds counted, int32 scalars: the ``HistSlots``
+    fields of the same names."""
+
+    subset_admitted: jax.Array
+    subset_pairs: jax.Array
+    code_passes: jax.Array
+    lane_builds: jax.Array
+    stack_variants: jax.Array
+    stack_variants_full: jax.Array
+
+
+def _unless_sharded(rec, pred, run, skip):
+    """``lax.cond(pred, run, skip)``, or ``run()`` where the grower runs
+    sharded: its psums may not sit under a data-dependent branch."""
+    if rec.axis_name is not None:
+        return run()
+    return jax.lax.cond(pred, run, skip)
+
+
+def _all_shards(rec, x):
+    """``x``, summed over the mesh axis where the grower runs sharded."""
+    return x if rec.axis_name is None else jax.lax.psum(x, rec.axis_name)
+
+
+def _skipped(rec, lv, width, hists, *built):
+    """What a build or level that runs nothing gives: [K, width] slots that
+    split nothing, ``built`` (a level's), no counts, ``hists`` unchanged."""
+    return (
+        jnp.full((rec.k_fits, width), -1, dtype=jnp.int32),
+        jnp.zeros((rec.k_fits, width), dtype=jnp.int32),
+        jnp.zeros((rec.k_fits, width), dtype=bool),
+        *built, _BuildCounts(*lv.zeros * 3), hists,
+    )
+
+
+# A level's histograms as rows, one a (lane, node): [K·M, S·B·F] from the
+# builders' [K, M, F, B, S], and back. The orders are the TPU kernel's,
+# whose output lies [S][K][B][F][M] in memory under that logical shape:
+# each way is ONE transpose of the node axis against the rest, between
+# shapes whose last two axes are both wide (a trailing axis of S = 2 or
+# B = 2 pads 64-fold to the chip's (8, 128) tiles).
+def _node_rows(hist):
+    return jnp.transpose(hist, (0, 1, 4, 3, 2)).reshape(
+        hist.shape[0] * hist.shape[1], -1
+    )
+
+
+def _node_hists(rec, rows, nodes, fg, bg):
+    by_cell = jnp.transpose(rows.reshape(rec.k_fits, nodes, -1), (0, 2, 1))
+    return jnp.transpose(
+        by_cell.reshape(rec.k_fits, rec.n_values + 1, bg, fg, nodes),
+        (0, 4, 3, 2, 1),
+    )
+
+
+def _group_stats(rec, grp, loc, chunk_nodes, sel, pairs):
+    """(gain, orig feat, bin, right child lighter) of the best split per
+    compact slot for ONE feature group ``grp`` (an entry of
+    ``rec.groups``); ``sel`` [K, M, node_subset] are the slots' admissible
+    columns (None: all). Also the (slot, feature) pairs of this group that
+    ``sel`` admits, [K, M] (None: all), and the slots' histograms
+    [K·M, Fg·Bg·S] for the next level to subtract from. ``pairs`` (None:
+    every slot is built): ``loc`` numbers sibling PAIRS, M/2 of them, and
+    holds the rows of one child of each; the other child is its parent
+    less the one built."""
+    gbinned, gmask, gb, gidx, build, operand = grp
+    nmask = None
+    if sel is not None:
+        with jax.named_scope("tree/node_subset"):
+            fid = (
+                jnp.arange(gbinned.shape[1], dtype=jnp.int32)
+                if gidx is None else gidx.astype(jnp.int32)
+            )
+            nmask = (sel[..., None] == fid).any(axis=2)  # [K, M, Fg]
+    with jax.named_scope("tree/histogram"):
+        # [K, M, Fg, Bg, V + 1] (value channels, hess) sums of the group
+        if pairs is None:
+            hist = build(
+                operand, loc, rec.g, rec.h, chunk_nodes, gb, lowp=rec.lowp,
+                exact_hess=rec.exact_hess,
+            )
+            # the Rabit-allreduce moment: per-shard partial histograms
+            # reduce over ICI; everything after sees the global histogram
+            hist = _all_shards(rec, hist)
+        else:
+            built_right, parent, slot_live = pairs
+            one = build(
+                operand, loc, rec.g, rec.h, chunk_nodes // 2, gb,
+                lowp=rec.lowp, exact_hess=rec.exact_hess,
+            )
+            rows = sibling_rows(
+                _node_rows(one), parent, built_right, slot_live
+            )
+            hist = _node_hists(rec, rows, chunk_nodes, gbinned.shape[1], gb)
+    with jax.named_scope("tree/split_search"):
+        best = _best_split(rec, hist, gmask, nmask, gb, gidx, chunk_nodes)
+    if not rec.keep:
+        rows = None
+    elif pairs is None:
+        rows = _node_rows(hist)
+    return best, None if nmask is None else nmask.sum(axis=2), rows
+
+
+def _best_split(rec, hist, gmask, nmask, gb, gidx, chunk_nodes):
+    """(gain, orig feat, bin, hessian sum, right child lighter) [K, M] of
+    the best split of each slot of ``hist`` [K, M, Fg, Bg, V + 1]."""
+    n_values, lam = rec.n_values, rec.lam
+    hh = hist[..., n_values]  # [K, M, Fg, Bg]
+    hl = jnp.cumsum(hh, axis=3)[..., :-1]
+    ht = hh.sum(axis=3, keepdims=True)
+    hr = ht - hl
+
+    def decrease(hg):
+        """GL²/(HL+λ) + GR²/(HR+λ) − G²/(H+λ) of one value channel."""
+        gl = jnp.cumsum(hg, axis=3)[..., :-1]
+        gt = hg.sum(axis=3, keepdims=True)
+        gr = gt - gl
+        parent = (gt**2) / (ht + lam)
+        return gl**2 / (hl + lam) + gr**2 / (hr + lam) - parent
+
+    total = decrease(hist[..., 0])
+    for v in range(1, n_values):
+        total = total + decrease(hist[..., v])
+    if rec.info_gain_norm == GINI:
+        # class 0: the decrease of the other classes' summed indicator
+        total = total + decrease(hist[..., :n_values].sum(axis=-1))
+    gain = 0.5 * total - rec.gam
+    valid = (
+        (hl >= rec.mcw)
+        & (hr >= rec.mcw)
+        & (gmask[:, None, :, None] > 0)
+    )
+    if nmask is not None:
+        valid = valid & nmask[..., None]
+    gain = jnp.where(valid, gain, -jnp.inf)
+
+    flat_gain = gain.reshape(gain.shape[0], chunk_nodes, -1)
+    best = jnp.argmax(flat_gain, axis=2)
+    best_gain = jnp.take_along_axis(flat_gain, best[..., None], axis=2)[..., 0]
+    best_feat = (best // (gb - 1)).astype(jnp.int32)
+    best_bin = (best % (gb - 1)).astype(jnp.int32)
+    if gidx is not None:
+        best_feat = gidx[best_feat].astype(jnp.int32)
+    # every row of a node lies in one bin of each feature: any
+    # feature's total is the node's weight
+    # the hessian sums of the two children at the split taken: the
+    # LIGHTER child is the one the next level builds (ties: the left)
+    def at_best(a):
+        return jnp.take_along_axis(
+            a.reshape(a.shape[0], chunk_nodes, -1), best[..., None],
+            axis=2,
+        )[..., 0]
+
+    right_lighter = at_best(hr) < at_best(hl)
+    return best_gain, best_feat, best_bin, ht[:, :, 0, 0], right_lighter
+
+
+def _node_subsets(rec, lv, c0, chunk_nodes):
+    """[K, M, node_subset] int32: the admissible columns of the nodes in
+    compact slots [c0, c0 + M), drawn from each node's heap index (see
+    ``_grow_tree_impl``)."""
+    with jax.named_scope("tree/node_subset"):
+        ids = jnp.arange(rec.max_nodes, dtype=jnp.int32)
+        slots = c0 + jnp.arange(chunk_nodes, dtype=jnp.int32)
+        # the node whose dense rank is the slot (0 where none is live:
+        # its draw is never read)
+        node_of = jnp.where(
+            lv.live[:, :, None] & (lv.rank[:, :, None] == slots),
+            ids[None, :, None], 0,
+        ).sum(axis=1)
+        heap = jnp.left_shift(jnp.int32(1), lv.level) + node_of  # [K, M]
+
+        def draw(j):
+            return jax.random.choice(
+                jax.random.fold_in(rec.node_key, j), rec.f,
+                (rec.node_subset,), replace=False,
+            ).astype(jnp.int32)
+
+        return jax.vmap(jax.vmap(draw))(heap)
+
+
+def _build_slots(local, c0, chunk_nodes, sib):
+    """[K, N] int32: the slot of the build over the compact node slots
+    [c0, c0 + chunk_nodes) each row goes to, -1 for a row that takes no
+    part. Every node is built (``sib`` None): the row's node, from 0.
+    A subtracting level: the row's sibling PAIR (nodes 2j and 2j + 1
+    are pair j), from 0 at c0 / 2, for the rows of the child the level
+    above chose to build."""
+    with jax.named_scope("tree/partition"):
+        if sib is None:
+            part = (local >= c0) & (local < c0 + chunk_nodes)
+            return jnp.where(part, local - c0, -1)
+        pair, p0 = local >> 1, c0 // 2
+        part = sib.takes_part & (pair >= p0) & (
+            pair < p0 + chunk_nodes // 2
+        )
+        return jnp.where(part, pair - p0, -1)
+
+
+def _chunk_stats(rec, lv, loc, c0, chunk_nodes, sib, hists):
+    """Best (feat, bin) per compact slot in [c0, c0 + chunk_nodes),
+    merged across feature groups (tie-break: lowest original feature
+    id — matches the single-group argmax order), whether the right
+    child of that split is the lighter, the builds' ``_BuildCounts``, and
+    ``hists`` (per group [K·keep, Fg·Bg·S]: the level's histograms so
+    far) with these slots' written in. ``loc``: ``_build_slots``' of the
+    same range."""
+    # dense numbering: a lane's live slots are those below its live count
+    slot_live = (
+        c0 + jnp.arange(chunk_nodes, dtype=jnp.int32)
+    ) < lv.live.sum(axis=1, dtype=jnp.int32)[:, None]
+    halves = [None] * len(rec.groups)
+    if sib is not None:
+        def of_chunk(a):
+            return jax.lax.dynamic_slice_in_dim(
+                a, c0 // 2, chunk_nodes // 2, axis=1
+            )
+
+        # rows of the kept histograms ([K·keep, Fg·Bg·S], lane-major)
+        parent_row = (
+            jnp.arange(rec.k_fits, dtype=jnp.int32)[:, None] * rec.keep
+            + of_chunk(sib.parent_slot)
+        ).reshape(-1)
+        with jax.named_scope("tree/histogram"):
+            halves = [
+                (
+                    of_chunk(sib.built_right),
+                    jnp.take(kept, parent_row, axis=0, mode="clip"),
+                    slot_live,
+                )
+                for kept in sib.hists
+            ]
+    sel = None
+    if rec.node_subset is not None and rec.node_subset < rec.f:
+        sel = _node_subsets(rec, lv, c0, chunk_nodes)
+    bg, bf, bb, bw, br = None, None, None, None, None
+    admitted = None
+    new_hists = []
+    for grp, half, kept in zip(
+        rec.groups, halves, hists or [None] * len(rec.groups)
+    ):
+        (gg, gf, gbin, gw, gr), adm, hist = _group_stats(
+            rec, grp, loc, chunk_nodes, sel, half,
+        )
+        if kept is not None:
+            # compact slots [c0, c0 + chunk) of this level: the next
+            # one's parents. A level's nodes past ``keep`` are the last
+            # level's, which nobody subtracts from (the update clamps
+            # there, onto slots never read again)
+            with jax.named_scope("tree/histogram"):
+                kept = jax.lax.dynamic_update_slice_in_dim(
+                    kept.reshape(rec.k_fits, rec.keep, -1),
+                    hist.reshape(rec.k_fits, chunk_nodes, -1)[:, :rec.keep],
+                    c0, axis=1,
+                ).reshape(kept.shape)
+            new_hists.append(kept)
+        if adm is not None:
+            admitted = adm if admitted is None else admitted + adm
+        if bg is None:
+            bg, bf, bb, bw, br = gg, gf, gbin, gw, gr
+        else:
+            with jax.named_scope("tree/split_search"):
+                take = (gg > bg) | ((gg == bg) & (gf < bf))
+                bg = jnp.where(take, gg, bg)
+                bf = jnp.where(take, gf, bf)
+                bb = jnp.where(take, gbin, bb)
+                br = jnp.where(take, gr, br)
+    with jax.named_scope("tree/split_search"):
+        if rec.info_gain_norm:
+            do_split = (bg > 0.0) & (2.0 * bg / bw >= rec.mig)
+        else:
+            do_split = bg > jnp.maximum(rec.mig, 0.0)
+        counts = _build_counts(
+            rec, slot_live, admitted,
+            chunk_nodes if sib is None else chunk_nodes // 2,
+        )
+        return (
+            jnp.where(do_split, bf, -1),
+            jnp.where(do_split, bb, 0),
+            do_split & br,
+            counts,
+            tuple(new_hists),
+        )  # [K, chunk] x 3, the counts, the kept histograms
+
+
+def _build_counts(rec, slot_live, admitted, build_w):
+    """The ``_BuildCounts`` of one build of every group at ``build_w``
+    slots: the live (node, feature) pairs the split search admitted and
+    all there were (0 unless the fit counts node subsets), and (static)
+    the passes over the codes, the lanes, the variants an operand carried
+    and all there could be."""
+    from .hist_pallas import carried_variants, code_passes
+
+    channels, builders = rec.n_values + 1, rec.plan.builders
+    admitted_n, pairs = jnp.int32(0), jnp.int32(0)
+    if rec.node_subset is not None:
+        pairs = slot_live.sum(dtype=jnp.int32) * rec.f
+        admitted_n = (
+            pairs if admitted is None else
+            jnp.where(slot_live, admitted, 0).sum(dtype=jnp.int32)
+        )
+    return _BuildCounts(
+        subset_admitted=admitted_n,
+        subset_pairs=pairs,
+        code_passes=jnp.int32(sum(
+            code_passes(b, rec.k_fits, channels, rec.lowp, build_w,
+                        rec.exact_hess) for b in builders
+        )),
+        lane_builds=jnp.int32(rec.k_fits * len(rec.groups)),
+        stack_variants=jnp.int32(sum(
+            carried_variants(b, channels, rec.lowp, rec.exact_hess)
+            for b in builders
+        )),
+        stack_variants_full=jnp.int32(2 * channels * len(rec.groups)),
+    )
+
+
+def _leaf_values(rec, sums_g, sum_h):
+    """-G/(H+λ) of each value channel from the leaves' [K, leaves]
+    sums: [K, leaves], or [K, leaves, V] at V > 1. Under ``GINI`` with
+    V > 1 the leaf is the whole class distribution, [K, leaves, V + 1]:
+    class 0's count is W less the others' (exact: they are integers),
+    divided like theirs, so that equal counts give equal shares and an
+    arg max can break ties by the lowest class. (A two-class leaf stays
+    its class-1 share: 1 - p is exact there.)"""
+    sums_g = [_all_shards(rec, sg) for sg in sums_g]
+    sum_h = _all_shards(rec, sum_h)
+    if rec.info_gain_norm == GINI and rec.n_values > 1:
+        sums_g = [-(sum_h + sum(sums_g))] + sums_g
+    leaves = [
+        -sg / (sum_h + _per_fit(rec.reg_lambda)[:, None]) for sg in sums_g
+    ]
+    return leaves[0] if len(leaves) == 1 else jnp.stack(leaves, axis=-1)
+
+
+def _compact_local(rec, hist_node):
+    """Dense live-slot numbering via occupancy + cumsum rank. Slot =
+    number of live node ids BELOW this row's id — identical numbering
+    to sorted-unique compaction, but built from one scatter-add and a
+    cumsum instead of sort + searchsorted (each searchsorted lowers to
+    a ~log2(N)-step binary-search while loop of gather fusions, and
+    three of them per level measured ~75% of deep forest exec). When
+    sharded, every shard agrees on the numbering because the occupancy
+    psums first. Returns ((live, rank), slot): live/rank are
+    [K, max_nodes] masks/prefix-ranks used to densify per-slot results
+    back into global node-id space gather-side."""
+    max_nodes = rec.max_nodes
+    occ = _all_shards(rec, _occupancy(hist_node, max_nodes))
+    live = occ > 0
+    live_i = live.astype(jnp.int32)
+    rank = jnp.cumsum(live_i, axis=1) - live_i  # exclusive prefix
+    slot = _small_table_lookup(
+        rank, jnp.minimum(hist_node, max_nodes - 1)
+    )
+    slot = jnp.where(hist_node >= max_nodes, rec.sentinel, slot).astype(
+        jnp.int32
+    )
+    return (live, rank), slot
+
+
+def _root_siblings(rec):
+    """The root's ``_Siblings``: pair 0's left child, every row taking
+    part, no parent to subtract from."""
+    k_fits, channels = rec.k_fits, rec.n_values + 1
+    return _Siblings(
+        takes_part=jnp.ones((k_fits, rec.n), dtype=bool),
+        n_pairs=jnp.ones((k_fits,), dtype=jnp.int32),
+        parent_slot=jnp.zeros((k_fits, rec.pair_slots), dtype=jnp.int32),
+        built_right=jnp.zeros((k_fits, rec.pair_slots), dtype=bool),
+        hists=tuple(
+            jnp.zeros((k_fits * rec.keep, gb_.shape[1] * bb * channels),
+                      dtype=jnp.float32)
+            for gb_, _, bb, *_ in rec.groups
+        ),
+    )
+
+
+def _level_body(rec, carry, level_idx):
+    """One level of the scan: compaction, builds and split search, routing,
+    what the next level needs. Rows whose node failed to split are DEAD
+    for histograms (its child would hold the same rows and fail the same
+    test), so the occupancy skip drops the dead bulk of deep levels;
+    ``node`` keeps routing them left, so leaves are unchanged."""
+    node, active, alive_in, sib = carry
+    live, rank, local = _compact(rec, node, active, alive_in)
+    # live compact slots at this level: slots are numbered densely from 0,
+    # so every live one is below a lane's count
+    n_live_k = live.sum(axis=1, dtype=jnp.int32)
+    lv = _Level(
+        live, rank, level_idx, local, sib.hists if rec.keep else (),
+        (jnp.int32(0), jnp.int32(0)),
+    )
+    n_built_k, runs, skipped, built_level = _level_builds(
+        rec, lv, sib, alive_in, n_live_k
+    )
+    feats_c, bins_c, right_c, built, counts, hists = built_level
+    if rec.max_depth_v is not None:
+        # per-lane depth cap: a lane past its depth emits no splits
+        # (identical trees to a program compiled at that lane's depth —
+        # dead levels route left and add nothing)
+        lane_live = (level_idx < rec.max_depth_v)[:, None]
+        feats_c = jnp.where(lane_live, feats_c, -1)
+        bins_c = jnp.where(lane_live, bins_c, 0)
+    alive = (feats_c >= 0).any()
+    # per-slot decisions into the GLOBAL node-slot arrays, gathered via the
+    # compaction rank: scatters serialize per index on TPU, searchsorted is
+    # a binary-search loop, and one post-scan densify over all levels was
+    # ~35% SLOWER. One-hot select, NOT take_along_axis: that gather lowered
+    # to a serializing custom fusion, 1.2 s of a 1.7 s depth-12 RF program.
+    with jax.named_scope("tree/partition"):
+        rank_c = jnp.minimum(lv.rank, rec.n_nodes - 1)
+        feats_d = jnp.where(live, _small_table_lookup(feats_c, rank_c), -1)
+        bins_d = jnp.where(live, _small_table_lookup(bins_c, rank_c), 0)
+        node, active, *takes_part = _route(
+            rec, lv, node, active, feats_c, bins_c, right_c, alive
+        )
+        if rec.keep:
+            sib = _next_siblings(rec, feats_c, right_c, takes_part[0], hists)
+    # the row-wide passes run: the compaction where ``alive_in``, the
+    # routing where ``alive`` (both on the sharded path)
+    passes = (
+        jnp.int32(2) if rec.axis_name is not None
+        else alive_in.astype(jnp.int32) + alive.astype(jnp.int32)
+    )
+    return (node, active, alive, sib), (feats_d, bins_d, HistSlots(
+        live=n_built_k.max(), built=built, chunks_run=runs,
+        chunks_skipped=skipped, rounds_label=lv.zeros[0],
+        rounds_residual=lv.zeros[1], nodes_built=n_built_k.sum(),
+        nodes_derived=(n_live_k - n_built_k).sum(), partition_run=passes,
+        partition_skipped=2 - passes, **counts._asdict(),
+    ))
+
+
+def _compact(rec, node, active, alive_in):
+    """(live, rank, local) of a level (``_Level``): ``_compact_local`` of
+    the active rows, the others at the sentinel. Once a level splits
+    nothing in any lane, every row is inactive and compaction would find
+    no live node: it is skipped for what it would give."""
+    def compact():
+        hist_node = jnp.where(active, node, rec.sentinel)
+        (live, rank), local = _compact_local(rec, hist_node)
+        # dead rows out of every histogram / occupancy check, regardless
+        # of which slot the sentinel landed on after compaction
+        return live, rank, jnp.where(active, local, rec.sentinel)
+
+    with jax.named_scope("tree/partition"):
+        return _unless_sharded(rec, alive_in, compact, lambda: (
+            jnp.zeros((rec.k_fits, rec.max_nodes), dtype=bool),
+            jnp.zeros((rec.k_fits, rec.max_nodes), dtype=jnp.int32),
+            jnp.full((rec.k_fits, rec.n), rec.sentinel, dtype=jnp.int32),
+        ))
+
+
+# A level's builds. Every level has the SAME static layout: `n_nodes`
+# compact slots in `num_chunks` chunks, live slots numbered densely from 0
+# so the per-chunk occupancy cond skips the empty tail. A level with few
+# live slots is built at the smallest rung of `ladder` that holds them: a
+# build costs by its WIDTH, not its rows (0.52 s at 256 slots, 0.26 at
+# 128, 0.17 at 64, 0.13 at 32 on a v5e at 1M x 302 x 32 bins, four lanes
+# one a pass; 0.16 at 64 and 0.086 at 32 where the lanes share a pass,
+# hist_pallas.binloop_lane_group). The sharded path keeps the full width.
+#
+# And a level past the root builds HALF its nodes: one child of each
+# sibling pair, at slot = the pair's number; the other is the parent's
+# histogram (kept by the level above) less it, in float32, as XGBoost's
+# ``hist`` updater and LightGBM do. The level above picks the child with
+# the smaller hessian sum (ties: the left), a rule of numerics: the
+# derived child inherits the rounding error of two builds, small beside
+# its own only if it is the heavier. Where every sum is exact in float32
+# the trees are bit for bit those of direct builds. Where
+# hist_pallas.histogram_plan finds no room for `keep` (and on the sharded
+# path) every node is built. The root is pair 0's left child.
+def _level_builds(rec, lv, sib, alive_in, n_live_k):
+    """(the nodes each lane built, the builds made and the chunks the
+    occupancy branch left out, the level's builds: [K, n_nodes] split
+    features, bins and right-lighter flags, the slots built, the
+    ``_BuildCounts``, the kept histograms)."""
+    n_live = n_live_k.max()
+    if rec.keep:
+        # Pair j's children sit in compact slots 2j, 2j + 1 iff every child
+        # of a split holds a row (so wherever ``min_child_weight`` > 0).
+        # A lane with a weightless child has fewer live nodes than twice
+        # its splits: the level builds every node, in chunks (soundness,
+        # not speed).
+        subtract = ((n_live_k == 2 * sib.n_pairs) | (lv.level == 0)).all()
+        n_built_k = jnp.where(subtract, sib.n_pairs, n_live_k)
+
+        def level():
+            return jax.lax.cond(
+                subtract,
+                lambda: _level_at(
+                    rec, lv, sib, rec.ladder[:-1], sib.n_pairs.max()
+                ),
+                lambda: _level_at(rec, lv, None, (), n_live),
+            )
+    else:
+        subtract = jnp.asarray(False)
+        n_built_k = n_live_k
+
+        def level():
+            return _level_at(rec, lv, None, rec.ladder[:-1], n_live)
+
+    # ---- early level exit: no-split is hereditary, so once a level
+    # produces zero splits every deeper level is all-leaves — skip the
+    # histogram work under a cond
+    builds = _unless_sharded(
+        rec, alive_in, level,
+        lambda: _skipped(rec, lv, rec.n_nodes, lv.hists, jnp.int32(0)),
+    )
+    # a rung is one build under a chunk's width, the chunk loop whole
+    # chunks (a chunk of pairs covers two chunks of nodes)
+    built = builds[3]
+    chunked = built >= rec.chunk_nodes
+    runs = jnp.where(
+        chunked, built // rec.chunk_nodes, (built > 0).astype(jnp.int32)
+    )
+    skipped = jnp.where(
+        chunked,
+        jnp.where(subtract, rec.num_pair_chunks, rec.num_chunks) - runs, 0,
+    )
+    return n_built_k, runs, skipped, builds
+
+
+def _level_at(rec, lv, sib, narrow, count):
+    """The level at the smallest of the ``narrow`` rungs that holds
+    ``count`` slots (``_one_build``), else in chunks."""
+    if not narrow:
+        return _chunked_level(rec, lv, sib)
+    rung = sum((count > w).astype(jnp.int32) for w in narrow)
+    return jax.lax.switch(
+        rung,
+        [partial(_one_build, rec, lv, w, sib) for w in narrow]
+        + [partial(_chunked_level, rec, lv, sib)],
+    )
+
+
+def _one_build(rec, lv, width, sib):
+    """The whole level in ONE build at ``width`` slots (a rung that holds
+    every live node, or (``sib``) every sibling pair), brought to the
+    [K, n_nodes] the scan carries."""
+    nodes = width if sib is None else 2 * width
+
+    def fit(a, fill):
+        return jnp.pad(
+            a[:, :rec.n_nodes],
+            ((0, 0), (0, max(rec.n_nodes - nodes, 0))),
+            constant_values=fill,
+        )
+
+    loc = _build_slots(lv.local, 0, nodes, sib)
+    cf, cb, cr, counts, hists = _chunk_stats(
+        rec, lv, loc, 0, nodes, sib, lv.hists
+    )
+    return (fit(cf, -1), fit(cb, 0), fit(cr, False), jnp.int32(width),
+            counts, hists)
+
+
+def _chunked_level(rec, lv, sib):
+    """The level in chunks of ``chunk_nodes`` slots a build: nodes, or
+    (``sib``) sibling pairs, two nodes each. A chunk no row reaches is
+    skipped (the sharded path computes every one)."""
+    width = rec.chunk_nodes if sib is None else 2 * rec.chunk_nodes
+    chunks = rec.num_chunks if sib is None else rec.num_pair_chunks
+
+    def chunk_body(ci, fb):
+        feats_a, bins_a, right_a, built, counts, hists = fb
+        c0 = ci * width
+        loc = _build_slots(lv.local, c0, width, sib)
+        occupied = (loc >= 0).any()
+        cf, cb, cr, cc, hists = _unless_sharded(
+            rec, occupied,
+            lambda: _chunk_stats(rec, lv, loc, c0, width, sib, hists),
+            lambda: _skipped(rec, lv, width, hists),
+        )
+        built = built + (
+            rec.chunk_nodes if rec.axis_name is not None
+            else jnp.where(occupied, rec.chunk_nodes, 0)
+        )
+        return (
+            jax.lax.dynamic_update_slice(feats_a, cf, (0, c0)),
+            jax.lax.dynamic_update_slice(bins_a, cb, (0, c0)),
+            jax.lax.dynamic_update_slice(right_a, cr, (0, c0)),
+            built,
+            jax.tree.map(jnp.add, counts, cc),
+            hists,
+        )
+
+    feats_a, bins_a, right_a, built, counts, hists = jax.lax.fori_loop(
+        0, chunks, chunk_body,
+        _skipped(rec, lv, chunks * width, lv.hists, jnp.int32(0)),
+    )
+    m = rec.n_nodes
+    return feats_a[:, :m], bins_a[:, :m], right_a[:, :m], built, counts, hists
+
+
+def _route(rec, lv, node, active, feats_c, bins_c, right_c, alive):
+    """Each row's child, whether it stays active, and (``keep``) whether
+    it is in the child the next level builds. With no split anywhere
+    (``alive`` false) every row goes left and leaves the tree."""
+    def route():
+        # gather via compact slots — cheaper
+        slot = jnp.clip(lv.local, 0, rec.n_nodes - 1)
+        row_feat = _small_table_lookup(feats_c, slot)  # [K, N]
+        if rec.keep:
+            # which child the next level builds rides in the table the
+            # threshold is looked up from (no gather of its own)
+            row_thr = _small_table_lookup(
+                bins_c + (right_c.astype(jnp.int32) << _BUILT_BIT), slot,
+            )
+            row_right = row_thr >> _BUILT_BIT
+            row_thr = row_thr & ((1 << _BUILT_BIT) - 1)
+        else:
+            row_thr = _small_table_lookup(bins_c, slot)
+        code = _row_feature_select(rec.binned, row_feat)
+        go_right = active & (row_feat >= 0) & (code > row_thr)
+        stays = active & (row_feat >= 0)
+        out = (node * 2 + go_right.astype(jnp.int32), stays)
+        if rec.keep:
+            out += (stays & (go_right == (row_right > 0)),)
+        return out
+
+    dead = jnp.zeros((rec.k_fits, rec.n), dtype=bool)
+    return _unless_sharded(
+        rec, alive, route,
+        lambda: (node * 2, dead) + (dead,) * bool(rec.keep),
+    )
+
+
+def _next_siblings(rec, feats_c, right_c, takes_part, hists):
+    """The next level's ``_Siblings``: its pairs are the nodes that split
+    here, numbered densely in slot order; pair j's parent is the j-th of
+    them (only a level that is not the last has a next, and its nodes sit
+    in the first ``keep`` slots)."""
+    keep = rec.keep
+    splits = feats_c >= 0
+    split = splits[:, :keep]
+    split_i = split.astype(jnp.int32)
+    order = jnp.cumsum(split_i, axis=1) - split_i
+    is_parent = split[:, None, :] & (
+        order[:, None, :]
+        == jnp.arange(rec.pair_slots, dtype=jnp.int32)[:, None]
+    )  # [K, pair_slots, keep]
+    return _Siblings(
+        takes_part=takes_part,
+        n_pairs=splits.sum(axis=1, dtype=jnp.int32),
+        parent_slot=jnp.where(
+            is_parent, jnp.arange(keep, dtype=jnp.int32), 0
+        ).sum(axis=2),
+        built_right=(is_parent & right_c[:, None, :keep]).any(axis=2),
+        hists=hists,
+    )
 
 
 def leaf_lookup(leaf_value: jax.Array, node: jax.Array) -> jax.Array:
@@ -2016,12 +2076,8 @@ def _forest_trees_scan(
     with jax.named_scope("tree/gradients"):
         gb = forest_gradients(jnp.asarray(target), k_fits, num_classes)
     ones = jnp.ones((k_fits, n), dtype=jnp.float32)
-    mi_k = jnp.broadcast_to(
-        jnp.asarray(min_instances, dtype=jnp.float32).reshape(-1), (k_fits,)
-    )
-    mg_k = jnp.broadcast_to(
-        jnp.asarray(min_info_gain, dtype=jnp.float32).reshape(-1), (k_fits,)
-    )
+    mi_k = jnp.broadcast_to(_per_fit(min_instances), (k_fits,))
+    mg_k = jnp.broadcast_to(_per_fit(min_info_gain), (k_fits,))
     # per-tree keys derived IN-PROGRAM (same threefry ops → identical draws
     # to the old eager derivation; keeps PRNGKey/split eager compiles off
     # the per-process critical path)
@@ -2031,20 +2087,13 @@ def _forest_trees_scan(
 
     def body(_, tk):
         rm_t, fm_t = _bag_masks(tk, sub, col, row_mask, n, f, bootstrap)
-        tree, node, slots = _grow_tree_impl(
-            binned, gb, ones, rm_t, fm_t,
-            max_depth=max_depth, num_bins=num_bins,
-            reg_lambda=0.0, gamma=0.0,
-            min_child_weight=mi_k, min_info_gain=mg_k,
-            hist_impl=hist_impl, lowp=lowp, feature_groups=feature_groups,
+        return _forest_tree(
+            binned, gb, ones, rm_t, fm_t, mi_k, mg_k, _node_key(tk),
+            max_depth=max_depth, num_bins=num_bins, hist_impl=hist_impl,
+            lowp=lowp, feature_groups=feature_groups,
             max_depth_v=max_depth_v, info_gain_norm=info_gain_norm,
-            node_subset=feature_subset, node_key=_node_key(tk),
+            node_subset=feature_subset,
         )
-        # this tree's prediction for EVERY row from the grower's own final
-        # routing (leaf lookup — no re-traversal)
-        with jax.named_scope("tree/outputs"):
-            pred_t = leaf_lookup(tree.leaf_value, node)
-        return None, (tree, pred_t, slots)
 
     _, (trees, preds, slots) = jax.lax.scan(body, None, tkeys)  # [T, K, ...]
     with jax.named_scope("tree/outputs"):
@@ -2052,6 +2101,20 @@ def _forest_trees_scan(
     return (
         jax.tree.map(lambda a: jnp.swapaxes(a, 0, 1), trees), outs, slots
     )
+
+
+def _forest_tree(binned, gb, ones, row_mask, feat_mask, mi_k, mg_k, node_key,
+                 **grow):
+    """One tree of a forest's scan as (carry, outputs): None and (the tree,
+    its prediction for EVERY row from the grower's own final routing — a
+    leaf lookup, no re-traversal — its ``HistSlots``)."""
+    tree, node, slots = _grow_tree_impl(
+        binned, gb, ones, row_mask, feat_mask, reg_lambda=0.0, gamma=0.0,
+        min_child_weight=mi_k, min_info_gain=mg_k, node_key=node_key, **grow,
+    )
+    with jax.named_scope("tree/outputs"):
+        pred_t = leaf_lookup(tree.leaf_value, node)
+    return None, (tree, pred_t, slots)
 
 
 def forest_gradients(target: jax.Array, k_fits: int, num_classes: int):
@@ -2118,18 +2181,10 @@ def fit_forest_batched(
     k_fits, n = row_mask.shape
     if feature_subset is None:
         feature_subset = int(binned.shape[1])
-    # host-side numpy for every small knob: a dtype-converting or
-    # broadcasting jnp op here is an EAGER device program compiled per
-    # process; f32 numpy arrays transfer without compiling anything, and
-    # the broadcasts/PRNG-key derivation happen INSIDE the jitted program
-    def _vec_np(v):
-        return np.asarray(
-            np.broadcast_to(np.asarray(v, dtype=np.float32).reshape(-1),
-                            (k_fits,))
-        )
-
-    sub = _vec_np(subsample_rate)
-    col = _vec_np(colsample_rate)
+    # host-side numpy for every small knob (``_lanes_np``); the
+    # broadcasts/PRNG-key derivation happen INSIDE the jitted program
+    sub = _lanes_np(subsample_rate, k_fits)
+    col = _lanes_np(colsample_rate, k_fits)
     mi = np.asarray(min_instances, dtype=np.float32)
     mg = np.asarray(min_info_gain, dtype=np.float32)
     seed_arr = np.asarray([seed], dtype=np.uint32)
@@ -2173,6 +2228,15 @@ def fit_forest_batched(
              hist_impl=_resolved_impl()),
     )
     return _fit_result(trees, outs, slots, return_outputs, return_slots)
+
+
+def _lanes_np(v, k_fits: int) -> np.ndarray:
+    """A knob as float32 [K] on the host: a dtype-converting or
+    broadcasting eager jnp op is a device program compiled per process,
+    and a float32 numpy array transfers without compiling anything."""
+    return np.asarray(
+        np.broadcast_to(np.asarray(v, dtype=np.float32).reshape(-1), (k_fits,))
+    )
 
 
 def _fit_result(trees, outputs, slots, return_outputs, return_slots):
@@ -2428,19 +2492,12 @@ def fit_boosted_batched(
     if (binary_weights and isinstance(row_mask, np.ndarray)
             and not binary_row_weights(row_mask)):
         raise ValueError("binary_weights stated of row weights other than 0/1")
-    # numpy, not eager jnp: dtype-converting/broadcasting eager ops each
-    # compile a device program per process; f32 numpy transfers compile
-    # nothing
-    def _np_f32(v):
-        return np.asarray(v, dtype=np.float32)
-
-    eta_v = np.asarray(
-        np.broadcast_to(_np_f32(eta).reshape(-1), (k_fits,))
+    # numpy, not eager jnp (``_lanes_np``)
+    eta_v = _lanes_np(eta, k_fits)
+    lam, gam, mcw, mig = (
+        np.asarray(v, dtype=np.float32)
+        for v in (reg_lambda, gamma, min_child_weight, min_info_gain)
     )
-    lam = _np_f32(reg_lambda)
-    gam = _np_f32(gamma)
-    mcw = _np_f32(min_child_weight)
-    mig = _np_f32(min_info_gain)
     if mesh is None:
         from ..parallel.mesh import execution_mesh
 
@@ -2457,7 +2514,7 @@ def fit_boosted_batched(
         return _fit_result(trees, margin, None, True, return_slots)
     # f32 numpy broadcast (no eager compile), then ONE device transfer
     margin = jnp.asarray(np.asarray(np.broadcast_to(
-        _np_f32(base_score).reshape(-1, 1), (k_fits, n)
+        np.asarray(base_score, dtype=np.float32).reshape(-1, 1), (k_fits, n)
     )))
     from ..compiler.dispatch import donating
     from ..utils.aot import aot_call
@@ -2503,45 +2560,24 @@ def _pad_axis(a: jax.Array, axis: int, multiple: int) -> jax.Array:
     return jnp.pad(a, widths)
 
 
-@lru_cache(maxsize=None)
-def _sharded_grow_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
-                         has_groups=False):
-    """jit(shard_map(grow)) for one (mesh, statics) combo, built once —
-    rebuilding per call would retrace every tree. Feature-group index
-    arrays (when present) are replicated: the feature axis is unsharded."""
+def _shard_mapped(mesh, body, row_specs, n_rep, has_groups, out_rows):
+    """shard_map(body) of a fit: its inputs are ``row_specs``, then
+    ``n_rep`` replicated ones (and the two feature-group index arrays,
+    ``has_groups``); its outputs the trees and the ``HistSlots``
+    (replicated) about ``out_rows``, the row outputs' spec."""
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.mesh import DATA_AXIS
-
-    size = mesh.shape[DATA_AXIS]
-
-    def body(binned, grad, hess, row_mask, feat_mask, lam, gam, mcw, mig,
-             *grp):
-        return _grow_tree_impl(
-            binned, grad, hess, row_mask, feat_mask,
-            max_depth=max_depth, num_bins=num_bins,
-            reg_lambda=lam, gamma=gam, min_child_weight=mcw,
-            min_info_gain=mig, hist_impl=hist_impl, lowp=lowp,
-            axis_name=DATA_AXIS, axis_size=size,
-            feature_groups=grp if grp else None,
-        )[0]
-
     rep = P()
-    sm = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(
-            P(DATA_AXIS, None),   # binned [N, F]
-            P(None, DATA_AXIS),   # grad [K, N]
-            P(None, DATA_AXIS),   # hess
-            P(None, DATA_AXIS),   # row_mask
-            rep, rep, rep, rep, rep,
-        ) + ((rep, rep) if has_groups else ()),
-        out_specs=Tree(split_feat=rep, split_bin=rep, leaf_value=rep),
+    return shard_map(
+        body, mesh=mesh,
+        in_specs=tuple(row_specs) + (rep,) * (n_rep + 2 * bool(has_groups)),
+        out_specs=(
+            Tree(split_feat=rep, split_bin=rep, leaf_value=rep), out_rows,
+            HistSlots(*(rep for _ in HistSlots._fields)),
+        ),
         check_vma=False,
     )
-    return jax.jit(sm)
 
 
 @lru_cache(maxsize=None)
@@ -2555,7 +2591,6 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
     tree's node-subset key rides the scan too (replicated: every shard
     draws the same subsets). Also emits [K, N] training outputs
     (row-sharded) like the single-device scan."""
-    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import DATA_AXIS
@@ -2571,20 +2606,13 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
 
         def one_tree(_, xs):
             rm_t, fm_t, nk = xs
-            tree, node, slots = _grow_tree_impl(
-                binned, gb, ones, rm_t, fm_t,
-                max_depth=max_depth, num_bins=num_bins,
-                reg_lambda=0.0, gamma=0.0,
-                min_child_weight=mi_k, min_info_gain=mg_k,
-                hist_impl=hist_impl, lowp=lowp,
-                axis_name=DATA_AXIS, axis_size=size,
+            return _forest_tree(
+                binned, gb, ones, rm_t, fm_t, mi_k, mg_k, nk,
+                max_depth=max_depth, num_bins=num_bins, hist_impl=hist_impl,
+                lowp=lowp, axis_name=DATA_AXIS, axis_size=size,
                 feature_groups=grp if grp else None,
-                info_gain_norm=info_gain_norm,
-                node_subset=feature_subset, node_key=nk,
+                info_gain_norm=info_gain_norm, node_subset=feature_subset,
             )
-            with jax.named_scope("tree/outputs"):
-                pred_t = leaf_lookup(tree.leaf_value, node)
-            return None, (tree, pred_t, slots)
 
         _, (trees, preds, slots) = jax.lax.scan(
             one_tree, None, (rmasks, fmasks, nkeys)
@@ -2596,28 +2624,15 @@ def _sharded_forest_scan_kernel(mesh, max_depth, num_bins, hist_impl, lowp,
             slots,
         )
 
-    rep = P()
-    sm = shard_map(
-        body_fn,
-        mesh=mesh,
-        in_specs=(
-            P(DATA_AXIS, None),        # binned [N, F]
-            P(DATA_AXIS),              # target [N]
-            P(None, None, DATA_AXIS),  # rmasks [T, K, N]
-            rep,                       # fmasks [T, K, F]
-            rep,                       # nkeys [T, 2]
-            rep, rep,
-        ) + ((rep, rep) if has_groups else ()),
-        out_specs=(
-            Tree(split_feat=rep, split_bin=rep, leaf_value=rep),
-            # the rows are the outputs' last axis
-            P(None, DATA_AXIS) if num_classes <= 2
-            else P(None, None, DATA_AXIS),
-            HistSlots(*(rep for _ in HistSlots._fields)),
-        ),
-        check_vma=False,
-    )
-    return jax.jit(sm)
+    return jax.jit(_shard_mapped(
+        mesh, body_fn,
+        # binned [N, F], target [N], rmasks [T, K, N]; then fmasks
+        # [T, K, F], nkeys [T, 2], mi_k, mg_k
+        (P(DATA_AXIS, None), P(DATA_AXIS), P(None, None, DATA_AXIS)), 4,
+        has_groups,
+        # the rows are the outputs' last axis
+        P(None, DATA_AXIS) if num_classes <= 2 else P(None, None, DATA_AXIS),
+    ))
 
 
 def _fit_forest_batched_sharded(
@@ -2639,8 +2654,8 @@ def _fit_forest_batched_sharded(
         lambda tk: _bag_masks(tk, sub, col, rm, n=n, f=f, bootstrap=bootstrap)
     )(tkeys)
     rmasks = _pad_axis(rmasks, 2, size)
-    mi_k = jnp.broadcast_to(jnp.asarray(mi, jnp.float32).reshape(-1), (k_fits,))
-    mg_k = jnp.broadcast_to(jnp.asarray(mg, jnp.float32).reshape(-1), (k_fits,))
+    mi_k = jnp.broadcast_to(_per_fit(mi), (k_fits,))
+    mg_k = jnp.broadcast_to(_per_fit(mg), (k_fits,))
     kern = _sharded_forest_scan_kernel(
         mesh, max_depth, num_bins, _resolved_impl(), lowp,
         has_groups=feature_groups is not None,
@@ -2662,7 +2677,6 @@ def _sharded_boost_kernel(mesh, num_rounds, max_depth, num_bins, objective,
                           info_gain_norm=0.0, binary_weights=False):
     """jit(shard_map(boosting rounds)): margins stay row-sharded across
     the scan; each round's histogram build psums over the data axis."""
-    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import DATA_AXIS
@@ -2680,25 +2694,12 @@ def _sharded_boost_kernel(mesh, num_rounds, max_depth, num_bins, objective,
             binary_weights=binary_weights,
         )
 
-    rep = P()
-    sm = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(
-            P(DATA_AXIS, None),   # binned
-            P(DATA_AXIS),         # y
-            P(None, DATA_AXIS),   # row_mask
-            P(None, DATA_AXIS),   # margin0
-            rep, rep, rep, rep, rep,
-        ) + ((rep, rep) if has_groups else ()),
-        out_specs=(
-            Tree(split_feat=rep, split_bin=rep, leaf_value=rep),
-            P(None, DATA_AXIS),
-            HistSlots(*(rep for _ in HistSlots._fields)),
-        ),
-        check_vma=False,
-    )
-    return jax.jit(sm)
+    rows = P(None, DATA_AXIS)
+    return jax.jit(_shard_mapped(
+        mesh, body,
+        # binned, y, row_mask, margin0; then eta_v, lam, gam, mcw, mig
+        (P(DATA_AXIS, None), P(DATA_AXIS), rows, rows), 5, has_groups, rows,
+    ))
 
 
 def _fit_boosted_batched_sharded(
@@ -2718,10 +2719,7 @@ def _fit_boosted_batched_sharded(
         jnp.asarray(base_score, dtype=jnp.float32).reshape(-1, 1),
         (k_fits, n_pad),
     ).astype(jnp.float32)
-    lam = jnp.asarray(lam, jnp.float32).reshape(-1)
-    gam = jnp.asarray(gam, jnp.float32).reshape(-1)
-    mcw = jnp.asarray(mcw, jnp.float32).reshape(-1)
-    mig = jnp.asarray(mig, jnp.float32).reshape(-1)
+    lam, gam, mcw, mig = (_per_fit(v) for v in (lam, gam, mcw, mig))
     kern = _sharded_boost_kernel(
         mesh, num_rounds, max_depth, num_bins, objective, _resolved_impl(),
         has_groups=feature_groups is not None,

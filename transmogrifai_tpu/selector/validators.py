@@ -349,8 +349,6 @@ class Validator:
         evaluator: Evaluator,
         extra_masks: Sequence[np.ndarray] = (),
     ) -> list[CandidateResult]:
-        import os
-
         plan = faults.active()
         if plan is not None:
             # inside the retried region: each retry attempt re-consults the
@@ -367,11 +365,6 @@ class Validator:
             batched_masks = lambda *a: dispatcher(*a)()  # noqa: E731
         else:
             batched_masks = getattr(est, "fit_arrays_batched_masks", None)
-        if os.environ.get("TPTPU_BATCHED_FITS") == "0":
-            # sequential fallback would pay len(points) extra full-data
-            # fits per family for lanes only the winner ever uses — the
-            # selector refits the winner directly instead
-            extra_masks = ()
         if batched_masks is not None:
             # the whole folds × grid sweep in as few compiled programs as
             # the family's static shapes allow (fold = batch-axis entry);
